@@ -1,6 +1,6 @@
 # NornicDB-TPU (ref: the reference's Makefile test/build targets)
 
-.PHONY: test test-fast lint lint-baseline sanitize jitgate smoke capacity-report chaos soak soak-ci soak-nornsan soak-multiworker bench bench-search bench-embed bench-generate bench-generate-smoke bench-workers bench-cypher native e2e-bench clean
+.PHONY: test test-fast lint lint-baseline sanitize jitgate smoke capacity-report chaos soak soak-ci soak-nornsan soak-multiworker bench-search bench-embed bench-generate bench-generate-smoke bench-workers bench-cypher native e2e-bench clean
 
 test:
 	python -m pytest tests/ -q
@@ -58,16 +58,6 @@ soak-multiworker:
 
 test-fast:
 	python -m pytest tests/ -q -x
-
-# headline TPU bench (stdout JSON artifact) + the sharded-vs-single search
-# trajectory (writes BENCH_search.json; stderr only — stdout stays reserved
-# for bench.py's artifact lines)
-bench:
-	python bench.py
-	python scripts/bench_search.py
-	python scripts/bench_embed.py
-	python scripts/bench_generate.py
-	python scripts/bench_cypher.py
 
 # passthrough: `make bench-search ROWS=10000000 DIMS=64 MODE=exact,ivf
 # BACKENDS=sharded_int8` regenerates the artifact at any scale; the

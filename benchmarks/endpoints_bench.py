@@ -9,8 +9,7 @@ Run: python benchmarks/endpoints_bench.py  (prints a JSON report).
        GraphQL / gRPC through N SO_REUSEPORT worker processes)
      python benchmarks/endpoints_bench.py --scaling     (sweep worker counts
        on the read-heavy endpoints and print the scaling table)
-Not invoked by the driver's bench.py (which stays the single-metric kNN
-headline); this is the protocol-stack profile.
+This is the protocol-stack profile.
 """
 
 from __future__ import annotations

@@ -111,13 +111,19 @@ class BackendConfig:
 
     # seconds a caller waits for PJRT init + first-touch before serving
     # from CPU host arrays (the init keeps running on the manager's
-    # worker thread; recovery is automatic when it completes)
-    acquire_timeout: float = 15.0
+    # worker thread; recovery is automatic when it completes). A cold
+    # acquisition on a healthy TPU v5e host took 11.1 and 13.6 s
+    # (PERF.md, PR 22): 15 s sat inside that spread and would degrade a
+    # healthy chip at start-up now and then
+    acquire_timeout: float = 30.0
     # health-probe cadence and per-probe budget
     probe_interval: float = 5.0
     probe_timeout: float = 5.0
     # a green probe slower than this counts as a failure (sick-but-alive
-    # accelerators must degrade too, not just dead ones)
+    # accelerators must degrade too, not just dead ones). Judged on the
+    # device's answer time between two back-to-back tiny programs, not on
+    # the wall-clock round trip: time queued behind serving work does not
+    # count (the whole round trip is still bounded by probe_timeout)
     probe_latency_threshold: float = 1.0
     # hysteresis: consecutive failures before READY -> DEGRADED_CPU, and
     # consecutive green probes before DEGRADED_CPU -> RECOVERING

@@ -73,10 +73,9 @@ class TPUEmbedder(Embedder):
     """bge-m3 architecture encoder on TPU (replaces pkg/embed/local_gguf.go +
     pkg/localllm llama.cpp path).
 
-    Batching policy (measured on a v5e chip, PROGRESS round-2 table): the
-    encoder is under-occupied at small batches — batch 32 runs 2.4x the
-    tokens/s of batch 8 at 512 tokens — so texts are tokenized without
-    padding, grouped into power-of-two sequence-length buckets, and run in
+    Batching policy: the encoder is under-occupied at small batches (by how
+    much on the chip: not measured on today's code), so texts are tokenized
+    without padding, grouped into power-of-two sequence-length buckets, and run in
     chunks of `opt_batch` per bucket. Both dims pad to a fixed shape grid,
     so the jit cache stays bounded (len buckets x batch classes) instead of
     recompiling per distinct batch length."""

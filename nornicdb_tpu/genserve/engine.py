@@ -366,7 +366,6 @@ class GenerationEngine:
         # degree of freedom, so no bucketing: every extra lane is real
         # attention work on every step
         self._lmax = self._max_seqs + 2
-        self._attn_impl: Optional[str] = None  # resolved at first dispatch
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
         self._queue: deque[_Seq] = deque()
@@ -519,7 +518,6 @@ class GenerationEngine:
                else contextlib.nullcontext())
         w = self._table_width
         lmax = self._lmax
-        impl = self._attn_for(kind)
         with ctx:
             pool = qwen2.init_kv_pages(self.cfg, self._usable_pages + 1,
                                        self._page_size)
@@ -544,7 +542,7 @@ class GenerationEngine:
                                            f"f{f}q{tq}x{w}")
                 ids, _lg, pool = qwen2.ragged_fused_step(
                     params, self.cfg, jnp.asarray(meta), pool,
-                    lmax=lmax, w=w, tq=tq, attn_impl=impl)
+                    lmax=lmax, w=w, tq=tq)
                 np.asarray(ids)  # force execution before serving
 
     # -- submission --------------------------------------------------------
@@ -895,20 +893,6 @@ class GenerationEngine:
             return jax.default_device(self._cpu_dev())
         return contextlib.nullcontext()
 
-    def _attn_for(self, kind) -> str:
-        """Attention implementation of the fused step for this platform:
-        the ragged Pallas kernel on a real TPU, the bit-identical XLA
-        block-gather everywhere else (including CPU fallback steps of a
-        TPU process — interpret-mode Pallas is a debug path, not a
-        serving path)."""
-        if kind == "cpu":
-            return "xla"
-        if self._attn_impl is None:
-            from nornicdb_tpu.ops import pallas_kernels as _pk
-
-            self._attn_impl = "pallas" if _pk._on_tpu() else "xla"
-        return self._attn_impl
-
     def _apply_platform(self, kind: str) -> None:
         """Handle a READY<->DEGRADED transition: the pool on the old
         platform is unreachable (or stale), so rebuild it and requeue
@@ -1201,8 +1185,7 @@ class GenerationEngine:
             try:
                 ids, _logits, self._pages = qwen2.ragged_fused_step(
                     params, self.cfg, jnp.asarray(meta), self._pages,
-                    lmax=lmax, w=w, tq=tq,
-                    attn_impl=self._attn_for(self._device_kind))
+                    lmax=lmax, w=w, tq=tq)
             except Exception:
                 # the failing dispatch may have CONSUMED the donated
                 # pool (donate_argnums): drop it at the dispatch site so

@@ -483,8 +483,10 @@ def ragged_fused_step(params, cfg: QwenConfig, meta: jax.Array,
     Lmax-2 is the chunk lane's table); ``tq`` is the static query width
     of the chunk attention block — ``tq == 1`` declares a decode-only
     step (no row may carry the chunk lane id); ``attn_impl`` picks "xla"
-    (block-gather reference), "pallas" (ragged TPU kernel) or
-    "pallas_interpret" (kernel under the CPU interpreter, tests).
+    (the block-gather — the one serving path, on a TPU too: Mosaic refuses
+    the ragged kernel at serving geometry, see ops/pallas_kernels.py),
+    "pallas" (ragged TPU kernel) or "pallas_interpret" (kernel under the
+    CPU interpreter, tests).
     Returns ((Lmax,) greedy token ids, (Lmax, V) f32 logits for
     ``logit_rows``, advanced pages); ``pages`` is DONATED.
     """

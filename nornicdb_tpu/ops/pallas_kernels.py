@@ -10,8 +10,9 @@ contracts against the (small, VMEM-resident) query block — the (Q, N) score
 matrix is produced tile-by-tile and never forces an extra HBM round-trip of
 the corpus. Top-k stays in XLA (lax.top_k fuses fine as an epilogue).
 
-On non-TPU backends the kernels run in Pallas interpret mode so tests work on
-the CPU mesh.
+Every kernel takes ``interpret``; only tests pass it (the Pallas interpreter
+on the CPU backend). Off-TPU the serving dispatchers in ops.similarity take
+the XLA path instead.
 """
 
 from __future__ import annotations
@@ -27,10 +28,10 @@ LANE = 128
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except (RuntimeError, IndexError):  # backend init failed / no devices
-        return False
+    """Is the default backend a TPU?  A backend that fails to initialise
+    RAISES here (jax.devices() does): answering False would quietly turn a
+    lost chip into the XLA-on-CPU path."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def _cosine_tile_kernel(q_ref, c_ref, out_ref):
@@ -95,10 +96,11 @@ def fused_cosine_topk(
     valid: jax.Array,
     k: int,
     tile_n: int = 512,
+    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Pallas-scored cosine top-k; auto-selects interpret mode off-TPU."""
+    """Pallas-scored cosine top-k."""
     scores = fused_cosine_scores(
-        queries, corpus, tile_n=tile_n, interpret=not _on_tpu()
+        queries, corpus, tile_n=tile_n, interpret=interpret
     )
     scores = jnp.where(valid[None, :], scores, -jnp.inf)
     return jax.lax.top_k(scores, k)
@@ -421,6 +423,16 @@ def streaming_cosine_topk_int8(
 
 
 # ------------------------------------------------- ragged paged attention
+#
+# NOT SERVED: the engine dispatches the XLA block-gather on every platform.
+# Mosaic (jax 0.9.0, v5e) refuses this kernel at Qwen2.5-0.5B geometry
+# (H=14, Hkv=2, Dh=64, page 16, P=16): first the (1, Tq) ``positions``
+# block ("last two dimensions of your block shape are divisible by 8 and
+# 128 … or be equal to the respective dimensions of the overall array"),
+# then the GQA broadcast+reshape ("infer-vector-layout: unsupported shape
+# cast", vector<256x2x64xbf16> -> vector<256x2x1x64xbf16>); ROADMAP S4 has
+# the rest. It stays as interpret-tested code for the online-softmax
+# rewrite S4/D2 plan.
 #
 # The genserve kernel (Ragged Paged Attention, PAPERS.md arXiv:2604.15464):
 # ONE device program serves a mixed batch of prefill and decode lanes over

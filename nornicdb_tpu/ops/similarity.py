@@ -151,6 +151,27 @@ if TOPK_EPILOGUE not in ("sort", "approx", "pallas"):
     )
 
 
+def _kernel_mode(streaming, n: int, auto_ok: bool = True) -> tuple[bool, bool]:
+    """``(use_kernel, interpret)`` for one top-k dispatch over ``n`` rows.
+
+    ``streaming=None`` auto-selects the Pallas streaming kernel on a TPU at
+    ``STREAMING_MIN_ROWS`` and up; ``True`` forces it wherever a TPU is
+    attached; ``False`` pins the XLA path.  Off-TPU all three take the XLA
+    path — serving never runs an interpreted kernel.  ``"interpret"`` is the
+    tests' explicit request for the kernel under the Pallas interpreter."""
+    if streaming == "interpret":
+        return True, True
+    if streaming is False:
+        return False, False
+    from nornicdb_tpu.ops.pallas_kernels import _on_tpu
+
+    if not _on_tpu():
+        return False, False
+    if streaming is None:
+        return auto_ok and n >= STREAMING_MIN_ROWS, False
+    return True, False
+
+
 def topk_backend(
     queries: jax.Array,
     corpus: jax.Array,
@@ -158,19 +179,18 @@ def topk_backend(
     k: int,
     exact: bool = False,
     use_bf16: bool = True,
-    streaming: Optional[bool] = None,
+    streaming=None,
     quantized: Optional[tuple[jax.Array, jax.Array]] = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Top-k dispatch for normalized inputs: the streaming Pallas kernel
     (ops.pallas_kernels.streaming_cosine_topk — one corpus read, no (Q, N)
     materialization) on TPU for large corpora, else the XLA
-    GEMM+approx_max_k path. `streaming=None` auto-selects; tests force it on
-    small corpora (interpret mode runs the same kernel off-TPU). The kernel
-    scores in bf16, so an explicit use_bf16=False keeps the XLA f32 path.
+    GEMM+approx_max_k path. ``streaming`` is read by :func:`_kernel_mode`.
+    The kernel scores in bf16, so an explicit use_bf16=False keeps the XLA
+    f32 path.
     `quantized=(c_i8, c_scale)` (quantize_rows of the same corpus) engages
     the int8 MXU kernel — 2x the bf16 MXU rate, half the corpus HBM read."""
     from nornicdb_tpu.ops.pallas_kernels import (
-        _on_tpu,
         pick_tile_n,
         quantize_rows,
         streaming_cosine_topk,
@@ -179,12 +199,9 @@ def topk_backend(
     )
 
     n = int(corpus.shape[0])
-    on_tpu = _on_tpu()
-    if streaming is None:
-        streaming = (
-            (not exact) and use_bf16 and on_tpu and n >= STREAMING_MIN_ROWS
-        )
-    if streaming and not exact:
+    use_kernel, interpret = _kernel_mode(
+        streaming, n, auto_ok=(not exact) and use_bf16)
+    if use_kernel and not exact:
         tile = pick_tile_n(n)
         rows = min(streaming_rows_for(k, tile), max(n // tile, 1))
         # tile must divide n (corpus capacities are 128-multiples, but a
@@ -196,11 +213,11 @@ def topk_backend(
                 return streaming_cosine_topk_int8(
                     q_i8, q_scale, quantized[0], quantized[1], valid,
                     min(k, n), tile_n=tile, rows=rows,
-                    interpret=not on_tpu, epilogue=TOPK_EPILOGUE,
+                    interpret=interpret, epilogue=TOPK_EPILOGUE,
                 )
             return streaming_cosine_topk(
                 queries, corpus, valid, min(k, n),
-                tile_n=tile, rows=rows, interpret=not on_tpu,
+                tile_n=tile, rows=rows, interpret=interpret,
                 epilogue=TOPK_EPILOGUE,
             )
     return cosine_topk(
@@ -244,17 +261,17 @@ def topk_backend_int8(
     c_scale: jax.Array,
     valid: jax.Array,
     k: int,
-    streaming: Optional[bool] = None,
+    streaming=None,
 ) -> tuple[jax.Array, jax.Array]:
     """Top-k dispatch for an int8-RESIDENT corpus (no f32/bf16 device copy
     exists — compressed residency, 4x the rows per HBM byte). On TPU at
     scale the streaming int8 Pallas bin-reduce kernel runs the MXU at the
-    int8 rate over the codes; elsewhere the XLA dequant-GEMM fallback.
+    int8 rate over the codes; elsewhere the XLA dequant-GEMM fallback
+    (``streaming`` as in :func:`_kernel_mode`).
     ``c_scale`` follows the quantize_rows convention (x ~= int8 / scale).
     Candidate scores are approximate (int8 + bf16 noise); callers rescore
     the candidate set exactly from the host f32 mirror."""
     from nornicdb_tpu.ops.pallas_kernels import (
-        _on_tpu,
         pick_tile_n,
         quantize_rows,
         streaming_cosine_topk_int8,
@@ -262,10 +279,8 @@ def topk_backend_int8(
     )
 
     n = int(c_i8.shape[0])
-    on_tpu = _on_tpu()
-    if streaming is None:
-        streaming = on_tpu and n >= STREAMING_MIN_ROWS
-    if streaming:
+    use_kernel, interpret = _kernel_mode(streaming, n)
+    if use_kernel:
         tile = pick_tile_n(n)
         rows = min(streaming_rows_for(k, tile), max(n // tile, 1))
         if n % tile == 0 and rows * tile >= k:
@@ -273,7 +288,7 @@ def topk_backend_int8(
             return streaming_cosine_topk_int8(
                 q_i8, q_scale, c_i8, c_scale, valid,
                 min(k, n), tile_n=tile, rows=rows,
-                interpret=not on_tpu, epilogue=TOPK_EPILOGUE,
+                interpret=interpret, epilogue=TOPK_EPILOGUE,
             )
     return cosine_topk_int8_xla(queries, c_i8, c_scale, valid, min(k, n))
 
@@ -1525,7 +1540,7 @@ class DeviceCorpus(HostCorpus):
         min_similarity: float = -1.0,
         exact: bool = False,
         n_probe: int = 0,
-        streaming: Optional[bool] = None,
+        streaming=None,
     ) -> list[list[tuple[str, float]]]:
         """Brute-force cosine top-k. Returned scores are exact; with the
         default exact=False, candidate membership uses the TPU-native

@@ -21,21 +21,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across the jax API rename
-    (check_rep in jax<0.7, check_vma after)."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # jax < 0.5 exports it under experimental
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except TypeError:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
-
 def make_mesh(
     axis_shapes: Optional[dict[str, int]] = None,
     devices: Optional[Sequence[jax.Device]] = None,

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from nornicdb_tpu.parallel.mesh import shard_map_compat
 
 NEG_INF = -1e30
 
@@ -97,11 +96,12 @@ def make_ring_attention(
         return (o_acc / jnp.maximum(denom, 1e-30)).astype(q.dtype)
 
     spec = P(None, axis_name, None, None)
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return jax.jit(sharded)
 

@@ -83,7 +83,7 @@ from nornicdb_tpu.ops.similarity import (
     topk_backend,
     topk_backend_int8,
 )
-from nornicdb_tpu.parallel.mesh import make_mesh, shard_map_compat
+from nornicdb_tpu.parallel.mesh import make_mesh
 
 logger = logging.getLogger(__name__)
 
@@ -115,7 +115,7 @@ def _sharded_search(
     mesh_static: Mesh,
     use_bf16: bool = True,
     exact: bool = False,
-    streaming: Optional[bool] = None,
+    streaming=None,
 ):
     """One XLA program: per-shard GEMM + top-local_k, ICI all-gather of
     (vals, global_idx) only, global merge.  Per-shard scoring dispatches
@@ -143,11 +143,12 @@ def _sharded_search(
         idx_all = jax.lax.all_gather(gidx, axis)
         return merge_topk(vals_all, idx_all, min(k, lk * n_shards))
 
-    return shard_map_compat(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh_static,
         in_specs=(P(), P(axis, None), P(axis)),
         out_specs=(P(), P()),
+        check_vma=False,
     )(queries, corpus, valid)
 
 
@@ -164,7 +165,7 @@ def _sharded_search_int8(
     local_k: int,
     axis: str,
     mesh_static: Mesh,
-    streaming: Optional[bool] = None,
+    streaming=None,
 ):
     """Compressed-residency sharded search: each shard scores its int8
     code slice (streaming int8 Pallas kernel on TPU, dequant-GEMM XLA
@@ -185,11 +186,12 @@ def _sharded_search_int8(
         idx_all = jax.lax.all_gather(gidx, axis)
         return merge_topk(vals_all, idx_all, min(k, lk * n_shards))
 
-    return shard_map_compat(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh_static,
         in_specs=(P(), P(axis, None), P(axis), P(axis)),
         out_specs=(P(), P()),
+        check_vma=False,
     )(queries, codes, scales, valid)
 
 
@@ -269,12 +271,13 @@ def _sharded_ivf_topk(
     rspec = P(axis) if has_residual else P()
     bspec = P(axis) if quantized else P()
     rsspec = P(axis) if (quantized and has_residual) else P()
-    return shard_map_compat(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh_static,
         in_specs=(P(), P(), P(axis), P(axis), P(axis), rspec, rspec,
                   bspec, rsspec),
         out_specs=(P(), P()),
+        check_vma=False,
     )(queries, centroids, blocks, counts, slotmap, residual, residual_slots,
       block_scales, residual_scales)
 
@@ -878,7 +881,7 @@ class ShardedCorpus(HostCorpus):
 
     def _quantized_search(
         self, q: np.ndarray, k: int, min_similarity: float,
-        local_k: int, streaming: Optional[bool],
+        local_k: int, streaming,
     ) -> list[list[tuple[str, float]]]:
         """Compressed-residency full scan: the int8 sharded program
         selects rescore_factor × k candidates per query (one fused device
@@ -952,7 +955,7 @@ class ShardedCorpus(HostCorpus):
         min_similarity: float = -1.0,
         exact: bool = False,
         n_probe: int = 0,
-        streaming: Optional[bool] = None,
+        streaming=None,
         local_k: int = 0,
     ) -> list[list[tuple[str, float]]]:
         """Sharded cosine top-k: per-shard GEMM + top-local_k, ICI

@@ -299,8 +299,8 @@ def build_spec(version: str = "0.4.0") -> dict:
         "/admin/tpu/status": {"get": _op(
             "Accelerator status (the reference's /admin/gpu/status "
             "analogue); reports initialised-backend state plus the "
-            "lifecycle manager's view, never blocks on a down device "
-            "relay", tag="admin")},
+            "lifecycle manager's view, never blocks on an unreachable "
+            "device", tag="admin")},
         "/admin/traces": {"get": _op(
             "Recent completed request traces (newest first): trace id, "
             "root span, duration, span count", tag="admin")},

@@ -16,11 +16,10 @@ Runs anywhere: with no accelerator it forces the 8-device virtual CPU mesh
 the identical partitioning/collective program XLA emits for a real mesh —
 the numbers are CPU numbers, labeled as such in ``meta.platform``, and the
 trajectory tracks the RELATIVE shapes over PRs, not absolute TPU latency
-(bench.py owns the headline TPU figure).
+(top-k latency on the chip: not measured).
 
-stdout stays EMPTY (the round artifact contract reserves it for bench.py's
-JSON lines when driven via ``make bench``); progress goes to stderr and the
-results to the --out file.
+stdout stays EMPTY; progress goes to stderr and the results to the --out
+file.
 
 Exit invariants recorded in the artifact and asserted non-zero-exit:
   - one fused device dispatch per batched sharded search;

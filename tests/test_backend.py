@@ -1,8 +1,8 @@
 """Backend lifecycle manager tests: probe → acquire → serve → degrade →
 recover (ISSUE 6 tentpole).
 
-A fault-injecting FakeHooks backend drives the scenarios a live TPU relay
-produces in production:
+A fault-injecting FakeHooks backend drives the scenarios a lost or hung
+accelerator produces in production:
 
 * hang-on-acquire — the caller's timeout fires, the service answers from
   CPU host arrays, and no caller ever blocks on PJRT init while holding a
@@ -18,6 +18,8 @@ produces in production:
 
 from __future__ import annotations
 
+import importlib
+import os
 import threading
 import time
 
@@ -28,6 +30,9 @@ from nornicdb_tpu import backend as backend_mod
 from nornicdb_tpu.backend import BackendManager, FakeHooks, hooks_from_env
 from nornicdb_tpu.errors import BackendLockHeldError, DeviceUnavailable
 from nornicdb_tpu.ops.similarity import DeviceCorpus
+
+# `backend.manager` is the accessor function; the module hides behind it
+manager_mod = importlib.import_module("nornicdb_tpu.backend.manager")
 
 DIMS = 16
 
@@ -152,6 +157,42 @@ class TestStateMachine:
         hooks.delay = 0.05  # over the latency threshold, under the timeout
         _wait_state(mgr, backend_mod.DEGRADED_CPU)
         assert mgr.counters.probe_failures >= mgr.degrade_after
+
+    @pytest.mark.parametrize("answer_s,wall_s,degrades", [
+        (0.001, 0.05, False),  # healthy chip, probe queued behind work
+        (0.05, 0.0, True),     # device itself slow to answer
+    ])
+    def test_latency_is_judged_on_the_answer_not_the_queue(
+            self, answer_s, wall_s, degrades):
+        """Hooks that can separate the device's answer time from the time
+        spent queued behind serving work return it, and THAT is held to
+        the threshold; the wall-clock round trip is only reported."""
+
+        class Hooks(FakeHooks):
+            def probe(self):
+                super().probe()
+                time.sleep(wall_s)
+                return answer_s
+
+        mgr = _mgr(Hooks("ok"), probe_latency_threshold=0.02,
+                   probe_timeout=1.0)
+        assert mgr.await_ready()
+        if degrades:
+            _wait_state(mgr, backend_mod.DEGRADED_CPU)
+            return
+        deadline = time.monotonic() + 5.0
+        while mgr.counters.probes < 8 and time.monotonic() < deadline:
+            time.sleep(0.02)
+        s = mgr.stats()
+        assert mgr.counters.probes >= 8
+        assert (s["state"], s["probe_failures_total"]) == \
+            (backend_mod.READY, 0)
+        assert s["probe_latency_s"] == answer_s
+        assert s["probe_wall_s"] >= wall_s
+
+    def test_real_probe_reports_its_answer_time(self):
+        answered = backend_mod.RealHooks().probe()
+        assert isinstance(answered, float) and 0.0 <= answered < 1.0
 
     def test_stats_shape(self):
         mgr = _mgr(FakeHooks("ok"))
@@ -536,3 +577,45 @@ class TestDefaultManagerWiring:
         finally:
             backend_mod.reset_default()
             backend_mod.configure()  # restore construction defaults
+
+
+class TestRealHooksTouch:
+    def test_touch_names_the_device(self):
+        """READY is reached on whatever platform answers (the CPU backend
+        here); the touch result says which, down to the device kind."""
+        import jax
+
+        info = backend_mod.RealHooks().touch()
+        dev = jax.devices()[0]
+        assert info == {"platform": dev.platform,
+                        "device_kind": dev.device_kind,
+                        "device_count": len(jax.devices())}
+        mgr = _mgr(FakeHooks("ok"))
+        assert mgr.await_ready()
+        assert mgr.stats()["device"]["device_kind"] == "fake"
+
+    @pytest.mark.parametrize("env_dir", [None, "/somewhere/else"])
+    def test_compile_cache_is_placed_from_outside_or_under_the_checkout(
+            self, monkeypatch, env_dir):
+        """JAX_COMPILATION_CACHE_DIR set: nothing is set in code (JAX
+        reads it itself).  Unset: one fixed directory under the checkout —
+        never a temp name, pid or time, which could not hit twice."""
+        import jax
+
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            jax.config.update("jax_compilation_cache_dir", "/as/found")
+            if env_dir is None:
+                monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+                want = manager_mod.COMPILE_CACHE_DIR
+            else:
+                monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+                want = "/as/found"
+            assert manager_mod.place_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert manager_mod.COMPILE_CACHE_DIR == os.path.join(
+            repo, ".jax_cache")
+

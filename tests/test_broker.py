@@ -102,13 +102,20 @@ def stack(tmp_path):
 # ---------------------------------------------------------------- serving
 class TestBrokerServing:
     def test_search_twin_path_bit_identical(self, stack):
+        """The broker's fused 5-query batch answers what five in-process
+        single-query calls answer: ids exactly, scores to one f32 ulp — the
+        socket loses nothing, but a (5, D) and a (1, D) GEMM are different
+        XLA programs and may round the last bit differently."""
         db, _broker, client, rng = stack
         q = rng.normal(size=(5, 32)).astype(np.float32)
         got = client.search(q, k=10)
         for i in range(5):
             want = db.search.vector_candidates(q[i], 10, -1.0)
-            assert [(h[0], h[1]) for h in got[i]] == \
-                [(id_, float(np.float32(s))) for id_, s in want]
+            assert [h[0] for h in got[i]] == [id_ for id_, _ in want]
+            a = np.asarray([h[1] for h in got[i]], np.float32)
+            b = np.asarray([s for _, s in want], np.float32)
+            assert (np.abs(a - b) <= np.spacing(np.maximum(
+                np.abs(a), np.abs(b)))).all(), (a, b)
 
     def test_with_content_enriches_from_storage(self, stack):
         _db, _broker, client, rng = stack

@@ -1,0 +1,218 @@
+"""The main path's device programs compile for a TPU v5e that is described,
+not attached: the TPU compiler is installed in the sandbox, so what it would
+refuse on the chip it refuses here, at the sizes the chip serves.
+
+Nothing runs — a passing compile says nothing about results or speed
+(`python chip_smoke.py` on the chip does).  The topology is described inside
+a fixture: only one process may load libtpu, and under pytest-xdist every
+worker imports this file, so nothing here may touch it at import time.  All
+chip compiles stay in this one file for the same reason.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+
+import numpy as np
+import pytest
+
+N_ONE_CHIP = 262_144      # chip_smoke.py's resident corpus
+N_FOUR_CHIPS = 1_048_576  # its --chips 4 corpus, 262,144 rows per chip
+DIMS = 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """v5e:2x2 described for the compiler, with the persistent compile
+    cache off: an entry compiled for a described chip is written but can
+    never be read back here."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from jax.sharding import Mesh
+
+    assert len(topo.devices) == 4
+    return Mesh(np.array(topo.devices), ("data",))
+
+
+@pytest.fixture
+def dispatch_as_on_tpu(monkeypatch):
+    """The dispatchers ask ``_on_tpu()``, which sees the CPU backend here;
+    steer them to the branch a TPU process takes."""
+    from nornicdb_tpu.ops import pallas_kernels
+
+    monkeypatch.setattr(pallas_kernels, "_on_tpu", lambda: True)
+
+
+def _sds(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _params_on(init, cfg, sharding):
+    """Shapes of ``init(cfg, key)`` placed on ``sharding`` — no array is
+    made (a described device cannot hold one)."""
+    import jax
+
+    shapes = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
+    return jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, sharding), shapes)
+
+
+# Q classes: 1 is the unbatched /nornicdb/search; QueryBatcher hands a
+# DeviceCorpus every size up to batch_max=256 unpadded, so an odd one too.
+# Capacity doubles on growth: the 64 documents chip_smoke.py embeds after
+# the bulk load push the resident buffer to 2 x 262,144 rows.
+@pytest.mark.parametrize("n,q", [
+    (N_ONE_CHIP, 1), (N_ONE_CHIP, 5), (N_ONE_CHIP, 64), (N_ONE_CHIP, 256),
+    (2 * N_ONE_CHIP, 1), (2 * N_ONE_CHIP, 256),
+])
+@pytest.mark.parametrize("k", [10, 100])
+def test_streaming_topk_one_chip(one_chip, dispatch_as_on_tpu, n, q, k):
+    """DeviceCorpus.search's dispatch over the f32 corpus the service
+    keeps resident: the bf16 streaming kernel must be what it selects."""
+    import jax
+    import jax.numpy as jnp
+
+    from nornicdb_tpu.ops.similarity import topk_backend
+
+    compiled = jax.jit(
+        lambda qs, c, v: topk_backend(qs, c, v, k)
+    ).lower(
+        _sds((q, DIMS), jnp.float32, one_chip),
+        _sds((n, DIMS), jnp.float32, one_chip),
+        _sds((n,), jnp.bool_, one_chip),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("q", [1, 256])
+@pytest.mark.parametrize("k", [10, 100])
+def test_streaming_topk_int8_one_chip(one_chip, dispatch_as_on_tpu, q, k):
+    import jax
+    import jax.numpy as jnp
+
+    from nornicdb_tpu.ops.similarity import topk_backend_int8
+
+    compiled = jax.jit(
+        lambda qs, c8, sc, v: topk_backend_int8(qs, c8, sc, v, k)
+    ).lower(
+        _sds((q, DIMS), jnp.float32, one_chip),
+        _sds((N_ONE_CHIP, DIMS), jnp.int8, one_chip),
+        _sds((N_ONE_CHIP,), jnp.float32, one_chip),
+        _sds((N_ONE_CHIP,), jnp.bool_, one_chip),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_sharded_search_four_chips(mesh4, dispatch_as_on_tpu, quantized):
+    """ShardedCorpus.search's one program over a 4-device mesh — per-shard
+    streaming kernel, all-gather merge — at the shape classes it pads a
+    16-query k=100 batch to (k_prog/local_k pow2; int8 oversamples
+    rescore_factor=4 x k)."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from nornicdb_tpu.parallel import sharded_index
+
+    rows = NamedSharding(mesh4, P("data", None))
+    vec = NamedSharding(mesh4, P("data"))
+    rep = NamedSharding(mesh4, P())
+    queries = _sds((16, DIMS), jnp.float32, rep)
+    valid = _sds((N_FOUR_CHIPS,), jnp.bool_, vec)
+    if quantized:
+        lowered = sharded_index._sharded_search_int8.lower(
+            queries,
+            _sds((N_FOUR_CHIPS, DIMS), jnp.int8, rows),
+            _sds((N_FOUR_CHIPS,), jnp.float32, vec),
+            valid, 512, 512, "data", mesh4,
+        )
+    else:
+        lowered = sharded_index._sharded_search.lower(
+            queries,
+            _sds((N_FOUR_CHIPS, DIMS), jnp.float32, rows),
+            valid, 128, 128, "data", mesh4,
+        )
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" in text
+    # one shard's rows per device, not the whole corpus on each
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    itemsize = 1 if quantized else 4
+    assert per_device < 1.1 * N_FOUR_CHIPS * DIMS * itemsize / 4 + (64 << 20)
+
+
+def test_bge_m3_forward_packed_full_width(one_chip):
+    """The embed path's program at bge-m3's published widths (1024 h, 16
+    heads, vocab 250,002, bf16), depth cut to 2 layers, for the (8, 512, 8)
+    pack class chip_smoke.py's long documents fill."""
+    import jax
+    import jax.numpy as jnp
+
+    from nornicdb_tpu.models import bge_m3
+
+    cfg = dataclasses.replace(bge_m3.BGE_M3, layers=2)
+    grid = _sds((8, 512), jnp.int32, one_chip)
+    cls = _sds((8,), jnp.int32, one_chip)
+    compiled = jax.jit(
+        lambda p, ids, seg, pos, cr, cc: bge_m3.forward_packed(
+            p, cfg, ids, seg, pos, cr, cc)
+    ).lower(
+        _params_on(bge_m3.init_params, cfg, one_chip),
+        grid, grid, grid, cls, cls,
+    ).compile()
+    out, = jax.tree.leaves(compiled.out_info)
+    assert out.shape == (8, 1024)
+
+
+@pytest.mark.parametrize("f,tq", [(8, 1), (64, 64)],
+                         ids=["decode", "prefill-chunk"])
+def test_ragged_fused_step_qwen_widths(one_chip, f, tq):
+    """The generation step at Qwen2.5-0.5B widths (896 h, 14/2 heads,
+    vocab 151,936), 2 layers, default engine geometry (8 lanes + chunk +
+    dump, page 16, 16-page tables, 129-page pool) with the attention
+    implementation the engine dispatches on a TPU: the XLA block-gather
+    (the ragged Pallas kernel does not lower — docs/generation.md)."""
+    import jax.numpy as jnp
+
+    from nornicdb_tpu.models import qwen2
+
+    cfg = dataclasses.replace(qwen2.QWEN25_05B, layers=2)
+    lmax, w, pages, page = 10, 16, 129, 16
+    meta, _ = qwen2.pack_ragged_meta(lmax, w, f)
+    pool = (cfg.layers, 2, pages, page, cfg.kv_heads,
+            cfg.hidden // cfg.heads)
+    compiled = qwen2.ragged_fused_step.lower(
+        _params_on(qwen2.init_params, cfg, one_chip), cfg,
+        _sds(meta.shape, jnp.int32, one_chip),
+        _sds(pool, jnp.bfloat16, one_chip),
+        lmax=lmax, w=w, tq=tq,
+    ).compile()
+    assert "tpu_custom_call" not in compiled.as_text()

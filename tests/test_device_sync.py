@@ -152,11 +152,11 @@ class TestIncrementalPatch:
         dc = DeviceCorpus(dims=dims, capacity=1024, quantize=True)
         data = _rand(512, dims, 12)
         dc.add_batch([f"v{i}" for i in range(512)], data)
-        dc.search(data[0], k=1, streaming=True)
+        dc.search(data[0], k=1, streaming="interpret")
         assert dc.sync_stats.full_uploads == 1
         nv = _rand(1, dims, 13)[0]
         dc.add("fresh", nv)
-        res = dc.search(nv, k=1, streaming=True)
+        res = dc.search(nv, k=1, streaming="interpret")
         assert res[0][0][0] == "fresh"
         assert abs(res[0][0][1] - 1.0) < 0.02
         assert dc.sync_stats.full_uploads == 1 and dc.sync_stats.patches == 1
@@ -164,7 +164,7 @@ class TestIncrementalPatch:
         # full requantize: int8 codes exactly; scales to within one float
         # ulp (XLA lowers the division differently per program shape)
         ref = _rebuild(dc, capacity=1024, quantize=True)
-        ref.search(nv, k=1, streaming=True)  # forces ref's full sync
+        ref.search(nv, k=1, streaming="interpret")  # forces ref's full sync
         np.testing.assert_array_equal(
             np.asarray(dc._dev_i8[0]), np.asarray(ref._dev_i8[0])
         )

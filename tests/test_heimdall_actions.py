@@ -8,8 +8,7 @@ phrasing and every label, but 20 specific pairings are held out
 (pretrain.action_eval_cases), so passing requires compositional
 generalization, not memorization.
 
-Micro settings here keep suite time bounded; the measured full-preset rates
-(500 steps / hidden 96) are recorded in PROGRESS.md.
+Micro settings here keep suite time bounded.
 """
 
 import json
@@ -31,7 +30,7 @@ def _norm(s: str) -> str:
 
 @pytest.fixture(scope="module")
 def action_ckpt(tmp_path_factory):
-    """Measured on this preset (PROGRESS.md r5): parse 56/57, exact 56/57
+    """Measured on this preset (CPU, an earlier round): parse 56/57, exact 56/57
     held-out, chat-e2e 37/56; ~3.5 min on one CPU core."""
     out = str(tmp_path_factory.mktemp("assistant_actions"))
     corpus = (pretrain.synth_corpus(0, repeats=6)

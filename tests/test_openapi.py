@@ -216,3 +216,22 @@ class TestAdminConfigEndpoints:
         assert _time.time() - t0 < 5, "status endpoint must not block"
         assert raw["framework"] == "jax"
         assert "backend_initialized" in raw
+
+    def test_tpu_status_states_the_device(self, server):
+        """Once the lifecycle manager has acquired, the status names the
+        platform AND the device kind (the CPU backend here), twice over:
+        from JAX and from the manager's touch."""
+        import jax
+
+        from nornicdb_tpu import backend
+
+        assert backend.manager().await_ready()
+        raw = json.loads(urllib.request.urlopen(
+            f"http://127.0.0.1:{server.port}/admin/tpu/status",
+            timeout=10).read())
+        dev = jax.devices()[0]
+        assert raw["backend_initialized"] is True
+        assert (raw["platform"], raw["device_kind"]) == \
+            (dev.platform, dev.device_kind)
+        assert raw["lifecycle"]["state"] == "READY"
+        assert raw["lifecycle"]["device"]["device_kind"] == dev.device_kind

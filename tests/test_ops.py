@@ -192,10 +192,48 @@ class TestPallasKernels:
         q = l2_normalize(jnp.asarray(_rand(8, 128, 20)))
         c = jnp.asarray(_rand(512, 128, 21))
         valid = jnp.ones(512, bool)
-        v1, i1 = fused_cosine_topk(q, c, valid, 5, tile_n=128)
+        v1, i1 = fused_cosine_topk(q, c, valid, 5, tile_n=128, interpret=True)
         v2, i2 = cosine_topk(q, l2_normalize(c), valid, 5, use_bf16=False)
         np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
         np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), atol=1e-4)
+
+
+class TestKernelDispatch:
+    """Where the streaming kernel runs is decided in one place: on a TPU
+    by size (or force), off it never — the Pallas interpreter is something
+    only a test asks for, by name."""
+
+    @pytest.mark.parametrize("on_tpu,streaming,n,want", [
+        (False, None, 1 << 20, (False, False)),
+        (False, True, 1 << 20, (False, False)),
+        (False, "interpret", 256, (True, True)),
+        (True, None, 65_535, (False, False)),
+        (True, None, 65_536, (True, False)),
+        (True, True, 256, (True, False)),
+        (True, False, 1 << 20, (False, False)),
+        (True, "interpret", 256, (True, True)),
+    ])
+    def test_kernel_mode(self, monkeypatch, on_tpu, streaming, n, want):
+        from nornicdb_tpu.ops import pallas_kernels, similarity
+
+        monkeypatch.setattr(pallas_kernels, "_on_tpu", lambda: on_tpu)
+        assert similarity._kernel_mode(streaming, n) == want
+
+    def test_failed_backend_init_raises(self, monkeypatch):
+        """A backend that cannot initialise must not read as "not a TPU"
+        (and so as the XLA-on-CPU path): the error reaches the caller."""
+        import jax
+
+        from nornicdb_tpu.ops import pallas_kernels, similarity
+
+        def boom():
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        monkeypatch.setattr(jax, "devices", boom)
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            pallas_kernels._on_tpu()
+        with pytest.raises(RuntimeError, match="Unable to initialize"):
+            similarity._kernel_mode(None, 1 << 20)
 
 
 class TestClusterPrunedSearch:
@@ -318,9 +356,9 @@ class TestStreamingTopK:
         for j in range(0, 500, 11):
             corpus.remove(f"v{j}")
         q = vecs[7]
-        # streaming=True forces the Pallas path (interpret off-TPU);
+        # streaming="interpret" forces the Pallas path (interpret off-TPU);
         # default path is the XLA approx_max_k — results must agree on top-1
-        a = corpus.search(q, k=5, streaming=True)
+        a = corpus.search(q, k=5, streaming="interpret")
         b = corpus.search(q, k=5, streaming=False)
         assert a[0][0][0] == b[0][0][0] == "v7"
         assert abs(a[0][0][1] - 1.0) < 1e-2
@@ -415,7 +453,7 @@ class TestStreamingTopK:
         ids = [f"v{i}" for i in range(400)]
         corpus.add_batch(ids, vecs)
         corpus.remove("v8")
-        a = corpus.search(vecs[7], k=5, streaming=True)
+        a = corpus.search(vecs[7], k=5, streaming="interpret")
         assert a[0][0][0] == "v7"
         assert abs(a[0][0][1] - 1.0) < 0.02
         assert "v8" not in {id_ for id_, _ in a[0]}

@@ -133,6 +133,6 @@ class TestShardedStreaming:
         vecs = rng.standard_normal((1024, 64)).astype(np.float32)
         sc.add_batch([f"v{i}" for i in range(1024)], vecs)
         q = vecs[42]
-        a = sc.search(q, k=5, streaming=True)
+        a = sc.search(q, k=5, streaming="interpret")
         b = sc.search(q, k=5, streaming=False)
         assert a[0][0][0] == b[0][0][0] == "v42"

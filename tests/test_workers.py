@@ -30,17 +30,11 @@ def pool_setup():
     primary = HttpServer(db, port=0)
     primary.start()
     pool = WorkerPool(db, primary.port, n_workers=2).start()
-    # wait for both workers to come up (spawn: fresh interpreter each)
-    deadline = time.time() + 60
-    up = False
-    while time.time() < deadline:
-        try:
-            _req(pool.port, "GET", "/health")
-            up = True
-            break
-        except OSError:
-            time.sleep(0.25)
-    assert up, "workers never started listening"
+    # wait until EACH worker has accepted a connection (spawn: a fresh
+    # interpreter each, and they finish booting at different times — one
+    # answering says nothing about the other)
+    seen = _workers_answering(pool.port, want=2, timeout=60)
+    assert len(seen) == 2, f"workers never all started listening: {seen}"
     yield db, primary, pool
     pool.stop()
     primary.stop()
@@ -62,17 +56,30 @@ def _req(port, method, path, body=None):
         conn.close()
 
 
+def _workers_answering(port, want, timeout):
+    """Distinct workers that answered fresh connections to the shared
+    port, polled until ``want`` of them did or ``timeout`` passed.  Bounded
+    by time, not by a number of connects: which listener the kernel hands
+    a connection is its business, that every worker accepts is ours."""
+    seen = set()
+    deadline = time.time() + timeout
+    while len(seen) < want and time.time() < deadline:
+        try:
+            _, headers, _ = _req(port, "GET", "/health")
+            seen.add(headers.get("X-Nornic-Worker"))
+        except OSError:
+            pass  # nobody listening yet
+        if len(seen) < want:
+            time.sleep(0.05)
+    return seen
+
+
 class TestWorkerPool:
     def test_connections_spread_across_workers(self, pool_setup):
         _, _, pool = pool_setup
         assert pool.alive() == 2
-        seen = set()
-        for _ in range(40):  # fresh connection each time: kernel rebalances
-            _, headers, _ = _req(pool.port, "GET", "/health")
-            seen.add(headers.get("X-Nornic-Worker"))
-            if len(seen) >= 2:
-                break
-        assert len(seen) >= 2, f"all 40 connections hit one worker: {seen}"
+        seen = _workers_answering(pool.port, want=2, timeout=30)
+        assert len(seen) >= 2, f"every connection hit one worker: {seen}"
 
     def test_search_cached_after_first_miss(self, pool_setup):
         _, _, pool = pool_setup
