@@ -18,6 +18,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from nornicdb_tpu.telemetry.tracing import tracer as _tracer
+
 
 class Embedder:
     """(ref: embed.Embedder pkg/embed/embed.go:71)"""
@@ -144,6 +146,10 @@ class TPUEmbedder(Embedder):
         self.stats = {
             "embedded": 0, "batches": 0, "cpu_fallback_batches": 0,
             "packed_dispatches": 0, "packed_tokens": 0,
+            # host-observed seconds of the embed.dispatch stage (uploads +
+            # the jitted call returning) and the embed.fetch stage (the
+            # blocking read-back: device execution + D2H)
+            "dispatch_seconds": 0.0, "fetch_seconds": 0.0,
         }
         # fleet telemetry: encoder parameter residency (weakref'd; summed
         # per component at /metrics render — telemetry/deviceprof.py)
@@ -255,10 +261,14 @@ class TPUEmbedder(Embedder):
                         s = seqs[pos]
                         ids[row, : len(s)] = s
                         mask[row, : len(s)] = 1
-                    emb = self._fwd(
-                        params, jnp.asarray(ids), jnp.asarray(mask)
-                    )
-                    emb = np.asarray(emb, np.float32)
+                    with _tracer.stage("embed.dispatch", self.stats,
+                                       "dispatch_seconds"):
+                        emb = self._fwd(
+                            params, jnp.asarray(ids), jnp.asarray(mask)
+                        )
+                    with _tracer.stage("embed.fetch", self.stats,
+                                       "fetch_seconds"):
+                        emb = np.asarray(emb, np.float32)
                     for row, pos in enumerate(chunk):
                         out[pos] = emb[row]
                     self.stats["batches"] += 1
@@ -284,15 +294,18 @@ class TPUEmbedder(Embedder):
         degraded = not isinstance(scope, contextlib.nullcontext)
         params = self._fallback_params() if degraded else self._serving_params()
         with scope:
-            emb = self._fwd_packed(
-                params,
-                jnp.asarray(packed.ids),
-                jnp.asarray(packed.seg),
-                jnp.asarray(packed.positions),
-                jnp.asarray(packed.cls_rows),
-                jnp.asarray(packed.cls_cols),
-            )
-            emb = np.asarray(emb, np.float32)
+            with _tracer.stage("embed.dispatch", self.stats,
+                               "dispatch_seconds"):
+                emb = self._fwd_packed(
+                    params,
+                    jnp.asarray(packed.ids),
+                    jnp.asarray(packed.seg),
+                    jnp.asarray(packed.positions),
+                    jnp.asarray(packed.cls_rows),
+                    jnp.asarray(packed.cls_cols),
+                )
+            with _tracer.stage("embed.fetch", self.stats, "fetch_seconds"):
+                emb = np.asarray(emb, np.float32)
         self.packed_shapes.add(packed.shape_class)
         self.stats["packed_dispatches"] += 1
         self.stats["packed_tokens"] += packed.tokens
@@ -326,27 +339,30 @@ class CachedEmbedder(Embedder):
         return hashlib.sha256(text.encode()).hexdigest()
 
     def embed_batch(self, texts: Sequence[str]) -> list[np.ndarray]:
-        out: list[Optional[np.ndarray]] = [None] * len(texts)
-        miss_idx: list[int] = []
-        with self._lock:
-            for i, t in enumerate(texts):
-                k = self._key(t)
-                if k in self._cache:
-                    self._cache.move_to_end(k)
-                    out[i] = self._cache[k]
-                    self.hits += 1
-                else:
-                    miss_idx.append(i)
-                    self.misses += 1
-        if miss_idx:
-            fresh = self.inner.embed_batch([texts[i] for i in miss_idx])
+        # span and profiler annotation only: the layer's counters are
+        # hits / misses; its self time is this span less its children
+        with _tracer.stage("embed.cache"):
+            out: list[Optional[np.ndarray]] = [None] * len(texts)
+            miss_idx: list[int] = []
             with self._lock:
-                for i, v in zip(miss_idx, fresh):
-                    out[i] = v
-                    self._cache[self._key(texts[i])] = v
-                    while len(self._cache) > self.capacity:
-                        self._cache.popitem(last=False)
-        return out  # type: ignore[return-value]
+                for i, t in enumerate(texts):
+                    k = self._key(t)
+                    if k in self._cache:
+                        self._cache.move_to_end(k)
+                        out[i] = self._cache[k]
+                        self.hits += 1
+                    else:
+                        miss_idx.append(i)
+                        self.misses += 1
+            if miss_idx:
+                fresh = self.inner.embed_batch([texts[i] for i in miss_idx])
+                with self._lock:
+                    for i, v in zip(miss_idx, fresh):
+                        out[i] = v
+                        self._cache[self._key(texts[i])] = v
+                        while len(self._cache) > self.capacity:
+                            self._cache.popitem(last=False)
+            return out  # type: ignore[return-value]
 
     def dimensions(self) -> int:
         return self.inner.dimensions()

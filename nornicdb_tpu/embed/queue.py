@@ -27,6 +27,7 @@ from nornicdb_tpu.errors import NotFoundError
 from nornicdb_tpu.storage.types import Engine, Node
 from nornicdb_tpu.telemetry.metrics import REGISTRY as _REGISTRY
 from nornicdb_tpu.telemetry.metrics import count_error as _count_error
+from nornicdb_tpu.telemetry.tracing import tracer as _tracer
 
 logger = logging.getLogger(__name__)
 
@@ -230,12 +231,29 @@ class EmbedWorker:
             return skipped
         # One flat batch through the embedder (all chunks of all nodes).
         flat = [c for _, chunks in jobs for c in chunks]
-        vectors = self._embed_with_retry(flat, [n.id for n, _ in jobs])
+        # embedq.* stages: this thread has no trace, so they are profiler
+        # annotations only (the idle gaps of an ingest capture)
+        with _tracer.stage("embedq.batch", attrs={"texts": len(flat)}):
+            vectors = self._embed_with_retry(flat, [n.id for n, _ in jobs])
         if vectors is None:
             # batch failed terminally: mark failures, keep pending for later
             with self._stats_lock:
                 self.stats.failed += len(jobs)
             return skipped
+        with _tracer.stage("embedq.index", attrs={"nodes": len(jobs)}):
+            processed, chunked = self._store_embedded(jobs, vectors)
+        with self._stats_lock:
+            self.stats.processed += processed
+            self.stats.batches += 1
+            self.stats.chunked_nodes += chunked
+        with self._cluster_lock:
+            self._since_cluster += processed
+            self._last_embed_ts = time.monotonic()
+        return processed + skipped
+
+    def _store_embedded(self, jobs, vectors) -> tuple[int, int]:
+        """Write each node's embedding back and run ``on_embedded`` (index
+        + auto-TLP).  Returns (nodes processed, nodes chunked)."""
         processed = 0
         chunked = 0
         pos = 0
@@ -265,14 +283,7 @@ class EmbedWorker:
                         _count_error("embed_queue")
             except NotFoundError:
                 self.storage.unmark_pending_embed(node.id)
-        with self._stats_lock:
-            self.stats.processed += processed
-            self.stats.batches += 1
-            self.stats.chunked_nodes += chunked
-        with self._cluster_lock:
-            self._since_cluster += processed
-            self._last_embed_ts = time.monotonic()
-        return processed + skipped
+        return processed, chunked
 
     def _embed_with_retry(
         self, texts: list[str], node_ids: Optional[list[str]] = None

@@ -95,6 +95,33 @@ def init_params(cfg: BgeConfig, key: jax.Array) -> dict:
     return params
 
 
+def _encoder_layer(blk: dict, cfg: BgeConfig, h: jax.Array,
+                   amask: jax.Array) -> jax.Array:
+    """One post-LN encoder layer over a (rows, cols, hidden) grid; the
+    named scopes put ``bge.layer.attn`` / ``bge.layer.mlp`` on the path of
+    every XLA op of a profiler capture."""
+    r, c, _ = h.shape
+    head_dim = cfg.hidden // cfg.heads
+    with jax.named_scope("bge.layer.attn"):
+        q = dense(blk["q"], h).reshape(r, c, cfg.heads, head_dim)
+        k = dense(blk["k"], h).reshape(r, c, cfg.heads, head_dim)
+        v = dense(blk["v"], h).reshape(r, c, cfg.heads, head_dim)
+        o = attention(q, k, v, amask).reshape(r, c, cfg.hidden)
+        h = layer_norm(blk["attn_ln"], h + dense(blk["o"], o))  # post-LN
+    with jax.named_scope("bge.layer.mlp"):
+        m = dense(blk["down"], jax.nn.gelu(dense(blk["up"], h)))
+        return layer_norm(blk["mlp_ln"], h + m)
+
+
+def _project_normalize(params: dict, cfg: BgeConfig,
+                       cls: jax.Array) -> jax.Array:
+    if cfg.dims != cfg.hidden:
+        cls = dense(params["proj"], cls)  # width-shrunk student -> dims
+    cls = cls.astype(jnp.float32)
+    norm = jnp.linalg.norm(cls, axis=-1, keepdims=True)
+    return cls / jnp.maximum(norm, 1e-12)
+
+
 def forward(
     params: dict,
     cfg: BgeConfig,
@@ -102,33 +129,24 @@ def forward(
     attention_mask: jax.Array,
 ) -> jax.Array:
     """(B, T) ids + (B, T) mask -> (B, dims) L2-normalized embeddings."""
-    b, t = input_ids.shape
-    # XLM-R position ids start at pad_token_id+1 and skip pads
-    positions = jnp.cumsum(attention_mask, axis=1) * attention_mask + cfg.pad_token_id
-    h = (
-        params["tok_emb"][input_ids]
-        + params["pos_emb"][positions]
-        + params["type_emb"][jnp.zeros_like(input_ids)]
-    )
-    h = layer_norm(params["emb_ln"], h)
-    # additive mask: (B, 1, 1, T)
-    neg = jnp.asarray(-1e30, jnp.float32)
-    amask = jnp.where(attention_mask[:, None, None, :] > 0, 0.0, neg)
-    head_dim = cfg.hidden // cfg.heads
+    with jax.named_scope("bge.embed"):
+        # XLM-R position ids start at pad_token_id+1 and skip pads
+        positions = (jnp.cumsum(attention_mask, axis=1) * attention_mask
+                     + cfg.pad_token_id)
+        h = (
+            params["tok_emb"][input_ids]
+            + params["pos_emb"][positions]
+            + params["type_emb"][jnp.zeros_like(input_ids)]
+        )
+        h = layer_norm(params["emb_ln"], h)
+        # additive mask: (B, 1, 1, T)
+        neg = jnp.asarray(-1e30, jnp.float32)
+        amask = jnp.where(attention_mask[:, None, None, :] > 0, 0.0, neg)
     for blk in params["blocks"]:
-        q = dense(blk["q"], h).reshape(b, t, cfg.heads, head_dim)
-        k = dense(blk["k"], h).reshape(b, t, cfg.heads, head_dim)
-        v = dense(blk["v"], h).reshape(b, t, cfg.heads, head_dim)
-        o = attention(q, k, v, amask).reshape(b, t, cfg.hidden)
-        h = layer_norm(blk["attn_ln"], h + dense(blk["o"], o))  # post-LN
-        m = dense(blk["down"], jax.nn.gelu(dense(blk["up"], h)))
-        h = layer_norm(blk["mlp_ln"], h + m)
-    cls = h[:, 0, :]  # CLS pooling (bge dense head)
-    if cfg.dims != cfg.hidden:
-        cls = dense(params["proj"], cls)  # width-shrunk student -> dims
-    cls = cls.astype(jnp.float32)
-    norm = jnp.linalg.norm(cls, axis=-1, keepdims=True)
-    return cls / jnp.maximum(norm, 1e-12)
+        h = _encoder_layer(blk, cfg, h, amask)
+    with jax.named_scope("bge.pool"):
+        cls = h[:, 0, :]  # CLS pooling (bge dense head)
+        return _project_normalize(params, cfg, cls)
 
 
 def forward_packed(
@@ -155,39 +173,30 @@ def forward_packed(
     (same contract as forward()'s bucket grid; NL-JAX03).
     Returns (S_cap, dims) L2-normalized embeddings.
     """
-    r, c = input_ids.shape
-    h = (
-        params["tok_emb"][input_ids]
-        + params["pos_emb"][positions]
-        + params["type_emb"][jnp.zeros_like(input_ids)]
-    )
-    h = layer_norm(params["emb_ln"], h)
-    # block-diagonal additive mask (R, 1, C, C): key visible to query iff
-    # same nonzero segment. Fully-masked pad queries softmax to uniform
-    # garbage that nothing gathers (no NaN: softmax is max-subtracted).
-    neg = jnp.asarray(-1e30, jnp.float32)
-    valid = seg_ids > 0
-    allowed = (
-        (seg_ids[:, :, None] == seg_ids[:, None, :])
-        & valid[:, :, None]
-        & valid[:, None, :]
-    )
-    amask = jnp.where(allowed[:, None, :, :], 0.0, neg)
-    head_dim = cfg.hidden // cfg.heads
+    with jax.named_scope("bge.embed"):
+        h = (
+            params["tok_emb"][input_ids]
+            + params["pos_emb"][positions]
+            + params["type_emb"][jnp.zeros_like(input_ids)]
+        )
+        h = layer_norm(params["emb_ln"], h)
+        # block-diagonal additive mask (R, 1, C, C): key visible to query
+        # iff same nonzero segment. Fully-masked pad queries softmax to
+        # uniform garbage that nothing gathers (no NaN: softmax is
+        # max-subtracted).
+        neg = jnp.asarray(-1e30, jnp.float32)
+        valid = seg_ids > 0
+        allowed = (
+            (seg_ids[:, :, None] == seg_ids[:, None, :])
+            & valid[:, :, None]
+            & valid[:, None, :]
+        )
+        amask = jnp.where(allowed[:, None, :, :], 0.0, neg)
     for blk in params["blocks"]:
-        q = dense(blk["q"], h).reshape(r, c, cfg.heads, head_dim)
-        k = dense(blk["k"], h).reshape(r, c, cfg.heads, head_dim)
-        v = dense(blk["v"], h).reshape(r, c, cfg.heads, head_dim)
-        o = attention(q, k, v, amask).reshape(r, c, cfg.hidden)
-        h = layer_norm(blk["attn_ln"], h + dense(blk["o"], o))  # post-LN
-        m = dense(blk["down"], jax.nn.gelu(dense(blk["up"], h)))
-        h = layer_norm(blk["mlp_ln"], h + m)
-    cls = h[cls_rows, cls_cols, :]  # (S_cap, hidden): segment CLS pooling
-    if cfg.dims != cfg.hidden:
-        cls = dense(params["proj"], cls)
-    cls = cls.astype(jnp.float32)
-    norm = jnp.linalg.norm(cls, axis=-1, keepdims=True)
-    return cls / jnp.maximum(norm, 1e-12)
+        h = _encoder_layer(blk, cfg, h, amask)
+    with jax.named_scope("bge.pool"):
+        cls = h[cls_rows, cls_cols, :]  # (S_cap, hidden): segment CLS pooling
+        return _project_normalize(params, cfg, cls)
 
 
 def shardings(cfg: BgeConfig) -> dict:

@@ -199,29 +199,34 @@ def streaming_cosine_topk(
     kern = functools.partial(
         _streaming_topk_kernel, rows=rows, tile_bits=tile_bits
     )
-    bins = pl.pallas_call(
-        kern,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((q, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tile_n, d), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile_n), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=jax.ShapeDtypeStruct((rows, q, tile_n), jnp.int32),
-        # every grid step maps to the same block: the running bins stay
-        # VMEM-resident for the whole sweep and are written back once
-        out_specs=pl.BlockSpec((rows, q, tile_n), lambda i: (0, 0, 0),
-                               memory_space=pltpu.VMEM),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * q * n * d,
-            bytes_accessed=n * d * corpus.dtype.itemsize
-            + q * d * queries.dtype.itemsize + rows * q * tile_n * 4,
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(queries, corpus, bias)
+    # stable names for a profiler capture: the scope is on the path of the
+    # XLA op, the kernel's own name on the custom call
+    with jax.named_scope("topk.stream.scan"):
+        bins = pl.pallas_call(
+            kern,
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((q, d), lambda i: (0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((tile_n, d), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, tile_n), lambda i: (0, i),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_shape=jax.ShapeDtypeStruct((rows, q, tile_n), jnp.int32),
+            # every grid step maps to the same block: the running bins stay
+            # VMEM-resident for the whole sweep and are written back once
+            out_specs=pl.BlockSpec((rows, q, tile_n), lambda i: (0, 0, 0),
+                                   memory_space=pltpu.VMEM),
+            cost_estimate=pl.CostEstimate(
+                flops=2 * q * n * d,
+                bytes_accessed=n * d * corpus.dtype.itemsize
+                + q * d * queries.dtype.itemsize + rows * q * tile_n * 4,
+                transcendentals=0,
+            ),
+            interpret=interpret,
+            name="topk_stream_scan",
+        )(queries, corpus, bias)
 
     # epilogue: top-k over the B = rows*tile_n packed bins (int order =
     # score order), then decode score + provenance from the packed bits
@@ -347,20 +352,21 @@ def _topk_bins(flat, k: int, *, epilogue: str, interpret: bool):
 def _decode_packed(bins, *, k, n, rows, tile_n, tile_bits,
                    epilogue: str = "sort", interpret: bool = False):
     """Top-k over packed bins + decode (score, global row)."""
-    q = bins.shape[1]
-    b_total = rows * tile_n
-    flat = jnp.swapaxes(bins, 0, 1).reshape(q, b_total)
-    top_packed, top_bin = _topk_bins(
-        flat, k, epilogue=epilogue, interpret=interpret
-    )
-    low_mask = (1 << tile_bits) - 1
-    tile_idx = top_packed & low_mask
-    idx = tile_idx * tile_n + top_bin % tile_n
-    # midpoint-reconstruct the truncated mantissa bits, then un-bias
-    score_bits = (top_packed & ~low_mask) | (1 << (tile_bits - 1))
-    vals = jax.lax.bitcast_convert_type(score_bits, jnp.float32) - 3.0
-    vals = jnp.where(top_packed > 0, vals, -jnp.inf)
-    return vals, jnp.clip(idx, 0, n - 1)
+    with jax.named_scope("topk.stream.merge"):
+        q = bins.shape[1]
+        b_total = rows * tile_n
+        flat = jnp.swapaxes(bins, 0, 1).reshape(q, b_total)
+        top_packed, top_bin = _topk_bins(
+            flat, k, epilogue=epilogue, interpret=interpret
+        )
+        low_mask = (1 << tile_bits) - 1
+        tile_idx = top_packed & low_mask
+        idx = tile_idx * tile_n + top_bin % tile_n
+        # midpoint-reconstruct the truncated mantissa bits, then un-bias
+        score_bits = (top_packed & ~low_mask) | (1 << (tile_bits - 1))
+        vals = jax.lax.bitcast_convert_type(score_bits, jnp.float32) - 3.0
+        vals = jnp.where(top_packed > 0, vals, -jnp.inf)
+        return vals, jnp.clip(idx, 0, n - 1)
 
 
 @functools.partial(
@@ -393,28 +399,31 @@ def streaming_cosine_topk_int8(
     kern = functools.partial(
         _streaming_topk_int8_kernel, rows=rows, tile_bits=tile_bits
     )
-    bins = pl.pallas_call(
-        kern,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((q, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((tile_n, d), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile_n), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile_n), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=jax.ShapeDtypeStruct((rows, q, tile_n), jnp.int32),
-        out_specs=pl.BlockSpec((rows, q, tile_n), lambda i: (0, 0, 0),
-                               memory_space=pltpu.VMEM),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * q * n * d,
-            bytes_accessed=n * d + q * d + rows * q * tile_n * 4,
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(q_i8, c_i8, scale.reshape(1, n), bias.reshape(1, n))
+    with jax.named_scope("topk.stream.scan"):
+        bins = pl.pallas_call(
+            kern,
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((q, d), lambda i: (0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((tile_n, d), lambda i: (i, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, tile_n), lambda i: (0, i),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, tile_n), lambda i: (0, i),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_shape=jax.ShapeDtypeStruct((rows, q, tile_n), jnp.int32),
+            out_specs=pl.BlockSpec((rows, q, tile_n), lambda i: (0, 0, 0),
+                                   memory_space=pltpu.VMEM),
+            cost_estimate=pl.CostEstimate(
+                flops=2 * q * n * d,
+                bytes_accessed=n * d + q * d + rows * q * tile_n * 4,
+                transcendentals=0,
+            ),
+            interpret=interpret,
+            name="topk_stream_scan_int8",
+        )(q_i8, c_i8, scale.reshape(1, n), bias.reshape(1, n))
     vals, idx = _decode_packed(
         bins, k=k, n=n, rows=rows, tile_n=tile_n, tile_bits=tile_bits,
         epilogue=epilogue, interpret=interpret,

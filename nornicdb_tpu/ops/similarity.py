@@ -51,8 +51,10 @@ def pad_to_multiple(n: int, m: int = LANE) -> int:
 @jax.jit
 def l2_normalize(x: jax.Array, eps: float = 1e-12) -> jax.Array:
     """Row-wise L2 normalization (ref: kernel_normalize_vectors cuda_kernels.cu:206)."""
-    norm = jnp.sqrt(jnp.sum(x.astype(jnp.float32) ** 2, axis=-1, keepdims=True))
-    return (x / jnp.maximum(norm, eps)).astype(x.dtype)
+    with jax.named_scope("l2_normalize"):
+        norm = jnp.sqrt(
+            jnp.sum(x.astype(jnp.float32) ** 2, axis=-1, keepdims=True))
+        return (x / jnp.maximum(norm, eps)).astype(x.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("use_bf16",))
@@ -129,9 +131,10 @@ def masked_dot_topk(
     caller's widened-boundary rescore contract budgets for f32 GEMM
     rounding only, not bf16.
     """
-    s = dot_scores(query[None, :], corpus, use_bf16=False)[0]
-    s = jnp.where(valid, s, -jnp.inf)
-    return s, jax.lax.top_k(s, k)[0]
+    with jax.named_scope("masked_dot_topk"):
+        s = dot_scores(query[None, :], corpus, use_bf16=False)[0]
+        s = jnp.where(valid, s, -jnp.inf)
+        return s, jax.lax.top_k(s, k)[0]
 
 
 # streaming Pallas top-k engages above this corpus size; below it the (Q, N)
@@ -426,6 +429,14 @@ class SyncStats:
     # through the QueryBatcher) — the counter the multi-process bench's
     # one-program-per-fused-batch invariant is asserted against
     device_dispatches: int = 0
+    # host-observed seconds of the dense device search's three stages,
+    # each fed by the tracer.stage of the same name: corpus.dispatch
+    # (sync/borrow + normalize + the top-k program launched),
+    # corpus.fetch (the blocking read-back: device execution + D2H, and
+    # whatever is queued ahead on the chip), corpus.format (slot -> id)
+    search_dispatch_seconds: float = 0.0
+    search_fetch_seconds: float = 0.0
+    search_format_seconds: float = 0.0
 
     def as_dict(self) -> dict:
         return asdict(self)
@@ -1092,9 +1103,11 @@ class HostCorpus:
         shared epilogue (ops.host_search.format_topk_results) so the
         cross-process read plane resolves identically by construction."""
         ids = self._ids if ids is None else ids
-        return format_topk_results(
-            vals, idx, n_queries, k, min_similarity, ids
-        )
+        with _tracer.stage("corpus.format", self.sync_stats,
+                           "search_format_seconds"):
+            return format_topk_results(
+                vals, idx, n_queries, k, min_similarity, ids
+            )
 
 
 class DeviceCorpus(HostCorpus):
@@ -1579,22 +1592,28 @@ class DeviceCorpus(HostCorpus):
                         time.perf_counter() - t0,
                     )
                     return pruned
-            t0 = time.perf_counter()
-            with self._borrow_device() as (corpus, valid, dev_i8, ids, _):
-                kk = min(k, self.capacity)
-                vals, idx = topk_backend(
-                    l2_normalize(jnp.asarray(q, dtype=self.dtype)), corpus,
-                    valid, kk, exact=exact, streaming=streaming,
-                    quantized=dev_i8 if self.quantize else None,
-                )
+            stats = self.sync_stats
+            with contextlib.ExitStack() as borrowed:
+                with _tracer.stage("corpus.dispatch", stats,
+                                   "search_dispatch_seconds") as dispatch:
+                    corpus, valid, dev_i8, ids, _ = borrowed.enter_context(
+                        self._borrow_device())
+                    kk = min(k, self.capacity)
+                    vals, idx = topk_backend(
+                        l2_normalize(jnp.asarray(q, dtype=self.dtype)),
+                        corpus, valid, kk, exact=exact, streaming=streaming,
+                        quantized=dev_i8 if self.quantize else None,
+                    )
                 # materialize INSIDE the borrow: the computation must
                 # finish before the patcher may donate the buffer it reads
-                vals_np = np.asarray(vals, np.float32)
-                idx_np = np.asarray(idx)
-            self.sync_stats.device_dispatches += 1
+                with _tracer.stage("corpus.fetch", stats,
+                                   "search_fetch_seconds") as fetch:
+                    vals_np = np.asarray(vals, np.float32)
+                    idx_np = np.asarray(idx)
+            stats.device_dispatches += 1
             _deviceprof.record_execute(
                 "search", "dense", _deviceprof.pow2_class(q.shape[0], "b"),
-                time.perf_counter() - t0,
+                dispatch.seconds + fetch.seconds,
             )
         except DeviceUnavailable:
             # degraded between the gate and the borrow

@@ -37,7 +37,8 @@ _QUEUE_WAIT_HIST = _REGISTRY.histogram(
 )
 _DEVICE_HIST = _REGISTRY.histogram(
     "nornicdb_search_device_seconds",
-    "Device dispatch time per search batch",
+    "Host-observed dispatch-to-result seconds per search dispatch "
+    "(the first call of a shape includes its compile)",
 )
 # observed coalesced batch sizes: the distribution (not just max/avg) is
 # what batch_window tuning needs — a bimodal histogram means the window is

@@ -42,7 +42,8 @@ logger = logging.getLogger(__name__)
 # queue-wait family is registered even before batching is ever enabled
 _DEVICE_HIST = _REGISTRY.histogram(
     "nornicdb_search_device_seconds",
-    "Device dispatch time per search batch",
+    "Host-observed dispatch-to-result seconds per search dispatch "
+    "(the first call of a shape includes its compile)",
 )
 _REGISTRY.histogram(
     "nornicdb_search_queue_wait_seconds",
@@ -678,16 +679,14 @@ class SearchService:
             corpus, hnsw = self._corpus, self._hnsw
         if corpus is not None:
             kwargs = self._corpus_search_kwargs(corpus)
-            t0 = time.perf_counter()
-            with _tracer.span("search.vector"):
+            # unbatched dispatches land in the same histogram the batcher
+            # feeds (host-observed seconds of the whole corpus.search),
+            # so the default (non-batched) configuration still reports it
+            with _tracer.stage("search.vector", _DEVICE_HIST):
                 res = corpus.search(
                     embedding, k=k, min_similarity=min_similarity,
                     **kwargs
                 )
-            # unbatched dispatches land in the same device-time
-            # histogram the batcher feeds, so the default (non-batched)
-            # configuration still reports device time
-            _DEVICE_HIST.observe(time.perf_counter() - t0)
             return res[0] if res else []
         if hnsw is not None:
             return [

@@ -81,6 +81,15 @@ from nornicdb_tpu.telemetry.tracing import tracer as _tracer
 log = logging.getLogger(__name__)
 
 
+def _decode_json(raw: bytes) -> dict:
+    if not raw:
+        return {}
+    try:
+        return json.loads(raw)
+    except json.JSONDecodeError:
+        raise NornicError("invalid JSON body")
+
+
 def _jsonable(v: Any) -> Any:
     if isinstance(v, Node):
         return {
@@ -202,12 +211,8 @@ class HttpServer:
 
     @staticmethod
     def _parse_body(raw: bytes) -> dict:
-        if not raw:
-            return {}
-        try:
-            return json.loads(raw or b"{}")
-        except json.JSONDecodeError:
-            raise NornicError("invalid JSON body")
+        with _tracer.stage("http.parse"):
+            return _decode_json(raw)
 
     # -- hot-path response cache (shared policy: server/respcache.py) -----
     @property
@@ -265,12 +270,15 @@ class HttpServer:
                 content_type="application/json",
                 extra_headers: Optional[dict[str, str]] = None,
             ):
-                data = (
-                    json.dumps(body).encode()
-                    if content_type == "application/json"
-                    else body.encode()
-                )
-                self._send_raw(code, data, content_type, extra_headers)
+                # http.respond: encode + socket write (span and profiler
+                # annotation only; the server has no stats object)
+                with _tracer.stage("http.respond"):
+                    data = (
+                        json.dumps(body).encode()
+                        if content_type == "application/json"
+                        else body.encode()
+                    )
+                    self._write(code, data, content_type, extra_headers)
 
             def _send_raw(
                 self,
@@ -280,6 +288,10 @@ class HttpServer:
                 extra_headers: Optional[dict[str, str]] = None,
             ) -> None:
                 """Pre-encoded body with the standard header set."""
+                with _tracer.stage("http.respond"):
+                    self._write(code, data, content_type, extra_headers)
+
+            def _write(self, code, data, content_type, extra_headers):
                 self._status = code
                 self.send_response(code)
                 self.send_header("Content-Type", content_type)
@@ -299,12 +311,21 @@ class HttpServer:
                 self.end_headers()
                 self.wfile.write(data)
 
-            def _raw_body(self) -> bytes:
+            def _read_body(self) -> bytes:
                 length = int(self.headers.get("Content-Length") or 0)
                 return self.rfile.read(length) if length else b""
 
+            def _raw_body(self) -> bytes:
+                # the search route keys its response cache on the raw
+                # bytes and decodes later (_parse_body): two http.parse
+                # spans, read and decode
+                with _tracer.stage("http.parse"):
+                    return self._read_body()
+
             def _body(self) -> dict:
-                return server_self._parse_body(self._raw_body())
+                # http.parse: body read + JSON decode
+                with _tracer.stage("http.parse"):
+                    return _decode_json(self._read_body())
 
             def _auth(self, permission: str = "read") -> Optional[dict]:
                 if not server_self.auth_required or server_self.authenticator is None:
@@ -726,8 +747,14 @@ class HttpServer:
             return
         if path == "/admin/traces":
             # recent completed traces, newest first (tentpole pillar 2)
+            # (?slow=1: the roots at or over slow_query_ms, kept in a ring
+            # of their own that faster traffic cannot evict)
             h._auth("admin")
-            h._send(200, {"traces": _tracer.traces()})
+            from urllib.parse import parse_qs, urlparse
+
+            slow = parse_qs(urlparse(h.path).query).get("slow", ["0"])[0]
+            h._send(200, {"traces": _tracer.traces(
+                slow=slow.lower() not in ("", "0", "false", "no"))})
             return
         if path.startswith("/admin/traces/"):
             h._auth("admin")
@@ -974,6 +1001,29 @@ class HttpServer:
             counters={"nornicdb_embed_processed", "nornicdb_embed_failed"},
         )
 
+        def _stage_seconds() -> dict:
+            # cumulative host-observed seconds of the embed path's stages
+            # (each fed by the tracer.stage of its boundary) beside the
+            # requests they are divided by; zeros until an engine serves
+            from nornicdb_tpu.serving.engine import EngineStats
+
+            engine = self.db.serving_engine()
+            stats = vars(engine.stats if engine is not None
+                         else EngineStats())
+            out = {f"serving_{k}_total": v for k, v in stats.items()
+                   if k.endswith("_seconds") or k == "requests"}
+            inner = getattr(getattr(engine, "inner", None), "stats", None)
+            for key in ("dispatch_seconds", "fetch_seconds"):
+                out[f"embed_{key}_total"] = (
+                    inner.get(key, 0.0) if isinstance(inner, dict) else 0.0)
+            return out
+
+        reg.stats_callback(
+            "nornicdb", _stage_seconds,
+            help_="Embed-path stage seconds (host-observed, cumulative)",
+            counters={"nornicdb_" + k for k in _stage_seconds()},
+        )
+
         def _search_stats() -> Optional[dict]:
             # the LAZY slot, never the property: /metrics must not force
             # search-service construction (and a full index build)
@@ -994,6 +1044,12 @@ class HttpServer:
                     "nornicdb_device_sync_full_uploads_total",
                 "nornicdb_search_corpus_sync_query_stall_s":
                     "nornicdb_device_sync_query_stall_seconds_total",
+                "nornicdb_search_corpus_sync_search_dispatch_seconds":
+                    "nornicdb_corpus_dispatch_seconds_total",
+                "nornicdb_search_corpus_sync_search_fetch_seconds":
+                    "nornicdb_corpus_fetch_seconds_total",
+                "nornicdb_search_corpus_sync_search_format_seconds":
+                    "nornicdb_corpus_format_seconds_total",
                 "nornicdb_search_batcher_queries":
                     "nornicdb_batched_queries_total",
                 "nornicdb_search_batcher_batches":
@@ -1006,6 +1062,9 @@ class HttpServer:
                 "nornicdb_search_corpus_sync_patches",
                 "nornicdb_search_corpus_sync_full_uploads",
                 "nornicdb_search_corpus_sync_query_stall_s",
+                "nornicdb_search_corpus_sync_search_dispatch_seconds",
+                "nornicdb_search_corpus_sync_search_fetch_seconds",
+                "nornicdb_search_corpus_sync_search_format_seconds",
                 "nornicdb_search_batcher_queries",
                 "nornicdb_search_batcher_batches",
                 "nornicdb_search_searches",

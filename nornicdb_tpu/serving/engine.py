@@ -69,8 +69,8 @@ class _Request:
     deadline: float = 0.0  # monotonic; 0 = none
     shed: bool = False     # terminally shed (dispatcher must skip)
     ctx: object = None     # caller's trace span (cross-thread hand-off)
-    enqueued: float = 0.0  # perf_counter at submit (queue-wait span)
-    queue_wait_recorded: bool = False  # once per request, not per batch
+    enqueued: float = 0.0  # perf_counter at submit (queue-wait stage)
+    done_at: float = 0.0   # perf_counter when its last batch returned
 
 
 @dataclass
@@ -86,6 +86,17 @@ class _Item:
 
 @dataclass
 class EngineStats:
+    """Counts and cumulative stage seconds of one engine.  Every
+    ``*_seconds`` field is fed by the ``tracer.stage`` of the same
+    boundary (docs/observability.md "Stage spans"); for one single-text
+    request the five stages tile enqueue -> return:
+    ``queue_wait | staging | staged_wait | device | wake``.
+
+    ``device_seconds`` is host-observed dispatch-to-result seconds of
+    ``serving.batch`` (uploads, launch, the blocking fetch, unpack); the
+    first call of a shape includes its compile.  It is not device time:
+    that is in a profiler capture."""
+
     batches: int = 0
     packed_batches: int = 0
     texts: int = 0
@@ -94,9 +105,14 @@ class EngineStats:
     sheds_queue_full: int = 0
     sheds_deadline: int = 0
     sheds_predicted: int = 0
-    staging_seconds: float = 0.0
-    overlap_seconds: float = 0.0
-    device_seconds: float = 0.0
+    requests: int = 0               # embed_batch calls answered
+    request_seconds: float = 0.0    # enqueue -> return, per request
+    queue_wait_seconds: float = 0.0  # enqueue -> staging picks it up, per text
+    staging_seconds: float = 0.0    # tokenize + plan + pack, per batch
+    staged_wait_seconds: float = 0.0  # pack done -> compute thread takes it
+    overlap_seconds: float = 0.0    # staging seconds inside a serving.batch
+    device_seconds: float = 0.0     # serving.batch (host-observed, see above)
+    wake_seconds: float = 0.0       # batch returned -> caller runs again
 
     def as_dict(self) -> dict:
         eff = (
@@ -116,7 +132,10 @@ class EngineStats:
             "sheds_deadline": self.sheds_deadline,
             "sheds_predicted": self.sheds_predicted,
             "staging_overlap_ratio": round(overlap, 4),
-            "device_seconds": round(self.device_seconds, 4),
+            "requests": self.requests,
+            **{name: round(getattr(self, name), 4) for name in (
+                "request_seconds", "queue_wait_seconds", "staging_seconds",
+                "staged_wait_seconds", "device_seconds", "wake_seconds")},
         }
 
 
@@ -148,7 +167,11 @@ class ServingEngine(Embedder):
         )
         self._stop = threading.Event()
         self._started = False
-        self._device_busy = False
+        # serving.batch intervals (perf_counter start, end) for the
+        # staging-overlap gauge: the open one's start, and the last few
+        # closed.  Written by the compute thread only.
+        self._batch_open: Optional[float] = None
+        self._batch_closed: deque[tuple[float, float]] = deque(maxlen=8)
         self._threads: list[threading.Thread] = []
         # ragged path needs a packed forward + a tokenizer on the inner
         # embedder; anything else still gets continuous batching through
@@ -194,7 +217,7 @@ class ServingEngine(Embedder):
             self._fail(item.req, ClosedError("serving engine stopped"))
         while True:
             try:
-                _, items = self._staged.get_nowait()
+                _, items, _ = self._staged.get_nowait()
             except queue_mod.Empty:
                 break
             for item in items:
@@ -224,8 +247,8 @@ class ServingEngine(Embedder):
         est = [len(t.split()) + 2 for t in texts]
         req = _Request(results=[None] * len(texts), remaining=len(texts))
         # worker-hop trace propagation (the QueryBatcher pattern): the
-        # compute thread attaches this to record serving.batch and the
-        # retroactive queue-wait span in the CALLER's trace
+        # engine threads record serving.batch and the retroactive
+        # queue-wait / staged-wait stages in the CALLER's trace
         req.ctx = _tracer.capture()
         req.enqueued = time.perf_counter()
         if cfg.deadline_ms > 0:
@@ -278,10 +301,17 @@ class ServingEngine(Embedder):
             _stats.QUEUE_TOKENS.set(self._queued_tokens)
             self._cond.notify_all()
         self._await(req)
-        _costmodel.record_latency(
-            "embed", time.perf_counter() - req.enqueued)
+        now = time.perf_counter()
+        _costmodel.record_latency("embed", now - req.enqueued)
         if req.error is not None:
             raise req.error
+        # caller threads race here: the engine lock (taken at admission
+        # above already) makes the three adds atomic
+        with self._lock:
+            _tracer.add_stage("serving.wake", req.done_at, now,
+                              self.stats, "wake_seconds")
+            self.stats.requests += 1
+            self.stats.request_seconds += now - req.enqueued
         return list(req.results)
 
     def _await(self, req: _Request) -> None:
@@ -372,56 +402,81 @@ class ServingEngine(Embedder):
                     scan.append(item)
                     if len(scan) >= 4096:
                         break
-            t0 = time.perf_counter()
-            busy0 = self._device_busy
-            scanned = 0
-            scan_budget = max(64, int(cfg.max_batch_tokens)) * 2
-            for item in scan:
-                if item.seq is None and self._packer is not None:
-                    item.seq = (
-                        self._tokenizer.encode(
-                            item.text, max_len=self._packer.max_len
+            # staging covers tokenize + plan + pack — the full host cost
+            # the overlap gauge claims to measure (a pass that ends up
+            # taking nothing is staging work too).  The span goes to the
+            # trace of the queue's head, which _take_batch always admits.
+            items, pack, failed = [], None, None
+            with _tracer.attach(scan[0].req.ctx), _tracer.stage(
+                "serving.stage", self.stats, "staging_seconds"
+            ) as staged:
+                scanned = 0
+                scan_budget = max(64, int(cfg.max_batch_tokens)) * 2
+                for item in scan:
+                    if item.seq is None and self._packer is not None:
+                        item.seq = (
+                            self._tokenizer.encode(
+                                item.text, max_len=self._packer.max_len
+                            )
+                            or [self._tokenizer.pad_id]
                         )
-                        or [self._tokenizer.pad_id]
-                    )
-                scanned += len(item.seq) if item.seq is not None else 1
-                if scanned >= scan_budget:
-                    break
-            with self._cond:
-                items, cap = self._take_batch()
-                _stats.QUEUE_DEPTH.set(self._queued_texts)
-                _stats.QUEUE_TOKENS.set(self._queued_tokens)
+                    scanned += len(item.seq) if item.seq is not None else 1
+                    if scanned >= scan_budget:
+                        break
+                with self._cond:
+                    items, cap = self._take_batch()
+                    _stats.QUEUE_DEPTH.set(self._queued_texts)
+                    _stats.QUEUE_TOKENS.set(self._queued_tokens)
+                if items:
+                    staged.set_attr("texts", len(items))
+                    try:
+                        pack = self._build_pack(items, cap)
+                    except Exception as e:
+                        logger.exception("serving pack build failed")
+                        failed = e
+            self._note_overlap(staged.start, staged.start + staged.seconds)
+            if failed is not None:
+                for item in items:
+                    self._fail(item.req, failed)
+                continue
             if not items:
                 continue
-            try:
-                pack = self._build_pack(items, cap)
-            except Exception as e:
-                logger.exception("serving pack build failed")
-                for item in items:
-                    self._fail(item.req, e)
-                continue
-            t1 = time.perf_counter()
-            busy1 = self._device_busy
-            # staging time covers tokenize + plan + pack — the full host
-            # cost the overlap gauge claims to measure
-            self.stats.staging_seconds += t1 - t0
-            self.stats.overlap_seconds += (t1 - t0) * (busy0 + busy1) / 2.0
-            if self.stats.staging_seconds > 0:
-                _stats.STAGING_OVERLAP.set(
-                    self.stats.overlap_seconds / self.stats.staging_seconds
-                )
+            # each text waited from its enqueue until this pass began
+            # (linger included): the stages of one request do not overlap
+            for item in items:
+                _tracer.add_stage(
+                    "serving.queue_wait", item.req.enqueued, staged.start,
+                    self.stats, "queue_wait_seconds", parent=item.req.ctx)
+            staged_at = staged.start + staged.seconds
             while not self._stop.is_set():
                 try:
                     # bounded put: the staging queue depth IS the double
                     # buffer — staging blocks here (not on the device)
                     # when compute falls behind
-                    self._staged.put((pack, items), timeout=0.5)
+                    self._staged.put((pack, items, staged_at), timeout=0.5)
                     break
                 except queue_mod.Full:
                     continue
             else:
                 for item in items:
                     self._fail(item.req, ClosedError("serving engine stopped"))
+
+    def _note_overlap(self, lo: float, hi: float) -> None:
+        """Add the part of the staging interval [lo, hi] that lay inside
+        a serving.batch interval to ``overlap_seconds`` and refresh the
+        gauge.  Batches run one at a time on the compute thread, so their
+        intervals are disjoint and the ratio cannot pass 1."""
+        open_at = self._batch_open  # read before the closed ones: a batch
+        # that closes in between is then counted once, by its start
+        inside = max(0.0, hi - max(lo, open_at)) if open_at is not None else 0.0
+        for b0, b1 in list(self._batch_closed):
+            if b0 != open_at:
+                inside += max(0.0, min(hi, b1) - max(lo, b0))
+        self.stats.overlap_seconds += inside
+        if self.stats.staging_seconds > 0:
+            _stats.STAGING_OVERLAP.set(
+                self.stats.overlap_seconds / self.stats.staging_seconds
+            )
 
     def _take_batch(self) -> tuple[list[_Item], int]:
         """Pop the next pack's worth of items (called under the lock).
@@ -493,34 +548,24 @@ class ServingEngine(Embedder):
     def _compute_loop(self) -> None:
         while not self._stop.is_set():
             try:
-                pack, items = self._staged.get(timeout=0.5)
+                pack, items, staged_at = self._staged.get(timeout=0.5)
             except queue_mod.Empty:
                 continue
-            self._device_busy = True
-            t0 = time.perf_counter()
-            # per-caller queue wait recorded retroactively into EACH
-            # batched request's trace; the device span attaches to the
-            # batch leader's (the QueryBatcher convention)
             reqs = []
             seen_req_ids = set()
             for item in items:
                 if id(item.req) not in seen_req_ids:
                     seen_req_ids.add(id(item.req))
                     reqs.append(item.req)
-            for req in reqs:
-                # once per REQUEST: a request split across several fused
-                # batches must not re-record queue wait spanning earlier
-                # batches' device compute
-                if req.ctx is not None and not req.queue_wait_recorded:
-                    req.queue_wait_recorded = True
-                    _tracer.add_span("serving.queue_wait", req.enqueued,
-                                     t0, parent=req.ctx)
+            # the batch span attaches to the batch leader's trace (the
+            # QueryBatcher convention)
             leader_ctx = next(
                 (r.ctx for r in reqs if r.ctx is not None), None)
+            batch = _tracer.stage("serving.batch", self.stats,
+                                  "device_seconds", {"texts": len(items)})
             try:
-                with _tracer.attach(leader_ctx), _tracer.span(
-                    "serving.batch", {"texts": len(items)}
-                ):
+                with _tracer.attach(leader_ctx), batch:
+                    self._batch_open = batch.start
                     if pack is not None:
                         emb = self.inner.embed_packed(pack)
                         vecs = unpack_results(pack, emb)
@@ -529,20 +574,27 @@ class ServingEngine(Embedder):
                             [i.text for i in items]
                         )
             except Exception as e:
-                self._device_busy = False
                 for item in items:
                     self._fail(item.req, e)
                 continue
-            self._device_busy = False
-            dt = time.perf_counter() - t0
+            finally:
+                self._batch_closed.append(
+                    (batch.start, batch.start + batch.seconds))
+                self._batch_open = None
+            # the pack waited from the end of its staging until this
+            # batch began: once on the counter, and in every caller's trace
+            for n, req in enumerate(reqs):
+                _tracer.add_stage(
+                    "serving.staged_wait", staged_at, batch.start,
+                    self.stats if n == 0 else None, "staged_wait_seconds",
+                    parent=req.ctx)
             # the embed path joins the deviceprof ledger (and with it
             # the cost model) keyed by packed-token pow2 class
             tokens = (pack.tokens if pack is not None
                       else sum(i.est_tokens for i in items))
             _deviceprof.record_execute(
                 "serving", "embed",
-                _deviceprof.pow2_class(max(tokens, 1), "t"), dt)
-            self.stats.device_seconds += dt
+                _deviceprof.pow2_class(max(tokens, 1), "t"), batch.seconds)
             self.stats.batches += 1
             self.stats.texts += len(items)
             _stats.BATCHES.inc()
@@ -553,11 +605,13 @@ class ServingEngine(Embedder):
                 self.stats.padded_tokens += r * c
                 _stats.PACKED_TOKENS_HIST.observe(pack.tokens)
                 _stats.PACK_EFFICIENCY_HIST.observe(pack.efficiency)
+            done_at = batch.start + batch.seconds
             for item, vec in zip(items, vecs):
                 req = item.req
                 req.results[item.idx] = vec
                 req.remaining -= 1
                 if req.remaining <= 0 and not req.shed:
+                    req.done_at = done_at
                     req.event.set()
 
     # -- observability -----------------------------------------------------

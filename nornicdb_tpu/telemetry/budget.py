@@ -52,6 +52,17 @@ SPAN_STAGE_MAP = {
     "search.vector": "device_sync",
     "device.sync": "device_sync",
     "search.rank": "host_merge",
+    # stage spans of the two served paths (docs/observability.md "Stage
+    # spans").  Not mapped, so that no interval counts twice in a stage's
+    # actual: embed.dispatch / embed.fetch (inside serving.batch),
+    # corpus.dispatch / corpus.fetch / corpus.format (inside search.vector
+    # or search.batch), embed.cache (contains the serving.* stages) and
+    # embedq.* (the embed worker has no request deadline).
+    "http.parse": "tokenize_pack",
+    "serving.stage": "tokenize_pack",
+    "serving.staged_wait": "admission_queue",
+    "serving.wake": "host_merge",
+    "http.respond": "host_merge",
 }
 
 _MAX_ENTRIES = 512

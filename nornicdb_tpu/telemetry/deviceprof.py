@@ -8,13 +8,16 @@ and HBM residency (the number every capacity decision in ROADMAP items
 1/3 hinges on) had no surface at all.  This module unifies them:
 
 - **Program registry** keyed ``(subsystem, kind, shape)``: every device
-  dispatch records its execute time; the first execute of a new key also
+  dispatch records its host-observed dispatch-to-result seconds (wall
+  time around the call and its blocking read-back; not device time,
+  which only a profiler capture has, and the first call of a shape
+  includes its compile); the first execute of a new key also
   counts as a compile (the ledger semantics genserve already proved —
   jitted programs compile once per static shape per process), and
   warmup paths may pre-register keys with :func:`record_compile`.
   Exposed as ``nornicdb_device_programs_total`` (distinct-program
-  compile counter) and ``nornicdb_device_program_seconds`` (execute-time
-  histogram), both labeled ``(subsystem, kind, shape)`` — callers are
+  compile counter) and ``nornicdb_device_program_seconds`` (histogram of
+  those host-observed seconds), both labeled ``(subsystem, kind, shape)`` — callers are
   responsible for bounded shape classes (everything device-side is
   already pow2-bucketed).
 - **HBM residency** ``nornicdb_hbm_bytes{component}``: components
@@ -73,7 +76,9 @@ _PROGRAMS = _REGISTRY.counter(
 )
 _EXEC_HIST = _REGISTRY.histogram(
     "nornicdb_device_program_seconds",
-    "Device program execute time by (subsystem, kind, shape)",
+    "Host-observed dispatch-to-result seconds of a device program by "
+    "(subsystem, kind, shape); the first call of a shape includes its "
+    "compile",
     labels=("subsystem", "kind", "shape"),
 )
 _PROFILE_CAPTURES = _REGISTRY.counter(
@@ -187,8 +192,10 @@ class DeviceProfiler:
 
     def record_execute(self, subsystem: str, kind: str, shape,
                        seconds: float) -> None:
-        """One device dispatch: execute-time histogram + first-seen
-        compile count."""
+        """One device dispatch: ``seconds`` are host-observed
+        dispatch-to-result seconds (a new key's first call includes its
+        compile), observed in the histogram, plus the first-seen compile
+        count."""
         key = (subsystem, kind, str(shape))
         with self._lock:
             entry = self._programs.get(key)

@@ -14,9 +14,22 @@ on the context, or the trace was not sampled, ``tracer.span()`` performs
 ONE contextvar read and returns a shared no-op handle — no allocation, no
 locking, no formatting.
 
+``tracer.stage(name, stats, field)`` is the one timing of a layer
+boundary: it reads ``perf_counter`` once on entry and once on exit and
+hands that single duration to (1) the layer's own cumulative counter,
+always, traced or not; (2) a child span of the active trace, when there
+is one; (3) a ``jax.profiler.TraceAnnotation`` named ``nornic.<name>``
+carrying ``trace_id`` / ``span_id`` / ``duration_ms``, when a profiler
+capture is running, so the host interval lands on the device trace's own
+clock and joins the ring's span by id.  ``add_stage`` is the retroactive
+form for intervals known only afterwards.  JAX is never imported from
+here: the annotation class is taken from ``sys.modules``.
+
 Completed traces land in a bounded ring buffer (``deque(maxlen=...)``,
 whose appends are atomic under the GIL — no lock held while recording)
-served at ``/admin/traces`` and ``/admin/traces/<id>``.  Span lists are
+served at ``/admin/traces`` and ``/admin/traces/<id>``; a root at or over
+the slow-query threshold is also kept in a second, smaller ring
+(``/admin/traces?slow=1``) that faster traffic cannot evict.  Span lists are
 plain lists appended in finish order; ``list.append`` is atomic, so a
 worker thread finishing a span never blocks an ingress thread.  A span
 finishing after its root closed still lands in the (already ringed)
@@ -29,10 +42,12 @@ import contextvars
 import os
 import random
 import re
+import sys
 import time
-import uuid
 from collections import deque
 from typing import Any, Optional
+
+from nornicdb_tpu.telemetry.slowlog import slow_log
 
 _TRACEPARENT_RE = re.compile(
     r"^([0-9a-f]{2})-([0-9a-f]{32})-([0-9a-f]{16})-([0-9a-f]{2})$"
@@ -40,6 +55,10 @@ _TRACEPARENT_RE = re.compile(
 
 # spans recorded per trace before further spans are counted-but-dropped
 MAX_SPANS_PER_TRACE = 512
+# slow roots kept beside the main ring (see Tracer._finish)
+SLOW_RING_CAPACITY = 64
+# profiler-trace name prefix of every stage annotation
+ANNOTATION_PREFIX = "nornic."
 
 
 def parse_traceparent(header: str) -> Optional[tuple[str, str, bool]]:
@@ -60,12 +79,15 @@ def format_traceparent(trace_id: str, span_id: str, sampled: bool = True) -> str
     return f"00-{trace_id}-{span_id}-{'01' if sampled else '00'}"
 
 
+# ids need to be unique, not unguessable: the Mersenne Twister costs no
+# system call (os.urandom / uuid4 do, once per span and per trace, and a
+# request of the embed path records a dozen spans)
 def _new_trace_id() -> str:
-    return uuid.uuid4().hex
+    return "%032x" % (random.getrandbits(128) or 1)
 
 
 def _new_span_id() -> str:
-    return os.urandom(8).hex()
+    return "%016x" % (random.getrandbits(64) or 1)
 
 
 class _Trace:
@@ -151,13 +173,21 @@ class Span:
         self.attrs[key] = value
 
     def __enter__(self) -> "Span":
-        self._start_wall = time.time()
-        self._t0 = time.perf_counter()
-        self._token = self._tracer._var.set(self)
+        self._begin(time.perf_counter())
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        duration = time.perf_counter() - self._t0
+        self._end(time.perf_counter() - self._t0, exc)
+        return False
+
+    def _begin(self, t0: float) -> None:
+        """Open at perf_counter reading ``t0`` (a stage hands its own)."""
+        self._start_wall = time.time()
+        self._t0 = t0
+        self._token = self._tracer._var.set(self)
+
+    def _end(self, duration: float, exc=None) -> None:
+        """Close with a duration measured by the caller."""
         if self._token is not None:
             self._tracer._var.reset(self._token)
             self._token = None
@@ -177,6 +207,82 @@ class Span:
         self.trace.record(rec)
         if self._is_root:
             self._tracer._finish(self.trace, self.name, duration)
+
+
+def _feed(stats, field: Optional[str], seconds: float) -> None:
+    """Add one stage's seconds to the layer's own counter: a key of a flat
+    stats dict, a field of a stats object, or (``field`` None) one
+    observation of a histogram cell."""
+    if stats is None:
+        return
+    if field is None:
+        stats.observe(seconds)
+    elif type(stats) is dict:
+        stats[field] += seconds
+    else:
+        setattr(stats, field, getattr(stats, field) + seconds)
+
+
+class Stage:
+    """One timing of a layer boundary, with three readers (module doc).
+
+    Untraced and with no profiler capture running it costs the two
+    ``perf_counter`` calls, one contextvar read, one ``is_enabled`` call
+    and the counter's add: no span, no id, no lock.  After exit ``start``
+    (the perf_counter reading on entry) and ``seconds`` let the site
+    derive neighbouring intervals from this one timing."""
+
+    __slots__ = ("_tracer", "_name", "_stats", "_field", "_attrs",
+                 "_span", "_ann", "start", "seconds")
+
+    def __init__(self, tracer: "Tracer", name: str, stats, field, attrs):
+        self._tracer = tracer
+        self._name = name
+        self._stats = stats
+        self._field = field
+        self._attrs = attrs
+        self._span: Optional[Span] = None
+        self._ann = None
+        self.start = 0.0
+        self.seconds = 0.0
+
+    @property
+    def span_id(self) -> Optional[str]:
+        return None if self._span is None else self._span.span_id
+
+    def set_attr(self, key: str, value: Any) -> None:
+        if self._span is not None:
+            self._span.set_attr(key, value)
+
+    def __enter__(self) -> "Stage":
+        tracer = self._tracer
+        cur = tracer._var.get()
+        span = None
+        if cur is not None:
+            span = self._span = Span(tracer, cur.trace, self._name,
+                                     cur.span_id, is_root=False,
+                                     attrs=self._attrs)
+        ann_cls = tracer._capturing()
+        if ann_cls is not None:
+            ann = self._ann = (
+                ann_cls(ANNOTATION_PREFIX + self._name) if span is None
+                else ann_cls(ANNOTATION_PREFIX + self._name,
+                             trace_id=span.trace.trace_id,
+                             span_id=span.span_id))
+            ann.__enter__()
+        self.start = time.perf_counter()
+        if span is not None:
+            span._begin(self.start)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.seconds = seconds = time.perf_counter() - self.start
+        _feed(self._stats, self._field, seconds)
+        if self._span is not None:
+            self._span._end(seconds, exc)
+        if self._ann is not None:
+            self._ann.set_metadata(duration_ms=seconds * 1e3)
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -215,6 +321,11 @@ class Tracer:
         except ValueError:
             self.sample_rate = 1.0
         self._ring: deque[dict[str, Any]] = deque(maxlen=capacity)
+        self._slow_ring: deque[dict[str, Any]] = deque(
+            maxlen=SLOW_RING_CAPACITY)
+        # jax.profiler.TraceAnnotation, once JAX has been imported by
+        # someone else (never from here)
+        self._annotation_cls = None
         self._var: contextvars.ContextVar[Optional[Span]] = (
             contextvars.ContextVar("nornicdb_trace_span", default=None)
         )
@@ -262,19 +373,64 @@ class Tracer:
         return Span(self, cur.trace, name, cur.span_id, is_root=False,
                     attrs=attrs)
 
+    def stage(self, name: str, stats=None, field: Optional[str] = None,
+              attrs: Optional[dict] = None) -> Stage:
+        """Time one layer boundary (see :class:`Stage`).  ``stats`` /
+        ``field`` name the layer's cumulative seconds counter (a dict key
+        or an attribute); ``field`` None observes ``stats`` as a
+        histogram cell; ``stats`` None feeds span and annotation only."""
+        return Stage(self, name, stats, field, attrs)
+
+    def add_stage(self, name: str, start_perf: float, end_perf: float,
+                  stats=None, field: Optional[str] = None,
+                  attrs: Optional[dict] = None,
+                  parent: Optional[Span] = None) -> None:
+        """Retroactive :meth:`stage` for an interval whose ends are
+        perf_counter readings other stages already took.  The profiler
+        annotation is instantaneous, written when this is called, and
+        says ``retro=1``: the interval is the ``duration_ms`` that ended
+        at ``age_ms`` before the annotation."""
+        seconds = end_perf - start_perf
+        _feed(stats, field, seconds)
+        cur = parent if parent is not None else self._var.get()
+        span_id = self.add_span(name, start_perf, end_perf, attrs, cur)
+        ann_cls = self._capturing()
+        if ann_cls is not None:
+            meta = {"retro": 1, "duration_ms": seconds * 1e3,
+                    "age_ms": (time.perf_counter() - end_perf) * 1e3}
+            if span_id is not None:
+                meta.update(trace_id=cur.trace.trace_id, span_id=span_id)
+            with ann_cls(ANNOTATION_PREFIX + name, **meta):
+                pass
+
+    def _capturing(self):
+        """``jax.profiler.TraceAnnotation`` while a profiler capture is
+        running, else None.  One ``is_enabled()`` call (tens of ns) once
+        JAX is imported; a dict lookup before that."""
+        cls = self._annotation_cls
+        if cls is None:
+            profiler = getattr(sys.modules.get("jax"), "profiler", None)
+            cls = getattr(profiler, "TraceAnnotation", None)
+            if cls is None or not hasattr(cls, "is_enabled"):
+                return None
+            self._annotation_cls = cls
+        return cls if cls.is_enabled() else None
+
     def add_span(self, name: str, start_perf: float, end_perf: float,
                  attrs: Optional[dict] = None,
-                 parent: Optional[Span] = None) -> None:
+                 parent: Optional[Span] = None) -> Optional[str]:
         """Retroactively record a completed span (measured with
         perf_counter timestamps) under ``parent`` or the active span —
         used where the timing is known only after the fact (per-caller
-        queue wait inside a shared batch)."""
+        queue wait inside a shared batch).  Returns the new span's id,
+        None when nothing was recorded."""
         cur = parent if parent is not None else self._var.get()
         if cur is None or isinstance(cur, _NoopSpan):
-            return
+            return None
+        span_id = _new_span_id()
         rec = {
             "name": name,
-            "span_id": _new_span_id(),
+            "span_id": span_id,
             "parent_id": cur.span_id,
             # display WALL timestamp back-derived from the perf offset; the
             # duration itself is pure perf_counter arithmetic
@@ -285,6 +441,7 @@ class Tracer:
         if attrs:
             rec["attrs"] = dict(attrs)
         cur.trace.record(rec)
+        return span_id
 
     # -- context plumbing --------------------------------------------------
     def capture(self) -> Optional[Span]:
@@ -362,7 +519,7 @@ class Tracer:
 
     # -- ring buffer -------------------------------------------------------
     def _finish(self, trace: _Trace, root_name: str, duration: float) -> None:
-        self._ring.append({
+        entry = {
             "trace_id": trace.trace_id,
             "root": root_name,
             "started": trace.started_wall,
@@ -370,14 +527,30 @@ class Tracer:
             "spans": trace.spans,
             "dropped_spans": trace.dropped_spans,
             "remote_parent": trace.remote_parent,
-        })
+        }
+        self._ring.append(entry)
+        # a slow root outlives the main ring (1.6 s of traffic at 160
+        # requests/s): the same entry, so late spans show in both.  The
+        # threshold is the slow-query log's (slow_query_ms; 0 disables).
+        if 0.0 < slow_log.threshold_s <= duration:
+            self._slow_ring.append(entry)
 
     def count(self) -> int:
         return len(self._ring)
 
-    def traces(self, limit: int = 100) -> list[dict[str, Any]]:
-        """Newest-first summaries for /admin/traces."""
-        entries = list(self._ring)[-limit:][::-1]
+    def _entries(self) -> list[dict[str, Any]]:
+        """Both rings, oldest first, each entry once (snapshot: iterating
+        a live deque races root-span finishes)."""
+        recent = list(self._ring)
+        held = {id(t) for t in recent}
+        return [t for t in list(self._slow_ring)
+                if id(t) not in held] + recent
+
+    def traces(self, limit: int = 100,
+               slow: bool = False) -> list[dict[str, Any]]:
+        """Newest-first summaries for /admin/traces (``slow``: the slow
+        ring only, /admin/traces?slow=1)."""
+        entries = list(self._slow_ring if slow else self._ring)[-limit:][::-1]
         return [
             {
                 "trace_id": t["trace_id"],
@@ -402,7 +575,7 @@ class Tracer:
         single-entry behavior."""
         # snapshot first: iterating the live deque would raise if another
         # thread's root span finishes (ring append) mid-scan
-        matches = [t for t in list(self._ring)
+        matches = [t for t in self._entries()
                    if t["trace_id"] == trace_id]
         if not matches:
             return None
@@ -447,6 +620,7 @@ class Tracer:
 
     def clear(self) -> None:
         self._ring.clear()
+        self._slow_ring.clear()
 
 
 tracer = Tracer()

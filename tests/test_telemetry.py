@@ -146,6 +146,14 @@ class TestTracing:
         with s1 as s:
             s.set_attr("k", "v")  # must not blow up
         assert tracer.count() == 0
+        # a stage on the same untraced context still moves its layer's
+        # counter, and builds no span (no id, nothing recorded)
+        stats = {"seconds": 0.0}
+        with tracer.stage("c", stats, "seconds") as st:
+            st.set_attr("k", "v")  # must not blow up either
+        assert stats["seconds"] == st.seconds > 0.0
+        assert st.span_id is None
+        assert tracer.count() == 0
 
     def test_traceparent_roundtrip(self):
         tp = format_traceparent("ab" * 16, "cd" * 8)
@@ -734,6 +742,34 @@ class TestOverheadMicrobench:
         print(f"untraced span overhead: {ratio:.2f}x "
               f"({base * 1e9 / self.N:.0f}ns -> {instr * 1e9 / self.N:.0f}ns/op)")
         assert ratio < 8.0, f"no-trace span path too slow: {ratio:.2f}x"
+
+    def test_untraced_stage_overhead_bounded(self):
+        """A stage always times and feeds its counter, so it costs more
+        than the no-op span: two perf_counter calls, one handle, one
+        ``is_enabled`` call, one add.  Stated bound: under 2.5 us a stage
+        and under 25x the bare work (measured here: ~1.1 us, ~9x)."""
+        state: dict = {}
+        stats = {"seconds": 0.0}
+        work = self._work
+
+        def baseline():
+            for i in range(self.N):
+                work(state, i)
+
+        def instrumented():
+            for i in range(self.N):
+                with tracer.stage("bench.op", stats, "seconds"):
+                    work(state, i)
+
+        assert tracer.capture() is None  # no active trace on this context
+        base = self._bench(baseline)
+        instr = self._bench(instrumented)
+        per_stage_us = (instr - base) / self.N * 1e6
+        print(f"untraced stage overhead: {instr / base:.2f}x "
+              f"(+{per_stage_us:.2f} us a stage)")
+        assert stats["seconds"] > 0.0
+        assert per_stage_us < 2.5, f"untraced stage: {per_stage_us:.2f} us"
+        assert instr / base < 25.0
 
     def test_disabled_tracer_overhead_bounded(self):
         state: dict = {}
