@@ -296,9 +296,8 @@ class SearchTuningConfig:
     # in f32 from the host mirror (oversampled rescore_factor × k)
     int8_residency: bool = False
     rescore_factor: int = 4
-    # micro-batching + write-behind sync (PR 2)
-    batching_enabled: bool = False
-    batch_window: float = 0.002
+    # most queries one corpus scan serves (every vector search shares the
+    # coalescing dispatcher) + write-behind sync (PR 2)
     batch_max: int = 256
     # batched-search admission: pending queries beyond batch_max_queue
     # shed with 429/RESOURCE_EXHAUSTED (0 = unbounded); queries older

@@ -37,7 +37,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nornicdb_tpu.ops.similarity import LANE, dot_scores, l2_normalize
+from nornicdb_tpu.ops.similarity import (
+    LANE,
+    dot_scores,
+    l2_normalize,
+    pad_query_block,
+)
 
 
 @dataclass
@@ -188,13 +193,11 @@ def ivf_search(
     identical in kind to the full-scan path."""
     q2 = np.atleast_2d(np.asarray(queries, np.float32))
     b = q2.shape[0]
-    # bucket B and k to powers of two so the jit caches a handful of
-    # shape classes instead of recompiling per client-supplied batch/limit
-    # (same rationale as the fallback path's candidate buckets)
-    b_pad = _next_pow2(b)
-    if b_pad != b:
-        q2 = np.concatenate([q2, np.zeros((b_pad - b, q2.shape[1]),
-                                          np.float32)])
+    # bucket B (the dense scan's query classes) and k (powers of two) so
+    # the jit caches a handful of shape classes instead of recompiling per
+    # client-supplied batch/limit (same rationale as the fallback path's
+    # candidate buckets)
+    q2 = pad_query_block(q2)
     k_prog = _next_pow2(max(k, 8))
     qn = l2_normalize(jnp.asarray(q2))
     n_probe = max(1, min(n_probe, layout.k))

@@ -25,7 +25,8 @@ import os
 import threading
 import time
 from dataclasses import asdict, dataclass
-from typing import Optional, Sequence
+from collections.abc import Sequence
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +47,88 @@ LANE = 128  # TPU lane width; min tile second dim
 
 def pad_to_multiple(n: int, m: int = LANE) -> int:
     return ((n + m - 1) // m) * m
+
+
+# -- query-block shape classes ------------------------------------------------
+# Every top-k program is jitted per (Q, k): a block of B queries is scanned
+# as a block of query_class(B) rows, zero rows making up the difference, so
+# that the batch sizes a coalescing dispatcher produces (1..batch_max) meet
+# a bounded grid of programs.  The smallest class is 8: a (1, 1024) f32 block
+# already occupies eight sublanes, so Q = 1..8 cost one scan alike.
+QUERY_CLASS_MIN = 8
+
+
+def query_class(b: int) -> int:
+    """Rows of the block that ``b`` queries are scanned as."""
+    return max(QUERY_CLASS_MIN, 1 << max(0, int(b) - 1).bit_length())
+
+
+def query_classes(max_batch: int) -> tuple[int, ...]:
+    """The grid: every class a block of 1..max_batch queries can map to."""
+    classes = [QUERY_CLASS_MIN]
+    while classes[-1] < max_batch:
+        classes.append(2 * classes[-1])
+    return tuple(classes)
+
+
+def pad_query_block(q: np.ndarray) -> np.ndarray:
+    """``q`` (B, D) with zero rows appended up to its class.  The padding
+    rows are scanned with the rest; their top-k is sliced off before any
+    row is resolved to ids."""
+    b = q.shape[0]
+    cls = query_class(b)
+    if cls == b:
+        return q
+    out = np.zeros((cls, q.shape[1]), q.dtype)
+    out[:b] = q
+    return out
+
+
+class LazyRows(Sequence):
+    """``search(..., defer=True)``: the rows of an eager answer with the
+    host's share of the work left undone.  ``fetch()`` blocks until the
+    scan's top-k is on the host (once, whoever calls it first; the program
+    was launched before this object was returned) and indexing resolves one
+    row to ``[(id, score)]`` on the thread that indexes it.  A dispatcher
+    that fans one scan out to many callers so can launch the next scan
+    before anything of this one is read back or formatted, and a row nobody
+    asks for is never formatted.  ``padded_rows`` says how many rows of the
+    scanned block were padding."""
+
+    def __init__(self, fetch, n: int, padded_rows: int = 0):
+        self._fetch = fetch  # () -> (row index -> [(id, score)])
+        self._row = None
+        self._error: Optional[BaseException] = None
+        self._lock = threading.Lock()
+        self._n = n
+        self.padded_rows = padded_rows
+
+    def fetch(self) -> "LazyRows":
+        if self._row is None:
+            with self._lock:
+                if self._error is not None:
+                    raise self._error
+                if self._row is None:
+                    try:
+                        self._row = self._fetch()
+                    except BaseException as e:
+                        self._error = e
+                        raise
+                    finally:
+                        self._fetch = None  # drops what the closure pins
+        return self
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return [self[j] for j in range(*i.indices(self._n))]
+        if i < 0:
+            i += self._n
+        if not 0 <= i < self._n:
+            raise IndexError(i)
+        return self.fetch()._row(i)
 
 
 @jax.jit
@@ -567,6 +650,11 @@ class HostCorpus:
         self._uploader_stop = threading.Event()
         self._uploader_wake = threading.Event()
         self._uploader_interval = 0.002
+        # (capacity, k, grid, options) whose query-class programs were
+        # compiled (warm_query_classes); the lock makes concurrent first
+        # sights of one key compile once
+        self._warm_classes: set[tuple] = set()
+        self._warm_lock = threading.Lock()
 
     def __len__(self) -> int:
         return len(self._slot_of)
@@ -882,8 +970,9 @@ class HostCorpus:
         self._wake_uploader()
 
     def _search_host(
-        self, q: np.ndarray, k: int, min_similarity: float
-    ) -> list[list[tuple[str, float]]]:
+        self, q: np.ndarray, k: int, min_similarity: float,
+        defer: bool = False,
+    ) -> Sequence[list[tuple[str, float]]]:
         """DEGRADED_CPU serving: exact NumPy top-k over the host arrays.
 
         Scoring holds _sync_lock: writers mutate _host rows IN PLACE, and
@@ -893,11 +982,12 @@ class HostCorpus:
         Writers briefly queue behind a degraded-mode scan — correctness
         over throughput while the accelerator is down."""
         self._backend_mgr().note_fallback("search")
-        return self._host_exact_topk(q, k, min_similarity)
+        return self._host_exact_topk(q, k, min_similarity, defer)
 
     def _host_exact_topk(
-        self, q: np.ndarray, k: int, min_similarity: float
-    ) -> list[list[tuple[str, float]]]:
+        self, q: np.ndarray, k: int, min_similarity: float,
+        defer: bool = False,
+    ) -> Sequence[list[tuple[str, float]]]:
         """Exact f32 top-k over the host arrays — the scoring core of
         ``_search_host``, reusable without the degraded-fallback accounting
         (the int8-resident corpus serves its ``exact=True`` contract here:
@@ -912,7 +1002,7 @@ class HostCorpus:
             )
             ids = self._ids
         return self._format_results(
-            vals, idx, q.shape[0], k, min_similarity, ids=ids,
+            vals, idx, q.shape[0], k, min_similarity, ids=ids, defer=defer,
         )
 
     # -- device sync engine ------------------------------------------------
@@ -1095,19 +1185,81 @@ class HostCorpus:
         k: int,
         min_similarity: float,
         ids: Optional[list[Optional[str]]] = None,
-    ) -> list[list[tuple[str, float]]]:
+        defer: bool = False,
+        padded_rows: int = 0,
+    ) -> Sequence[list[tuple[str, float]]]:
         """Resolve slot indices to ids. `ids` must be the slot map captured
         with the buffer the indices came from (_borrow_device) — resolving
         against live self._ids would misattribute results if a background
         compaction remapped the slot space mid-search. Delegates to the
         shared epilogue (ops.host_search.format_topk_results) so the
-        cross-process read plane resolves identically by construction."""
+        cross-process read plane resolves identically by construction.
+        ``defer`` answers a LazyRows over the same arrays and id snapshot:
+        each row is resolved (one ``corpus.format`` stage) when it is
+        asked for."""
         ids = self._ids if ids is None else ids
+        if defer:
+            row = self._row_resolver(vals, idx, k, min_similarity, ids)
+            return LazyRows(lambda: row, n_queries, padded_rows)
         with _tracer.stage("corpus.format", self.sync_stats,
                            "search_format_seconds"):
             return format_topk_results(
                 vals, idx, n_queries, k, min_similarity, ids
             )
+
+    def _row_resolver(self, vals, idx, k, min_similarity, ids):
+        """Row index -> that row's ``[(id, score)]``, formatted when asked
+        for (one ``corpus.format`` stage a row): what a LazyRows indexes."""
+        return lambda i: self._format_results(
+            vals[i:i + 1], idx[i:i + 1], 1, k, min_similarity, ids,
+        )[0]
+
+    def warm_query_classes(self, k: int, max_batch: int, **options) -> None:
+        """Compile this ``k``'s program for every query class up to
+        ``max_batch``, the first time ``k`` is seen at this capacity and
+        with these search ``options``; a set lookup after that.  The first
+        query of a ``k`` pays a compile anyway: it pays for the grid
+        (classes side by side, one scan of zero queries each), so that no
+        later batch size meets a new program on a request's path.  While
+        the backend serves from the host there is nothing to compile."""
+        key = (self.capacity, k, max_batch, tuple(sorted(options.items())))
+        if key in self._warm_classes or len(self._slot_of) == 0:
+            return
+        if not self._device_gate():
+            return
+        with self._warm_lock:
+            if key in self._warm_classes:
+                return
+
+            ctx = _tracer.capture()  # the grid is this request's cost
+
+            def scan(cls: int) -> None:
+                try:
+                    with _tracer.attach(ctx):
+                        rows = self.search(
+                            np.zeros((cls, self.dims), np.float32), k,
+                            defer=True, **options)
+                        if isinstance(rows, LazyRows):
+                            rows.fetch()  # the program ran to its end
+                except Exception:
+                    # the class's own first batch will raise it to a caller
+                    logger.warning("query class %d (k=%d) failed to warm",
+                                   cls, k, exc_info=True)
+
+            threads = [
+                threading.Thread(target=scan, args=(cls,),
+                                 name="nornicdb-class-warm", daemon=True)
+                for cls in query_classes(max_batch)
+            ]
+            for t in threads:
+                t.start()
+            # NL-LK02 suppression: _warm_lock exists for this wait (a second
+            # first sight of the key waits for the one compile); it is a
+            # leaf, taken with no other lock held, and the scans take none
+            # of their caller's
+            for t in threads:
+                t.join()  # nornlint: disable=NL-LK02
+            self._warm_classes.add(key)
 
 
 class DeviceCorpus(HostCorpus):
@@ -1554,7 +1706,8 @@ class DeviceCorpus(HostCorpus):
         exact: bool = False,
         n_probe: int = 0,
         streaming=None,
-    ) -> list[list[tuple[str, float]]]:
+        defer: bool = False,
+    ) -> Sequence[list[tuple[str, float]]]:
         """Brute-force cosine top-k. Returned scores are exact; with the
         default exact=False, candidate membership uses the TPU-native
         approx_max_k or (on TPU at scale, the default serving path) the
@@ -1564,7 +1717,9 @@ class DeviceCorpus(HostCorpus):
         only the n_probe nearest clusters are scored (IVF pruning,
         ref: SearchWithClusters kmeans.go:816). Returns per-query
         [(id, score)] filtered by min_similarity (ref: Search gpu.go:1519,
-        MinSimilarity semantics search.go:157-205)."""
+        MinSimilarity semantics search.go:157-205).  The block is scanned at
+        its query class (pad_query_block); ``defer`` leaves each row's id
+        resolution to whoever indexes it (LazyRows)."""
         if len(self._slot_of) == 0:
             return [[] for _ in range(np.atleast_2d(queries).shape[0])]
         q = np.atleast_2d(np.asarray(queries, np.float32))
@@ -1572,9 +1727,10 @@ class DeviceCorpus(HostCorpus):
         # the manager's worker thread (bounded by the config timeout), a
         # degraded one routes this search to the exact host path
         if not self._device_gate():
-            return self._search_host(q, k, min_similarity)
+            return self._search_host(q, k, min_similarity, defer)
         from nornicdb_tpu.telemetry import deviceprof as _deviceprof
 
+        b = q.shape[0]
         try:
             if n_probe > 0:
                 t0 = time.perf_counter()
@@ -1599,28 +1755,54 @@ class DeviceCorpus(HostCorpus):
                     corpus, valid, dev_i8, ids, _ = borrowed.enter_context(
                         self._borrow_device())
                     kk = min(k, self.capacity)
+                    block = pad_query_block(q)
+                    if self.dtype != jnp.float32:
+                        block = jnp.asarray(block, dtype=self.dtype)
+                    # an f32 block goes to the jitted normalize as it is:
+                    # the upload rides that call instead of one of its own
                     vals, idx = topk_backend(
-                        l2_normalize(jnp.asarray(q, dtype=self.dtype)),
+                        l2_normalize(block),
                         corpus, valid, kk, exact=exact, streaming=streaming,
                         quantized=dev_i8 if self.quantize else None,
                     )
-                # materialize INSIDE the borrow: the computation must
-                # finish before the patcher may donate the buffer it reads
-                with _tracer.stage("corpus.fetch", stats,
-                                   "search_fetch_seconds") as fetch:
-                    vals_np = np.asarray(vals, np.float32)
-                    idx_np = np.asarray(idx)
-            stats.device_dispatches += 1
-            _deviceprof.record_execute(
-                "search", "dense", _deviceprof.pow2_class(q.shape[0], "b"),
-                dispatch.seconds + fetch.seconds,
-            )
+                # launched: the borrow now ends with the read-back, which a
+                # deferred answer leaves to whoever fetches it
+                held = borrowed.pop_all()
         except DeviceUnavailable:
             # degraded between the gate and the borrow
-            return self._search_host(q, k, min_similarity)
-        return self._format_results(
-            vals_np, idx_np, q.shape[0], k, min_similarity, ids=ids,
-        )
+            return self._search_host(q, k, min_similarity, defer)
+
+        def read_back() -> tuple[np.ndarray, np.ndarray]:
+            # materialize INSIDE the borrow: the computation must finish
+            # before the patcher may donate the buffer it reads
+            with held, _tracer.stage("corpus.fetch", stats,
+                                     "search_fetch_seconds") as fetched:
+                vals_np = np.asarray(vals, np.float32)[:b]
+                idx_np = np.asarray(idx)[:b]
+            stats.device_dispatches += 1
+            _deviceprof.record_execute(
+                "search", "dense", f"b{query_class(b)}",
+                dispatch.seconds + fetched.seconds,
+            )
+            return vals_np, idx_np
+
+        if not defer:
+            try:
+                vals_np, idx_np = read_back()
+            except DeviceUnavailable:
+                return self._search_host(q, k, min_similarity)
+            return self._format_results(
+                vals_np, idx_np, b, k, min_similarity, ids=ids,
+            )
+
+        def fetch():
+            try:
+                vals_np, idx_np = read_back()
+            except DeviceUnavailable:
+                return self._search_host(q, k, min_similarity).__getitem__
+            return self._row_resolver(vals_np, idx_np, k, min_similarity, ids)
+
+        return LazyRows(fetch, b, query_class(b) - b)
 
     def score_subset(
         self, query: np.ndarray, ids: list[str]

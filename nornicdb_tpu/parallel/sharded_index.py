@@ -53,6 +53,7 @@ import functools
 import logging
 import threading
 import time
+from collections.abc import Sequence
 from dataclasses import asdict, dataclass, field
 from typing import Optional
 
@@ -80,6 +81,8 @@ from nornicdb_tpu.ops.similarity import (
     dot_scores,
     l2_normalize,
     merge_topk,
+    pad_query_block,
+    query_class,
     topk_backend,
     topk_backend_int8,
 )
@@ -829,12 +832,7 @@ class ShardedCorpus(HostCorpus):
         if not layout_ok:
             return None
         b = q.shape[0]
-        b_pad = _next_pow2(b)
-        q2 = q
-        if b_pad != b:
-            q2 = np.concatenate(
-                [q, np.zeros((b_pad - b, q.shape[1]), np.float32)]
-            )
+        q2 = pad_query_block(q)
         quantized = layout.quantized
         k_dev = k * self.rescore_factor if quantized else k
         k_prog = _next_pow2(max(k_dev, local_k, 8))
@@ -890,12 +888,7 @@ class ShardedCorpus(HostCorpus):
         every returned id; only candidate MEMBERSHIP carries int8 noise,
         which the oversample is sized to absorb."""
         b = q.shape[0]
-        b_pad = _next_pow2(b)
-        q2 = q
-        if b_pad != b:
-            q2 = np.concatenate(
-                [q, np.zeros((b_pad - b, q.shape[1]), np.float32)]
-            )
+        q2 = pad_query_block(q)
         # inline borrow (the _pruned_search idiom): the host mirror must be
         # captured ATOMICALLY with the int8 buffers — a background
         # compaction rebinds self._host, and slots of the old buffer
@@ -957,7 +950,8 @@ class ShardedCorpus(HostCorpus):
         n_probe: int = 0,
         streaming=None,
         local_k: int = 0,
-    ) -> list[list[tuple[str, float]]]:
+        defer: bool = False,
+    ) -> Sequence[list[tuple[str, float]]]:
         """Sharded cosine top-k: per-shard GEMM + top-local_k, ICI
         all-gather merge — one device dispatch for the whole (possibly
         batched) query block.  Scores are exact; with the default
@@ -968,14 +962,16 @@ class ShardedCorpus(HostCorpus):
         fitted cluster index routes through the fused sharded IVF
         program instead.  quantized=True corpora select candidates from
         the int8 codes and exact-rescore the merged set from the host
-        f32 mirror (exact=True serves the host mirror directly)."""
+        f32 mirror (exact=True serves the host mirror directly).  ``defer``
+        as in DeviceCorpus.search: the full scan's rows are resolved to ids
+        by whoever indexes them."""
         q = np.atleast_2d(np.asarray(queries, np.float32))
         if len(self._slot_of) == 0:
             return [[] for _ in range(q.shape[0])]
         # same lifecycle gate as DeviceCorpus.search: cold acquisition on
         # the manager's worker thread, degraded -> exact host fallback
         if not self._device_gate():
-            return self._search_host(q, k, min_similarity)
+            return self._search_host(q, k, min_similarity, defer)
         try:
             if n_probe > 0:
                 pruned = self._pruned_search(
@@ -994,22 +990,18 @@ class ShardedCorpus(HostCorpus):
                     q, k, min_similarity, local_k, streaming
                 )
             b = q.shape[0]
-            # power-of-two shape classes for batch, k, and local_k: the
-            # program is shape-keyed jit over a collective, and the
-            # QueryBatcher hands us every coalesced batch size from
-            # 1..batch_max — without padding each one compiles a fresh
-            # XLA program on the serving hot path (same rationale and
-            # scheme as _pruned_search).  Padding lk upward only widens
+            # shape classes for batch (the query classes every corpus
+            # pads to), k and local_k (powers of two): the program is
+            # shape-keyed jit over a collective, and the QueryBatcher
+            # hands us every coalesced batch size from 1..batch_max —
+            # without padding each one compiles a fresh XLA program on
+            # the serving hot path (same rationale and scheme as
+            # _pruned_search).  Padding lk upward only widens
             # each shard's contribution, so exact mode stays lossless
             # (lk >= min(k, local_n) still holds) and approx recall can
             # only improve; padded query rows are zeros, sliced off the
             # result before formatting.
-            b_pad = _next_pow2(b)
-            q2 = q
-            if b_pad != b:
-                q2 = np.concatenate(
-                    [q, np.zeros((b_pad - b, q.shape[1]), np.float32)]
-                )
+            q2 = pad_query_block(q)
             with self._borrow_device() as (dev, dev_valid, _i8, ids, _):
                 # shard geometry comes from the BORROWED buffer, not self:
                 # _borrow_device's sync may have just grown/re-sharded the
@@ -1037,20 +1029,20 @@ class ShardedCorpus(HostCorpus):
                     idx_np = np.asarray(idx)[:b]
                 t1 = time.perf_counter()
         except DeviceUnavailable:
-            return self._search_host(q, k, min_similarity)
+            return self._search_host(q, k, min_similarity, defer)
         self.shard_stats.dispatches += 1
         self.shard_stats.last_dispatch_s = t1 - t0
         _SHARDED_SEARCH_HIST.observe(t1 - t0)
         _deviceprof.record_execute(
-            "search", "sharded", _deviceprof.pow2_class(b, "b"), t1 - t0)
+            "search", "sharded", f"b{query_class(b)}", t1 - t0)
         if not exact and lk < local_n:
             # detect saturation on the UNSLICED merged width: a shard
             # contributing all lk of its oversampled candidates is the
             # truncation signal, regardless of the caller's k
             self._note_local_k_overflows(idx_np, lk, local_n)
         out = self._format_results(
-            vals_np[:, :k], idx_np[:, :k], q.shape[0], k, min_similarity,
-            ids=ids,
+            vals_np[:, :k], idx_np[:, :k], b, k, min_similarity,
+            ids=ids, defer=defer, padded_rows=query_class(b) - b,
         )
         merge_s = time.perf_counter() - t1
         self.shard_stats.last_merge_s = merge_s

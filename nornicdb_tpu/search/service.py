@@ -26,29 +26,17 @@ from nornicdb_tpu.embed.base import Embedder
 from nornicdb_tpu.embed.queue import build_embedding_text
 from nornicdb_tpu.errors import NotFoundError
 from nornicdb_tpu.ops.similarity import DeviceCorpus
+# imported with the service (not on first use) so that /metrics renders the
+# dispatcher's families before the first vector search
+from nornicdb_tpu.search.batcher import QueryBatcher
 from nornicdb_tpu.search.bm25 import BM25Index
 from nornicdb_tpu.search.fusion import adaptive_rrf_weights, apply_mmr, fuse_rrf
 from nornicdb_tpu.search.hnsw import HNSWIndex
 from nornicdb_tpu.search.tuner import TUNE_OUTCOMES, IVFTuner, TuneState
 from nornicdb_tpu.storage.types import Engine, Node
-from nornicdb_tpu.telemetry.metrics import REGISTRY as _REGISTRY
 from nornicdb_tpu.telemetry.tracing import tracer as _tracer
 
 logger = logging.getLogger(__name__)
-
-# same families the QueryBatcher feeds (idempotent re-resolution by
-# name, so neither module depends on the other's import order or private
-# cells): unbatched corpus dispatches report device time too, and the
-# queue-wait family is registered even before batching is ever enabled
-_DEVICE_HIST = _REGISTRY.histogram(
-    "nornicdb_search_device_seconds",
-    "Host-observed dispatch-to-result seconds per search dispatch "
-    "(the first call of a shape includes its compile)",
-)
-_REGISTRY.histogram(
-    "nornicdb_search_queue_wait_seconds",
-    "Time a batched search waited for its batch to dispatch",
-)
 
 
 @dataclass
@@ -119,10 +107,10 @@ class SearchConfig:
     # f32 from the host mirror, so served scores stay exact
     int8_residency: bool = False
     rescore_factor: int = 4
-    # micro-batching of concurrent searches into one device dispatch
+    # most queries one corpus scan serves: every vector search goes through
+    # the coalescing dispatcher (search/batcher.py), which sends what is
+    # queued when the chip comes free and never lingers for company
     # (SURVEY §7 hard part f)
-    batching_enabled: bool = False
-    batch_window: float = 0.002
     batch_max: int = 256
     # batched-search admission control (ROADMAP item 3): pending queries
     # beyond batch_max_queue shed with ResourceExhausted (0 = unbounded);
@@ -611,14 +599,19 @@ class SearchService:
 
     def _batched_corpus_search(
         self, queries: np.ndarray, k: int, min_similarity: float
-    ) -> list:
+    ):
         """One device dispatch for the whole batch: the corpus search
-        (single-device or mesh-sharded) takes the stacked (B, D) block."""
+        (single-device or mesh-sharded) takes the stacked (B, D) block and
+        scans it at its query class.  The first batch of a ``k`` compiles
+        that ``k``'s whole class grid, so no later batch size meets a new
+        program; rows come back deferred, each caller resolving its own."""
         with self._lock:
             corpus = self._corpus  # promotion may swap it mid-flight
+        kwargs = self._corpus_search_kwargs(corpus)
+        corpus.warm_query_classes(k, self.config.batch_max, **kwargs)
         return corpus.search(
-            queries, k=k, min_similarity=min_similarity,
-            **self._corpus_search_kwargs(corpus),
+            queries, k=k, min_similarity=min_similarity, defer=True,
+            **kwargs,
         )
 
     def corpus(self):
@@ -630,20 +623,16 @@ class SearchService:
 
     def ensure_batcher(self):
         """The service's QueryBatcher, created on first use with the
-        config's batching knobs.  The device broker (server/broker.py)
-        calls this even when ``batching_enabled`` is off for in-process
-        callers: cross-worker traffic must coalesce into fused device
-        dispatches regardless of how the primary's own callers dispatch."""
+        config's batching knobs: the one dispatcher that in-process callers
+        (vector_candidates) and the device broker (server/broker.py) share,
+        so cross-worker traffic coalesces with the primary's own."""
         batcher = getattr(self, "_batcher", None)
         if batcher is None:
             with self._lock:
                 batcher = getattr(self, "_batcher", None)
                 if batcher is None:
-                    from nornicdb_tpu.search.batcher import QueryBatcher
-
                     batcher = self._batcher = QueryBatcher(
                         self._batched_corpus_search,
-                        window=self.config.batch_window,
                         max_batch=self.config.batch_max,
                         max_queue=self.config.batch_max_queue,
                         deadline=self.config.batch_deadline_ms / 1000.0,
@@ -662,12 +651,6 @@ class SearchService:
             # benign race — _maybe_promote_sharded re-checks under _lock
             # and the cooldown gate keeps the retry cheap.
             self._maybe_promote_sharded()
-        if (
-            self.config.batching_enabled
-            and self._corpus is not None
-        ):
-            self.stats.vector_candidates += 1
-            return self.ensure_batcher().search(embedding, k, min_similarity)
         # snapshot index refs under the lock, dispatch OUTSIDE it: the
         # round-5 deadlock was exactly a device acquisition hanging while
         # this lock was held, wedging every later search/index call. The
@@ -678,16 +661,11 @@ class SearchService:
             self.stats.vector_candidates += 1
             corpus, hnsw = self._corpus, self._hnsw
         if corpus is not None:
-            kwargs = self._corpus_search_kwargs(corpus)
-            # unbatched dispatches land in the same histogram the batcher
-            # feeds (host-observed seconds of the whole corpus.search),
-            # so the default (non-batched) configuration still reports it
-            with _tracer.stage("search.vector", _DEVICE_HIST):
-                res = corpus.search(
-                    embedding, k=k, min_similarity=min_similarity,
-                    **kwargs
-                )
-            return res[0] if res else []
+            # every query with a device corpus shares the one dispatcher:
+            # alone it is dispatched at once on this thread, in company it
+            # shares the next scan (the `search.vector` stage is the
+            # dispatching caller's)
+            return self.ensure_batcher().search(embedding, k, min_similarity)
         if hnsw is not None:
             return [
                 (i, s)
@@ -700,8 +678,7 @@ class SearchService:
         """Search-stack observability bundle for the server stats/metrics
         surface: index/search counters, the corpus's device-sync accounting
         (patches vs full uploads, bytes, query stall), and the query
-        batcher's observed batch sizes — the numbers the batch window and
-        uploader cadence are tuned from."""
+        dispatcher's counters (queries a scan, padding rows, queue wait)."""
         from dataclasses import asdict
 
         out: dict = asdict(self.stats)
@@ -1108,8 +1085,5 @@ class SearchService:
         would re-upload the zombie corpus on every recovery)."""
         with self._lock:
             corpus = self._corpus
-            batcher = getattr(self, "_batcher", None)
         if corpus is not None and hasattr(corpus, "stop_uploader"):
             corpus.stop_uploader()
-        if batcher is not None:
-            batcher.close()

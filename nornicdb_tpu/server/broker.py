@@ -12,7 +12,7 @@ primary's existing fused-dispatch machinery:
 
 * search tickets go through ``SearchService.ensure_batcher()`` —
   cross-worker queries coalesce with each other (and with the primary's
-  own traffic) into ONE device program per batch window, the WindVE
+  own traffic) into ONE device program per scan, the WindVE
   many-ingest-one-device shape (PAPERS.md);
 * embed requests ride ``Embedder.embed_batch`` — behind ``cli serve`` that
   is the continuous ragged batching ServingEngine with its admission
@@ -515,20 +515,23 @@ class DeviceBroker:
                 STATUS_DEGRADED, f"backend {mgr.state}"
             )
         batcher = service.ensure_batcher()
+        tickets: list = []
         try:
             # submit the whole block THEN wait: tickets from this worker,
             # other workers, and the primary's own callers coalesce into
-            # the same batch window — the fused-dispatch invariant the
-            # multiproc bench asserts
-            tickets = [
-                batcher.submit(q[i], k, min_sim) for i in range(q.shape[0])
-            ]
+            # the same scan (what queues while one is in flight goes as
+            # the next) — the fused-dispatch invariant the multiproc
+            # bench asserts
+            for i in range(q.shape[0]):
+                tickets.append(batcher.submit(q[i], k, min_sim))
             results = [batcher.wait(t) for t in tickets]
         except ResourceExhausted as e:
+            batcher.withdraw(tickets)
             self.counters["search_shed"] += 1
             _REQUESTS.labels("search", "shed").inc()
             return _status_payload(STATUS_RESOURCE_EXHAUSTED, str(e))
         except Exception as e:
+            batcher.withdraw(tickets)
             self.counters["search_error"] += 1
             _REQUESTS.labels("search", "error").inc()
             log.exception("broker search failed")

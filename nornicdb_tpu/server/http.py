@@ -828,8 +828,9 @@ class HttpServer:
                 stats["genserve"] = gen_engine.stats_snapshot()
             search = getattr(self.db, "search", None)
             if search is not None and hasattr(search, "stats_snapshot"):
-                # index/search counters + device-sync patching + query
-                # batcher sizes (tune batch_window / uploader cadence here)
+                # index/search counters + device-sync patching + the query
+                # dispatcher (queries a scan, padding rows, queue wait;
+                # the uploader cadence is tuned from the sync counters)
                 stats["search"] = search.stats_snapshot()
             wal = self.db.wal_stats()
             if wal is not None:
@@ -1056,6 +1057,10 @@ class HttpServer:
                     "nornicdb_query_batches_total",
                 "nornicdb_search_batcher_max_batch":
                     "nornicdb_query_batch_max",
+                "nornicdb_search_batcher_padded_rows":
+                    "nornicdb_query_batch_padded_rows_total",
+                "nornicdb_search_batcher_queue_wait_seconds":
+                    "nornicdb_query_batch_queue_wait_seconds_total",
             },
             counters={
                 "nornicdb_search_corpus_sync_bytes_uploaded",
@@ -1067,6 +1072,8 @@ class HttpServer:
                 "nornicdb_search_corpus_sync_search_format_seconds",
                 "nornicdb_search_batcher_queries",
                 "nornicdb_search_batcher_batches",
+                "nornicdb_search_batcher_padded_rows",
+                "nornicdb_search_batcher_queue_wait_seconds",
                 "nornicdb_search_searches",
                 "nornicdb_search_indexed",
                 "nornicdb_search_removed",

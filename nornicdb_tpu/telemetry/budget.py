@@ -48,16 +48,17 @@ SPAN_STAGE_MAP = {
     "genserve.prefill": "prefill",
     "genserve.decode": "decode",
     "serving.batch": "device_sync",
-    "search.batch": "device_sync",
     "search.vector": "device_sync",
     "device.sync": "device_sync",
     "search.rank": "host_merge",
     # stage spans of the two served paths (docs/observability.md "Stage
     # spans").  Not mapped, so that no interval counts twice in a stage's
     # actual: embed.dispatch / embed.fetch (inside serving.batch),
-    # corpus.dispatch / corpus.fetch / corpus.format (inside search.vector
-    # or search.batch), embed.cache (contains the serving.* stages) and
-    # embedq.* (the embed worker has no request deadline).
+    # corpus.dispatch / corpus.fetch (inside search.vector's interval),
+    # embed.cache (contains the serving.* stages) and embedq.* (the embed
+    # worker has no request deadline).  corpus.format is each caller's own
+    # row, after its scan's interval.
+    "corpus.format": "host_merge",
     "http.parse": "tokenize_pack",
     "serving.stage": "tokenize_pack",
     "serving.staged_wait": "admission_queue",

@@ -277,10 +277,6 @@ def main(argv=None) -> int:
     counts = [int(x) for x in args.workers.split(",") if x.strip()]
     duration = 2.5 if args.quick else args.duration
     cores = os.cpu_count() or 1
-    # a slightly wider batch window than the serving default: the bench's
-    # point is cross-worker fusion, and on the CPU "device" a dispatch
-    # costs ~2x the default 2ms window, which caps fusion at ~1.5
-    os.environ.setdefault("NORNICDB_SEARCH_BATCH_WINDOW", "0.004")
     eprint(f"[bench_workers] sweep {counts} x {duration}s on {cores} cores")
 
     t_start = time.time()

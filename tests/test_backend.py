@@ -489,10 +489,7 @@ class TestServiceUnderFault:
 
         mgr = _mgr(FakeHooks("hang"), acquire_timeout=0.3)
         storage = MemoryEngine()
-        svc = SearchService(
-            storage, dims=DIMS,
-            config=SearchConfig(batching_enabled=True, batch_window=0.005),
-        )
+        svc = SearchService(storage, dims=DIMS, config=SearchConfig())
         rng = np.random.default_rng(3)
         vecs = rng.standard_normal((12, DIMS)).astype(np.float32)
         for i in range(12):

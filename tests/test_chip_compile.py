@@ -84,13 +84,26 @@ def _params_on(init, cfg, sharding):
         lambda s: _sds(s.shape, s.dtype, sharding), shapes)
 
 
-# Q classes: 1 is the unbatched /nornicdb/search; QueryBatcher hands a
-# DeviceCorpus every size up to batch_max=256 unpadded, so an odd one too.
+# Q classes: every vector search goes through the coalescing dispatcher,
+# and the corpus scans a block of B queries at query_class(B): the grid
+# below is all a DeviceCorpus is ever handed (batch_max = 256), the lone
+# /nornicdb/search query included (class 8).
 # Capacity doubles on growth: the 64 documents chip_smoke.py embeds after
 # the bulk load push the resident buffer to 2 x 262,144 rows.
-@pytest.mark.parametrize("n,q", [
-    (N_ONE_CHIP, 1), (N_ONE_CHIP, 5), (N_ONE_CHIP, 64), (N_ONE_CHIP, 256),
-    (2 * N_ONE_CHIP, 1), (2 * N_ONE_CHIP, 256),
+Q_GRID = (8, 16, 32, 64, 128, 256)
+
+
+def test_q_grid_is_what_the_dispatcher_emits():
+    from nornicdb_tpu.ops.similarity import query_class, query_classes
+    from nornicdb_tpu.search.service import SearchConfig
+
+    batch_max = SearchConfig().batch_max
+    assert query_classes(batch_max) == Q_GRID
+    assert {query_class(b) for b in range(1, batch_max + 1)} == set(Q_GRID)
+
+
+@pytest.mark.parametrize("n,q", [(N_ONE_CHIP, q) for q in Q_GRID] + [
+    (2 * N_ONE_CHIP, Q_GRID[0]), (2 * N_ONE_CHIP, Q_GRID[-1]),
 ])
 @pytest.mark.parametrize("k", [10, 100])
 def test_streaming_topk_one_chip(one_chip, dispatch_as_on_tpu, n, q, k):
@@ -111,7 +124,7 @@ def test_streaming_topk_one_chip(one_chip, dispatch_as_on_tpu, n, q, k):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("q", [1, 256])
+@pytest.mark.parametrize("q", [Q_GRID[0], Q_GRID[-1]])
 @pytest.mark.parametrize("k", [10, 100])
 def test_streaming_topk_int8_one_chip(one_chip, dispatch_as_on_tpu, q, k):
     import jax
@@ -130,34 +143,42 @@ def test_streaming_topk_int8_one_chip(one_chip, dispatch_as_on_tpu, q, k):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
-def test_sharded_search_four_chips(mesh4, dispatch_as_on_tpu, quantized):
+@pytest.mark.parametrize("quantized,q,k", [
+    (False, Q_GRID[0], 10), (False, Q_GRID[0], 100),
+    (False, Q_GRID[-1], 10), (False, Q_GRID[-1], 100),
+    (False, 16, 100), (True, 16, 100),
+], ids=["f32-q8-k10", "f32-q8-k100", "f32-q256-k10", "f32-q256-k100",
+        "f32-q16-k100", "int8-q16-k100"])
+def test_sharded_search_four_chips(mesh4, dispatch_as_on_tpu, quantized, q, k):
     """ShardedCorpus.search's one program over a 4-device mesh — per-shard
     streaming kernel, all-gather merge — at the shape classes it pads a
-    16-query k=100 batch to (k_prog/local_k pow2; int8 oversamples
-    rescore_factor=4 x k)."""
+    batch to: the query grid's ends and a 16-query batch (k_prog/local_k
+    pow2 of k, at least 8; int8 oversamples rescore_factor=4 x k)."""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from nornicdb_tpu.ops.ivf import _next_pow2
     from nornicdb_tpu.parallel import sharded_index
 
     rows = NamedSharding(mesh4, P("data", None))
     vec = NamedSharding(mesh4, P("data"))
     rep = NamedSharding(mesh4, P())
-    queries = _sds((16, DIMS), jnp.float32, rep)
+    queries = _sds((q, DIMS), jnp.float32, rep)
     valid = _sds((N_FOUR_CHIPS,), jnp.bool_, vec)
     if quantized:
+        k_dev = _next_pow2(max(4 * k, 8))
         lowered = sharded_index._sharded_search_int8.lower(
             queries,
             _sds((N_FOUR_CHIPS, DIMS), jnp.int8, rows),
             _sds((N_FOUR_CHIPS,), jnp.float32, vec),
-            valid, 512, 512, "data", mesh4,
+            valid, k_dev, k_dev, "data", mesh4,
         )
     else:
+        k_prog = _next_pow2(max(k, 8))
         lowered = sharded_index._sharded_search.lower(
             queries,
             _sds((N_FOUR_CHIPS, DIMS), jnp.float32, rows),
-            valid, 128, 128, "data", mesh4,
+            valid, k_prog, k_prog, "data", mesh4,
         )
     compiled = lowered.compile()
     text = compiled.as_text()

@@ -258,7 +258,7 @@ class TestBudget:
     def test_spans_alone_still_attribute(self):
         bk = budget.breakdown_for(
             "never-opened",
-            [{"name": "search.batch", "duration_ms": 3.0}])
+            [{"name": "search.vector", "duration_ms": 3.0}])
         assert bk["stages"][0]["stage"] == "device_sync"
         assert bk["stages"][0]["predicted_ms"] is None
         assert "route" not in bk

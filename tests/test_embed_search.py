@@ -326,14 +326,17 @@ class TestQueryBatcher:
         from nornicdb_tpu.search.batcher import QueryBatcher
 
         calls = []
+        gate = threading.Event()
 
         def batch_fn(queries, k, min_sim):
             calls.append(queries.shape[0])
+            if len(calls) == 1:
+                gate.wait(10)  # the first scan is in flight: the rest queue
             return [
                 [(f"id{int(q[0])}", float(q[0]))] * min(k, 1) for q in queries
             ]
 
-        b = QueryBatcher(batch_fn, window=0.05)
+        b = QueryBatcher(batch_fn)
         results = {}
 
         def one(i):
@@ -342,8 +345,12 @@ class TestQueryBatcher:
         threads = [threading.Thread(target=one, args=(i,)) for i in range(8)]
         for t in threads:
             t.start()
+        deadline = time.monotonic() + 10
+        while len(b._pending) < 7 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        gate.set()
         for t in threads:
-            t.join()
+            t.join(10)
         assert sum(calls) == 8
         assert len(calls) <= 2  # coalesced, not 8 dispatches
         assert results[3] == [("id3", 3.0)]
@@ -355,7 +362,7 @@ class TestQueryBatcher:
         def batch_fn(queries, k, min_sim):
             return [[("a", 0.9), ("b", 0.5), ("c", 0.1)][:k] for _ in queries]
 
-        b = QueryBatcher(batch_fn, window=0.001)
+        b = QueryBatcher(batch_fn)
         out = b.search(np.zeros(4, np.float32), k=2, min_similarity=0.4)
         assert out == [("a", 0.9), ("b", 0.5)]
 
@@ -365,7 +372,7 @@ class TestQueryBatcher:
         def batch_fn(queries, k, min_sim):
             raise RuntimeError("device fell over")
 
-        b = QueryBatcher(batch_fn, window=0.001)
+        b = QueryBatcher(batch_fn)
         with pytest.raises(RuntimeError):
             b.search(np.zeros(4, np.float32), k=1)
 
@@ -377,10 +384,7 @@ class TestQueryBatcher:
 
         eng = MemoryEngine()
         emb = HashEmbedder(32)
-        svc = SearchService(
-            eng, embedder=emb,
-            config=SearchConfig(batching_enabled=True, batch_window=0.01),
-        )
+        svc = SearchService(eng, embedder=emb, config=SearchConfig())
         svc.attach(eng)
         for i in range(20):
             n = Node(id=f"n{i}", properties={"content": f"text number {i}"})
@@ -398,7 +402,8 @@ class TestQueryBatcher:
             t.join()
         for i in range(6):
             assert outs[i][0][0] == f"n{i}"
-        assert svc._batcher.stats.batches <= 3
+        stats = svc._batcher.stats
+        assert stats.queries == 6 and 1 <= stats.batches <= 6
 
 
 class TestRankCache:

@@ -364,7 +364,7 @@ class TestRemoteTraceMerge:
         tracer.clear()
         tp = format_traceparent("ad" * 16, "cd" * 8)
         with tracer.start_trace("broker.search", traceparent=tp):
-            with tracer.span("search.batch", {"batch_size": 3}):
+            with tracer.span("search.vector", {"batch_size": 3}):
                 pass
         assert tracer.merge_remote("ad" * 16, [
             {"name": "worker.search", "span_id": "ab" * 8,

@@ -150,8 +150,7 @@ class TestMMR:
 def _hnsw_service(engine=None):
     return SearchService(
         engine or MemoryEngine(),
-        config=SearchConfig(backend="hnsw", batching_enabled=False,
-                            mmr_enabled=False),
+        config=SearchConfig(backend="hnsw", mmr_enabled=False),
     )
 
 

@@ -533,7 +533,7 @@ class TestQueryBatcherAdmission:
             release.wait(5.0)
             return [[("id", 1.0)] for _ in range(len(queries))]
 
-        b = QueryBatcher(slow_search, window=10.0, max_batch=64, max_queue=2)
+        b = QueryBatcher(slow_search, max_batch=64, max_queue=2)
         results = []
 
         def caller():
@@ -556,15 +556,31 @@ class TestQueryBatcherAdmission:
     def test_deadline_sheds_and_never_wedges(self):
         from nornicdb_tpu.search.batcher import QueryBatcher
 
+        release = threading.Event()
+
         def stuck_search(queries, k, min_sim):
-            time.sleep(5.0)
+            release.wait(5.0)
             return [[("id", 1.0)] for _ in range(len(queries))]
 
-        b = QueryBatcher(stuck_search, window=0.001, deadline=0.2)
+        b = QueryBatcher(stuck_search, deadline=0.2)
+        # the caller that finds the dispatcher idle leads the stuck scan on
+        # its own thread (bounded by the device path, as any dispatch is);
+        # a caller queued behind it gives up at deadline + grace
+        leader = threading.Thread(
+            target=lambda: b.search(np.ones(4, np.float32), 1))
+        leader.start()
+        while not b._in_flight:
+            time.sleep(0.005)
         t0 = time.monotonic()
-        with pytest.raises(ResourceExhausted):
-            b.search(np.ones(4, np.float32), 1)
-        assert time.monotonic() - t0 < 4.0
+        try:
+            with pytest.raises(ResourceExhausted):
+                b.search(np.ones(4, np.float32), 1)
+            assert time.monotonic() - t0 < 4.0
+            assert not b._pending  # withdrawn, not scanned for nobody
+        finally:
+            release.set()
+            leader.join(timeout=10)
+        assert not leader.is_alive()
 
     def test_dispatch_time_shedding(self):
         from nornicdb_tpu.search.batcher import QueryBatcher
@@ -575,15 +591,31 @@ class TestQueryBatcherAdmission:
         COST_MODEL.reset()
         calls = []
 
+        release = threading.Event()
+
         def search_fn(queries, k, min_sim):
             calls.append(len(queries))
+            if len(calls) == 1:
+                release.wait(5.0)
             return [[("id", 1.0)] for _ in range(len(queries))]
 
-        b = QueryBatcher(search_fn, window=0.5, deadline=0.05)
-        # enqueue, then let the deadline lapse before the window flushes
+        b = QueryBatcher(search_fn, deadline=0.05)
+        leader = threading.Thread(
+            target=lambda: b.search(np.ones(4, np.float32), 1))
+        leader.start()
+        while not b._in_flight:
+            time.sleep(0.005)
+        # queued behind the scan in flight, then the deadline lapses before
+        # the next scan is launched: shed at dispatch, never scanned
+        ticket = b.submit(np.ones(4, np.float32), 1)
+        time.sleep(0.1)
+        release.set()
         with pytest.raises(ResourceExhausted):
-            b.search(np.ones(4, np.float32), 1)
+            b.wait(ticket)
+        leader.join(timeout=10)
+        assert not leader.is_alive()
         assert b.stats.sheds_deadline >= 1
+        assert calls == [1]
 
 
 # ----------------------------------------------------------- HTTP edge

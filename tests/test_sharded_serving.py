@@ -374,10 +374,8 @@ class TestMergeSentinels:
 class TestServingIntegration:
     @needs_device
     def test_batched_queries_one_dispatch(self):
-        """QueryBatcher -> sharded corpus: N concurrent searches collapse
+        """QueryBatcher -> sharded corpus: N queued searches collapse
         into ONE fused device dispatch (the batch rides the (B, D) GEMM)."""
-        import threading
-
         from nornicdb_tpu.search.batcher import QueryBatcher
 
         data = _rand(512, seed=41)
@@ -389,19 +387,12 @@ class TestServingIntegration:
         def batch_fn(queries, k, min_sim):
             return sc.search(queries, k=k, min_similarity=min_sim)
 
-        batcher = QueryBatcher(batch_fn, window=0.05, max_batch=64)
+        batcher = QueryBatcher(batch_fn, max_batch=64)
         before = sc.shard_stats.dispatches
-        results = {}
-
-        def one(i):
-            results[i] = batcher.search(data[i], k=3)
-
-        threads = [threading.Thread(target=one, args=(i,))
-                   for i in range(12)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=30)
+        # queued while no scan is in flight (the broker's submit-then-wait):
+        # the first waiter leads one scan for all twelve
+        tickets = [batcher.submit(data[i], k=3) for i in range(12)]
+        results = {i: batcher.wait(t) for i, t in enumerate(tickets)}
         assert len(results) == 12
         for i, rows in results.items():
             assert rows[0][0] == f"b{i}"

@@ -254,17 +254,27 @@ class TestServedPaths:
     def test_vector_search_tree_and_counters(self, stack):
         db, _, server = stack
         sync = db.search.corpus().sync_stats
+        request = {"vector": [0.5] * DIMS, "limit": 5,
+                   "include_content": False}
+        # the first query of a k compiles that k's class grid (its trace
+        # holds one scan a class): the steady tree is the second's
+        _post(server.port, "/nornicdb/search",
+              {**request, "vector": [0.5, -0.5] * (DIMS // 2)})
         before = sync.as_dict()
-        trace_id, body = _post(
-            server.port, "/nornicdb/search",
-            {"vector": [0.5] * DIMS, "limit": 5, "include_content": False})
+        trace_id, body = _post(server.port, "/nornicdb/search", request)
         assert len(body["results"]) == 5
         entry = _trace(trace_id, want=8)
         root = entry["tree"][0]
+        # a lone query leads its own scan on its own thread, then formats
+        # its own row.  `search.vector` is one retroactive timing from the
+        # launch to the end of the read-back (under load two callers share
+        # those two halves), so the corpus stages stand beside it
         assert _shape(root) == ("http.POST", [
             ("http.parse", []), ("http.parse", []),
-            ("search.vector", [("corpus.dispatch", []), ("corpus.fetch", []),
-                               ("corpus.format", [])]),
+            ("search.queue_wait", []),
+            ("search.vector", []),
+            ("corpus.dispatch", []), ("corpus.fetch", []),
+            ("corpus.format", []),
             ("http.respond", []),
         ])
         _assert_inside(root)
@@ -278,8 +288,9 @@ class TestServedPaths:
             assert delta[field] == pytest.approx(by_name[span], abs=1e-9)
             assert delta[field] > 0.0
         assert (delta["search_dispatch_seconds"]
-                + delta["search_fetch_seconds"]
-                + delta["search_format_seconds"]) <= by_name["search.vector"]
+                + delta["search_fetch_seconds"]) <= by_name["search.vector"]
+        batcher = db.search.stats_snapshot()["batcher"]
+        assert batcher["queue_wait_seconds"] >= by_name["search.queue_wait"]
 
     def test_stage_seconds_reach_metrics_and_admin_stats(self, stack):
         db, _, server = stack

@@ -471,7 +471,7 @@ class TestPrometheusGolden:
             str(tmp_path / "db"), Config(async_writes=True)
         )
         db.set_embedder(HashEmbedder(32))
-        db.search.config = SearchConfig(batching_enabled=True)
+        db.search.config = SearchConfig()
         server = HttpServer(db, port=0)
         server.start()
         slow_log.configure(threshold_s=1e-9)
@@ -549,15 +549,16 @@ class TestBatcherTelemetry:
         def batch_fn(queries, k, min_sim):
             return [[("id", 0.9)] for _ in range(queries.shape[0])]
 
-        b = QueryBatcher(batch_fn, window=0.01, max_batch=8)
+        b = QueryBatcher(batch_fn, max_batch=8)
         with tracer.start_trace("caller") as root:
             res = b.search(np.ones(4, np.float32), k=1)
         assert res == [("id", 0.9)]
         entry = tracer.trace(root.trace_id)
         names = {s["name"] for s in entry["spans"]}
         assert "search.queue_wait" in names
-        assert "search.batch" in names  # leader's device span
+        assert "search.vector" in names  # the dispatching caller's stage
         assert b.stats.batches == 1
+        assert b.stats.queue_wait_seconds >= 0.0
 
 
 # ------------------------------------------------------------ async flush

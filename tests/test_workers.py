@@ -308,7 +308,7 @@ def _vector_body(db, text="device plane document 3", limit=5):
     return {"vector": [float(x) for x in vec], "limit": limit}
 
 
-def _post_search(port, body, tries=40):
+def _post_search(port, body, tries=120):
     last = None
     for _ in range(tries):
         try:
