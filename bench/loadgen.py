@@ -3,6 +3,9 @@ neither the chip nor the server's GIL.  A cell's file says which loop:
 
 * ``"loop": "closed"``: ``clients`` connections, each sends its next request
   when the last one is answered (a saturating load: the rate is the result).
+  An op whose answer is a stream (``STREAM = True`` in its file) is read
+  event by event: the send time, the arrival of every content event and the
+  ids it carried are kept, so the first and the last are known.
 * ``"loop": "open"``: requests are due at ``rate_per_s``, evenly paced
   (``"arrivals": "uniform"``, the constant-rate schedule of wrk2 ``-R``,
   vegeta ``-rate`` and k6's ``constant-arrival-rate``), dealt round-robin to
@@ -32,13 +35,21 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 RETRY_CAP_S = 1.0  # a 429's Retry-After is honoured up to this, once
 
 
+_LOADED: dict = {}
+
+
 def load_file(rel: str):
-    """A module of the benchmark, found by its path under ``bench/``."""
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + rel[:-3].replace("/", "_"), os.path.join(HERE, rel))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    """A module of the benchmark, found by its path under ``bench/`` (one
+    instance a process, so that what it caches is cached once)."""
+    if rel not in _LOADED:
+        if HERE not in sys.path:
+            sys.path.insert(0, HERE)  # a family file imports reference.py
+        spec = importlib.util.spec_from_file_location(
+            "bench_" + rel[:-3].replace("/", "_"), os.path.join(HERE, rel))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _LOADED[rel] = mod
+    return _LOADED[rel]
 
 
 def load_op(name: str):
@@ -82,6 +93,44 @@ def run_clients(n_clients: int, send, stop: threading.Event,
     return records
 
 
+def stream_once(op, port: int, body: bytes):
+    """One streamed answer on a connection of its own (the route closes it
+    when the stream ends).  The answer kept: when the request was sent, the
+    ids of every content event and each event's arrival in ms after the
+    send.  A stream that carries an error event, or ends without its
+    terminator, is status 598."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+    ids, chunk_ms, fault, done = [], [], None, False
+    sent = time.monotonic()
+    try:
+        conn.request("POST", op.PATH, body,
+                     {"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        if resp.status != 200:
+            return resp.status, resp.getheader("Retry-After"), resp.read()
+        for line in resp:
+            got = op.event(line)
+            if got is None:
+                continue
+            kind, payload = got
+            if kind == "content":
+                chunk_ms.append(round((time.monotonic() - sent) * 1e3, 3))
+                ids.extend(payload)
+            elif kind == "error":
+                fault = payload
+            elif kind == "done":
+                done = True
+    except (http.client.HTTPException, OSError, ValueError) as e:
+        fault = fault or repr(e)[:200]
+    finally:
+        conn.close()
+    answer = {"sent": sent, "ids": ids, "chunk_ms": chunk_ms}
+    if fault or not done:
+        answer["error"] = fault or "the stream ended without [DONE]"
+    return (598 if "error" in answer else 200), None, \
+        json.dumps(answer).encode()
+
+
 class HttpSender:
     """One keep-alive connection per client; bodies built before ``go``."""
 
@@ -99,6 +148,7 @@ class HttpSender:
         self.conns = [None] * cell["clients"]
         self.built_late = 0
         self.keep_every = cell.get("keep_every", 1)
+        self.streams_answers = getattr(self.op, "STREAM", False)
 
     def _post(self, client: int, body: bytes):
         for attempt in (0, 1):  # a dropped keep-alive connection reopens
@@ -126,13 +176,16 @@ class HttpSender:
             self.built_late += 1
             body = self.op.encode(self.streams[client].request(index),
                                   self.cell["params"])
-        status, retry_after, raw = self._post(client, body)
+        post = (lambda: stream_once(self.op, self.port, body)) \
+            if self.streams_answers \
+            else (lambda: self._post(client, body))
+        status, retry_after, raw = post()
         retried = False
         if status == 429:  # the documented shed: one retry, as a client does
             retried = True
             time.sleep(min(float(retry_after or 1), RETRY_CAP_S))
-            status, _, raw = self._post(client, body)
-        keep = status == 200 and index % self.keep_every == 0
+            status, _, raw = post()
+        keep = status in (200, 598) and index % self.keep_every == 0
         return status, retried, raw.decode() if keep else None
 
 
@@ -160,8 +213,7 @@ def main() -> None:
 
     def work():
         cpu0, wall0 = time.process_time(), time.monotonic()
-        result["records"] = run_clients(cell["clients"], sender, stop,
-                                        rate)
+        result["records"] = run_clients(cell["clients"], sender, stop, rate)
         result["client_cpu_share"] = (time.process_time() - cpu0) / max(
             time.monotonic() - wall0, 1e-9)
 

@@ -21,16 +21,17 @@ def check(run):
     import reference
 
     limits, model = run.config["limits"], run.config["model"]
+    family = run.models["model"][0]
     picks = run.sample([r for r in run.answered if r[6]],
                        longest=lambda r: len(run.request(r)))
     if not picks:
         return [], [], 0
     texts = [run.request(r) for r in picks]
     served = np.asarray([decode(r[6].encode()) for r in picks])
-    ref = reference.embed_reference(model, run.params, texts)
+    ref = family.embed_reference(model, run.params, texts)
     numbers = reference.check_vectors(limits, served, ref)
     ctl = []
     if run.control:
-        low = reference.embed_reference(model, run.params, texts, mode="fp8")
+        low = family.embed_reference(model, run.params, texts, mode="fp8")
         ctl = reference.check_vectors(limits, low, ref)
     return numbers, ctl, len(picks)

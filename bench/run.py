@@ -6,10 +6,12 @@
 Everything about a cell is data found by name: ``BENCHMARK.json`` names the
 cell's configuration (``bench/configs/<configuration>.json``) and traffic
 (``bench/workloads/<traffic>.json``), the traffic names its op
-(``bench/ops/<op>.py``), and every per-layer metric is
-``bench/metrics/<metric>.json``.  The serving stack is built exactly as
-``nornicdb serve --embedder tpu --model-preset bge_m3`` builds it, with
-``backend.fallback = "fail"``.
+(``bench/ops/<op>.py``), every per-layer metric is
+``bench/metrics/<metric>.json``, and each model of the configuration names
+its family (``bench/models/<family>.py``: the program's config object, the
+seeded weights, how ``cmd_serve`` wires it, its plain reference and its work
+functions).  The serving stack is built as ``nornicdb serve`` builds it, with
+the configuration's options (``backend.fallback = "fail"`` in all of them).
 
 The last stdout line is the result.  Earlier stdout lines are JSON facts
 (phase seconds, compiles and sheds in the window, client CPU share, HBM by
@@ -42,6 +44,7 @@ import numpy as np  # noqa: E402
 
 import loadgen  # noqa: E402
 import reference  # noqa: E402
+import selfcheck  # noqa: E402
 import trace as trace_mod  # noqa: E402
 import traffic  # noqa: E402
 import work  # noqa: E402
@@ -62,6 +65,16 @@ def dig(tree: dict, path: str):
     for key in path.split("."):
         tree = tree[key]
     return tree
+
+
+def load_family(name: str):
+    """``bench/models/<family>.py``, found by the name a configuration
+    gives its model: the harness itself names no model."""
+    rel = f"models/{name}.py"
+    if not str(name).replace("_", "").isalnum() \
+            or not os.path.isfile(os.path.join(HERE, rel)):
+        sys.exit(f"model family {name!r}: no bench/{rel}")
+    return loadgen.load_file(rel)
 
 
 def overlay(base: dict, over: dict) -> dict:
@@ -175,36 +188,36 @@ def when_ready(mgr, call, what: str, wait_s: float = 300.0):
     return call()
 
 
+MODEL_ROLES = ("model", "generator")  # the embedder, the assistant's decoder
+
+
 def build_stack(app_cfg, config: dict, seed: int, data_dir: str):
-    """What cmd_serve wires (nornicdb_tpu/cli.py), with the benchmark's
-    seeded weights handed to the embedder."""
+    """What cmd_serve wires (nornicdb_tpu/cli.py).  Each model the
+    configuration has (``model``: the embedder; ``generator``: optional) is
+    made and wired by its family file, with the benchmark's seeded weights.
+    Returns the db, the server and ``{role: (family, params, handle)}``."""
     import nornicdb_tpu
     import nornicdb_tpu.telemetry as telemetry
     from nornicdb_tpu import genserve
-    from nornicdb_tpu.embed import CachedEmbedder, TPUEmbedder
-    from nornicdb_tpu.models import bge_m3
     from nornicdb_tpu.search import service as search_service
     from nornicdb_tpu.server import HttpServer
-    from nornicdb_tpu.serving import ServingEngine
 
+    families = {role: load_family(config[role]["family"])
+                for role in MODEL_ROLES if config.get(role)}
     telemetry.configure(**vars(app_cfg.telemetry))
     search_service.configure_defaults(**vars(app_cfg.search))
     genserve.configure(app_cfg.genserve)
     db = nornicdb_tpu.open_db(data_dir)
-    model = config["model"]
-    fields = bge_m3.BgeConfig.__dataclass_fields__
-    cfg = bge_m3.BgeConfig(**{k: v for k, v in model.items() if k in fields})
-    if model.get("preset"):
-        preset = getattr(bge_m3, model["preset"])
-        if cfg != preset:
-            sys.exit(f"{config['name']}: sizes differ from the serve preset "
-                     f"{model['preset']}: {cfg} != {preset}")
-    params = reference.make_params(model, seed)
-    embedder = TPUEmbedder(cfg=cfg, params=params, max_len=model["max_len"])
-    db.set_embedder(CachedEmbedder(ServingEngine(embedder, app_cfg.serving)))
+    models = {}
+    for role, family in families.items():
+        spec = config[role]
+        family.program_config(spec)  # refuses sizes that are not the preset's
+        params = family.make_params(spec, seed)
+        models[role] = (family, params,
+                        family.install(db, app_cfg, spec, params))
     http = HttpServer(db, port=0)
     http.start()
-    return db, embedder, http, params
+    return db, http, models
 
 
 def load_corpus(db, mgr, config: dict, seed: int, keep: bool):
@@ -264,19 +277,26 @@ def load_corpus(db, mgr, config: dict, seed: int, keep: bool):
 
 def counters(db, embedder, mgr) -> dict:
     """The program's own counters, read as they stand (deltas are taken over
-    the window)."""
-    engine, worker = db.serving_engine(), db._embed_worker
+    the window).  ``genserve`` is the generation engine's, and the same
+    counters at nought where the deployment has no engine.
+    (``tests/test_stage_spans.py`` compiles this function from the source
+    and calls it so.)"""
+    from nornicdb_tpu.genserve import GenStats
+
+    engine, worker, gen = db.serving_engine(), db._embed_worker, \
+        db.genserve_engine()
     return {"search": db.search.stats_snapshot(),
             "engine": dict(vars(engine.stats)),
             "embed_worker": dict(vars(worker.stats)),
             "embedder": dict(embedder.stats),
+            "genserve": gen.stats_snapshot() if gen else GenStats().as_dict(),
             "backend": {k: v for k, v in mgr.stats().items()
                         if isinstance(v, (int, float))}}
 
 
 def sheds_of(c: dict) -> int:
-    e = c["engine"]
-    return e["sheds_queue_full"] + e["sheds_deadline"] + e["sheds_predicted"]
+    return sum(v for root in ("engine", "genserve")
+               for k, v in c.get(root, {}).items() if k.startswith("sheds_"))
 
 
 def prewarm_packs(cell: dict, db, mgr, seed: int) -> dict:
@@ -314,10 +334,38 @@ def prewarm_packs(cell: dict, db, mgr, seed: int) -> dict:
                 "packed_programs", []))}
 
 
+def prime(cell: dict, op, seed: int, port: int) -> list:
+    """What the deployment has served before the sessions begin: the cell's
+    ``prime`` requests, one after the other, from a client of their own
+    (stream ``clients``), with ``prime.params`` laid over the cell's.  A chat
+    deployment has answered under its system prompt before: the prefix cache
+    holds it.  (Sixteen sessions that begin at once against a cold cache
+    each prefill the whole prompt: hits are looked up at admission, and a
+    prompt's pages are published only when its last chunk lands.)  Returns
+    the records, for the count of prompt tokens."""
+    spec = cell.get("prime")
+    if not spec:
+        return []
+    stream = traffic.Stream(cell, seed, cell["clients"])
+    params = overlay(cell["params"], spec.get("params", {}))
+    records = []
+    for index in range(spec["requests"]):
+        t0 = time.monotonic()
+        status, _, raw = loadgen.stream_once(
+            op, port, op.encode(stream.request(index), params))
+        if status != 200:
+            sys.exit(f"the prime request failed: {status} {raw[:300]!r}")
+        records.append([cell["clients"], index, t0, time.monotonic(), status,
+                        False, raw.decode(), 0.0])
+    return records
+
+
 def warm_up(cell: dict, snap, compiles: Compiles, go_at: float) -> dict:
     """The cell's own traffic, unmeasured, until it is steady: for
     ``quiet_s`` no program compiled, nothing was shed, work completed in
-    every second and, in an open loop, the server was not behind (a stall
+    every second, the counter ``until`` names has risen as far as it says
+    (a closed loop of long requests: each session's first one is the
+    start-up) and, in an open loop, the server was not behind (a stall
     leaves a backlog that drains for seconds at four fifths of capacity: a
     window that begins inside it measures the stall).  (Each pack class's
     first dispatch is a compile or a cache load that the cost model learns
@@ -327,6 +375,8 @@ def warm_up(cell: dict, snap, compiles: Compiles, go_at: float) -> dict:
     rate = cell["rate_per_s"] if cell["loop"] == "open" else 0.0
     last_bad = time.monotonic()
     first_ops = last_ops = dig(snap(), w["progress"])
+    until = w.get("until")  # a counter that has to rise first, and by what
+    risen_from = dig(snap(), until["counter"]) if until else 0
     last_sheds, behind = sheds_of(snap()), 0.0
     while True:
         time.sleep(1.0)
@@ -338,7 +388,9 @@ def warm_up(cell: dict, snap, compiles: Compiles, go_at: float) -> dict:
                 or compiles.between(now - 1.0, now):
             last_bad = now
         last_ops, last_sheds = ops, sheds
-        steady = now - last_bad >= w["quiet_s"]
+        steady = now - last_bad >= w["quiet_s"] and (
+            not until or dig(c, until["counter"]) - risen_from
+            >= until["rise"])
         if (now - go_at >= w["min_s"] and steady) or now - go_at >= w["max_s"]:
             if not steady:
                 print(f"warm-up not steady after {w['max_s']} s",
@@ -416,6 +468,10 @@ def measure(cell, child, seconds, snap, compiles, tracer, out):
     got = load_json(out)
     extra.update(client_cpu_share=got["client_cpu_share"],
                  bodies_built_late=got["built_late"])
+    if got["built_late"] and cell.get("rate_metric"):
+        print(f"{got['built_late']} request bodies were built after `go`: "
+              "the rate may be the load generator's, not the server's "
+              "(prebuilt_per_client)", file=sys.stderr)
     return got["records"], (t0, t1), (before, after), extra
 
 
@@ -425,10 +481,17 @@ def percentile(values, q: float) -> float:
 
 
 def counter_ratio(spec: dict, before: dict, after: dict, bench: dict):
+    """``numerator`` / ``denominator``, each a dotted path into the
+    program's counters (read as a delta over the window) or, as
+    ``bench.<name>``, one of the window's own numbers.  A path that does not
+    resolve (a counter that this program does not have: a parent laid under
+    a newer benchmark) finds nothing to read."""
     def read(path):
-        if path.startswith("bench."):
-            return bench.get(path[6:])
-        return dig(after, path) - dig(before, path)
+        try:
+            return bench[path[6:]] if path.startswith("bench.") \
+                else dig(after, path) - dig(before, path)
+        except (KeyError, TypeError):
+            return None
 
     num, den = read(spec["numerator"]), read(spec["denominator"])
     if num is None or not den:
@@ -470,10 +533,14 @@ class Produced:
     request made again from the seed, the seeded rows and weights, and a
     seeded sample."""
 
-    def __init__(self, cell, config, seed, answered, rows, params, control):
+    def __init__(self, cell, config, seed, answered, rows=None, models=None,
+                 control=False, records=(), counters=None):
         self.cell, self.config, self.seed = cell, config, seed
-        self.answered, self.rows, self.params = answered, rows, params
-        self.control = control
+        self.answered, self.rows, self.control = answered, rows, control
+        self.models = models or {}        # role -> (family, params, handle)
+        self.params = self.models.get("model", (None, None))[1]
+        self.records = records            # every request sent, warm-up too
+        self.counters = counters or {}    # the program's, once traffic ended
         self._streams: dict = {}
 
     def request(self, rec):
@@ -533,8 +600,9 @@ def run_cell(args) -> dict:
     compiles, pauses = Compiles(), Pauses()
     data_dir = os.path.join(scratch, "data") \
         if config["deployment"]["data_dir"] == "fresh" else ""
-    db, embedder, http, params = build_stack(app_cfg, config, args.seed,
-                                             data_dir)
+    if cell["loop"] == "closed" and cell.get("latency_metrics"):
+        sys.exit(f"{args.workload}: {selfcheck.CLOSED_LOOP_LATENCY}")
+    db, http, models = build_stack(app_cfg, config, args.seed, data_dir)
     phase["stack"] = time.monotonic()
     out = os.path.join(scratch, "loadgen.json")
     child = start_child(cell, args.seed, http.port, out)
@@ -542,8 +610,11 @@ def run_cell(args) -> dict:
         rows = load_corpus(db, mgr, config, args.seed,
                            keep=getattr(op, "NEEDS_ROWS", False))
         phase["loaded"] = time.monotonic()
-        snap = lambda: counters(db, embedder, mgr)  # noqa: E731
+        snap = lambda: counters(db, models["model"][2], mgr)  # noqa: E731
         prewarmed = prewarm_packs(cell, db, mgr, args.seed)
+        primed = prime(cell, op, args.seed, http.port)
+        if primed:
+            prewarmed["prime_s"] = primed[-1][3] - primed[0][2]
     except BaseException:
         child.kill()
         child.wait()
@@ -557,8 +628,10 @@ def run_cell(args) -> dict:
         cell, child, args.seconds, snap, compiles, tracer, out)
     setup_s = t0 - T_START
 
+    at_end = snap()  # every request has ended: the child has exited
     inside = [r for r in records if t0 <= r[3] <= t1]
     done = [r for r in inside if r[4] == 200]
+    streamed = getattr(op, "STREAM", False)
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                for d in devices)
     from nornicdb_tpu.telemetry import deviceprof
@@ -583,7 +656,31 @@ def run_cell(args) -> dict:
     lat_ms = [(r[3] - r[2]) * 1e3 for r in done]
     seconds = t1 - t0
     values = {"setup_s": setup_s}
-    bench = {"completed": len(done)}  # the window, as the benchmark saw it
+    # the window, as the benchmark saw it
+    bench = {"completed": len(done), "seconds": seconds}
+    if streamed:
+        # content tokens that arrived inside the window, whichever request
+        # they belong to (one that ended after the window too): how many a
+        # second, and the time from one to the next in its stream (every gap
+        # that ended inside the window: all of the window's decoding); and
+        # each request's wait for its first one
+        got = {id(r): op.decode(r[6]) for r in records if r[4] == 200}
+        at = [[a["sent"] + ms / 1e3 for ms in a["chunk_ms"]]
+              for a in got.values()]
+        gaps = [b - a for times in at for a, b in zip(times, times[1:])
+                if t0 <= b <= t1]
+        first_ms = [got[id(r)]["chunk_ms"][0] for r in done
+                    if got[id(r)]["chunk_ms"]]
+        bench["tokens"] = sum(len(got[id(r)]["ids"]) for r in done)
+        bench["tokens_per_s"] = sum(
+            1 for times in at for t in times if t0 <= t <= t1) / seconds
+        if first_ms:
+            bench["first_chunk_p50_ms"] = percentile(first_ms, 50)
+        if gaps:
+            bench["ms_per_token"] = 1e3 * sum(gaps) / len(gaps)
+            bench["ms_per_token_p50"] = 1e3 * percentile(gaps, 50)
+            if cell.get("token_gap_metric"):
+                values[cell["token_gap_metric"]] = bench["ms_per_token"]
     if done:
         if cell.get("rate_metric"):
             values[cell["rate_metric"]] = len(done) / seconds
@@ -594,7 +691,7 @@ def run_cell(args) -> dict:
                      p99_ms=percentile(lat_ms, 99),
                      rate_per_s=len(done) / seconds)
         emit(requests=len(done), **{k: v for k, v in bench.items()
-                                    if k != "completed"})
+                                    if k not in ("completed", "seconds")})
         # stalls: requests over three times the median, by when they ended
         slow = sorted((r for r in done if (r[3] - r[2]) * 1e3
                        > 3 * percentile(lat_ms, 50)), key=lambda r: r[3])
@@ -617,12 +714,16 @@ def run_cell(args) -> dict:
         cap = {**trace_mod.reduce_capture(cap_events), "events": cap_events}
         lo, hi = tracer.span
         in_trace = [r for r in done if lo <= r[3] <= hi]
-        made = Produced(cell, config, args.seed, in_trace, None, None, False)
-        model = config["model"]
-        ctx = {"completed": len(in_trace), "token_lengths": [
-            len(reference.tokenize(made.request(r), model["vocab_size"],
-                                   model["max_len"]))
-            for r in in_trace] if cell["request"] == "text" else []}
+        if hasattr(op, "trace_context"):  # the op says what the trace held
+            ctx = op.trace_context(Produced(
+                cell, config, args.seed, [r for r in records if r[4] == 200]),
+                in_trace, lo, hi)
+        else:
+            made = Produced(cell, config, args.seed, in_trace)
+            family = models["model"][0]
+            ctx = {"completed": len(in_trace), "token_lengths": [
+                family.token_length(config["model"], made.request(r))
+                for r in in_trace] if cell["request"] == "text" else []}
         peaks = work.peaks_for(devices[0].device_kind) \
             if not args.rehearse_cpu else work.peaks_for("TPU v5e")
         metrics, unread = {}, []
@@ -644,6 +745,7 @@ def run_cell(args) -> dict:
             emit(unread=unread)
             print(f"NOTHING TO READ for {unread}: a pattern that matches no "
                   "program, or a counter that did not move", file=sys.stderr)
+        emit(xla_ops=trace_mod.op_seconds(cap_events, cap["window"]))
         device.update(busy_s=cap["busy_s"], window_s=cap["window_s"])
         result["breakdown"] = cap["breakdown"]
     result["metrics"] = {n: {"value": v, "unit": units[n]}
@@ -651,8 +753,17 @@ def run_cell(args) -> dict:
     result["device"] = device
 
     http.stop()
+    if db.genserve_engine() is not None:
+        db.genserve_engine().stop()
+    t_check = time.monotonic()
+    # a stream is the window's if any of it fell inside: a window shorter than
+    # two requests finishes few, and decodes sixteen at a time
+    pool = [r for r in records if r[4] == 200 and r[3] >= t0 and r[2] <= t1] \
+        if streamed else done
     numbers, ctl, compared = op.check(Produced(
-        cell, config, args.seed, done, rows, params, args.control))
+        cell, config, args.seed, pool, rows, models, args.control,
+        primed + records, at_end))
+    emit(check_s=time.monotonic() - t_check)
     numbers += [
         reference.number("compared", compared, min(cell["check_n"], 8),
                          "higher"),

@@ -167,6 +167,10 @@ def check_manifest(m: dict, faults: list[str]) -> None:
             f"{need} s > 43200")
 
 
+CLOSED_LOOP_LATENCY = ("a saturating closed loop's latency is connections / "
+                       "rate: it reports none (latency_metrics)")
+
+
 def check_files(m: dict, faults: list[str]) -> None:
     say = faults.append
     import loadgen
@@ -195,6 +199,17 @@ def check_files(m: dict, faults: list[str]) -> None:
                     "limits", "hbm_reckoning", "assumed", "rehearsal"):
             if key not in cfg:
                 say(f"config {c['name']}: the file lacks {key!r}")
+        for role in ("model", "generator"):
+            family = cfg.get(role, {}).get("family")
+            if role in cfg and not (isinstance(family, str) and os.path.isfile(
+                    os.path.join(HERE, "models", family + ".py"))):
+                say(f"config {c['name']}: {role}.family {family!r} names no "
+                    "bench/models/<family>.py")
+            elif role in cfg:
+                fam = loadgen.load_file(f"models/{family}.py")
+                for attr in ("program_config", "make_params", "install"):
+                    if not hasattr(fam, attr):
+                        say(f"bench/models/{family}.py lacks {attr}")
         reck = cfg.get("hbm_reckoning", {})
         parts = sum(v for k, v in reck.items() if k.endswith("_bytes")
                     and k not in ("total_bytes", "chip_hbm_bytes"))
@@ -221,9 +236,19 @@ def check_files(m: dict, faults: list[str]) -> None:
                     "a result")
         elif cell.get("loop") != "closed":
             say(f"cell {w['name']}: loop is 'closed' or 'open'")
+        elif cell.get("latency_metrics"):
+            say(f"cell {w['name']}: {CLOSED_LOOP_LATENCY}")
+        if cell.get("request") not in ("vector", "text", "chat"):
+            say(f"cell {w['name']}: request is 'vector', 'text' or 'chat'")
+        if cell.get("token_gap_metric") and not (
+                os.path.isfile(op_path) and getattr(
+                    loadgen.load_op(cell["op"]), "STREAM", False)):
+            say(f"cell {w['name']}: token_gap_metric is read off a streamed "
+                "op (STREAM = True)")
         if not cell.get("assumed"):
             say(f"cell {w['name']}: the traffic file lists what it assumed")
-        for name in [cell.get("rate_metric"), *cell.get("latency_metrics", {})]:
+        for name in [cell.get("rate_metric"), cell.get("token_gap_metric"),
+                     *cell.get("latency_metrics", {})]:
             metric = next((e for e in m["end_to_end"] if e["name"] == name),
                           None)
             if name and (metric is None or w["name"] not in
@@ -293,9 +318,22 @@ def check_trace(faults: list[str]) -> None:
         faults.append(f"work.topk_scans: {scans}")
     if work.least_seconds(scans, peaks) != (0.06, "compute"):
         faults.append(f"work.least_seconds: {work.least_seconds(scans, peaks)}")
-    emb = work.embed_texts(cfg, [3, 5])
+    import loadgen
+
+    emb = loadgen.load_file("models/bge_m3.py").embed_texts(cfg, [3, 5])
     if emb["flops"] != 2.0 * 256 * 8 + 4.0 * 2 * 4 * 34:
-        faults.append(f"work.embed_texts: {emb}")
+        faults.append(f"models/bge_m3.embed_texts: {emb}")
+    qwen = loadgen.load_file("models/qwen2.py")
+    gen = {"generator": {"hidden": 4, "intermediate": 8, "layers": 2,
+                         "heads": 2, "kv_heads": 1, "vocab_size": 10,
+                         "dtype": "bfloat16"}}
+    # per layer q 16 + k 8 + v 8 + o 16 + 3 x 32 = 144 parameters: 288 in all
+    toks = qwen.gen_tokens(gen, [[0.5, 2, 6]], [[6, 8]], 3)
+    want = 0.5 * (576.0 * 4 + 32.0 * 18) + 576.0 * 2 + 32.0 * 15 + 240.0
+    per = qwen.param_bytes(gen["generator"])  # (40 + 2 x 152) x 2 + 5 x 16
+    if qwen.matmul_params(gen["generator"]) != 288 or toks != {
+            "flops": want, "bytes": 768.0} or per != 768:
+        faults.append(f"models/qwen2.gen_tokens: {toks} != {want}, {per} B")
     try:
         work.peaks_for("no such chip")
         faults.append("work.peaks_for: an unknown device is not an error")

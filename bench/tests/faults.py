@@ -4,7 +4,9 @@
 
 Each fault alters an answer where the program produces it; ``run.py`` has to
 come out with ``"correct": false``.  (Of the faults a cell can have, these
-cells have only this kind: they train nothing and span no chips.)
+cells have only this kind: they train nothing and span no chips.)  The chat
+cell's four: a produced id shifted by one, a stale prefix page, decode
+positions off by one, fp8 weights under the engine.
 """
 
 import os
@@ -68,8 +70,70 @@ def coarse_vectors():
     TPUEmbedder.embed_packed = coarse
 
 
+def shifted_token():
+    """Every token the scheduler emits is the argmax's neighbour: the id is
+    altered where it is produced (and decoding goes on from it)."""
+    from nornicdb_tpu.genserve.engine import GenerationEngine
+
+    plain = GenerationEngine._emit
+    GenerationEngine._emit = lambda self, seq, tok: plain(
+        self, seq, (tok + 1) % self.cfg.vocab_size)
+
+
+def stale_prefix_page():
+    """The prefix cache's keys commit to a page's place, not its content:
+    a prompt adopts the pages another prompt left there, its user message's
+    among them."""
+    import hashlib
+
+    from nornicdb_tpu.genserve.engine import GenerationEngine
+
+    GenerationEngine._prefix_page_keys = lambda self, toks: [
+        hashlib.sha1(b"page %d" % i).digest()
+        for i in range(len(toks) // self._page_size)]
+
+
+def decode_position_off():
+    """Every decode row of the fused step writes and attends one cache slot
+    too far (its prefill rows are where they belong)."""
+    import numpy as np
+
+    from nornicdb_tpu.models import qwen2
+
+    plain = qwen2.ragged_fused_step
+
+    def off(params, cfg, meta, pages, *, lmax, w, tq, **kw):
+        m = np.array(meta)
+        f = (m.shape[0] - lmax - lmax * w) // 4
+        lane, pos = m[f:2 * f], m[3 * f:4 * f]
+        pos[(lane < lmax - 2) & (pos >= 0)] += 1
+        return plain(params, cfg, m, pages, lmax=lmax, w=w, tq=tq, **kw)
+
+    qwen2.ragged_fused_step = off
+
+
+def fp8_weights():
+    """The engine serves the weights rounded to fp8 (e4m3, a scale a
+    tensor): the precision step below the bf16 the configuration states."""
+    import jax
+
+    import reference
+    from nornicdb_tpu.genserve.engine import GenerationEngine
+
+    plain = GenerationEngine.__init__
+
+    def low(self, params, *a, **kw):
+        plain(self, jax.tree.map(
+            lambda x: reference.fp8(x.astype("float32")).astype(x.dtype)
+            if x.ndim == 2 else x, params), *a, **kw)
+
+    GenerationEngine.__init__ = low
+
+
 FAULTS = {f.__name__: f for f in (wrong_ids, wrong_scores, wrong_vectors,
-                                  coarse_vectors)}
+                                  coarse_vectors, shifted_token,
+                                  stale_prefix_page, decode_position_off,
+                                  fp8_weights)}
 
 if __name__ == "__main__":
     FAULTS[sys.argv.pop(1)]()

@@ -14,8 +14,17 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
+import loadgen  # noqa: E402
 import reference  # noqa: E402
 import traffic  # noqa: E402
+
+bge_m3 = loadgen.load_file("models/bge_m3.py")
+qwen2 = loadgen.load_file("models/qwen2.py")
+
+
+# a size a test run can hold at which fp8 is as far off as at the cell's own
+CONTROL_SIZE = {"layers": 8, "hidden": 256, "heads": 4, "kv_heads": 2,
+                "intermediate": 1024, "vocab_size": 32768}
 
 
 def config(name):
@@ -43,12 +52,39 @@ def test_fp8_forward_fails_the_memory_limits(seed):
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     cfg = config("memory-1m-bge-m3")
     model = {**cfg["model"], **cfg["rehearsal"]["model"], "layers": 4}
-    params = reference.make_params(model, seed)
+    params = bge_m3.make_params(model, seed)
     rng = traffic.rng_for(seed, 5)
     texts = [traffic.text_of(rng, n) for n in (8, 20, 60, 250, 400)]
-    ref = reference.embed_reference(model, params, texts)
+    ref = bge_m3.embed_reference(model, params, texts)
     assert all(n["ok"] for n in
                reference.check_vectors(cfg["limits"], ref, ref))
-    low = reference.embed_reference(model, params, texts, mode="fp8")
+    low = bge_m3.embed_reference(model, params, texts, mode="fp8")
     control = reference.check_vectors(cfg["limits"], low, ref)
     assert not all(n["ok"] for n in control), control
+
+
+@pytest.mark.parametrize("seed", [1, 2147483659, 3000000019])
+def test_fp8_decoder_fails_the_assistant_limits(seed):
+    """The reference decodes greedily (each token from one cache-free
+    forward); put in the program's place it reads a gap of 0, and the fp8
+    forward of the same prompts and tokens puts first, somewhere, a token
+    that lies further under the reference's best than the limit allows."""
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    cfg = config("assistant-1m-qwen2.5-0.5b")
+    spec = {**cfg["generator"], **cfg["rehearsal"]["generator"],
+            **CONTROL_SIZE}
+    params = qwen2.make_params(spec, seed)
+    rng = traffic.rng_for(seed, 5)
+    seqs = []
+    for n in (40, 150):
+        prompt = rng.integers(4, spec["vocab_size"], n).tolist()
+        out = []
+        for _ in range(24):
+            row = [len(prompt) + len(out) - 1]
+            out.append(int(qwen2.reference_logits(
+                spec, params, prompt + out, row, pad_to=512)[0].argmax()))
+        seqs.append((prompt, out))
+    gaps, low = qwen2.greedy_gaps(spec, params, seqs, control=True)
+    limit = cfg["limits"]["greedy_gap_max"]
+    assert max(float(g.max()) for g in gaps) == 0.0
+    assert max(float(g.max()) for g in low) > limit, low

@@ -16,7 +16,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 CASES = [("wrong_ids", "vec-search-k100", "recall_at_k_mean"),
          ("wrong_scores", "vec-search-k100", "score_err_max"),
          ("wrong_vectors", "mem-embed-query", "embed_dist_max"),
-         ("coarse_vectors", "mem-embed-query", "embed_dist_max")]
+         ("coarse_vectors", "mem-embed-query", "embed_dist_max"),
+         ("shifted_token", "mem-chat-sys4k", "greedy_gap_max"),
+         ("stale_prefix_page", "mem-chat-sys4k", "greedy_gap_max"),
+         ("decode_position_off", "mem-chat-sys4k", "greedy_gap_max"),
+         ("fp8_weights", "mem-chat-sys4k", "greedy_gap_max")]
 
 
 def drive(script_args):
@@ -28,7 +32,8 @@ def drive(script_args):
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("cell", ["vec-search-k100", "mem-embed-query"])
+@pytest.mark.parametrize("cell", ["vec-search-k100", "mem-embed-query",
+                                  "mem-chat-sys4k"])
 def test_sound_run_is_correct(cell):
     result = drive([os.path.join(HERE, "..", "run.py"), "--workload", cell])
     assert result["correct"] is True, result["compared"]

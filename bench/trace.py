@@ -130,6 +130,20 @@ def breakdown(events, window, top: int = 10) -> dict:
     return {"device_ops": rank(by_name), "idle_gaps": rank(gaps)}
 
 
+def op_seconds(events, window, top: int = 12) -> list:
+    """The operations inside the programs (the ``XLA Ops`` line) by summed
+    seconds: ``[name, seconds, runs]``.  For an earlier line of a traced run,
+    not for a metric: the compiler names them (``fusion.12``, ``copy.3``)."""
+    by_name: dict[str, list] = {}
+    for spans in _clip(events, OPS_LINE, window).values():
+        for a, b, name in spans:
+            slot = by_name.setdefault(_short(name), [0.0, 0])
+            slot[0] += b - a
+            slot[1] += 1
+    return [[k, v[0], v[1]] for k, v in sorted(
+        by_name.items(), key=lambda kv: -kv[1][0])[:top]]
+
+
 def reduce_capture(events: list[tuple]) -> dict:
     window = window_of(events)
     return {"window": window, "window_s": window[1] - window[0],

@@ -1,7 +1,8 @@
 """The one traffic generator: every cell's requests come from here.
 
 A cell's file (``bench/workloads/<cell>.json``) is data: the op, the number
-of clients, and the op's parameters (``k``, ``dims``, length ``bands``).  The
+of clients, and the op's parameters (``k``, ``dims``, length ``bands``, a
+chat's ``system_tokens`` / ``user_tokens``).  The
 generator turns ``(cell, seed, client, index)`` into the same request in any
 process, so the load generator (a child that never imports JAX) and the
 comparison in the serving process agree on what was sent without passing
@@ -53,20 +54,39 @@ def vector_wire(vec: np.ndarray) -> list[float]:
     return np.round(vec.astype(np.float64), 7).tolist()
 
 
+def round_lengths(lo: int, hi: int, clients: int) -> np.ndarray:
+    """One round's lengths: ``clients`` values spread evenly from ``lo`` to
+    ``hi``.  Each round deals them to the clients in an order drawn from the
+    seed, so every round of every seed holds the same work."""
+    return np.linspace(lo, hi, clients).round().astype(np.int64)
+
+
 class Stream:
     """The requests of one client of one cell, by index."""
 
     def __init__(self, cell: dict, seed: int, client: int):
         self.params = cell["params"]
-        self.kind = cell["request"]  # "vector" | "text"
-        self.seed, self.client = seed, client
+        self.kind = cell["request"]  # "vector" | "text" | "chat"
+        self.seed, self.client, self.clients = seed, client, cell["clients"]
         if self.kind == "text":
             base = band_lengths(self.params["bands"])
             self._lengths = rng_for(seed, 2, client).permutation(base)
+        elif self.kind == "chat":
+            # the run's one system message: the same words in every request
+            self._system = text_of(rng_for(seed, 7),
+                                   self.params["system_tokens"])
+        elif self.kind != "vector":
+            raise ValueError(f"request kind {self.kind!r}: vector, text, chat")
 
     def request(self, index: int):
         rng = rng_for(self.seed, 3, self.client, index)
         if self.kind == "vector":
             wire = vector_wire(unit_rows(rng, 1, self.params["dims"])[0])
             return np.asarray(wire, np.float32)
+        if self.kind == "chat":
+            u = self.params["user_tokens"]
+            deal = rng_for(self.seed, 2, index).permutation(
+                round_lengths(u["lo"], u["hi"], self.clients))
+            return {"system": self._system,
+                    "user": text_of(rng, int(deal[self.client % len(deal)]))}
         return text_of(rng, int(self._lengths[index % len(self._lengths)]))

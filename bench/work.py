@@ -4,6 +4,7 @@ yardstick stays the same whatever later implements the work.
 
 Every function returns ``{"flops": ..., "bytes": ...}``; :func:`least_seconds`
 turns that into the least time one chip could take and says which bound it.
+What a model's tokens need is in its family file (``bench/models/``).
 """
 
 from __future__ import annotations
@@ -44,25 +45,3 @@ def topk_queries(config: dict, queries: int) -> dict:
     """``queries`` answered, however they were grouped: the algorithm needs
     their multiplies, and the corpus read at least once."""
     return topk_scans(config, 1 if queries else 0, queries)
-
-
-def matmul_params(model: dict) -> int:
-    """Parameters every token multiplies against (q, k, v, o, up, down of
-    each layer); the embedding tables are gathers, not multiplies."""
-    h, i = model["hidden"], model["intermediate"]
-    return model["layers"] * (4 * h * h + 2 * h * i)
-
-
-def embed_texts(config: dict, token_lengths) -> dict:
-    """Forward passes over texts of the given token lengths: 2 FLOPs per
-    matmul parameter per token, plus QK^T and PV (4 * hidden * len^2 per
-    layer per text).  Bytes: the weights once (any number of texts can
-    share one read)."""
-    m = config["model"]
-    tokens = float(sum(token_lengths))
-    squares = float(sum(n * n for n in token_lengths))
-    flops = 2.0 * matmul_params(m) * tokens \
-        + 4.0 * m["layers"] * m["hidden"] * squares
-    return {"flops": flops,
-            "bytes": float(matmul_params(m) * BYTES_OF[m["dtype"]])
-            if tokens else 0.0}
