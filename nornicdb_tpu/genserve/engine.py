@@ -6,20 +6,27 @@ cache: admitting a second request means waiting for the first to finish,
 and every distinct prompt length compiles a fresh cache shape.  This
 engine owns the generation path end to end:
 
+* **A decoder-family seam.**  The engine serves any decoder whose module
+  owns the config class and exposes ``init_pages(cfg, num_pages,
+  page_size)``, ``num_pages(pool)`` and ``fused_step(params, cfg, meta,
+  pages, *, lmax, w, tq)`` (``models/qwen2.py``: K/V pages;
+  ``models/deepseek_v2.py``: latent pages, routed experts), resolved once
+  from ``type(cfg)``.  What a page holds is the family's; which pages a
+  sequence holds, the row layout of a step (``nornicdb_tpu/ragged.py``) and the
+  prefix cache are the scheduler's.
 * **Paged KV cache** (Ragged Paged Attention, PAPERS.md).  One pooled
   buffer of fixed-size pages shared by every sequence, with per-sequence
-  page tables (``models/qwen2.py`` ``init_kv_pages`` /
-  ``paged_prefill_chunk`` / ``paged_decode_step``).  Attention
-  block-gathers each sequence's pages; sequences join and leave the
-  running batch at step boundaries by allocating/freeing pages — no
-  cache reallocation, no cross-request shape coupling.  A
-  numerically-equivalent dense fallback path (``mode="dense"``) keeps a
-  per-sequence dense cache for escape-hatch deployments and as the
-  equivalence reference the test suite holds the paged path to.
+  page tables.  Attention block-gathers each sequence's pages; sequences
+  join and leave the running batch at step boundaries by
+  allocating/freeing pages — no cache reallocation, no cross-request
+  shape coupling.  A numerically-equivalent dense fallback path
+  (``mode="dense"``, for a family that has ``prefill`` / ``decode_step``)
+  keeps a per-sequence dense cache for escape-hatch deployments and as
+  the equivalence reference the test suite holds the paged path to.
 * **One fused ragged step per iteration** (genserve v2).  Each
-  scheduler iteration submits a SINGLE device program
-  (``models/qwen2.py`` ``ragged_fused_step``) serving every decode lane
-  plus at most one prompt-prefill chunk as ragged per-lane metadata —
+  scheduler iteration submits a SINGLE device program (the family's
+  ``fused_step``) serving every decode lane plus at most one
+  prompt-prefill chunk as ragged per-lane metadata —
   no per-phase prefill/decode program split, half the dispatch overhead
   per generated token.  The flat token batch and the chunk width are
   power-of-two bucketed (the ``round_up_pow2`` discipline), so the
@@ -68,6 +75,7 @@ gauges.
 from __future__ import annotations
 
 import hashlib
+import importlib
 import logging
 import queue as queue_mod
 import threading
@@ -84,6 +92,12 @@ from nornicdb_tpu.errors import (
     ResourceExhausted,
 )
 from nornicdb_tpu.genserve import stats as _stats
+from nornicdb_tpu.ragged import (
+    ROUTING_COUNTERS,
+    pack_ragged_meta,
+    pages_for,
+    round_up_pow2,
+)
 from nornicdb_tpu.telemetry import budget as _budget
 from nornicdb_tpu.telemetry import costmodel as _costmodel
 from nornicdb_tpu.telemetry import deviceprof as _deviceprof
@@ -125,6 +139,15 @@ class GenStats:
     errors: int = 0
     pool_resets: int = 0
     cpu_steps: int = 0
+    # routed experts (a family that has them appends these to its step's
+    # one int vector, nornicdb_tpu/ragged.py ROUTING_COUNTERS; others leave 0):
+    # top-k assignments that fell on experts held here, rows routed (one
+    # per row per expert layer), and per expert layer the fullest held
+    # expert's rows and the held experts that got any row, summed
+    expert_assignments: int = 0
+    expert_rows_max: int = 0
+    experts_hit: int = 0
+    routed_rows: int = 0
 
     def as_dict(self) -> dict:
         return {k: getattr(self, k) for k in self.__dataclass_fields__}
@@ -328,7 +351,8 @@ class _Seq:
 
 
 class GenerationEngine:
-    """Paged-KV continuous-batching decode engine for one Qwen2 model."""
+    """Paged continuous-batching decode engine for one decoder, of any
+    family (see the module note)."""
 
     def __init__(self, params, cfg, tokenizer=None, config=None,
                  manager=None):
@@ -346,9 +370,16 @@ class GenerationEngine:
         # that a warmed engine compiles nothing new in its timed pass
         self.programs: set = set()
         self._manager = manager
+        # the decoder family: the module that owns the config class
+        self._family = importlib.import_module(type(cfg).__module__)
+        if config.mode == "dense" and not all(
+                hasattr(self._family, fn) for fn in ("prefill",
+                                                     "decode_step")):
+            raise ValueError(
+                f"genserve.mode='dense' needs a dense-mode prefill and "
+                f"decode_step, and {self._family.__name__} has only the "
+                "paged fused step: serve it with genserve.mode='paged'")
         self._page_size = max(1, int(config.page_size))
-        from nornicdb_tpu.models.qwen2 import pages_for, round_up_pow2
-
         self._table_width = pages_for(int(config.max_seq_tokens),
                                       self._page_size)
         self._usable_pages = int(config.pool_pages) - 1  # page 0 = null
@@ -403,7 +434,7 @@ class GenerationEngine:
         total = int(pool.size) * pool.dtype.itemsize
         # kv_prefix is the prefix-cache-resident SUBSET of kv_pages (not
         # additive residency): how much of the pool is pinned shareable
-        per_page = total // max(1, pool.shape[2])
+        per_page = total // max(1, self._family.num_pages(pool))
         return {"kv_pages": total,
                 "kv_prefix": len(self._prefix_cache) * per_page}
 
@@ -452,8 +483,6 @@ class GenerationEngine:
         are the CONTIGUOUS pow2 range between those bounds — the warmup
         ladder walks all of them, not just the endpoints, or a mid-range
         step would pay a steady-state compile."""
-        from nornicdb_tpu.models.qwen2 import round_up_pow2
-
         classes: list[tuple[int, int]] = []
         f = 8
         while True:
@@ -508,7 +537,6 @@ class GenerationEngine:
         if not ready and (self.config.fallback or "cpu") != "cpu":
             return  # degraded + fail policy: requests will shed anyway
         kind = "default" if ready else "cpu"
-        from nornicdb_tpu.models import qwen2
         import contextlib
         import jax
         import jax.numpy as jnp
@@ -519,13 +547,13 @@ class GenerationEngine:
         w = self._table_width
         lmax = self._lmax
         with ctx:
-            pool = qwen2.init_kv_pages(self.cfg, self._usable_pages + 1,
-                                       self._page_size)
+            pool = self._family.init_pages(
+                self.cfg, self._usable_pages + 1, self._page_size)
             for f, tq in self._ragged_classes():
                 if time.monotonic() >= deadline:
                     break
                 meta, (tokens, lane_id, lane_pos, positions, logit_rows,
-                       lane_tables) = qwen2.pack_ragged_meta(lmax, w, f)
+                       lane_tables) = pack_ragged_meta(lmax, w, f)
                 tokens[:] = 0
                 lane_id[:] = lmax - 1
                 lane_pos[:] = 0
@@ -540,7 +568,7 @@ class GenerationEngine:
                 self.programs.add(("ragged", f, tq, w))
                 _deviceprof.record_compile("genserve", "ragged",
                                            f"f{f}q{tq}x{w}")
-                ids, _lg, pool = qwen2.ragged_fused_step(
+                ids, _lg, pool = self._family.fused_step(
                     params, self.cfg, jnp.asarray(meta), pool,
                     lmax=lmax, w=w, tq=tq)
                 np.asarray(ids)  # force execution before serving
@@ -925,10 +953,8 @@ class GenerationEngine:
 
     def _ensure_pool(self):
         if self._pages is None and self.config.mode != "dense":
-            from nornicdb_tpu.models import qwen2
-
             with self._platform_ctx():
-                self._pages = qwen2.init_kv_pages(
+                self._pages = self._family.init_pages(
                     self.cfg, self._usable_pages + 1, self._page_size)
         return self._pages
 
@@ -954,8 +980,6 @@ class GenerationEngine:
         _stats.PREFIX_PAGES.set(len(self._prefix_cache))
 
     def _admit(self) -> None:
-        from nornicdb_tpu.models.qwen2 import pages_for
-
         paged = self.config.mode != "dense"
         while len(self._running) < self._max_seqs:
             hits: list[int] = []
@@ -1046,8 +1070,6 @@ class GenerationEngine:
         (requeued at the queue head for readmission).  Returns False only
         when the sequence had to be shed (cannot happen for a lone
         sequence: its own bound fits the pool by construction)."""
-        from nornicdb_tpu.models.qwen2 import pages_for
-
         need = pages_for(seq.cache_len + 1, self._page_size)
         while len(seq.page_ids) < need:
             pid = self._alloc_page()
@@ -1096,10 +1118,9 @@ class GenerationEngine:
         """ONE device program per scheduler iteration: every running
         decode lane plus at most one prompt-prefill chunk (the oldest
         admitted sequence still prefilling), as ragged per-lane metadata
-        into ``qwen2.ragged_fused_step``.  Long prompts never stall the
+        into the family's ``fused_step``.  Long prompts never stall the
         running batch — they ride the same program — and decode lanes
         never pay a separate dispatch while any prompt is prefilling."""
-        from nornicdb_tpu.models import qwen2
         import jax.numpy as jnp
 
         active = [s for s in self._running if s.state == _DECODE]
@@ -1122,9 +1143,9 @@ class GenerationEngine:
             remaining = (len(chunk_seq.prefill_tokens)
                          - chunk_seq.prefill_pos)
             tq = min(self._prefill_chunk,
-                     qwen2.round_up_pow2(remaining, 16))
+                     round_up_pow2(remaining, 16))
             n_valid = min(remaining, tq)
-            f = qwen2.round_up_pow2(ndec + n_valid, 8)
+            f = round_up_pow2(ndec + n_valid, 8)
             half = f // 2
             if (ndec + n_valid < f and half >= 8
                     and half - ndec >= (n_valid + 1) // 2):
@@ -1144,12 +1165,12 @@ class GenerationEngine:
             tq, piece, n_valid, final = 1, [], 0, False
             # flat token rows: decode lanes first, then the chunk, then
             # padding up to the pow2 bucket — F scales with REAL tokens
-            f = qwen2.round_up_pow2(ndec, 8)
+            f = round_up_pow2(ndec, 8)
         lmax, w = self._lmax, self._table_width
         # ONE packed int32 host array per step (one H2D transfer); the
         # names below are writable views into it
         meta, (tokens, lane_id, lane_pos, positions, logit_rows,
-               lane_tables) = qwen2.pack_ragged_meta(lmax, w, f)
+               lane_tables) = pack_ragged_meta(lmax, w, f)
         tokens[:] = 0
         lane_id[:] = lmax - 1                        # dump lane default
         lane_pos[:] = 0
@@ -1183,7 +1204,7 @@ class GenerationEngine:
         _deviceprof.record_compile("genserve", "ragged", shape)
         with self._platform_ctx():
             try:
-                ids, _logits, self._pages = qwen2.ragged_fused_step(
+                ids, _logits, self._pages = self._family.fused_step(
                     params, self.cfg, jnp.asarray(meta), self._pages,
                     lmax=lmax, w=w, tq=tq)
             except Exception:
@@ -1197,11 +1218,19 @@ class GenerationEngine:
                 raise
             # greedy argmax runs inside the program: (Lmax,) ints cross
             # to host, not the (Lmax, V) logits (~MBs/step at real
-            # vocabs) — a bounded 4B-per-row sync, the step's output
+            # vocabs) — a bounded 4B-per-row sync, the step's output; a
+            # family with routed experts appends its routing counts to
+            # the same vector, so they cost no second read
             # nornlint: disable=NL-JAX06
             host = np.asarray(ids)
         t1 = time.perf_counter()
         dt = t1 - t0
+        routing = dict(zip(ROUTING_COUNTERS, host[lmax:].tolist()))
+        for name, count in routing.items():
+            setattr(self.stats, name, getattr(self.stats, name) + count)
+        if routing:
+            _stats.EXPERT_ASSIGNMENTS.inc(routing["expert_assignments"])
+            _stats.EXPERT_ROWS_MAX.set(routing["expert_rows_max"])
         _deviceprof.record_execute("genserve", "ragged", shape, dt)
         # the one dispatch served both phases: observability stays
         # per-phase (retroactive spans in each submitter's trace, the
@@ -1264,17 +1293,16 @@ class GenerationEngine:
     def _dense_prefill(self, seq: _Seq) -> None:
         """mode="dense" fallback: per-sequence dense (1, Tmax) cache, the
         pre-genserve decode path — the numeric reference."""
-        from nornicdb_tpu.models import qwen2
         import jax.numpy as jnp
 
         toks = seq.prefill_tokens
-        max_len = qwen2.round_up_pow2(
+        max_len = round_up_pow2(
             min(len(toks) + seq.max_new, int(self.config.max_seq_tokens)))
         t0 = time.perf_counter()
         params = self._active_params()
         self.programs.add(("dense_prefill", len(toks), max_len))
         with self._platform_ctx():
-            logits, seq.dense_cache = qwen2.prefill(
+            logits, seq.dense_cache = self._family.prefill(
                 params, self.cfg, jnp.asarray([toks], jnp.int32), max_len)
             # bounded sync: one token id, the prefill's output
             # nornlint: disable=NL-JAX06
@@ -1330,7 +1358,6 @@ class GenerationEngine:
             self._dense_decode(seq)
 
     def _dense_decode(self, seq: _Seq) -> None:
-        from nornicdb_tpu.models import qwen2
         import jax.numpy as jnp
 
         t0 = time.perf_counter()
@@ -1339,7 +1366,7 @@ class GenerationEngine:
         self.programs.add(("dense_step", max_len))
         with self._platform_ctx():
             try:
-                logits, seq.dense_cache = qwen2.decode_step(
+                logits, seq.dense_cache = self._family.decode_step(
                     params, self.cfg, jnp.asarray([seq.out[-1]], jnp.int32),
                     seq.dense_cache, jnp.asarray(seq.dense_len))
             except Exception:

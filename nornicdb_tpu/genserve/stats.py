@@ -93,3 +93,19 @@ PREFIX_PAGES = _REGISTRY.gauge(
     "KV pages currently indexed by the shared-prefix cache (resident "
     "and adoptable, whether or not any sequence holds them)",
 )
+# routed experts (a decoder family that has them; 0 forever otherwise):
+# top-k assignments that fell on the experts THIS process holds — under
+# expert parallelism its share of the routed work; rate() against
+# generated + prefilled tokens shows routing drifting off this rank
+EXPERT_ASSIGNMENTS = _REGISTRY.counter(
+    "nornicdb_genserve_expert_assignments_total",
+    "Top-k expert assignments that fell on experts held by this process "
+    "(summed over the expert layers of every fused step)",
+)
+# the last fused step's imbalance: rows of its fullest held expert, summed
+# over the expert layers (even routing = assignments / held experts)
+EXPERT_ROWS_MAX = _REGISTRY.gauge(
+    "nornicdb_genserve_expert_rows_max",
+    "Rows the fullest held expert got in the last fused step, summed over "
+    "the expert layers",
+)

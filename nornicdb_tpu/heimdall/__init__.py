@@ -16,6 +16,7 @@ from nornicdb_tpu.heimdall.manager import (
     HeimdallMetrics,
     QwenGenerator,
     TemplateGenerator,
+    WeightsGenerator,
 )
 from nornicdb_tpu.heimdall.registry import (
     MODEL_CLASSIFICATION,
@@ -31,6 +32,7 @@ from nornicdb_tpu.heimdall.registry import (
 __all__ = [
     "Bifrost", "EngineGenerator", "Generator", "HeimdallManager",
     "HeimdallMetrics", "QwenGenerator", "TemplateGenerator",
+    "WeightsGenerator",
     "PromptContext", "PromptExample", "TokenBudget", "GenerateParams",
     "CYPHER_PRIMER", "estimate_tokens",
     "ModelInfo", "ModelRegistry", "MetricsRegistry",

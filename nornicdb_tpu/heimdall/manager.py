@@ -7,9 +7,10 @@ streaming (:561), Bifrost SSE notification bus (bifrost.go:15), model
 registry (types.go:20-37), plugin actions (plugin.go), metrics
 (metrics.go).
 
-The generation backend is the Qwen2 decoder on TPU
-(nornicdb_tpu.models.qwen2 — replaces pkg/localllm llama.cpp), with a
-deterministic template fallback when no weights are mounted.
+The generation backend is a decoder on TPU behind the genserve engine
+(nornicdb_tpu.models: the Qwen2 in-image checkpoint, or any mounted
+decoder family — replaces pkg/localllm llama.cpp), with a deterministic
+template fallback when no weights are mounted.
 """
 
 from __future__ import annotations
@@ -42,6 +43,7 @@ from nornicdb_tpu.heimdall.registry import (
     ModelInfo,
     ModelRegistry,
 )
+from nornicdb_tpu.ragged import round_up_pow2
 
 
 @dataclass
@@ -111,14 +113,34 @@ def _cap_new_tokens(max_tokens: int, max_context: int) -> int:
     """Bound decode length to one trained window beyond the prompt:
     positions past 2x max_context are deep rope extrapolation for an
     in-image from-scratch model (held-out action rates were measured at
-    prompt<=window + window new tokens).  ONE implementation for both
-    weights-backed generators (QwenGenerator, EngineGenerator) so the
+    prompt<=window + window new tokens).  ONE implementation for every
+    weights-backed generator (QwenGenerator, EngineGenerator) so the
     window policy can never diverge between the sync and engine paths."""
     return max(1, min(max_tokens, max_context))
 
 
+class WeightsGenerator(Generator):
+    """A mounted decoder of ANY family (``cfg``'s module is the family:
+    genserve/engine.py): what ``db.set_heimdall_generator`` needs to front
+    it with the genserve engine — ``cfg`` / ``params`` / ``tokenizer`` /
+    ``max_context`` — and nothing else.  It has no synchronous path of its
+    own: with ``genserve.enabled`` off there is nothing to serve it."""
+
+    def __init__(self, cfg, params, tokenizer, max_context: int = 256):
+        self.cfg = cfg
+        self.params = params
+        self.tokenizer = tokenizer
+        self.max_context = max_context
+
+    def generate(self, prompt: str, max_tokens: int = 128) -> str:
+        raise RuntimeError(
+            f"a {type(self.cfg).__name__} decoder is served by the genserve "
+            "engine only: set genserve.enabled")
+
+
 class QwenGenerator(Generator):
-    """Qwen2-on-TPU backend (replaces llama.cpp generation)."""
+    """Qwen2-on-TPU backend for the in-image toy checkpoint, with its own
+    synchronous path (replaces llama.cpp generation)."""
 
     def __init__(self, cfg=None, params=None, tokenizer=None, seed: int = 0,
                  max_context: int = 256):
@@ -161,7 +183,7 @@ class QwenGenerator(Generator):
         max_tokens = self._cap_new_tokens(max_tokens)
         # bucketed cache length: one compiled program per power-of-two
         # bucket instead of one per distinct prompt length
-        max_len = self.qwen2.round_up_pow2(len(ids) + max_tokens)
+        max_len = round_up_pow2(len(ids) + max_tokens)
         logits, caches = self.qwen2.prefill(
             self.params, self.cfg, jnp.asarray([ids], jnp.int32), max_len
         )
@@ -189,7 +211,7 @@ class QwenGenerator(Generator):
 class EngineGenerator(Generator):
     """Generator served by the genserve continuous-batching engine.
 
-    Replaces the synchronous QwenGenerator path when genserve is enabled:
+    Replaces the synchronous per-request path when genserve is enabled:
     every chat/QC generation becomes a submit into the shared paged-KV
     engine, so concurrent requests decode in ONE running batch instead of
     serializing, and admission control / deadline shedding apply
@@ -200,10 +222,10 @@ class EngineGenerator(Generator):
     def __init__(self, engine, max_context: int = 256):
         self.engine = engine
         self.tokenizer = engine.tokenizer
-        # same trained-window recency trim as QwenGenerator
+        # same trained-window recency trim as the generator it fronts
         self.max_context = max_context
-        # expose the backing model like QwenGenerator (pretrain tooling
-        # and the model registry read these)
+        # expose the backing model like the generator it fronts (pretrain
+        # tooling and the model registry read these)
         self.cfg = engine.cfg
         self.params = engine.params
 

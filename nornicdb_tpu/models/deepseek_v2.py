@@ -1,0 +1,493 @@
+"""DeepSeek-V2-architecture decoder in JAX: multi-head latent attention (MLA)
+over a latent page pool, group-limited routed experts beside shared ones,
+told which of the routed experts this process holds.
+
+The layer (huggingface.co/deepseek-ai/DeepSeek-V2 ``config.json`` /
+``modeling_deepseek.py``), pre-norm RMSNorm, no biases, untied head:
+
+* attention: ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` -> per head
+  ``[q_nope | q_pe]``; ``[c_kv | k_pe] = x W_kva``, ``c_kv = RMSNorm(c_kv)``,
+  ``k_pe = RoPE(k_pe)`` (ONE head, shared by all), ``q_pe = RoPE(q_pe)``;
+  per head ``[k_nope | v] = c_kv W_kvb``; scores ``(q_nope.k_nope +
+  q_pe.k_pe) * (nope + rope)^-0.5 * m^2`` with the YaRN ``m = 0.1 *
+  mscale_all_dim * ln(factor) + 1``, causal softmax in f32.  RoPE is YaRN
+  (:func:`yarn_inv_freq`).  What a token leaves in the cache, per layer,
+  is ``[c_kv after its norm | k_pe after RoPE]``: ``kv_lora_rank +
+  qk_rope_head_dim`` values (:func:`init_pages`).
+* the ABSORBED form, the same mathematics with W_kvb folded into the query
+  and the output: ``q~ = q_nope W_kvb,k^T`` (``kv_lora_rank`` wide), ``s =
+  q~.c_kv + q_pe.k_pe``, ``o = (sum_u p c_kv(u)) W_kvb,v``: every head
+  attends ONE cached row and per-head K/V over the cache never exists.
+  The serving step (:func:`mla_moe_fused_step`) uses it for its decode
+  block and its chunk block alike, and so does the plain :func:`forward`;
+  the expanded form as published is the reference's
+  (``models/reference/deepseek_v2.py``).
+* feed-forward: the first ``first_k_dense_replace`` layers a dense SwiGLU;
+  the others ``p = softmax(x W_g)`` in f32 over ALL ``n_routed_experts``,
+  the experts in ``n_group`` groups, a group's score its best expert's,
+  the ``topk_group`` best groups kept, the ``num_experts_per_tok`` best
+  experts among them, gates ``routed_scaling_factor * p`` (not
+  renormalised), plus the shared experts (one SwiGLU of ``n_shared_experts
+  * moe_intermediate_size``) for every row.
+
+``held_experts = (first, count)`` says which routed experts this process
+holds (expert parallelism: the model's ``n_group`` is the number of
+devices a layer's experts are spread over, a group is one device's).  The
+router keeps its published width and top-k; only assignments that fall on
+held experts are computed (a masked matmul over the held ones: no dropped
+tokens, no capacity factor) and what the absent experts would add is left
+out: the partial result goes to the next layer.  Nothing here stands in for
+the other ranks or their exchange.
+
+Departures from the checkpoint's layout, none from its mathematics: RoPE
+rotates half-pairs ``(i, i + d/2)`` where the checkpoint interleaves ``(2i,
+2i+1)`` (a column permutation of ``W_qb`` / ``W_kva``); ``W_kvb`` is kept
+as its two column blocks ``kv_b_k`` / ``kv_b_v``; an expert's three
+matrices are stacked over the held experts.
+
+``DeepSeekV2Config()`` is the published model.  Presets:
+DEEPSEEK_V2_EP8_5L (one of eight expert-parallel ranks, 1 dense + 4 expert
+layers, 1/8 vocabulary: the benchmark's cut), DEEPSEEK_V2_SMALL (tests).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from nornicdb_tpu.ragged import NULL_PAGE, unpack_ragged_meta
+from nornicdb_tpu.models.layers import dense, rms_norm
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepSeekV2Config:
+    vocab_size: int = 102400
+    hidden_size: int = 5120
+    num_hidden_layers: int = 60
+    first_k_dense_replace: int = 1
+    num_attention_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 12288
+    moe_intermediate_size: int = 1536
+    n_routed_experts: int = 160      # the router's width, as published
+    held_experts: tuple = (0, 160)   # (first, count) of them held here
+    n_shared_experts: int = 2
+    num_experts_per_tok: int = 6
+    n_group: int = 8
+    topk_group: int = 3
+    routed_scaling_factor: float = 16.0
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_factor: float = 40.0
+    rope_original_max_position_embeddings: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 0.707
+    rope_mscale_all_dim: float = 0.707
+    max_position_embeddings: int = 163840
+    dtype: str = "bfloat16"
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token leaves in the cache, per layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def page_row_width(self) -> int:
+        """A pool row: ``latent_width`` padded with zeros to whole 128-lane
+        tiles (576 -> 640).  The TPU's default layout of an array whose
+        minor dimension is not a multiple of 128 puts ANOTHER dimension
+        minor (here the pages), and a step that scatters rows and gathers
+        pages then copies the whole pool to row-major and back, every step
+        (PERF.md, PR 29 and PR 30); with whole tiles the default IS
+        row-major and scatter, gather and the donated buffer agree."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def expert_layers(self) -> int:
+        return self.num_hidden_layers - self.first_k_dense_replace
+
+
+DEEPSEEK_V2_EP8_5L = DeepSeekV2Config(
+    vocab_size=12800, num_hidden_layers=5, held_experts=(0, 20))
+DEEPSEEK_V2_SMALL = DeepSeekV2Config(
+    vocab_size=512, hidden_size=128, num_hidden_layers=3,
+    num_attention_heads=8, q_lora_rank=48, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    intermediate_size=256, moe_intermediate_size=64, n_routed_experts=16,
+    held_experts=(0, 16), n_shared_experts=1, num_experts_per_tok=4,
+    n_group=4, topk_group=2, routed_scaling_factor=4.0,
+    rope_original_max_position_embeddings=64, max_position_embeddings=2560,
+)
+
+
+# ------------------------------------------------------------------ YaRN
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(cfg: DeepSeekV2Config) -> np.ndarray:
+    """The ``qk_rope_head_dim / 2`` rotary frequencies: each blended between
+    the plain ``f = theta^(-2i/d)`` and the interpolated ``f / factor`` by
+    a linear ramp over the correction range (the dimensions that turn
+    ``beta_fast`` .. ``beta_slow`` times within the original context keep
+    ``f`` .. take ``f / factor``)."""
+    d, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    plain = 1.0 / base ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def correction_dim(rotations: float) -> float:
+        return d * math.log(cfg.rope_original_max_position_embeddings
+                            / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    return plain / cfg.rope_factor * ramp + plain * (1.0 - ramp)
+
+
+def rope_scale(cfg: DeepSeekV2Config) -> float:
+    """What cos and sin are multiplied by: ``mscale / mscale_all_dim``."""
+    return _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) \
+        / _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+
+
+def softmax_scale(cfg: DeepSeekV2Config) -> float:
+    m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _rope_tables(cfg: DeepSeekV2Config, max_pos: int):
+    """(max_pos, rope/2) cos and sin, angles in float64 then f32."""
+    angles = np.outer(np.arange(max_pos, dtype=np.float64),
+                      yarn_inv_freq(cfg))
+    scale = rope_scale(cfg)
+    return (jnp.asarray(np.cos(angles) * scale, jnp.float32),
+            jnp.asarray(np.sin(angles) * scale, jnp.float32))
+
+
+def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotate half-pairs of the last axis; cos/sin broadcast against
+    ``x[..., :d/2]``."""
+    xf = x.astype(jnp.float32)
+    d2 = x.shape[-1] // 2
+    x1, x2 = xf[..., :d2], xf[..., d2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+# --------------------------------------------------------------- weights
+def init_params(cfg: DeepSeekV2Config, key: jax.Array) -> dict:
+    """Seeded weights: N(0, 1/fan_in) matrices, unit norm scales.  Only the
+    held experts are made."""
+    dt = jnp.dtype(cfg.dtype)
+    h, heads = cfg.hidden_size, cfg.num_attention_heads
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    held, im = cfg.held_experts[1], cfg.moe_intermediate_size
+
+    def mat(k, *shape, fan_in):
+        return (jax.random.normal(k, shape, jnp.float32)
+                * fan_in ** -0.5).astype(dt)
+
+    def mlp(k, width, lead=()):
+        k = jax.random.split(k, 3)
+        return {"gate": mat(k[0], *lead, h, width, fan_in=h),
+                "up": mat(k[1], *lead, h, width, fan_in=h),
+                "down": mat(k[2], *lead, width, h, fan_in=width)}
+
+    ones = lambda n: {"scale": jnp.ones((n,), jnp.float32)}  # noqa: E731
+    keys = jax.random.split(key, cfg.num_hidden_layers + 2)
+    params = {"tok_emb": mat(keys[0], cfg.vocab_size, h, fan_in=h),
+              "lm_head": {"w": mat(keys[1], h, cfg.vocab_size, fan_in=h)},
+              "final_norm": ones(h), "blocks": []}
+    for li in range(cfg.num_hidden_layers):
+        k = jax.random.split(keys[2 + li], 10)
+        blk = {
+            "attn_norm": ones(h), "mlp_norm": ones(h),
+            "q_a": {"w": mat(k[0], h, cfg.q_lora_rank, fan_in=h)},
+            "q_a_norm": ones(cfg.q_lora_rank),
+            "q_b": {"w": mat(k[1], cfg.q_lora_rank, heads * (nope + rope),
+                             fan_in=cfg.q_lora_rank)},
+            "kv_a": {"w": mat(k[2], h, cfg.kv_lora_rank + rope, fan_in=h)},
+            "kv_a_norm": ones(cfg.kv_lora_rank),
+            "kv_b_k": mat(k[3], cfg.kv_lora_rank, heads, nope,
+                          fan_in=cfg.kv_lora_rank),
+            "kv_b_v": mat(k[4], cfg.kv_lora_rank, heads, vd,
+                          fan_in=cfg.kv_lora_rank),
+            "o": {"w": mat(k[5], heads * vd, h, fan_in=heads * vd)},
+        }
+        if li < cfg.first_k_dense_replace:
+            blk["mlp"] = mlp(k[6], cfg.intermediate_size)
+        else:
+            blk["router"] = mat(k[7], h, cfg.n_routed_experts, fan_in=h)
+            blk["experts"] = mlp(k[8], im, lead=(held,))
+            blk["shared"] = mlp(k[9], cfg.n_shared_experts * im)
+        params["blocks"].append(blk)
+    return params
+
+
+# --------------------------------------------------------- feed-forward
+def _swiglu(mlp: dict, x: jax.Array) -> jax.Array:
+    gate = dense({"w": mlp["gate"]}, x)
+    return dense({"w": mlp["down"]},
+                 jax.nn.silu(gate) * dense({"w": mlp["up"]}, x))
+
+
+def route(cfg: DeepSeekV2Config, router: jax.Array, x: jax.Array):
+    """Group-limited greedy top-k over ALL routed experts, in f32.
+    x (N, hidden) -> (expert ids (N, k), gates (N, k) = scaling * p)."""
+    e, g = cfg.n_routed_experts, cfg.n_group
+    logits = jnp.einsum("nh,he->ne", x.astype(jnp.float32),
+                        router.astype(jnp.float32), precision=_HI)
+    p = jax.nn.softmax(logits, axis=-1)
+    _, groups = jax.lax.top_k(p.reshape(-1, g, e // g).max(axis=-1),
+                              cfg.topk_group)
+    kept = jax.nn.one_hot(groups, g, dtype=jnp.bool_).any(axis=1)
+    allowed = jnp.repeat(kept, e // g, axis=1)
+    gates, ids = jax.lax.top_k(jnp.where(allowed, p, 0.0),
+                               cfg.num_experts_per_tok)
+    return ids, gates * cfg.routed_scaling_factor
+
+
+def routed_experts(cfg: DeepSeekV2Config, blk: dict, x: jax.Array,
+                   valid: jax.Array | None = None):
+    """What the HELD experts add for rows x (N, hidden), and the routing
+    counts over the ``valid`` rows: f32 (N, hidden), int32 (3,) =
+    (assignments on held experts, the fullest held expert's rows, held
+    experts that got a row).
+
+    A masked matmul: every held expert's gate/up runs over every row (at
+    serving batch sizes the cost is reading the expert's weights, once,
+    whoever is routed to it) and a row's gate, zero where it was not
+    routed to that expert, scales the activation before ONE down
+    projection over (expert, width)."""
+    first, count = cfg.held_experts
+    with jax.named_scope("moe.route"):
+        ids, gates = route(cfg, blk["router"], x)
+        # (N, k, count) one-hot of the held experts' local ids: an id
+        # outside first .. first+count-1 gives a zero row
+        on = jax.nn.one_hot(ids - first, count, dtype=jnp.float32)
+        weight = jnp.einsum("nk,nkc->nc", gates, on)
+        rows = on.sum(axis=1)
+        if valid is not None:
+            rows = rows * valid[:, None].astype(jnp.float32)
+        per_expert = rows.sum(axis=0)
+        counts = jnp.stack([per_expert.sum(), per_expert.max(),
+                            (per_expert > 0).sum()]).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        ex = blk["experts"]
+        gate = jnp.einsum("nh,chi->nci", x, ex["gate"],
+                          preferred_element_type=jnp.float32)
+        up = jnp.einsum("nh,chi->nci", x, ex["up"],
+                        preferred_element_type=jnp.float32)
+        act = (jax.nn.silu(gate) * up * weight[:, :, None]).astype(x.dtype)
+        out = jnp.einsum("nci,cih->nh", act, ex["down"],
+                         preferred_element_type=jnp.float32)
+    return out, counts
+
+
+def _feed_forward(cfg: DeepSeekV2Config, blk: dict, h: jax.Array,
+                  valid: jax.Array | None = None):
+    """h (N, hidden) -> (h + its feed-forward, routing counts (3,))."""
+    x = rms_norm(blk["mlp_norm"], h, cfg.rms_norm_eps)
+    if "mlp" in blk:
+        return h + _swiglu(blk["mlp"], x), jnp.zeros((3,), jnp.int32)
+    routed, counts = routed_experts(cfg, blk, x, valid)
+    with jax.named_scope("moe.shared"):
+        shared = _swiglu(blk["shared"], x)
+    return h + (routed + shared.astype(jnp.float32)).astype(h.dtype), counts
+
+
+# ------------------------------------------------------------- attention
+def _project(cfg: DeepSeekV2Config, blk: dict, h: jax.Array, cos, sin):
+    """Rows h (N, hidden) at the positions of cos/sin (N, rope/2) ->
+    q_nope (N, heads, nope), q_pe (N, heads, rope) rotated, and the cached
+    row [c_kv after its norm | k_pe rotated] (N, latent_width)."""
+    heads, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    x = rms_norm(blk["attn_norm"], h, cfg.rms_norm_eps)
+    c_q = rms_norm(blk["q_a_norm"], dense(blk["q_a"], x), cfg.rms_norm_eps)
+    q = dense(blk["q_b"], c_q).reshape(
+        -1, heads, nope + cfg.qk_rope_head_dim)
+    kv = dense(blk["kv_a"], x)
+    c_kv = rms_norm(blk["kv_a_norm"], kv[:, :cfg.kv_lora_rank],
+                    cfg.rms_norm_eps)
+    k_pe = _rope(kv[:, cfg.kv_lora_rank:], cos, sin)
+    q_pe = _rope(q[..., nope:], cos[:, None], sin[:, None])
+    return q[..., :nope], q_pe, jnp.concatenate([c_kv, k_pe], axis=-1)
+
+
+def absorb_query(blk: dict, q_nope: jax.Array, q_pe: jax.Array):
+    """[q~ | q_pe] (..., heads, latent_width): q_nope through W_kvb,k, so a
+    head's score against a cached row is one dot product."""
+    q_lat = jnp.einsum("...hn,chn->...hc", q_nope, blk["kv_b_k"],
+                       preferred_element_type=jnp.float32)
+    return jnp.concatenate([q_lat.astype(q_nope.dtype), q_pe], axis=-1)
+
+
+def attend_absorbed(cfg: DeepSeekV2Config, blk: dict, q_abs: jax.Array,
+                    rows: jax.Array, mask: jax.Array) -> jax.Array:
+    """q_abs (L, T, heads, width) against the cached rows (L, S, width)
+    under the additive mask (L, 1, T, S) -> (L, T, heads, v_head_dim);
+    width = latent_width, or page_row_width with zeros behind on both
+    sides.  The values are the rows themselves (their c_kv part), expanded
+    through W_kvb,v after the weighted sum."""
+    s = jnp.einsum("lthd,lsd->lhts", q_abs, rows,
+                   preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(s * softmax_scale(cfg) + mask, axis=-1)
+    # over the whole row (the k_pe columns ride along and are dropped):
+    # slicing the gathered rows first would copy them
+    o_lat = jnp.einsum("lhts,lsd->lhtd", p.astype(rows.dtype), rows,
+                       preferred_element_type=jnp.float32)
+    o_lat = o_lat[..., :cfg.kv_lora_rank].astype(rows.dtype)
+    return jnp.einsum("lhtc,chv->lthv", o_lat, blk["kv_b_v"],
+                      preferred_element_type=jnp.float32).astype(rows.dtype)
+
+
+def _logits(params: dict, cfg: DeepSeekV2Config, h: jax.Array) -> jax.Array:
+    x = rms_norm(params["final_norm"], h, cfg.rms_norm_eps)
+    return jnp.einsum("...h,hv->...v", x, params["lm_head"]["w"],
+                      preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def forward(params: dict, cfg: DeepSeekV2Config,
+            input_ids: jax.Array) -> jax.Array:
+    """(B, T) -> (B, T, V) f32 logits, causal, no cache: the plain batched
+    forward in the configuration's dtype, attention in the absorbed form."""
+    b, t = input_ids.shape
+    cos, sin = (jnp.tile(a, (b, 1)) for a in _rope_tables(cfg, t))
+    mask = jnp.where(jnp.tril(jnp.ones((t, t), bool)), 0.0, -1e30)[None, None]
+    h = params["tok_emb"][input_ids].reshape(b * t, -1)
+    lanes = lambda a: a.reshape(b, t, *a.shape[1:])  # noqa: E731
+    for blk in params["blocks"]:
+        q_nope, q_pe, rows = _project(cfg, blk, h, cos, sin)
+        o = attend_absorbed(
+            cfg, blk, absorb_query(blk, lanes(q_nope), lanes(q_pe)),
+            lanes(rows), mask)
+        h = h + dense(blk["o"], o.reshape(b * t, -1))
+        h, _ = _feed_forward(cfg, blk, h)
+    return _logits(params, cfg, h).reshape(b, t, -1)
+
+
+# ------------------------------------------------ the latent page pool
+def init_pages(cfg: DeepSeekV2Config, num_pages: int,
+               page_size: int) -> jax.Array:
+    """One pooled latent cache: (layers, num_pages, page_size,
+    page_row_width): one row a token a layer (``[c_kv | k_pe | zeros]``),
+    no K/V axis, no head axis.  Page 0 is the null page.  The step
+    scatters rows by (page, slot) and gathers whole pages by page id: both
+    index the leading page axes and leave the row contiguous, so the pool
+    keeps one layout (see ``page_row_width``)."""
+    return jnp.zeros((cfg.num_hidden_layers, num_pages, page_size,
+                      cfg.page_row_width), jnp.dtype(cfg.dtype))
+
+
+def num_pages(pool: jax.Array) -> int:
+    """Pages of a pool made by :func:`init_pages` (null page included)."""
+    return pool.shape[1]
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "lmax", "w", "tq"),
+                   donate_argnums=(3,))
+def mla_moe_fused_step(params, cfg: DeepSeekV2Config, meta: jax.Array,
+                       pages: jax.Array, *, lmax: int, w: int, tq: int):
+    """One fused prefill+decode step over the latent pool, on the engine's
+    flat rows (``nornicdb_tpu/ragged.py``: ``meta`` holds F token rows, their
+    lanes and positions, ``lmax`` logit rows and the ``(lmax, w)`` page
+    tables; ``tq`` is the chunk block's static width, 1 = decode only).
+
+    Each row's ``[c_kv | k_pe]`` is written once to its (page, slot); the
+    decode block (one query a lane) and the chunk block (``tq`` queries of
+    the chunk lane) then attend their lanes' gathered pages in the
+    absorbed form.  Returns ``(ints, logits, pages)``: ``ints`` = the
+    ``lmax`` greedy ids followed by the step's routing counts (ROUTING_
+    COUNTERS order: assignments on held experts, the fullest held expert's
+    rows and the held experts hit, each summed over the expert layers, and
+    rows routed = valid rows x expert layers), so one device-to-host read
+    carries both; ``logits`` (lmax, V) f32 for ``logit_rows``; ``pages``
+    is DONATED."""
+    tokens, lane_id, lane_pos, positions, logit_rows, lane_tables = \
+        unpack_ragged_meta(meta, lmax, w)
+    f = tokens.shape[0]
+    ps = pages.shape[2]
+    max_len = w * ps
+    cos_t, sin_t = _rope_tables(cfg, max_len)
+    valid = positions >= 0
+    pos_c = jnp.clip(positions, 0, max_len - 1)
+    cos, sin = cos_t[pos_c], sin_t[pos_c]
+    lane_c = jnp.clip(lane_id, 0, lmax - 1)
+    slot_c = jnp.clip(lane_pos, 0, tq - 1)
+    is_chunk = lane_id == lmax - 2
+    phys = jnp.where(
+        valid, lane_tables[lane_c, jnp.clip(pos_c // ps, 0, w - 1)],
+        NULL_PAGE)
+    off = pos_c % ps
+    # decode block: the decode lanes and, last, a dump lane for every row
+    # that is not a decode row (masked everywhere, never gathered back)
+    ldec = lmax - 1
+    dec_lane = jnp.where(is_chunk | ~valid, ldec - 1,
+                         jnp.minimum(lane_c, ldec - 1))
+    pos_dec = jnp.full((ldec, 1), -1, jnp.int32).at[dec_lane, 0].set(
+        jnp.where(valid & ~is_chunk, positions, -1))
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, max_len), 1)
+    mask_dec = jnp.where(slot[None] <= pos_dec[:, :, None],
+                         0.0, -1e30)[:, None]
+    dec_tables = lane_tables[:ldec]
+    if tq > 1:
+        # chunk rows scatter into the (1, tq) block; every other row's
+        # index lands out of bounds on the lane axis and is dropped
+        chunk_row = jnp.where(is_chunk & valid, 0, 1)
+        pos_chk = jnp.full((1, tq), -1, jnp.int32).at[
+            chunk_row, slot_c].set(positions, mode="drop")
+        mask_chk = jnp.where(slot[None] <= pos_chk[:, :, None],
+                             0.0, -1e30)[:, None]
+        chunk_table = lane_tables[lmax - 2][None]
+    h = params["tok_emb"][tokens]                    # (F, hidden)
+    pad = cfg.page_row_width - cfg.latent_width      # zeros: score nothing
+    counts = jnp.zeros((3,), jnp.int32)
+    for li, blk in enumerate(params["blocks"]):
+        with jax.named_scope("mla.project"):
+            q_nope, q_pe, row = _project(cfg, blk, h, cos, sin)
+            pages = pages.at[li, phys, off].set(
+                jnp.pad(row, ((0, 0), (0, pad))))
+        with jax.named_scope("mla.absorb"):
+            q_abs = jnp.pad(absorb_query(blk, q_nope, q_pe),
+                            ((0, 0), (0, 0), (0, pad)))  # (F, heads, row)
+        with jax.named_scope("mla.attend"):
+            q_dec = jnp.zeros((ldec, 1) + q_abs.shape[1:], q_abs.dtype)
+            q_dec = q_dec.at[dec_lane, 0].set(q_abs)
+            o_dec = attend_absorbed(
+                cfg, blk, q_dec,
+                pages[li, dec_tables].reshape(ldec, max_len, -1), mask_dec)
+            o = o_dec[dec_lane, 0]                   # (F, heads, v)
+            if tq > 1:
+                q_chk = jnp.zeros((1, tq) + q_abs.shape[1:], q_abs.dtype)
+                q_chk = q_chk.at[chunk_row, slot_c].set(q_abs, mode="drop")
+                o_chk = attend_absorbed(
+                    cfg, blk, q_chk,
+                    pages[li, chunk_table].reshape(1, max_len, -1), mask_chk)
+                o = jnp.where(is_chunk[:, None, None], o_chk[0, slot_c], o)
+        h = h + dense(blk["o"], o.reshape(f, -1))
+        h, layer_counts = _feed_forward(cfg, blk, h, valid)
+        counts = counts + layer_counts
+    logits = _logits(params, cfg, h[jnp.clip(logit_rows, 0, f - 1)])
+    routed = valid.sum().astype(jnp.int32) * cfg.expert_layers
+    ints = jnp.concatenate([jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                            counts, routed[None]])
+    return ints, logits, pages
+
+
+# the decoder-family seam (genserve/engine.py); no dense-mode pair
+fused_step = mla_moe_fused_step

@@ -17,7 +17,6 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from nornicdb_tpu.models.layers import (
     apply_rope,
@@ -30,6 +29,7 @@ from nornicdb_tpu.models.layers import (
     rms_norm,
     rope_freqs,
 )
+from nornicdb_tpu.ragged import NULL_PAGE
 
 
 @dataclass(frozen=True)
@@ -231,16 +231,6 @@ def _cached_step(params, cfg: QwenConfig, token: jax.Array, caches,
     return _logits(params, cfg, h)[:, 0, :], new_caches
 
 
-def round_up_pow2(n: int, floor: int = 64) -> int:
-    """Bucket a KV-cache length so jits stay bounded: without this, every
-    distinct prompt length compiles a fresh prefill + decode_step (the
-    same policy as TPUEmbedder's length buckets, embed/base.py)."""
-    out = floor
-    while out < n:
-        out *= 2
-    return out
-
-
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(3,))
 def decode_step(params, cfg: QwenConfig, token: jax.Array, caches,
                 pos: jax.Array):
@@ -266,14 +256,8 @@ def decode_step(params, cfg: QwenConfig, token: jax.Array, caches,
 # block-gathers each sequence's pages into contiguous (S = P*page_size)
 # keys and masks by true length.  Physical page 0 is RESERVED as the null/
 # scratch page: padded lanes and padded chunk positions route their writes
-# there, so a static-shape program never corrupts a live page.
-
-NULL_PAGE = 0
-
-
-def pages_for(n_tokens: int, page_size: int) -> int:
-    """Logical pages needed to hold n_tokens cache slots."""
-    return max(1, -(-n_tokens // page_size))
+# there, so a static-shape program never corrupts a live page
+# (``NULL_PAGE``, ``pages_for``: nornicdb_tpu/ragged.py).
 
 
 def init_kv_pages(cfg: QwenConfig, num_pages: int, page_size: int) -> jax.Array:
@@ -453,21 +437,6 @@ def paged_prefill_chunk(params, cfg: QwenConfig, chunk_ids: jax.Array,
 # bit-identical to the sequential chunk-then-decode programs.
 
 
-def pack_ragged_meta(lmax: int, w: int, f: int):
-    """Allocate the packed int32 metadata array for one fused step and
-    return (meta, views): views are writable slices (tokens, lane_id,
-    lane_pos, positions, logit_rows, lane_tables) of ``meta``."""
-    meta = np.empty((4 * f + lmax + lmax * w,), np.int32)
-    tokens = meta[:f]
-    lane_id = meta[f:2 * f]
-    lane_pos = meta[2 * f:3 * f]
-    positions = meta[3 * f:4 * f]
-    logit_rows = meta[4 * f:4 * f + lmax]
-    lane_tables = meta[4 * f + lmax:].reshape(lmax, w)
-    return meta, (tokens, lane_id, lane_pos, positions, logit_rows,
-                  lane_tables)
-
-
 @functools.partial(
     jax.jit, static_argnames=("cfg", "lmax", "w", "tq", "attn_impl"),
     donate_argnums=(3,),
@@ -575,6 +544,26 @@ def ragged_fused_step(params, cfg: QwenConfig, meta: jax.Array,
     h_sel = h[jnp.clip(logit_rows, 0, f - 1)]        # (Lmax, 1, hidden)
     logits = _logits(params, cfg, h_sel)[:, 0, :]
     return jnp.argmax(logits, axis=-1), logits, pages
+
+
+# -- the decoder-family seam (genserve/engine.py resolves this module from
+# type(cfg) and calls these three; ``prefill`` / ``decode_step`` above are
+# the optional dense-mode pair) ----------------------------------------------
+# the seam's name for the pool maker; ``init_kv_pages`` stays because the
+# benchmark's files call it (bench/tests/test_qwen2_reference.py)
+init_pages = init_kv_pages
+
+
+def fused_step(params, cfg: QwenConfig, meta, pages, *, lmax: int, w: int,
+               tq: int):
+    """The family's step: :func:`ragged_fused_step`, looked up when called
+    (fault injectors and tests replace the module attribute)."""
+    return ragged_fused_step(params, cfg, meta, pages, lmax=lmax, w=w, tq=tq)
+
+
+def num_pages(pool: jax.Array) -> int:
+    """Pages of a pool made by :func:`init_pages` (null page included)."""
+    return pool.shape[2]
 
 
 def generate(

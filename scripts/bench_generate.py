@@ -89,9 +89,10 @@ def bench_sequential(params, cfg, requests, eos_id: int) -> dict:
     import jax.numpy as jnp
 
     from nornicdb_tpu.models import qwen2
+    from nornicdb_tpu.ragged import round_up_pow2
 
     def run_one(prompt, max_new):
-        max_len = qwen2.round_up_pow2(len(prompt) + max_new)
+        max_len = round_up_pow2(len(prompt) + max_new)
         logits, caches = qwen2.prefill(
             params, cfg, jnp.asarray([prompt], jnp.int32), max_len)
         tok = int(np.asarray(logits)[0].argmax())

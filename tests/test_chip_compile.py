@@ -224,10 +224,11 @@ def test_ragged_fused_step_qwen_widths(one_chip, f, tq):
     import jax.numpy as jnp
 
     from nornicdb_tpu.models import qwen2
+    from nornicdb_tpu.ragged import pack_ragged_meta
 
     cfg = dataclasses.replace(qwen2.QWEN25_05B, layers=2)
     lmax, w, pages, page = 10, 16, 129, 16
-    meta, _ = qwen2.pack_ragged_meta(lmax, w, f)
+    meta, _ = pack_ragged_meta(lmax, w, f)
     pool = (cfg.layers, 2, pages, page, cfg.kv_heads,
             cfg.hidden // cfg.heads)
     compiled = qwen2.ragged_fused_step.lower(
@@ -237,3 +238,40 @@ def test_ragged_fused_step_qwen_widths(one_chip, f, tq):
         lmax=lmax, w=w, tq=tq,
     ).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("f,tq", [(16, 1), (64, 64)],
+                         ids=["decode", "prefill-chunk"])
+def test_mla_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
+    """The DeepSeek-V2 step at the benchmark's cut (published widths, 1 + 4
+    layers, 20 held experts, 12,800-row head) and the cell's engine geometry
+    (16 lanes + chunk + dump, page 16, 512-page tables, 8,193-page pool):
+    it fits the chip beside the deployment's 5.43 GB, the latent pool goes
+    in and out in ONE row-major layout, and no pool-sized copy is left in
+    the step (a 576-wide row makes the compiler put the pages axis minor
+    and copy the whole pool to row-major and back: PERF.md section 5)."""
+    import re
+
+    import jax.numpy as jnp
+
+    from nornicdb_tpu.ragged import pack_ragged_meta
+    from nornicdb_tpu.models import deepseek_v2 as ds
+
+    cfg = ds.DEEPSEEK_V2_EP8_5L
+    lmax, w, pages, page = 18, 512, 8193, 16
+    meta, _ = pack_ragged_meta(lmax, w, f)
+    pool = (cfg.num_hidden_layers, pages, page, cfg.page_row_width)
+    compiled = ds.fused_step.lower(
+        _params_on(ds.init_params, cfg, one_chip), cfg,
+        _sds(meta.shape, jnp.int32, one_chip),
+        _sds(pool, jnp.bfloat16, one_chip), lmax=lmax, w=w, tq=tq,
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= int(np.prod(pool)) * 2  # donated
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < (16 << 30) - 5_430_000_000
+    text = compiled.as_text()
+    shape = "bf16[%s]" % ",".join(map(str, pool))
+    layouts = set(re.findall(re.escape(shape) + r"\{([0-9,]+)", text))
+    assert layouts == {"3,2,1,0"}, layouts
+    assert not re.search(re.escape(shape) + r"\S* copy\(", text)

@@ -29,6 +29,7 @@ from nornicdb_tpu.genserve import GenerationEngine
 from nornicdb_tpu.models import layers, qwen2
 from nornicdb_tpu.models.tokenizer import HashTokenizer
 from nornicdb_tpu.ops import pallas_kernels as pk
+from nornicdb_tpu.ragged import pack_ragged_meta, round_up_pow2
 
 CFG = qwen2.QWEN_SMALL
 PARAMS = qwen2.init_params(CFG, jax.random.PRNGKey(0))
@@ -183,9 +184,9 @@ class TestFusedStep:
                 jnp.asarray(len(prompt)))
         tq = 32
         n_valid = len(chunk_prompt)
-        f = qwen2.round_up_pow2(2 + n_valid, 16)
+        f = round_up_pow2(2 + n_valid, 16)
         meta, (tokens, lane_id, lane_pos, positions, logit_rows,
-               lane_tables) = qwen2.pack_ragged_meta(lmax, w, f)
+               lane_tables) = pack_ragged_meta(lmax, w, f)
         tokens[:] = 0
         lane_id[:] = lmax - 1
         lane_pos[:] = 0
@@ -227,9 +228,9 @@ class TestFusedStep:
         prompt = _prompt(21, seed=5)
         tq = 32
         n_valid = len(prompt)
-        f = qwen2.round_up_pow2(n_valid, 16)
+        f = round_up_pow2(n_valid, 16)
         meta, (tokens, lane_id, lane_pos, positions, logit_rows,
-               lane_tables) = qwen2.pack_ragged_meta(lmax, w, f)
+               lane_tables) = pack_ragged_meta(lmax, w, f)
         tokens[:] = 0
         lane_id[:] = lmax - 1
         lane_pos[:] = 0
@@ -377,7 +378,7 @@ class TestWarmupCoverage:
         # 32 + 3 -> F buckets {32, 48->64}; ALL contiguous pow2 stops
         assert (32, 32) in classes and (64, 32) in classes
         for fa, tqa in classes:
-            assert fa == qwen2.round_up_pow2(fa, 8)
+            assert fa == round_up_pow2(fa, 8)
 
     def test_warmup_then_steady_traffic_compiles_nothing(self):
         """One shape-class compile per (F, Tq) bucket at warmup; varied

@@ -443,14 +443,24 @@ def _module_name(lowered) -> str:
 def _program_modules() -> dict:
     """metric -> XLA module name of the jitted callable it is meant to
     find, lowered from the callable the serve stack runs."""
+    import jax
     import jax.numpy as jnp
 
+    from nornicdb_tpu.models import deepseek_v2
     from nornicdb_tpu.ops.pallas_kernels import streaming_cosine_topk
 
     embedder = TPUEmbedder(cfg=F32_CFG)
     ids, sel = jnp.ones((2, 16), jnp.int32), jnp.zeros((2,), jnp.int32)
     q, c = jnp.ones((1, 128)), jnp.ones((1024, 128))
+    dsv2 = deepseek_v2.DEEPSEEK_V2_SMALL
+    lmax, w, f = 4, 8, 16
     return {
+        "step_roofline.dsv2": _module_name(deepseek_v2.fused_step.lower(
+            jax.eval_shape(lambda: deepseek_v2.init_params(
+                dsv2, jax.random.PRNGKey(0))), dsv2,
+            jax.ShapeDtypeStruct((4 * f + lmax + lmax * w,), jnp.int32),
+            jax.eval_shape(lambda: deepseek_v2.init_pages(dsv2, 9, 16)),
+            lmax=lmax, w=w, tq=16)),
         "fwd_roofline.embed": _module_name(embedder._fwd_packed.lower(
             embedder.params, ids, ids, ids + 2, sel, sel)),
         "knn_roofline.search": _module_name(streaming_cosine_topk.lower(
