@@ -20,12 +20,11 @@ import jax.numpy as jnp
 
 from nornicdb_tpu.models.layers import (
     apply_rope,
-    attention,
     dense,
+    grouped_attention,
     init_dense,
     init_rms_norm,
     normal_init,
-    repeat_kv,
     rms_norm,
     rope_freqs,
 )
@@ -96,7 +95,6 @@ def init_params(cfg: QwenConfig, key: jax.Array) -> dict:
 def _block(cfg: QwenConfig, blk: dict, h, angles, mask, kv_cache=None, pos=None):
     b, t, _ = h.shape
     head_dim = cfg.hidden // cfg.heads
-    n_rep = cfg.heads // cfg.kv_heads
     x = rms_norm(blk["attn_norm"], h, cfg.rms_eps)
     q = dense(blk["q"], x).reshape(b, t, cfg.heads, head_dim)
     k = dense(blk["k"], x).reshape(b, t, cfg.kv_heads, head_dim)
@@ -110,7 +108,9 @@ def _block(cfg: QwenConfig, blk: dict, h, angles, mask, kv_cache=None, pos=None)
         cv = jax.lax.dynamic_update_slice(cv, v, (0, pos, 0, 0))
         new_cache = (ck, cv)
         k, v = ck, cv
-    o = attention(q, repeat_kv(k, n_rep), repeat_kv(v, n_rep), mask)
+    kv_len = k.shape[1]
+    o = grouped_attention(q, k.reshape(b, kv_len, -1),
+                          v.reshape(b, kv_len, -1), mask)
     h = h + dense(blk["o"], o.reshape(b, t, cfg.heads * head_dim))
     x = rms_norm(blk["mlp_norm"], h, cfg.rms_eps)
     m = dense(blk["down"], jax.nn.silu(dense(blk["gate"], x)) * dense(blk["up"], x))
@@ -258,14 +258,26 @@ def decode_step(params, cfg: QwenConfig, token: jax.Array, caches,
 # scratch page: padded lanes and padded chunk positions route their writes
 # there, so a static-shape program never corrupts a live page
 # (``NULL_PAGE``, ``pages_for``: nornicdb_tpu/ragged.py).
+#
+# A cache slot's row is its K (or V) heads side by side, kv_heads * head_dim
+# wide: at Qwen2.5's widths 2 x 64 = ONE 128-lane tile.  With (kv_heads,
+# head_dim) minor, the 64-wide minor dimension made the TPU compiler put
+# another axis minor; the step's scatter and its gather each wanted their
+# own layout and every step copied the whole pool there and back (9.4 + 9.0
+# ms of the chip at 8,193 pages: PERF.md section 6, PR 31).  The rows reach
+# the contraction as stored (``layers.grouped_attention``): no ``repeat_kv``
+# copy, which the chip made in f32 at 528 MB a layer, and no split of a row.
+# What the block-gather still does: it reads EVERY page of EVERY lane's
+# table, live or not (attention over real lengths: ROADMAP G1).
 
 
 def init_kv_pages(cfg: QwenConfig, num_pages: int, page_size: int) -> jax.Array:
     """One pooled KV buffer: (layers, 2[k|v], num_pages, page_size,
-    kv_heads, head_dim).  Page 0 is the null page (see module note)."""
+    kv_heads * head_dim), a cache slot's K (or V) heads side by side in
+    one row.  Page 0 is the null page (see module note)."""
     head_dim = cfg.hidden // cfg.heads
     return jnp.zeros(
-        (cfg.layers, 2, num_pages, page_size, cfg.kv_heads, head_dim),
+        (cfg.layers, 2, num_pages, page_size, cfg.kv_heads * head_dim),
         jnp.dtype(cfg.dtype),
     )
 
@@ -284,18 +296,19 @@ def _apply_rope_rows(x: jax.Array, angles: jax.Array) -> jax.Array:
     ).astype(x.dtype)
 
 
-def _paged_attention(cfg: QwenConfig, pages, li, page_tables, q, mask):
+def _paged_attention(pages, li, page_tables, q, mask):
     """Block-gather one layer's K/V pages for every sequence and attend.
-    page_tables: (B, P) physical page ids; q: (B, T, H, Dh)."""
+    page_tables: (B, P) physical page ids; q: (B, T, H, Dh).  The rows go
+    from the pool to the contraction as they are stored."""
     b, p = page_tables.shape
-    ps = pages.shape[3]
-    n_rep = cfg.heads // cfg.kv_heads
-    head_dim = cfg.hidden // cfg.heads
-    k_all = pages[li, 0][page_tables].reshape(
-        b, p * ps, cfg.kv_heads, head_dim)
-    v_all = pages[li, 1][page_tables].reshape(
-        b, p * ps, cfg.kv_heads, head_dim)
-    return attention(q, repeat_kv(k_all, n_rep), repeat_kv(v_all, n_rep), mask)
+    _, _, _, ps, row = pages.shape
+    # the layer's K (then V) sliced out of the pool first, then gathered:
+    # one gather from the whole pool (``pages[li, 0, page_tables]``) moves
+    # fewer bytes by the compiler's account and is slower on the chip (9.3
+    # against 7.8 ms a decode-only step at 8,193 pages: PERF.md section 6)
+    k_all = pages[li, 0][page_tables].reshape(b, p * ps, row)
+    v_all = pages[li, 1][page_tables].reshape(b, p * ps, row)
+    return grouped_attention(q, k_all, v_all, mask)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(3,))
@@ -331,12 +344,12 @@ def paged_decode_step(params, cfg: QwenConfig, tokens: jax.Array,
         x = rms_norm(blk["attn_norm"], h, cfg.rms_eps)
         q = dense(blk["q"], x).reshape(b, 1, cfg.heads, head_dim)
         k = dense(blk["k"], x).reshape(b, 1, cfg.kv_heads, head_dim)
-        v = dense(blk["v"], x).reshape(b, 1, cfg.kv_heads, head_dim)
+        v = dense(blk["v"], x)
         q = _apply_rope_rows(q, angles)
         k = _apply_rope_rows(k, angles)
-        pages = pages.at[li, 0, phys, off].set(k[:, 0])
+        pages = pages.at[li, 0, phys, off].set(k.reshape(b, -1))
         pages = pages.at[li, 1, phys, off].set(v[:, 0])
-        o = _paged_attention(cfg, pages, li, page_tables, q, mask)
+        o = _paged_attention(pages, li, page_tables, q, mask)
         h = h + dense(blk["o"], o.reshape(b, 1, cfg.heads * head_dim))
         x = rms_norm(blk["mlp_norm"], h, cfg.rms_eps)
         h = h + dense(
@@ -381,12 +394,12 @@ def paged_prefill_chunk(params, cfg: QwenConfig, chunk_ids: jax.Array,
         x = rms_norm(blk["attn_norm"], h, cfg.rms_eps)
         q = dense(blk["q"], x).reshape(1, c, cfg.heads, head_dim)
         k = dense(blk["k"], x).reshape(1, c, cfg.kv_heads, head_dim)
-        v = dense(blk["v"], x).reshape(1, c, cfg.kv_heads, head_dim)
+        v = dense(blk["v"], x)
         q = _apply_rope_rows(q, angles)
         k = _apply_rope_rows(k, angles)
-        pages = pages.at[li, 0, phys, off].set(k[0])
+        pages = pages.at[li, 0, phys, off].set(k.reshape(c, -1))
         pages = pages.at[li, 1, phys, off].set(v[0])
-        o = _paged_attention(cfg, pages, li, page_table[None], q, mask)
+        o = _paged_attention(pages, li, page_table[None], q, mask)
         h = h + dense(blk["o"], o.reshape(1, c, cfg.heads * head_dim))
         x = rms_norm(blk["mlp_norm"], h, cfg.rms_eps)
         h = h + dense(
@@ -434,7 +447,10 @@ def paged_prefill_chunk(params, cfg: QwenConfig, chunk_ids: jax.Array,
 # slot; their attention output is garbage never gathered. Masked slots
 # add -1e30 before the f32 softmax, so exp underflows to exactly 0.0 and
 # null/foreign page content contributes nothing — the fused logits stay
-# bit-identical to the sequential chunk-then-decode programs.
+# bit-identical to the sequential chunk-then-decode programs, and to the
+# dense path: all of them attend through ``layers.grouped_attention``.
+# Both blocks gather whole tables: the decode block all Lmax lanes' W pages
+# (chunk and dump lanes included), the chunk block its lane's W pages.
 
 
 @functools.partial(
@@ -504,34 +520,36 @@ def ragged_fused_step(params, cfg: QwenConfig, meta: jax.Array,
         x = rms_norm(blk["attn_norm"], h, cfg.rms_eps)
         q = dense(blk["q"], x).reshape(f, 1, cfg.heads, head_dim)
         k = dense(blk["k"], x).reshape(f, 1, cfg.kv_heads, head_dim)
-        v = dense(blk["v"], x).reshape(f, 1, cfg.kv_heads, head_dim)
+        v = dense(blk["v"], x)
         q = _apply_rope_rows(q, angles)
         k = _apply_rope_rows(k, angles)
-        pages = pages.at[li, 0, phys, off].set(k[:, 0])
+        pages = pages.at[li, 0, phys, off].set(k.reshape(f, -1))
         pages = pages.at[li, 1, phys, off].set(v[:, 0])
         q_dec = jnp.zeros((lmax, 1, cfg.heads, head_dim), q.dtype)
         q_dec = q_dec.at[dec_lane, 0].set(q[:, 0])
         if attn_impl == "xla":
-            o_dec = _paged_attention(cfg, pages, li, lane_tables, q_dec,
+            o_dec = _paged_attention(pages, li, lane_tables, q_dec,
                                      mask_dec)
         else:
             from nornicdb_tpu.ops import pallas_kernels as _pk
 
+            # the kernel's view of a row: (Hkv, Dh)
+            k_heads, v_heads = (
+                pages[li, i].reshape(-1, ps, cfg.kv_heads, head_dim)
+                for i in (0, 1))
             o_dec = _pk.ragged_paged_attention(
-                q_dec, pages[li, 0], pages[li, 1], lane_tables, pos_dec,
+                q_dec, k_heads, v_heads, lane_tables, pos_dec,
                 interpret=(attn_impl == "pallas_interpret"))
         o = o_dec[dec_lane, 0]                       # (F, H, Dh)
         if tq > 1:
             q_chk = jnp.zeros((1, tq, cfg.heads, head_dim), q.dtype)
             q_chk = q_chk.at[chunk_row, slot_c].set(q[:, 0], mode="drop")
             if attn_impl == "xla":
-                o_chk = _paged_attention(cfg, pages, li, chunk_table,
+                o_chk = _paged_attention(pages, li, chunk_table,
                                          q_chk, mask_chk)
             else:
-                from nornicdb_tpu.ops import pallas_kernels as _pk
-
                 o_chk = _pk.ragged_paged_attention(
-                    q_chk, pages[li, 0], pages[li, 1], chunk_table,
+                    q_chk, k_heads, v_heads, chunk_table,
                     pos_chk, interpret=(attn_impl == "pallas_interpret"))
             o = jnp.where(is_chunk[:, None, None], o_chk[0, slot_c], o)
         o = o[:, None]                               # (F, 1, H, Dh)

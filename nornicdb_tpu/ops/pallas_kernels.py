@@ -451,11 +451,15 @@ def streaming_cosine_topk_int8(
 # block-gather materialization never exists. Pages accumulate into a VMEM
 # scratch K/V strip across the page-grid dimension; the last page step runs
 # the full (unsoftmax-split) attention for the lane, so the arithmetic is
-# EXACTLY layers.attention's — same einsum contractions, same f32 softmax —
-# and the outputs stay bit-identical to the XLA reference fallback
-# (qwen2._paged_attention), which the dense-equivalence suite holds the
-# engine to. A flash-style running softmax would break that contract for no
-# VMEM win at serving sizes (P*ps <= max_seq_tokens).
+# EXACTLY layers.attention's over repeated K/V heads — same einsum
+# contractions, same f32 softmax. The served XLA block-gather
+# (qwen2._paged_attention) contracts grouped instead, over rows as the pool
+# stores them (layers.grouped_attention): the same products and the same
+# f32 accumulation in another order of reduction, so the two agree to a
+# bf16 ulp by construction and bit for bit on the CPU backend, where the
+# equivalence suite compares them (tests/test_genserve_ragged.py). A
+# flash-style running softmax would break that for no VMEM win at serving
+# sizes (P*ps <= max_seq_tokens).
 #
 # Ragged metadata: positions (L, Tq) carries each query row's cache slot,
 # -1 marking padding rows. Padding rows mask EVERY key slot (-1e30): the
@@ -510,7 +514,9 @@ def ragged_paged_attention(
     """Mixed prefill+decode attention over the paged KV pool: one grid row
     per lane, per-lane page tables scalar-prefetched so only that lane's
     pages stream HBM->VMEM. Returns (L, Tq, H, Dh) in q.dtype, bit-identical
-    to gathering the lane's pages and calling layers.attention."""
+    to gathering the lane's pages and calling layers.attention over
+    repeated K/V heads. The pool's rows are (Hkv * Dh) wide: callers pass
+    its (num_pages, ps, Hkv, Dh) view."""
     l, tq, h, dh = q.shape
     ps, hkv = k_pages.shape[1], k_pages.shape[2]
     p = tables.shape[1]

@@ -213,31 +213,67 @@ def test_bge_m3_forward_packed_full_width(one_chip):
     assert out.shape == (8, 1024)
 
 
-@pytest.mark.parametrize("f,tq", [(8, 1), (64, 64)],
-                         ids=["decode", "prefill-chunk"])
-def test_ragged_fused_step_qwen_widths(one_chip, f, tq):
+# (lanes, table pages, pool pages, flat rows, chunk width, ceiling on
+# cost_analysis()'s bytes accessed or None)
+_QWEN_STEP = {
+    "decode": (10, 16, 129, 8, 1, None),
+    "prefill-chunk": (10, 16, 129, 64, 64, None),
+    "cell-decode": (18, 512, 8193, 16, 1, 1.76e9),
+    "cell-prefill-chunk": (18, 512, 8193, 64, 64, 2.47e9),
+}
+
+
+@pytest.mark.parametrize("case", list(_QWEN_STEP))
+def test_ragged_fused_step_qwen_widths(one_chip, case):
     """The generation step at Qwen2.5-0.5B widths (896 h, 14/2 heads,
-    vocab 151,936), 2 layers, default engine geometry (8 lanes + chunk +
-    dump, page 16, 16-page tables, 129-page pool) with the attention
-    implementation the engine dispatches on a TPU: the XLA block-gather
-    (the ragged Pallas kernel does not lower — docs/generation.md)."""
+    vocab 151,936), 2 layers, page 16, with the attention implementation
+    the engine dispatches on a TPU: the XLA block-gather (the ragged Pallas
+    kernel does not lower — docs/generation.md).  At the default engine
+    geometry (8 lanes + chunk + dump, 16-page tables, 129-page pool) and at
+    the benchmark cell's (16 lanes + chunk + dump, 512-page tables,
+    8,193-page pool), where what the step moves is held too: the pool goes
+    in and out donated, in ONE row-major layout and with no pool-sized
+    copy (a 64-wide row made the compiler put another axis minor and copy
+    the whole pool there and back every step), no f32 array of the
+    gathered cache's extent exists (the copy ``repeat_kv`` made: 528 MB a
+    layer), and the bytes accessed stay under what this program read when
+    it was written, 1.470 / 2.057 GB, plus a fifth (its predecessor read
+    9.98 / 11.32 GB: PERF.md section 6)."""
+    import re
+
+    import jax
     import jax.numpy as jnp
 
     from nornicdb_tpu.models import qwen2
     from nornicdb_tpu.ragged import pack_ragged_meta
 
     cfg = dataclasses.replace(qwen2.QWEN25_05B, layers=2)
-    lmax, w, pages, page = 10, 16, 129, 16
+    lmax, w, pages, f, tq, bytes_ceiling = _QWEN_STEP[case]
     meta, _ = pack_ragged_meta(lmax, w, f)
-    pool = (cfg.layers, 2, pages, page, cfg.kv_heads,
-            cfg.hidden // cfg.heads)
+    pool = jax.eval_shape(
+        lambda: qwen2.init_kv_pages(cfg, pages, 16)).shape  # nothing made
     compiled = qwen2.ragged_fused_step.lower(
         _params_on(qwen2.init_params, cfg, one_chip), cfg,
         _sds(meta.shape, jnp.int32, one_chip),
         _sds(pool, jnp.bfloat16, one_chip),
         lmax=lmax, w=w, tq=tq,
     ).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert pool[-1] == 128  # a row is one whole lane tile
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= int(np.prod(pool)) * 2  # donated
+    shape = "bf16[%s]" % ",".join(map(str, pool))
+    layouts = set(re.findall(re.escape(shape) + r"\{([0-9,]+)", text))
+    assert layouts == {"4,3,2,1,0"}, layouts
+    assert not re.search(re.escape(shape) + r"\S* copy\(", text)
+    gathered = r"f32\[%d,%d,(%d|%d,%d),64\]" % (
+        lmax, w * 16, cfg.heads, cfg.kv_heads, cfg.heads // cfg.kv_heads)
+    assert not re.search(gathered, text)
+    if bytes_ceiling is not None:
+        cost = compiled.cost_analysis()
+        cost = cost[0] if isinstance(cost, list) else cost
+        assert cost["bytes accessed"] < bytes_ceiling
 
 
 @pytest.mark.parametrize("f,tq", [(16, 1), (64, 64)],

@@ -433,14 +433,15 @@ def test_the_step_carries_its_scopes_and_its_own_module_name():
 
 # ------------------------------------ (i) Qwen's lowered step unchanged
 @pytest.mark.parametrize("tq,sha", [
-    (16, "b1d2f275cae9a2ebc2e02e979f42fc925cda5b5cf169c19d9f71cd26c0b7bb92"),
-    (1, "46828d1475fee0e8117fb27bc7523c2c13f3c51fddb03cf5696fa66291d8a774")])
+    (16, "8d314f28f4e06ff9347a552c6e641ccaee3ffcb880ec45eab66c5f5e7473e8a3"),
+    (1, "c31ce962ff80a5ac1649f8f4e96cd14da0e92ca00df1fb9d2c7e2db7b9b23240")])
 def test_qwens_lowered_step_is_what_it_was_before_the_family_seam(tq, sha):
-    """The lowered text of ``ragged_fused_step`` for one step class, as it
-    was at the commit before the decoder-family seam (PR 30): the seam
-    moved the scheduler's helpers out of ``models/qwen2.py`` and may not
-    have moved the program.  A PR that MEANS to change Qwen's step renews
-    the two digests (and measures ``mem-chat-sys4k``)."""
+    """The lowered text of ``ragged_fused_step`` for one step class is what
+    it was when last measured: a PR that does not mean to change Qwen's
+    step (PR 30's family seam moved the scheduler's helpers out of
+    ``models/qwen2.py``) may not move the program.  A PR that MEANS to
+    change it renews the two digests and measures ``mem-chat-sys4k``; PR 31
+    did (128-wide pool rows, ``layers.grouped_attention``)."""
     cfg = qwen2.QWEN_SMALL
     params = jax.eval_shape(lambda: qwen2.init_params(cfg,
                                                       jax.random.PRNGKey(0)))

@@ -6,7 +6,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from nornicdb_tpu.models import bge_m3, qwen2, training, weights
+from nornicdb_tpu.models import bge_m3, layers, qwen2, training, weights
 from nornicdb_tpu.models.tokenizer import HashTokenizer
 from nornicdb_tpu.parallel import make_mesh
 
@@ -98,6 +98,45 @@ class TestQwen:
             qwen_params, cfg, [1, 2], max_new_tokens=8, eos_id=99999
         )
         assert len(out) == 8  # eos never sampled -> full length
+
+
+class TestGroupedAttention:
+    """``layers.grouped_attention`` (every Qwen path's attention: K/V rows
+    as the page pool stores them, no ``repeat_kv`` copy) against the
+    repeated form it replaces.  The two differ by order of reduction only."""
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("all_masked", [False, True],
+                             ids=["causal", "row-all-masked"])
+    @pytest.mark.parametrize("heads,kv_heads", [(14, 2), (4, 4)])
+    @pytest.mark.parametrize("t", [1, 64])
+    def test_matches_repeated_attention(self, t, heads, kv_heads,
+                                        all_masked, dtype):
+        b, s_len, dh = 3, 128, 64
+        rng = np.random.default_rng(t * 100 + heads)
+        q = jnp.asarray(rng.standard_normal((b, t, heads, dh)), dtype)
+        k = jnp.asarray(rng.standard_normal((b, s_len, kv_heads, dh)), dtype)
+        v = jnp.asarray(rng.standard_normal((b, s_len, kv_heads, dh)), dtype)
+        # sequence i has 40 + 30 i slots behind its first query
+        pos = 40 + 30 * np.arange(b)[:, None] + np.arange(t)[None]
+        open_ = np.arange(s_len)[None, None] <= pos[:, :, None]
+        if all_masked:
+            open_[1] = False  # a padding lane: finite, uniform over garbage
+        mask = jnp.asarray(np.where(open_, 0.0, -1e30)[:, None], jnp.float32)
+        got = layers.grouped_attention(
+            q, k.reshape(b, s_len, -1), v.reshape(b, s_len, -1), mask)
+        n_rep = heads // kv_heads
+        want = layers.attention(
+            q, layers.repeat_kv(k, n_rep), layers.repeat_kv(v, n_rep), mask)
+        assert got.shape == want.shape and got.dtype == want.dtype
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        assert np.isfinite(got).all()
+        if dtype == "float32":
+            np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
+        else:  # one unit in bf16's last place (8 significant bits)
+            big = np.maximum(np.abs(got), np.abs(want))
+            ulp = 2.0 ** (np.floor(np.log2(np.maximum(big, 1e-30))) - 7)
+            assert (np.abs(got - want) <= ulp).all()
 
 
 class TestTokenizer:
