@@ -1,6 +1,6 @@
 # NornicDB-TPU (ref: the reference's Makefile test/build targets)
 
-.PHONY: test test-fast lint lint-baseline sanitize jitgate smoke capacity-report chaos soak soak-ci soak-nornsan soak-multiworker bench-search bench-embed bench-generate bench-generate-smoke bench-workers bench-cypher native e2e-bench clean
+.PHONY: test test-fast lint lint-baseline sanitize jitgate smoke capacity-report chaos soak soak-ci soak-nornsan soak-multiworker bench-search bench-embed bench-workers bench-cypher native e2e-bench clean
 
 test:
 	python -m pytest tests/ -q
@@ -72,18 +72,6 @@ bench-search:
 # batch invariant at exit)
 bench-embed:
 	python scripts/bench_embed.py
-
-# sequential generate() vs paged-KV continuous batching at mixed prompt/
-# output lengths (writes BENCH_generate.json; asserts the bounded
-# compiled-program-count invariant at exit)
-bench-generate:
-	python scripts/bench_generate.py
-
-# tiny gating smoke of the generation engine: 8 requests through the
-# fused ragged step, asserts steady-state (no fresh compiles in the
-# timed pass) and at least one shared-prefix cache hit
-bench-generate-smoke:
-	JAX_PLATFORMS=cpu python scripts/bench_generate.py --smoke
 
 # 1/2/4/8-worker prefork scaling sweep under mixed search+embed+Cypher
 # load (writes BENCH_multiproc.json; asserts the one-program-per-fused-

@@ -134,8 +134,7 @@ def cmd_serve(args) -> int:
     # with an assistant checkpoint mounted, build + warm the generation
     # engine now: the paged prefill/decode programs compile before traffic
     # instead of inside the first request's deadline
-    if os.environ.get("NORNICDB_ASSISTANT_MODEL") and \
-            app_cfg.genserve.enabled:
+    if os.environ.get("NORNICDB_ASSISTANT_MODEL"):
         _ = db.heimdall
         gen_engine = db.genserve_engine()
         if gen_engine is not None:

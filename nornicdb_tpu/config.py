@@ -186,12 +186,6 @@ class GenServeConfig:
     ``NORNICDB_GENSERVE_MAX_SEQS``, ``NORNICDB_GENSERVE_DEADLINE_MS``,
     ``NORNICDB_GENSERVE_FALLBACK``)."""
 
-    # master switch: off = Heimdall keeps the synchronous per-request path
-    enabled: bool = True
-    # "paged" = paged-KV continuous batching; "dense" = the per-sequence
-    # dense-cache fallback path (numerically equivalent, no cross-request
-    # decode batching — the equivalence reference and escape hatch)
-    mode: str = "paged"
     # KV page geometry: slots per page and physical pages in the pool
     # (one page is reserved as the null/scratch page)
     page_size: int = 16

@@ -381,9 +381,8 @@ class DB:
     def _wire_genserve(self, generator):
         """Front a weights-backed generator with the genserve
         continuous-batching engine (paged-KV decode, admission control,
-        deadline shedding — docs/generation.md).  Template/stub
-        generators pass through unchanged; so does genserve.enabled=False
-        (the synchronous per-request path stays the escape hatch)."""
+        deadline shedding — docs/generation.md): the one way a decoder is
+        served.  Template/stub generators pass through unchanged."""
         if self._genserve is not None:
             self._genserve.stop()
             self._genserve = None
@@ -391,19 +390,11 @@ class DB:
         if not all(hasattr(generator, a)
                    for a in ("params", "cfg", "tokenizer")):
             return generator
-        from nornicdb_tpu import genserve
-
-        gcfg = genserve.current_config()
-        if not getattr(gcfg, "enabled", True):
-            return generator
         from nornicdb_tpu.heimdall import EngineGenerator
 
-        self._genserve = genserve.GenerationEngine(
-            generator.params, generator.cfg,
-            tokenizer=generator.tokenizer, config=gcfg)
-        return EngineGenerator(
-            self._genserve,
-            max_context=getattr(generator, "max_context", 256))
+        served = EngineGenerator.serving(generator)
+        self._genserve = served.engine
+        return served
 
     def genserve_engine(self):
         """The generation engine behind Heimdall, or None when generation

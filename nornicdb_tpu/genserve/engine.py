@@ -1,10 +1,9 @@
 """Continuous-batching generation engine over a paged KV cache.
 
-The synchronous path this replaces (``heimdall.QwenGenerator.generate``)
-runs one prompt at a time against a dense per-request ``(B, Tmax)`` KV
-cache: admitting a second request means waiting for the first to finish,
-and every distinct prompt length compiles a fresh cache shape.  This
-engine owns the generation path end to end:
+The one way a decoder is served: every chat, QC and GraphRAG generation
+is a submit into this engine, so concurrent requests decode in ONE running
+batch and no prompt length compiles a cache shape of its own.  It owns the
+generation path end to end:
 
 * **A decoder-family seam.**  The engine serves any decoder whose module
   owns the config class and exposes ``init_pages(cfg, num_pages,
@@ -19,10 +18,8 @@ engine owns the generation path end to end:
   page tables.  Attention block-gathers each sequence's pages; sequences
   join and leave the running batch at step boundaries by
   allocating/freeing pages — no cache reallocation, no cross-request
-  shape coupling.  A numerically-equivalent dense fallback path
-  (``mode="dense"``, for a family that has ``prefill`` / ``decode_step``)
-  keeps a per-sequence dense cache for escape-hatch deployments and as
-  the equivalence reference the test suite holds the paged path to.
+  shape coupling.  What a family's step computes is held to a plain
+  float32 forward under ``models/reference/`` within a tolerance.
 * **One fused ragged step per iteration** (genserve v2).  Each
   scheduler iteration submits a SINGLE device program (the family's
   ``fused_step``) serving every decode lane plus at most one
@@ -31,10 +28,7 @@ engine owns the generation path end to end:
   per generated token.  The flat token batch and the chunk width are
   power-of-two bucketed (the ``round_up_pow2`` discipline), so the
   program-class ledger stays bounded at one entry per (F, Tq) bucket
-  pair, not one per (prefill, decode) shape combination.  On TPU the
-  attention inner loop is the ragged paged Pallas kernel
-  (``ops/pallas_kernels.py``); elsewhere the bit-identical XLA
-  block-gather fallback serves.
+  pair, not one per (prefill, decode) shape combination.
 * **Shared-prefix KV caching.**  Full prompt pages are content-hashed
   (a chained digest, so a page's key commits to everything before it)
   and kept resident after their sequence finishes; a new prompt whose
@@ -266,8 +260,7 @@ class GenHandle:
 
     def stream_text(self) -> Iterator[str]:
         """Decoded text deltas (diffs of the running decode, so any
-        tokenizer's spacing rules hold — same contract as the synchronous
-        QwenGenerator.generate_stream)."""
+        tokenizer's spacing rules hold)."""
         tokenizer = self._engine.tokenizer
         if tokenizer is None:
             raise ValueError("engine has no tokenizer; stream tokens instead")
@@ -309,7 +302,7 @@ class _Seq:
     __slots__ = (
         "handle", "prompt", "out", "max_new", "eos_id", "state",
         "prefill_tokens", "prefill_pos", "page_ids", "page_table",
-        "cache_len", "admit_no", "dense_cache", "dense_len",
+        "cache_len", "admit_no",
         "submitted_at", "first_token_at", "counted",
         "trace_ctx", "submitted_perf", "prefix_keys", "re_prefill",
     )
@@ -328,8 +321,6 @@ class _Seq:
         self.page_table: Optional[np.ndarray] = None
         self.cache_len = 0
         self.admit_no = -1
-        self.dense_cache = None  # mode="dense": per-seq dense KV caches
-        self.dense_len = 0
         self.submitted_at = time.monotonic()
         self.first_token_at = 0.0
         self.counted = False
@@ -372,13 +363,6 @@ class GenerationEngine:
         self._manager = manager
         # the decoder family: the module that owns the config class
         self._family = importlib.import_module(type(cfg).__module__)
-        if config.mode == "dense" and not all(
-                hasattr(self._family, fn) for fn in ("prefill",
-                                                     "decode_step")):
-            raise ValueError(
-                f"genserve.mode='dense' needs a dense-mode prefill and "
-                f"decode_step, and {self._family.__name__} has only the "
-                "paged fused step: serve it with genserve.mode='paged'")
         self._page_size = max(1, int(config.page_size))
         self._table_width = pages_for(int(config.max_seq_tokens),
                                       self._page_size)
@@ -517,7 +501,7 @@ class GenerationEngine:
         before their timed passes and then assert the steady-state
         program set never grows).
 
-        Paged mode compiles directly against a THROWAWAY pool on the
+        It compiles directly against a THROWAWAY pool on the
         caller thread (the jit cache is shared; the scheduler's pool and
         state are never touched, so warmup is safe while serving), GATED
         through the backend manager first — a wedged accelerator at boot
@@ -525,14 +509,8 @@ class GenerationEngine:
         ``fallback="fail"``) instead of hanging startup in a raw
         dispatch.  ``timeout`` bounds both the gate and the compile loop
         (checked between compiles; one compile itself is uninterruptible,
-        like any jit dispatch).  Dense mode falls back to one tiny
-        end-to-end request."""
+        like any jit dispatch)."""
         deadline = time.monotonic() + timeout
-        if self.config.mode == "dense":
-            handle = self.submit([1, 2, 3], max_new_tokens=2, deadline_ms=0)
-            while not handle.done and time.monotonic() < deadline:
-                time.sleep(0.01)
-            return
         ready = self._mgr().await_ready(timeout)
         if not ready and (self.config.fallback or "cpu") != "cpu":
             return  # degraded + fail policy: requests will shed anyway
@@ -585,8 +563,8 @@ class GenerationEngine:
         self.start()
         prompt = [int(t) for t in prompt_ids] or [1]
         # bound to the page table: keep the prompt TAIL (the recency rule
-        # heimdall's synchronous generator already applies) and leave room
-        # for at least one generated token
+        # heimdall's generators apply to their trained window) and leave
+        # room for at least one generated token
         limit = int(self.config.max_seq_tokens)
         if len(prompt) > limit - 1:
             prompt = prompt[-(limit - 1):]
@@ -770,7 +748,6 @@ class GenerationEngine:
         if drop and seq in self._running:
             self._running.remove(seq)
         self._release_pages(seq)
-        seq.dense_cache = None
         if error is None:
             self._count_outcome(seq, "ok")
         elif isinstance(error, ResourceExhausted):
@@ -946,13 +923,12 @@ class GenerationEngine:
                 seq.page_table = None
                 seq.cache_len = 0
                 seq.prefill_pos = 0
-                seq.dense_cache = None
                 seq.state = _QUEUED
                 self._queue.appendleft(seq)
             _stats.QUEUE_DEPTH.set(len(self._queue))
 
     def _ensure_pool(self):
-        if self._pages is None and self.config.mode != "dense":
+        if self._pages is None:
             with self._platform_ctx():
                 self._pages = self._family.init_pages(
                     self.cfg, self._usable_pages + 1, self._page_size)
@@ -966,11 +942,7 @@ class GenerationEngine:
             self.stats.cpu_steps += 1
         self._ensure_pool()
         self._admit()
-        if self.config.mode == "dense":
-            self._prefill_one()
-            self._decode_step()
-        else:
-            self._fused_step()
+        self._fused_step()
         self._publish_gauges()
 
     def _publish_gauges(self) -> None:
@@ -980,28 +952,24 @@ class GenerationEngine:
         _stats.PREFIX_PAGES.set(len(self._prefix_cache))
 
     def _admit(self) -> None:
-        paged = self.config.mode != "dense"
         while len(self._running) < self._max_seqs:
             hits: list[int] = []
-            keys: list[bytes] = []
             with self._cond:
                 if not self._queue:
                     return
                 seq = self._queue[0]
                 toks = seq.prompt + seq.out
-                need = (pages_for(len(toks) + 1, self._page_size)
-                        if paged else 0)
-                if paged:
-                    keys = self._prefix_page_keys(toks)
-                    # cap reuse below the full prompt: the final chunk
-                    # must prefill at least one token to produce the
-                    # first-token logits
-                    cap = (len(toks) - 1) // self._page_size
-                    for idx in range(min(len(keys), cap)):
-                        pid = self._prefix_cache.get(keys[idx])
-                        if pid is None:
-                            break
-                        hits.append(pid)
+                need = pages_for(len(toks) + 1, self._page_size)
+                keys = self._prefix_page_keys(toks)
+                # cap reuse below the full prompt: the final chunk must
+                # prefill at least one token to produce the first-token
+                # logits
+                cap = (len(toks) - 1) // self._page_size
+                for idx in range(min(len(keys), cap)):
+                    pid = self._prefix_cache.get(keys[idx])
+                    if pid is None:
+                        break
+                    hits.append(pid)
                 # idle cached hits count as "available" but adopting
                 # them consumes that availability — exclude them before
                 # comparing against the fresh-page requirement
@@ -1024,32 +992,31 @@ class GenerationEngine:
             seq.state = _PREFILL
             seq.admit_no = self._admit_counter
             self._admit_counter += 1
-            if need:
-                seq.prefix_keys = keys
-                table = np.zeros((self._table_width,), np.int32)
-                seq.page_ids = []
-                for pid in hits:
-                    # shared pages: take a reference, refresh LRU
-                    self._page_refs[pid] = \
-                        self._page_refs.get(pid, 0) + 1
-                    self._prefix_cache.move_to_end(self._page_hash[pid])
-                    seq.page_ids.append(pid)
-                for _ in range(need - len(hits)):
-                    pid = self._alloc_page()  # availability checked above
-                    self._page_refs[pid] = 1
-                    seq.page_ids.append(pid)
-                table[:len(seq.page_ids)] = seq.page_ids
-                seq.page_table = table
-                if hits:
-                    reused = len(hits) * self._page_size
-                    # cached pages already hold these tokens' KV:
-                    # prefill starts at the novel suffix
-                    seq.prefill_pos = reused
-                    seq.cache_len = reused
-                    seq.handle.prefix_reused_tokens = reused
-                    self.stats.prefix_hits += len(hits)
-                    self.stats.prefix_reused_tokens += reused
-                    _stats.PREFIX_HITS.inc(len(hits))
+            seq.prefix_keys = keys
+            table = np.zeros((self._table_width,), np.int32)
+            seq.page_ids = []
+            for pid in hits:
+                # shared pages: take a reference, refresh LRU
+                self._page_refs[pid] = \
+                    self._page_refs.get(pid, 0) + 1
+                self._prefix_cache.move_to_end(self._page_hash[pid])
+                seq.page_ids.append(pid)
+            for _ in range(need - len(hits)):
+                pid = self._alloc_page()  # availability checked above
+                self._page_refs[pid] = 1
+                seq.page_ids.append(pid)
+            table[:len(seq.page_ids)] = seq.page_ids
+            seq.page_table = table
+            if hits:
+                reused = len(hits) * self._page_size
+                # cached pages already hold these tokens' KV:
+                # prefill starts at the novel suffix
+                seq.prefill_pos = reused
+                seq.cache_len = reused
+                seq.handle.prefix_reused_tokens = reused
+                self.stats.prefix_hits += len(hits)
+                self.stats.prefix_reused_tokens += reused
+                _stats.PREFIX_HITS.inc(len(hits))
             seq.re_prefill = bool(seq.out)
             if seq.out:
                 self.stats.readmissions += 1
@@ -1107,13 +1074,12 @@ class GenerationEngine:
             )
         self._running.remove(victim)
         self._release_pages(victim)
-        victim.dense_cache = None
         victim.state = _QUEUED
         with self._cond:
             self._queue.appendleft(victim)
             _stats.QUEUE_DEPTH.set(len(self._queue))
 
-    # -- the fused ragged step (paged mode) --------------------------------
+    # -- the fused ragged step ---------------------------------------------
     def _fused_step(self) -> None:
         """ONE device program per scheduler iteration: every running
         decode lane plus at most one prompt-prefill chunk (the oldest
@@ -1277,49 +1243,6 @@ class GenerationEngine:
                 self._register_prefix(chunk_seq)
                 self._emit(chunk_seq, int(host[ndec]))
 
-    # -- prefill (dense mode) ----------------------------------------------
-    def _prefill_one(self) -> None:
-        """Run ONE prompt prefill for the oldest sequence still waiting
-        (dense escape-hatch mode only; paged mode fuses prefill into
-        :meth:`_fused_step`)."""
-        pre = [s for s in self._running if s.state == _PREFILL]
-        if not pre:
-            return
-        seq = min(pre, key=lambda s: s.admit_no)
-        if self._expired(seq):
-            return
-        self._dense_prefill(seq)
-
-    def _dense_prefill(self, seq: _Seq) -> None:
-        """mode="dense" fallback: per-sequence dense (1, Tmax) cache, the
-        pre-genserve decode path — the numeric reference."""
-        import jax.numpy as jnp
-
-        toks = seq.prefill_tokens
-        max_len = round_up_pow2(
-            min(len(toks) + seq.max_new, int(self.config.max_seq_tokens)))
-        t0 = time.perf_counter()
-        params = self._active_params()
-        self.programs.add(("dense_prefill", len(toks), max_len))
-        with self._platform_ctx():
-            logits, seq.dense_cache = self._family.prefill(
-                params, self.cfg, jnp.asarray([toks], jnp.int32), max_len)
-            # bounded sync: one token id, the prefill's output
-            # nornlint: disable=NL-JAX06
-            tok = int(jnp.argmax(logits[0]))
-        _stats.PREFILL_HIST.observe(time.perf_counter() - t0)
-        self.stats.prefill_chunks += 1
-        if seq.re_prefill:
-            self.stats.prefill_tokens_re += len(toks)
-            _stats.PREFILL_TOKENS.labels("re").inc(len(toks))
-        else:
-            self.stats.prefill_tokens_first += len(toks)
-            _stats.PREFILL_TOKENS.labels("first").inc(len(toks))
-        seq.prefill_pos = len(toks)
-        seq.dense_len = len(toks)
-        seq.cache_len = len(toks)
-        self._emit(seq, tok)
-
     def _emit(self, seq: _Seq, tok: int) -> None:
         """Deliver one generated token and advance lifecycle state."""
         seq.out.append(tok)
@@ -1350,41 +1273,6 @@ class GenerationEngine:
             return True
         return False
 
-    # -- decode (dense mode) -----------------------------------------------
-    def _decode_step(self) -> None:
-        active = [s for s in self._running if s.state == _DECODE]
-        active = [s for s in active if not self._expired(s)]
-        for seq in active:
-            self._dense_decode(seq)
-
-    def _dense_decode(self, seq: _Seq) -> None:
-        import jax.numpy as jnp
-
-        t0 = time.perf_counter()
-        params = self._active_params()
-        max_len = seq.dense_cache[0][0].shape[1]
-        self.programs.add(("dense_step", max_len))
-        with self._platform_ctx():
-            try:
-                logits, seq.dense_cache = self._family.decode_step(
-                    params, self.cfg, jnp.asarray([seq.out[-1]], jnp.int32),
-                    seq.dense_cache, jnp.asarray(seq.dense_len))
-            except Exception:
-                # the donated per-sequence cache may be consumed: drop it
-                # so a requeue re-prefills instead of reading a poisoned
-                # buffer (NL-JAX04)
-                seq.dense_cache = None
-                raise
-            # bounded sync: one token id, the step's output
-            # nornlint: disable=NL-JAX06
-            tok = int(jnp.argmax(logits[0]))
-        _stats.DECODE_HIST.observe(time.perf_counter() - t0)
-        self.stats.decode_steps += 1
-        self.stats.decode_lane_tokens += 1
-        seq.dense_len += 1
-        seq.cache_len += 1
-        self._emit(seq, tok)
-
     # -- observability -----------------------------------------------------
     def stats_snapshot(self) -> dict:
         out = self.stats.as_dict()
@@ -1395,7 +1283,7 @@ class GenerationEngine:
         out["prefix_pages"] = len(self._prefix_cache)
         out["usable_pages"] = self._usable_pages
         out["page_size"] = self._page_size
-        out["mode"] = self.config.mode
+        out["mode"] = "paged"  # API: the one way a decoder is served
         out["device_kind"] = self._device_kind or "unstarted"
         out["max_seqs"] = self._max_seqs
         # copy first: the scheduler thread adds to the ledger concurrently

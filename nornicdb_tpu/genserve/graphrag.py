@@ -147,7 +147,7 @@ class GraphRAGService:
             answer = handle.text()  # ResourceExhausted -> 429 at the edge
             generated = len(handle.tokens)
             prefix_reused = getattr(handle, "prefix_reused_tokens", 0)
-            mode = engine.config.mode
+            mode = "paged"  # API: the engine's one way to serve
         else:
             # extractive fallback: no generation weights mounted — answer
             # from the retrieved context so the endpoint (and its tests /

@@ -43,7 +43,6 @@ from nornicdb_tpu.heimdall.registry import (
     ModelInfo,
     ModelRegistry,
 )
-from nornicdb_tpu.ragged import round_up_pow2
 
 
 @dataclass
@@ -102,45 +101,34 @@ class Generator:
         return [self.generate(p, max_tokens) for p in prompts]
 
 
-def _trim_prompt_ids(tokenizer, prompt: str, max_context: int) -> list[int]:
-    """Shared weights-backed prompt policy: keep the prompt TAIL within
-    the model's trained window — for in-image checkpoints rope positions
-    beyond it were never seen in training."""
-    return tokenizer.encode(prompt, add_special=False)[-max_context:] or [1]
-
-
-def _cap_new_tokens(max_tokens: int, max_context: int) -> int:
-    """Bound decode length to one trained window beyond the prompt:
-    positions past 2x max_context are deep rope extrapolation for an
-    in-image from-scratch model (held-out action rates were measured at
-    prompt<=window + window new tokens).  ONE implementation for every
-    weights-backed generator (QwenGenerator, EngineGenerator) so the
-    window policy can never diverge between the sync and engine paths."""
-    return max(1, min(max_tokens, max_context))
-
-
 class WeightsGenerator(Generator):
     """A mounted decoder of ANY family (``cfg``'s module is the family:
     genserve/engine.py): what ``db.set_heimdall_generator`` needs to front
     it with the genserve engine — ``cfg`` / ``params`` / ``tokenizer`` /
-    ``max_context`` — and nothing else.  It has no synchronous path of its
-    own: with ``genserve.enabled`` off there is nothing to serve it."""
+    ``max_context`` — and nothing else.  It has no path of its own: the
+    engine is the one way a decoder is served
+    (:meth:`EngineGenerator.serving`)."""
 
     def __init__(self, cfg, params, tokenizer, max_context: int = 256):
         self.cfg = cfg
         self.params = params
         self.tokenizer = tokenizer
+        # prompts are trimmed to the model's trained window: for in-image
+        # checkpoints rope positions beyond it were never seen in training
         self.max_context = max_context
 
     def generate(self, prompt: str, max_tokens: int = 128) -> str:
         raise RuntimeError(
             f"a {type(self.cfg).__name__} decoder is served by the genserve "
-            "engine only: set genserve.enabled")
+            "engine only: front it with EngineGenerator.serving(...) or "
+            "db.set_heimdall_generator(...)")
 
 
-class QwenGenerator(Generator):
-    """Qwen2-on-TPU backend for the in-image toy checkpoint, with its own
-    synchronous path (replaces llama.cpp generation)."""
+class QwenGenerator(WeightsGenerator):
+    """``WeightsGenerator`` with Qwen's defaults (``QWEN_SMALL``, seeded
+    weights, ``HashTokenizer``).  The name stays because ``bench/``, the soak
+    harness and ``pretrain.load_generator`` construct it by keyword
+    (ROADMAP D11)."""
 
     def __init__(self, cfg=None, params=None, tokenizer=None, seed: int = 0,
                  max_context: int = 256):
@@ -149,75 +137,36 @@ class QwenGenerator(Generator):
         from nornicdb_tpu.models import qwen2
         from nornicdb_tpu.models.tokenizer import HashTokenizer
 
-        self.cfg = cfg if cfg is not None else qwen2.QWEN_SMALL
-        self.params = (
-            params if params is not None
-            else qwen2.init_params(self.cfg, jax.random.PRNGKey(seed))
-        )
-        self.tokenizer = tokenizer or HashTokenizer(self.cfg.vocab_size)
-        self.qwen2 = qwen2
-        # prompts are trimmed to the model's trained window: for in-image
-        # checkpoints rope positions beyond it were never seen in training
-        self.max_context = max_context
-
-    def generate(self, prompt: str, max_tokens: int = 128) -> str:
-        ids = _trim_prompt_ids(self.tokenizer, prompt, self.max_context)
-        out = self.qwen2.generate(
-            self.params, self.cfg, ids,
-            max_new_tokens=self._cap_new_tokens(max_tokens),
-            eos_id=getattr(self.tokenizer, "eos_id", -1),
-        )
-        return self.tokenizer.decode(out)
-
-    def _cap_new_tokens(self, max_tokens: int) -> int:
-        return _cap_new_tokens(max_tokens, self.max_context)
-
-    def generate_stream(self, prompt: str, max_tokens: int = 128):
-        """TRUE incremental decode (ref: GenerationModel streaming,
-        llama.go:748 + generate.go): prefill once, then one jitted
-        decode_step per yielded delta. Deltas are text diffs of the running
-        decode so any tokenizer's spacing/punctuation rules hold."""
-        import jax.numpy as jnp
-
-        ids = _trim_prompt_ids(self.tokenizer, prompt, self.max_context)
-        max_tokens = self._cap_new_tokens(max_tokens)
-        # bucketed cache length: one compiled program per power-of-two
-        # bucket instead of one per distinct prompt length
-        max_len = round_up_pow2(len(ids) + max_tokens)
-        logits, caches = self.qwen2.prefill(
-            self.params, self.cfg, jnp.asarray([ids], jnp.int32), max_len
-        )
-        eos = getattr(self.tokenizer, "eos_id", -1)
-        tok = int(jnp.argmax(logits, axis=-1)[0])
-        out: list[int] = []
-        prev_text = ""
-        pos = len(ids)
-        while len(out) < max_tokens and tok != eos:
-            out.append(tok)
-            text = self.tokenizer.decode(out)
-            if text != prev_text:
-                yield text[len(prev_text):]
-                prev_text = text
-            if len(out) >= max_tokens:
-                break
-            logits, caches = self.qwen2.decode_step(
-                self.params, self.cfg, jnp.asarray([tok], jnp.int32),
-                caches, jnp.asarray(pos),
-            )
-            tok = int(jnp.argmax(logits, axis=-1)[0])
-            pos += 1
+        cfg = cfg if cfg is not None else qwen2.QWEN_SMALL
+        if params is None:
+            params = qwen2.init_params(cfg, jax.random.PRNGKey(seed))
+        super().__init__(cfg, params,
+                         tokenizer or HashTokenizer(cfg.vocab_size),
+                         max_context)
 
 
 class EngineGenerator(Generator):
     """Generator served by the genserve continuous-batching engine.
 
-    Replaces the synchronous per-request path when genserve is enabled:
-    every chat/QC generation becomes a submit into the shared paged-KV
-    engine, so concurrent requests decode in ONE running batch instead of
+    Every chat/QC generation is a submit into the shared paged-KV engine,
+    so concurrent requests decode in ONE running batch instead of
     serializing, and admission control / deadline shedding apply
     (ResourceExhausted surfaces as HTTP 429 / Bolt transient at the
     edges).  Streaming is native: tokens are yielded as the scheduler
     produces them."""
+
+    @classmethod
+    def serving(cls, generator, config=None, manager=None):
+        """The served path for a weights-backed ``generator`` (anything
+        with ``cfg`` / ``params`` / ``tokenizer``): a GenerationEngine over
+        its weights, fronted by this class.  The caller stops
+        ``.engine``."""
+        from nornicdb_tpu.genserve import GenerationEngine
+
+        engine = GenerationEngine(
+            generator.params, generator.cfg, tokenizer=generator.tokenizer,
+            config=config, manager=manager)
+        return cls(engine, max_context=getattr(generator, "max_context", 256))
 
     def __init__(self, engine, max_context: int = 256):
         self.engine = engine
@@ -230,10 +179,18 @@ class EngineGenerator(Generator):
         self.params = engine.params
 
     def _ids(self, prompt: str) -> list[int]:
-        return _trim_prompt_ids(self.tokenizer, prompt, self.max_context)
+        """The prompt's TAIL within the model's trained window: for
+        in-image checkpoints rope positions beyond it were never seen in
+        training."""
+        ids = self.tokenizer.encode(prompt, add_special=False)
+        return ids[-self.max_context:] or [1]
 
     def _cap(self, max_tokens: int) -> int:
-        return _cap_new_tokens(max_tokens, self.max_context)
+        """Bound decode length to one trained window beyond the prompt:
+        positions past 2x max_context are deep rope extrapolation for an
+        in-image from-scratch model (held-out action rates were measured at
+        prompt<=window + window new tokens)."""
+        return max(1, min(max_tokens, self.max_context))
 
     def generate(self, prompt: str, max_tokens: int = 128) -> str:
         return self.tokenizer.decode(self.engine.generate(
@@ -248,8 +205,7 @@ class EngineGenerator(Generator):
                       max_tokens: int = 128) -> list[str]:
         """Submit the whole batch up front: the engine's scheduler decodes
         every prompt in one continuous batch (this is the Heimdall QC
-        path — previously one synchronous generate() per suggested
-        edge)."""
+        path)."""
         cap = self._cap(max_tokens)
         handles = [self.engine.submit(self._ids(p), max_new_tokens=cap)
                    for p in prompts]

@@ -7,8 +7,8 @@ checkpoints, so instead of serving template output forever, this module
 trains small REAL models on a synthetic, deterministic domain corpus —
 the assistant decoder with a next-token LM loss and the embedding encoder
 with InfoNCE — saves them as safetensors checkpoints, and loads them back
-into the same serving paths real weights would use (QwenGenerator's
-prefill + KV-cache decode; TPUEmbedder's bucketed batching).
+into the same serving paths real weights would use (the genserve engine's
+fused step over its paged pool; TPUEmbedder's bucketed batching).
 
 This gives the full weight lifecycle — init → train → checkpoint → load →
 serve — exercised end-to-end with weights that demonstrably learned
@@ -383,8 +383,9 @@ def train_assistant(
 
 
 def load_generator(model_dir: str):
-    """Checkpoint dir -> heimdall.QwenGenerator running the trained weights
-    through the real prefill + KV-cache decode path."""
+    """Checkpoint dir -> heimdall.QwenGenerator holding the trained weights:
+    ``db.set_heimdall_generator`` (or ``EngineGenerator.serving``) fronts it
+    with the genserve engine, the one path that generates."""
     import jax
 
     from nornicdb_tpu.heimdall.manager import QwenGenerator

@@ -431,136 +431,6 @@ def streaming_cosine_topk_int8(
     return vals / q_scale[:, None], idx
 
 
-# ------------------------------------------------- ragged paged attention
-#
-# NOT SERVED: the engine dispatches the XLA block-gather on every platform.
-# Mosaic (jax 0.9.0, v5e) refuses this kernel at Qwen2.5-0.5B geometry
-# (H=14, Hkv=2, Dh=64, page 16, P=16): first the (1, Tq) ``positions``
-# block ("last two dimensions of your block shape are divisible by 8 and
-# 128 … or be equal to the respective dimensions of the overall array"),
-# then the GQA broadcast+reshape ("infer-vector-layout: unsupported shape
-# cast", vector<256x2x64xbf16> -> vector<256x2x1x64xbf16>); ROADMAP S4 has
-# the rest. It stays as interpret-tested code for the online-softmax
-# rewrite S4/D2 plan.
-#
-# The genserve kernel (Ragged Paged Attention, PAPERS.md arXiv:2604.15464):
-# ONE device program serves a mixed batch of prefill and decode lanes over
-# the paged KV pool. Each grid row is one lane; its (P,) page-table row is a
-# scalar-prefetch operand, so the BlockSpec index_map DMAs exactly that
-# lane's physical pages HBM->VMEM — the XLA path's (L, P, ps, Hkv, Dh)
-# block-gather materialization never exists. Pages accumulate into a VMEM
-# scratch K/V strip across the page-grid dimension; the last page step runs
-# the full (unsoftmax-split) attention for the lane, so the arithmetic is
-# EXACTLY layers.attention's over repeated K/V heads — same einsum
-# contractions, same f32 softmax. The served XLA block-gather
-# (qwen2._paged_attention) contracts grouped instead, over rows as the pool
-# stores them (layers.grouped_attention): the same products and the same
-# f32 accumulation in another order of reduction, so the two agree to a
-# bf16 ulp by construction and bit for bit on the CPU backend, where the
-# equivalence suite compares them (tests/test_genserve_ragged.py). A
-# flash-style running softmax would break that for no VMEM win at serving
-# sizes (P*ps <= max_seq_tokens).
-#
-# Ragged metadata: positions (L, Tq) carries each query row's cache slot,
-# -1 marking padding rows. Padding rows mask EVERY key slot (-1e30): the
-# softmax degenerates to a finite uniform over garbage the scheduler never
-# gathers back, and no NaN can propagate. Decode lanes are (Tq=1 valid row),
-# the prefill chunk is one lane with up to Tq valid rows — same program.
-
-
-def _ragged_attn_kernel(tbl_ref, q_ref, k_ref, v_ref, pos_ref, out_ref,
-                        k_scr, v_scr, *, n_rep: int):
-    del tbl_ref  # consumed by the index_maps (scalar prefetch)
-    j = pl.program_id(1)
-    p = pl.num_programs(1)
-    ps = k_ref.shape[1]
-    k_scr[pl.ds(j * ps, ps)] = k_ref[0]
-    v_scr[pl.ds(j * ps, ps)] = v_ref[0]
-
-    @pl.when(j == p - 1)
-    def _attend():
-        q = q_ref[0]                      # (Tq, H, Dh)
-        k = k_scr[:]                      # (S = P*ps, Hkv, Dh)
-        v = v_scr[:]
-        s_len, hkv, dh = k.shape
-        # GQA expansion, layers.repeat_kv's broadcast+reshape per lane
-        k = jnp.broadcast_to(
-            k[:, :, None, :], (s_len, hkv, n_rep, dh)
-        ).reshape(s_len, hkv * n_rep, dh)
-        v = jnp.broadcast_to(
-            v[:, :, None, :], (s_len, hkv, n_rep, dh)
-        ).reshape(s_len, hkv * n_rep, dh)
-        s = jnp.einsum("qhd,khd->hqk", q, k,
-                       preferred_element_type=jnp.float32)
-        s = s * (q.shape[-1] ** -0.5)
-        slot = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], s_len), 1)
-        mask = jnp.where(slot <= pos_ref[0][:, None], 0.0, -1e30)
-        s = s + mask[None]
-        prob = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("hqk,khd->qhd", prob.astype(v.dtype), v,
-                       preferred_element_type=jnp.float32)
-        out_ref[0] = o.astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def ragged_paged_attention(
-    q: jax.Array,          # (L, Tq, H, Dh) rope'd queries, lane-padded
-    k_pages: jax.Array,    # (num_pages, ps, Hkv, Dh) — one layer's K pool
-    v_pages: jax.Array,    # (num_pages, ps, Hkv, Dh)
-    tables: jax.Array,     # (L, P) int32 physical page ids (NULL pads)
-    positions: jax.Array,  # (L, Tq) int32 cache slot per query row; -1 = pad
-    interpret: bool = False,
-) -> jax.Array:
-    """Mixed prefill+decode attention over the paged KV pool: one grid row
-    per lane, per-lane page tables scalar-prefetched so only that lane's
-    pages stream HBM->VMEM. Returns (L, Tq, H, Dh) in q.dtype, bit-identical
-    to gathering the lane's pages and calling layers.attention over
-    repeated K/V heads. The pool's rows are (Hkv * Dh) wide: callers pass
-    its (num_pages, ps, Hkv, Dh) view."""
-    l, tq, h, dh = q.shape
-    ps, hkv = k_pages.shape[1], k_pages.shape[2]
-    p = tables.shape[1]
-    n_rep = h // hkv
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(l, p),
-        in_specs=[
-            pl.BlockSpec((1, tq, h, dh), lambda i, j, tbl: (i, 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ps, hkv, dh),
-                         lambda i, j, tbl: (tbl[i, j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ps, hkv, dh),
-                         lambda i, j, tbl: (tbl[i, j], 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tq), lambda i, j, tbl: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, tq, h, dh),
-                               lambda i, j, tbl: (i, 0, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((p * ps, hkv, dh), k_pages.dtype),
-            pltpu.VMEM((p * ps, hkv, dh), v_pages.dtype),
-        ],
-    )
-    s_len = p * ps
-    return pl.pallas_call(
-        functools.partial(_ragged_attn_kernel, n_rep=n_rep),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((l, tq, h, dh), q.dtype),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * l * tq * s_len * h * dh,
-            bytes_accessed=(
-                2 * l * s_len * hkv * dh * k_pages.dtype.itemsize
-                + 2 * l * tq * h * dh * q.dtype.itemsize
-            ),
-            transcendentals=l * h * tq * s_len,  # softmax exp
-        ),
-        interpret=interpret,
-    )(tables, q, k_pages, v_pages, positions)
-
-
 def pick_tile_n(n: int, preferred: int = 1024) -> int:
     """Largest power-of-two tile (>=128) that divides n, capped at
     `preferred`. Corpus capacities are LANE (128) multiples, so 128 always

@@ -257,7 +257,7 @@ def build_spec(version: str = "0.4.0") -> dict:
                   "properties": {
                       "answer": {"type": "string"},
                       "mode": {"type": "string",
-                               "enum": ["paged", "dense", "extractive"]},
+                               "enum": ["paged", "extractive"]},
                       "sources": {"type": "array",
                                   "items": {"type": "object"}},
                       "context": {"type": "object"},

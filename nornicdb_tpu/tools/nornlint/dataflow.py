@@ -268,7 +268,7 @@ def _stmt_exprs(stmt: ast.stmt) -> list[ast.AST]:
 
 def _assigned_names(stmt: ast.stmt) -> set[str]:
     """Dotted names this statement rebinds (``x``, ``self.attr``,
-    ``seq.dense_cache``).  A subscript store (``self.x[0] = ...``) does
+    ``seq.page_table``).  A subscript store (``self.x[0] = ...``) does
     NOT rebind the base and is excluded on purpose."""
     out: set[str] = set()
 

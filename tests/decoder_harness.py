@@ -1,0 +1,145 @@
+"""What the tests of every decoder family share: a driver of the family's
+``fused_step`` that packs rows as the scheduler does, and the readings the
+tolerances are stated on.  A family is its module (``models/qwen2.py``,
+``models/deepseek_v2.py``: ``init_pages`` / ``fused_step``); what judges it
+is the plain float32 forward of ``models/reference/<family>.py``.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from nornicdb_tpu.ragged import (
+    ROUTING_COUNTERS,
+    pack_ragged_meta,
+    round_up_pow2,
+)
+
+PAGE, WIDTH, LMAX = 16, 8, 4  # 8 pages a lane = 128 slots; 2 decode lanes
+
+
+def fp8(x):
+    """Round to e4m3 with one scale a tensor: the precision step below
+    bfloat16, for the control that has to read outside the tolerance."""
+    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 448.0
+    return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
+
+
+def typical(got, want) -> float:
+    """Median over positions of the position's largest logit error."""
+    return float(np.median(np.abs(np.asarray(got) - np.asarray(want))
+                           .max(axis=-1)))
+
+
+def largest(got, want) -> float:
+    return float(np.abs(np.asarray(got) - np.asarray(want)).max())
+
+
+def tokens(seed: int, n: int, vocab: int) -> list[int]:
+    return np.random.default_rng(seed).integers(4, vocab, n).tolist()
+
+
+def with_norm_scales(params, seed: int):
+    """``params`` with every norm's ``scale`` drawn around 1 (spread 0.1),
+    so that a norm left out of a forward shows."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 64))
+
+    def walk(tree):
+        if isinstance(tree, dict):
+            return {k: (1.0 + 0.1 * jax.random.normal(next(keys), v.shape)
+                        if k == "scale" else walk(v))
+                    for k, v in tree.items()}
+        if isinstance(tree, list):
+            return [walk(v) for v in tree]
+        return tree
+
+    return walk(params)
+
+
+def blank_step(lmax: int, w: int, f: int):
+    """``pack_ragged_meta`` with every row a padding row, every table null:
+    (meta, views)."""
+    meta, views = pack_ragged_meta(lmax, w, f)
+    toks, lane, lpos, pos, rows, tables = views
+    toks[:], lane[:], lpos[:], pos[:] = 0, lmax - 1, 0, -1
+    rows[:], tables[:] = 0, 0
+    return meta, views
+
+
+def table_of(*pages):
+    table = np.zeros(WIDTH, np.int32)
+    table[:len(pages)] = pages
+    return table
+
+
+def reference_rows(forward, params, cfg, ids, out, **kw):
+    """The reference's logits at every position that produced a token of
+    ``out`` after the prompt ``ids``."""
+    logits = np.asarray(forward(params, cfg, ids + out[:-1], **kw))
+    return logits[len(ids) - 1:]
+
+
+def greedy_gap(forward, params, cfg, ids, out) -> float:
+    """How far under the reference's best logit the served tokens' reference
+    logits lie, at worst: the benchmark's ``greedy_gap``, and what the chip
+    is held to.  0.0 where every served token is the reference's argmax."""
+    logits = reference_rows(forward, params, cfg, list(ids), list(out))
+    served = logits[np.arange(len(out)), out]
+    return float((logits.max(-1) - served).max())
+
+
+class Pool:
+    """Drives ``family.fused_step`` as the scheduler does: one chunk of one
+    lane beside the decode rows of others, through one donated pool."""
+
+    def __init__(self, family, cfg, params, pages: int = 40):
+        self.family, self.cfg, self.params = family, cfg, params
+        self.pool = family.init_pages(cfg, pages, PAGE)
+        self.counts = np.zeros(len(ROUTING_COUNTERS), np.int64)
+
+    def step(self, decode=(), chunk=None):
+        """decode: [(token, position, table)]; chunk: (tokens, start,
+        table).  Returns the logits of each decode row, then of the
+        chunk's last row."""
+        n_valid = len(chunk[0]) if chunk else 0
+        tq = round_up_pow2(n_valid, 16) if chunk else 1
+        f = round_up_pow2(len(decode) + n_valid, 8)
+        meta, (toks, lane, lpos, pos, rows, tables) = blank_step(
+            LMAX, WIDTH, f)
+        for i, (tok, at, table) in enumerate(decode):
+            toks[i], lane[i], pos[i], rows[i] = tok, i, at, i
+            tables[i] = table
+        if chunk:
+            ids, start, table = chunk
+            for j, tok in enumerate(ids):
+                at = len(decode) + j
+                toks[at], lane[at], lpos[at] = tok, LMAX - 2, j
+                pos[at] = start + j
+            tables[LMAX - 2] = table
+            rows[len(decode)] = len(decode) + n_valid - 1
+        donated = self.pool
+        ints, logits, self.pool = self.family.fused_step(
+            self.params, self.cfg, jnp.asarray(meta), donated,
+            lmax=LMAX, w=WIDTH, tq=tq)
+        assert donated.is_deleted(), "the step copied the pool"
+        ints = np.asarray(ints)
+        # the greedy ids, then the routing counts of a family that routes
+        assert ints.shape[0] - LMAX in (0, len(ROUTING_COUNTERS))
+        assert (ints[:LMAX] == np.asarray(logits).argmax(-1)).all()
+        if ints.shape[0] > LMAX:
+            self.counts += ints[LMAX:]
+        return np.asarray(logits)[:len(decode) + bool(chunk)]
+
+    def serve(self, ids, table, start=0, steps=6, chunk=16):
+        """Prefill ``ids[start:]`` in chunks, then decode greedily:
+        (produced ids, the logits of every produced position)."""
+        at, logits = start, None
+        while at < len(ids):
+            piece = ids[at:at + chunk]
+            logits = self.step(chunk=(piece, at, table))[-1]
+            at += len(piece)
+        rows, out = [logits], [int(logits.argmax())]
+        for n in range(len(ids), len(ids) + steps - 1):
+            rows.append(self.step(decode=[(out[-1], n, table)])[0])
+            out.append(int(rows[-1].argmax()))
+        return out, np.stack(rows)

@@ -35,12 +35,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from nornicdb_tpu.ragged import (
-    ROUTING_COUNTERS,
-    pack_ragged_meta,
-    pages_for,
-    round_up_pow2,
+import decoder_harness as harness
+from decoder_harness import (
+    LMAX,
+    PAGE,
+    WIDTH,
+    Pool,
+    fp8,
+    table_of,
+    tokens as draw,
+    typical,
+    with_norm_scales,
 )
+from nornicdb_tpu.ragged import ROUTING_COUNTERS, pages_for
 from nornicdb_tpu.models import deepseek_v2 as ds
 from nornicdb_tpu.models import qwen2
 from nornicdb_tpu.models.reference import deepseek_v2 as ref
@@ -49,7 +56,6 @@ BF16 = ds.DEEPSEEK_V2_SMALL
 F32 = dataclasses.replace(BF16, dtype="float32")
 F32_TOL = 2e-4
 BF16_TOL = 0.25
-PAGE, WIDTH, LMAX = 16, 8, 4  # 8 pages a lane = 128 slots; 2 decode lanes
 
 
 def make_params(cfg, seed: int):
@@ -57,19 +63,8 @@ def make_params(cfg, seed: int):
     row's scores spread by 0.5, so fewer tokens sit on a routing edge:
     PERF.md section 6) and non-trivial norm scales, so that a norm left
     out shows."""
-    params = ds.init_params(cfg, jax.random.PRNGKey(seed))
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1000), 64))
-
-    def scales(tree):
-        if isinstance(tree, dict):
-            return {k: (1.0 + 0.1 * jax.random.normal(next(keys), v.shape)
-                        if k == "scale" else scales(v))
-                    for k, v in tree.items()}
-        if isinstance(tree, list):
-            return [scales(v) for v in tree]
-        return tree
-
-    params = scales(params)
+    params = with_norm_scales(
+        ds.init_params(cfg, jax.random.PRNGKey(seed)), seed + 1000)
     for blk in params["blocks"]:
         if "router" in blk:
             blk["router"] = (blk["router"].astype(jnp.float32)
@@ -102,19 +97,8 @@ def attend_expanded(cfg, blk, q_nope, q_pe, rows, mask):
     return jnp.einsum("lhts,lshv->lthv", p, v, precision="highest")
 
 
-def fp8(x):
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 448.0
-    return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
-
-
-def typical(got, want) -> float:
-    """Median over positions of the position's largest logit error."""
-    return float(np.median(np.abs(np.asarray(got) - np.asarray(want))
-                           .max(axis=-1)))
-
-
 def tokens(seed: int, n: int, vocab: int = BF16.vocab_size) -> list[int]:
-    return np.random.default_rng(seed).integers(4, vocab, n).tolist()
+    return draw(seed, n, vocab)
 
 
 # ------------------------------------------------ (a) forward = reference
@@ -258,70 +242,9 @@ def test_absorbed_attention_is_the_expanded_one(seed):
 
 
 # ----------------------- (f) chunked prefill, decode, prefix pages
-class Pool:
-    """Drives ``fused_step`` as the scheduler does: one chunk of one lane
-    beside the decode rows of others, through one donated pool."""
-
-    def __init__(self, cfg, params, pages: int = 40):
-        self.cfg, self.params = cfg, params
-        self.pool = ds.init_pages(cfg, pages, PAGE)
-        self.counts = np.zeros(len(ROUTING_COUNTERS), np.int64)
-
-    def step(self, decode=(), chunk=None):
-        """decode: [(token, position, table)]; chunk: (tokens, start,
-        table).  Returns the logits of each decode row, then of the
-        chunk's last row."""
-        n_valid = len(chunk[0]) if chunk else 0
-        tq = round_up_pow2(n_valid, 16) if chunk else 1
-        f = round_up_pow2(len(decode) + n_valid, 8)
-        meta, (toks, lane, lpos, pos, rows, tables) = pack_ragged_meta(
-            LMAX, WIDTH, f)
-        toks[:], lane[:], lpos[:], pos[:] = 0, LMAX - 1, 0, -1
-        rows[:], tables[:] = 0, 0
-        for i, (tok, at, table) in enumerate(decode):
-            toks[i], lane[i], pos[i], rows[i] = tok, i, at, i
-            tables[i] = table
-        if chunk:
-            ids, start, table = chunk
-            for j, tok in enumerate(ids):
-                at = len(decode) + j
-                toks[at], lane[at], lpos[at] = tok, LMAX - 2, j
-                pos[at] = start + j
-            tables[LMAX - 2] = table
-            rows[len(decode)] = len(decode) + n_valid - 1
-        ints, logits, self.pool = ds.fused_step(
-            self.params, self.cfg, jnp.asarray(meta), self.pool,
-            lmax=LMAX, w=WIDTH, tq=tq)
-        ints = np.asarray(ints)
-        assert ints.shape == (LMAX + len(ROUTING_COUNTERS),)
-        assert (ints[:LMAX] == np.asarray(logits).argmax(-1)).all()
-        self.counts += ints[LMAX:]
-        return np.asarray(logits)[:len(decode) + bool(chunk)]
-
-    def serve(self, ids, table, start=0, steps=6, chunk=16):
-        """Prefill ``ids[start:]`` in chunks, then decode greedily:
-        (produced ids, the logits of every produced position)."""
-        at, logits = start, None
-        while at < len(ids):
-            piece = ids[at:at + chunk]
-            logits = self.step(chunk=(piece, at, table))[-1]
-            at += len(piece)
-        rows, out = [logits], [int(logits.argmax())]
-        for n in range(len(ids), len(ids) + steps - 1):
-            rows.append(self.step(decode=[(out[-1], n, table)])[0])
-            out.append(int(rows[-1].argmax()))
-        return out, np.stack(rows)
-
-
-def table_of(*pages):
-    table = np.zeros(WIDTH, np.int32)
-    table[:len(pages)] = pages
-    return table
-
-
+# (``decoder_harness.Pool`` drives ``fused_step`` as the scheduler does)
 def reference_rows(cfg, params, ids, out, **kw):
-    logits = np.asarray(ref.forward(params, cfg, ids + out[:-1], **kw))
-    return logits[len(ids) - 1:]
+    return harness.reference_rows(ref.forward, params, cfg, ids, out, **kw)
 
 
 @pytest.mark.parametrize("cfg,tol,seed", [
@@ -336,7 +259,7 @@ def test_latent_pool_serving_is_the_reference_at_every_position(cfg, tol,
     params = make_params(cfg, seed)
     prefix = tokens(seed, 3 * PAGE)
     a, b = prefix + tokens(seed + 1, 21), prefix + tokens(seed + 2, 30)
-    pool = Pool(cfg, params)
+    pool = Pool(ds, cfg, params)
     error = (lambda got, want: np.abs(got - want).max()) if cfg is F32 \
         else typical
     out_a, got_a = pool.serve(a, table_of(1, 2, 3, 4, 5, 6), steps=12)
@@ -362,7 +285,7 @@ def test_decode_lanes_beside_a_chunk_read_what_they_read_alone():
     params = make_params(F32, 5)
     a, b, c = tokens(1, 20), tokens(2, 27), tokens(3, 13)
     ta, tb, tc = table_of(1, 2), table_of(3, 4), table_of(5)
-    alone, mixed = Pool(F32, params), Pool(F32, params)
+    alone, mixed = Pool(ds, F32, params), Pool(ds, F32, params)
     for pool in (alone, mixed):
         pool.serve(a, ta, steps=1)
         pool.serve(b, tb, steps=1)
@@ -384,8 +307,9 @@ def test_routing_counts_equal_a_numpy_count(held):
     full = make_params(F32, 7)
     params, cfg = hold_experts(full, F32, *held)
     ids = tokens(7, 13)
-    pool = Pool(cfg, params)
+    pool = Pool(ds, cfg, params)
     pool.step(chunk=(ids, 0, table_of(1)))
+    assert pool.counts.any()  # the step's int vector carried its counts
     hid = params["tok_emb"][jnp.asarray(ids)].astype(jnp.float32)
     angles = np.outer(np.arange(13), ref.yarn_inv_freq(cfg))
     cos, sin = (jnp.asarray(f(angles), jnp.float32) for f in (np.cos, np.sin))
@@ -464,18 +388,33 @@ def engine_config(**kw):
                                     prefill_chunk=16, deadline_ms=0), **kw})
 
 
-def test_dense_mode_is_refused_with_its_sentence():
+def test_there_is_one_way_to_run_a_decoder():
+    """No option selects a path: ``GenServeConfig`` has neither ``mode``
+    nor ``enabled``, every family module exposes the seam's three names and
+    a plain reference beside it, and no family has a step of another
+    kind."""
+    import importlib
+
+    from nornicdb_tpu.config import GenServeConfig
     from nornicdb_tpu.genserve import GenerationEngine
 
-    params = jax.eval_shape(lambda: ds.init_params(BF16,
-                                                   jax.random.PRNGKey(0)))
-    with pytest.raises(ValueError, match=r"genserve\.mode='dense' needs a "
-                       r"dense-mode prefill and decode_step.*deepseek_v2"):
-        GenerationEngine(params, BF16, config=engine_config(mode="dense"))
-    # the family that has the pair is not refused
-    q = jax.eval_shape(lambda: qwen2.init_params(qwen2.QWEN_SMALL,
-                                                 jax.random.PRNGKey(0)))
-    GenerationEngine(q, qwen2.QWEN_SMALL, config=engine_config(mode="dense"))
+    fields = GenServeConfig.__dataclass_fields__
+    assert "mode" not in fields and "enabled" not in fields
+    with pytest.raises(TypeError):
+        engine_config(mode="dense")
+    for family, small in ((ds, BF16), (qwen2, qwen2.QWEN_SMALL)):
+        for name in ("init_pages", "num_pages", "fused_step"):
+            assert callable(getattr(family, name)), (family.__name__, name)
+        for gone in ("prefill", "decode", "decode_step", "generate"):
+            assert not hasattr(family, gone), (family.__name__, gone)
+        plain = importlib.import_module(
+            family.__name__.replace(".models.", ".models.reference."))
+        assert callable(plain.forward)
+        params = jax.eval_shape(lambda: family.init_params(
+            small, jax.random.PRNGKey(0)))
+        engine = GenerationEngine(params, small, config=engine_config())
+        assert engine._family is family
+        assert engine.stats_snapshot()["mode"] == "paged"
 
 
 def test_the_engine_serves_the_family_through_its_latent_pool():
@@ -500,9 +439,8 @@ def test_the_engine_serves_the_family_through_its_latent_pool():
         engine.stop()
     assert stats["prefix_reused_tokens"] == 32
     for prompt, out in seqs:
-        logits = reference_rows(cfg, params, prompt, out)
-        served = logits[np.arange(len(out)), out]
-        assert (logits.max(-1) - served).max() < F32_TOL
+        assert harness.greedy_gap(ref.forward, params, cfg, prompt,
+                                  out) < F32_TOL
     steps_rows = stats["prefill_tokens_first"] + stats["decode_lane_tokens"]
     assert stats["routed_rows"] == steps_rows * cfg.expert_layers
     assert 0 < stats["expert_assignments"] < stats["routed_rows"] * 4
@@ -569,6 +507,6 @@ def test_heimdall_streams_the_references_greedy_continuation_over_sse():
             db.genserve_engine().stop()
         db.close()
     assert len(seen) == 1 and len(out) == 6
-    logits = reference_rows(cfg, params, seen[0], out)
-    assert (logits.max(-1) - logits[np.arange(6), out]).max() < F32_TOL
+    assert harness.greedy_gap(ref.forward, params, cfg, seen[0],
+                              out) < F32_TOL
     assert pages_for(len(seen[0]) + 6, PAGE) <= 96

@@ -89,13 +89,24 @@ class TestHeldOutActionRate:
         """The STATED RATE contract: >=90% of held-out prompts parse to the
         right action type, and >=80% produce the exact intended Cypher
         (whitespace-insensitive). Measured on this preset: 98%/98%."""
+        from nornicdb_tpu.config import GenServeConfig
+        from nornicdb_tpu.heimdall import EngineGenerator
+
         out, _ = action_ckpt
-        gen = pretrain.load_generator(out)
+        # the served path: the checkpoint behind a generation engine, the
+        # held-out prompts in one continuous batch
+        gen = EngineGenerator.serving(
+            pretrain.load_generator(out),
+            config=GenServeConfig(deadline_ms=0, max_queue=128))
         cases = pretrain.action_eval_cases()
+        try:
+            texts = gen.generate_many(
+                [f"user: {c['prompt']} assistant:" for c in cases],
+                max_tokens=64)
+        finally:
+            gen.engine.stop()
         parsed = correct = 0
-        for c in cases:
-            text = gen.generate(f"user: {c['prompt']} assistant:",
-                                max_tokens=64)
+        for c, text in zip(cases, texts):
             a = HeimdallManager.try_parse_action(text)
             if a is None or a.get("action") != c["action"]:
                 continue

@@ -6,6 +6,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import genserve_harness
+from genserve_harness import stop_what_the_test_started  # noqa: F401
 from nornicdb_tpu.models import bge_m3, layers, qwen2, training, weights
 from nornicdb_tpu.models.tokenizer import HashTokenizer
 from nornicdb_tpu.parallel import make_mesh
@@ -75,28 +77,28 @@ class TestQwen:
         np.testing.assert_allclose(la[:, :3], lb[:, :3], atol=1e-4)
         assert np.abs(la[:, 3] - lb[:, 3]).max() > 1e-3
 
-    def test_kv_cache_decode_matches_full_forward(self, qwen_params):
-        """Greedy decode with KV cache == argmax over repeated full forwards."""
+    def test_served_decode_matches_full_forward(self, qwen_params):
+        """The engine's greedy continuation == argmax over repeated full
+        forwards: at every position the served token's ``forward`` logit is
+        that forward's best, within the rounding of two programs that
+        reduce in different orders (``genserve_harness.GAP_TOL``)."""
         cfg = qwen2.QWEN_SMALL
         prompt = [1, 2, 3]
-        got = qwen2.generate(qwen_params, cfg, prompt, max_new_tokens=5)
-        # reference: repeated full forward
-        ids = list(prompt)
-        want = []
-        for _ in range(5):
-            logits = qwen2.forward(
-                qwen_params, cfg, jnp.asarray([ids], jnp.int32)
-            )
-            nxt = int(jnp.argmax(logits[0, -1]))
-            want.append(nxt)
-            ids.append(nxt)
-        assert got == want
+        eng = genserve_harness.engine()
+        got = eng.generate(prompt, max_new_tokens=5)
+        assert len(got) == 5
+        for n, tok in enumerate(got):
+            logits = np.asarray(qwen2.forward(
+                qwen_params, cfg, jnp.asarray([prompt + got[:n]], jnp.int32)
+            ))[0, -1]
+            assert logits.max() - logits[tok] < genserve_harness.GAP_TOL
 
-    def test_eos_stops(self, qwen_params):
-        cfg = qwen2.QWEN_SMALL
-        out = qwen2.generate(
-            qwen_params, cfg, [1, 2], max_new_tokens=8, eos_id=99999
-        )
+    def test_runs_to_max_new_tokens_where_eos_never_comes(self):
+        class NeverEnds:
+            eos_id = 99999  # outside the vocabulary: never sampled
+
+        eng = genserve_harness.engine(tokenizer=NeverEnds())
+        out = eng.generate([1, 2], max_new_tokens=8)
         assert len(out) == 8  # eos never sampled -> full length
 
 

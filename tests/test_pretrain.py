@@ -18,6 +18,16 @@ import nornicdb_tpu
 from nornicdb_tpu.models import pretrain
 
 
+def _served(generator):
+    """A loaded checkpoint behind a generation engine: the served path (the
+    caller stops ``.engine``)."""
+    from nornicdb_tpu.config import GenServeConfig
+    from nornicdb_tpu.heimdall import EngineGenerator
+
+    return EngineGenerator.serving(generator,
+                                   config=GenServeConfig(deadline_ms=0))
+
+
 @pytest.fixture(scope="module")
 def assistant_ckpt(tmp_path_factory):
     out = str(tmp_path_factory.mktemp("assistant"))
@@ -64,7 +74,7 @@ class TestAssistantTraining:
     def test_loss_drops_and_facts_learned(self, assistant_ckpt):
         out, stats = assistant_ckpt
         assert stats["loss_last"] < stats["loss_first"] * 0.3, stats
-        gen = pretrain.load_generator(out)
+        gen = _served(pretrain.load_generator(out))
         # XLA CPU reductions are thread-count nondeterministic, so at
         # these micro training settings one individual capital can come
         # out confused run-to-run (e.g. norway -> copenhagen). Assert a
@@ -72,18 +82,15 @@ class TestAssistantTraining:
         # score ~1/12 expected accuracy, a trained model lands far above
         # — the test still cannot pass without learning, but no single
         # confusion flakes it.
-        correct = 0
-        answers = {}
-        for country, capital in pretrain._CAPITALS.items():
-            ids = gen.tokenizer.encode(f"the capital of {country} is",
-                                       add_special=False)
-            toks = gen.qwen2.generate(
-                gen.params, gen.cfg, ids, max_new_tokens=4,
-                eos_id=gen.tokenizer.eos_id,
-            )
-            answers[country] = gen.tokenizer.decode(toks)
-            if capital in answers[country]:
-                correct += 1
+        try:
+            texts = gen.generate_many(
+                [f"the capital of {country} is"
+                 for country in pretrain._CAPITALS], max_tokens=4)
+        finally:
+            gen.engine.stop()
+        answers = dict(zip(pretrain._CAPITALS, texts))
+        correct = sum(capital in answers[country]
+                      for country, capital in pretrain._CAPITALS.items())
         assert correct >= 8, (
             f"only {correct}/{len(pretrain._CAPITALS)} capitals learned "
             f"(random weights would score ~1): {answers}"
@@ -97,22 +104,17 @@ class TestAssistantTraining:
     def test_chat_e2e_serves_model_output(self, assistant_ckpt):
         """Full stack: NORNICDB_ASSISTANT_MODEL → db.heimdall →
         /v1/chat/completions → trained-model tokens through the
-        prefill + KV-cache decode path (not the template generator)."""
-        from nornicdb_tpu.heimdall.manager import (
-            EngineGenerator,
-            QwenGenerator,
-        )
+        generation engine (not the template generator)."""
+        from nornicdb_tpu.heimdall.manager import EngineGenerator
         from nornicdb_tpu.server import HttpServer
 
         out, _ = assistant_ckpt
         os.environ["NORNICDB_ASSISTANT_MODEL"] = out
         try:
             db = nornicdb_tpu.open_db("")
-            # weights-backed path: either the synchronous QwenGenerator
-            # (genserve disabled) or the genserve continuous-batching
-            # EngineGenerator fronting the same weights — never template
-            assert isinstance(db.heimdall.generator,
-                              (QwenGenerator, EngineGenerator))
+            # weights-backed path: the genserve continuous-batching
+            # EngineGenerator fronting the checkpoint — never template
+            assert isinstance(db.heimdall.generator, EngineGenerator)
             server = HttpServer(db, port=0)
             server.start()
             try:
@@ -261,10 +263,13 @@ class TestTokenStreaming:
 
     def test_stream_deltas_match_generate(self, assistant_ckpt):
         ckpt_dir, _ = assistant_ckpt
-        gen = pretrain.load_generator(ckpt_dir)
+        gen = _served(pretrain.load_generator(ckpt_dir))
         prompt = "user: what is the capital of norway ? assistant:"
-        full = gen.generate(prompt, max_tokens=12)
-        deltas = list(gen.generate_stream(prompt, max_tokens=12))
+        try:
+            full = gen.generate(prompt, max_tokens=12)
+            deltas = list(gen.generate_stream(prompt, max_tokens=12))
+        finally:
+            gen.engine.stop()
         assert len(deltas) > 1, "true streaming must yield multiple deltas"
         assert "".join(deltas) == full
 
@@ -272,10 +277,15 @@ class TestTokenStreaming:
         from nornicdb_tpu.heimdall import HeimdallManager
 
         ckpt_dir, _ = assistant_ckpt
-        mgr = HeimdallManager(pretrain.load_generator(ckpt_dir))
-        chunks = list(mgr.chat_stream(
-            [{"role": "user", "content": "what is the capital of norway ?"}],
-            max_tokens=12))
+        gen = _served(pretrain.load_generator(ckpt_dir))
+        mgr = HeimdallManager(gen)
+        try:
+            chunks = list(mgr.chat_stream(
+                [{"role": "user",
+                  "content": "what is the capital of norway ?"}],
+                max_tokens=12))
+        finally:
+            gen.engine.stop()
         content = [c["choices"][0]["delta"].get("content", "")
                    for c in chunks if c.get("choices")]
         assert sum(1 for c in content if c) > 1

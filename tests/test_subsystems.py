@@ -307,10 +307,18 @@ class TestHeimdall:
         assert "Heimdall" in text
 
     def test_qwen_generator_runs(self):
-        from nornicdb_tpu.heimdall import QwenGenerator
+        from nornicdb_tpu.config import GenServeConfig
+        from nornicdb_tpu.heimdall import EngineGenerator, QwenGenerator
 
         gen = QwenGenerator()
-        out = gen.generate("hello world", max_tokens=4)
+        with pytest.raises(RuntimeError, match="genserve engine only"):
+            gen.generate("hello world", max_tokens=4)
+        served = EngineGenerator.serving(
+            gen, config=GenServeConfig(deadline_ms=0))
+        try:
+            out = served.generate("hello world", max_tokens=4)
+        finally:
+            served.engine.stop()
         assert isinstance(out, str) and out
 
     def test_http_chat_endpoint(self):
