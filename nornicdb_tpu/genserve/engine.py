@@ -8,7 +8,7 @@ generation path end to end:
 * **A decoder-family seam.**  The engine serves any decoder whose module
   owns the config class and exposes ``init_pages(cfg, num_pages,
   page_size)``, ``num_pages(pool)`` and ``fused_step(params, cfg, meta,
-  pages, *, lmax, w, tq)`` (``models/qwen2.py``: K/V pages;
+  pages, *, lmax, w, tq, prev)`` (``models/qwen2.py``: K/V pages;
   ``models/deepseek_v2.py``: latent pages, routed experts), resolved once
   from ``type(cfg)``.  What a page holds is the family's; which pages a
   sequence holds, the row layout of a step (``nornicdb_tpu/ragged.py``) and the
@@ -29,6 +29,17 @@ generation path end to end:
   power-of-two bucketed (the ``round_up_pow2`` discipline), so the
   program-class ledger stays bounded at one entry per (F, Tq) bucket
   pair, not one per (prefill, decode) shape combination.
+* **One step in flight.**  Step N+1 is planned, packed and dispatched
+  while step N runs, and N's ids are read after N+1 is queued on the
+  device: a decode row whose token is still in flight names the entry of
+  N's id vector that holds it and the step reads it there
+  (``nornicdb_tpu/ragged.py``).  The plan needs counts only; ``</s>`` is
+  found one step late, and that lane's row in N+1 is an OVERRUN row: its
+  token is dropped and its write lands past the sequence's end on a page
+  the sequence still owns (pages go back to the pool, and prefix pages
+  are published, only once every step with a row of theirs was read).
+  What needs a sequence's tokens on the host (eviction with re-prefill,
+  a pool shed) reads the step in flight first.
 * **Shared-prefix KV caching.**  Full prompt pages are content-hashed
   (a chained digest, so a page's key commits to everything before it)
   and kept resident after their sequence finishes; a new prompt whose
@@ -76,7 +87,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass
-from typing import Iterator, Optional, Sequence
+from typing import Iterator, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -133,6 +144,16 @@ class GenStats:
     errors: int = 0
     pool_resets: int = 0
     cpu_steps: int = 0
+    # one step in flight: steps dispatched while the one before was
+    # unread; rows whose token was dropped because their sequence had
+    # ended (</s>, a deadline, a cancel) by the time they were read;
+    # times the step in flight had to be read before planning (pool
+    # pressure); seconds blocked in the device-to-host read of a step's
+    # ids (large = the device sets the pace, ~0 = the host does)
+    overlapped_steps: int = 0
+    overrun_rows: int = 0
+    drains: int = 0
+    read_wait_seconds: float = 0.0
     # routed experts (a family that has them appends these to its step's
     # one int vector, nornicdb_tpu/ragged.py ROUTING_COUNTERS; others leave 0):
     # top-k assignments that fell on experts held here, rows routed (one
@@ -302,7 +323,7 @@ class _Seq:
     __slots__ = (
         "handle", "prompt", "out", "max_new", "eos_id", "state",
         "prefill_tokens", "prefill_pos", "page_ids", "page_table",
-        "cache_len", "admit_no",
+        "cache_len", "admit_no", "src", "row_step",
         "submitted_at", "first_token_at", "counted",
         "trace_ctx", "submitted_perf", "prefix_keys", "re_prefill",
     )
@@ -321,6 +342,13 @@ class _Seq:
         self.page_table: Optional[np.ndarray] = None
         self.cache_len = 0
         self.admit_no = -1
+        # state, prefill_pos and cache_len are the PLAN's: they advance
+        # when a step is dispatched, not when it is read.  src: where the
+        # next input token is, -1 = out[-1] on the host, else the entry
+        # of the unread step's ids; row_step: the last dispatched step
+        # that holds a row of this sequence
+        self.src = -1
+        self.row_step = 0
         self.submitted_at = time.monotonic()
         self.first_token_at = 0.0
         self.counted = False
@@ -339,6 +367,20 @@ class _Seq:
     def trace_id(self) -> Optional[str]:
         ctx = self.trace_ctx
         return None if ctx is None else ctx.trace_id
+
+
+class _Flight(NamedTuple):
+    """One dispatched fused step whose ids the host has not read yet."""
+
+    no: int
+    ids: object                 # device: Lmax greedy ids [+ routing]
+    t0: float                   # perf_counter at dispatch
+    shape: str
+    tq: int
+    active: list                # decode rows' sequences, in lane order
+    chunk_seq: Optional[_Seq]
+    n_valid: int
+    final: bool                 # the chunk's last piece: picks a token
 
 
 class GenerationEngine:
@@ -393,6 +435,14 @@ class GenerationEngine:
             range(1, self._usable_pages + 1))
         self._pages = None
         self._admit_counter = 0
+        # one step in flight: the dispatched, unread step; how many steps
+        # were dispatched and which was read last; ended sequences that
+        # keep their pages until a step with a row of theirs is read
+        self._inflight: Optional[_Flight] = None
+        self._step_no = 0
+        self._read_no = 0
+        self._zombies: list[_Seq] = []
+        self._no_ids: dict = {}  # platform -> the first step's ``prev``
         # shared-prefix page cache (scheduler-owned, like the pool):
         #   _page_refs     pid -> live holders (sequences sharing it)
         #   _prefix_cache  chain-key -> pid, LRU order (oldest first);
@@ -476,7 +526,7 @@ class GenerationEngine:
             f *= 2
         c = 16
         while True:
-            # the bucket-edge clamp in _fused_step can shrink a Tq=c
+            # the bucket-edge clamp in _launch can shrink a Tq=c
             # chunk down to exactly c//2 flat rows, so lo starts there
             lo = 1 if c == 16 else c // 2
             hi = c + max(0, self._max_seqs - 1)
@@ -546,9 +596,11 @@ class GenerationEngine:
                 self.programs.add(("ragged", f, tq, w))
                 _deviceprof.record_compile("genserve", "ragged",
                                            f"f{f}q{tq}x{w}")
+                # the served variant: ``prev`` is always an array
                 ids, _lg, pool = self._family.fused_step(
                     params, self.cfg, jnp.asarray(meta), pool,
-                    lmax=lmax, w=w, tq=tq)
+                    lmax=lmax, w=w, tq=tq,
+                    prev=self._blank_ids(kind))
                 np.asarray(ids)  # force execution before serving
 
     # -- submission --------------------------------------------------------
@@ -661,6 +713,7 @@ class GenerationEngine:
         while not self._stop.is_set():
             with self._cond:
                 while (not self._queue and not self._running
+                       and self._inflight is None
                        and not self._stop.is_set()):
                     self._cond.wait(0.25)
                 if self._stop.is_set():
@@ -680,6 +733,9 @@ class GenerationEngine:
                     _stats.SHEDS.labels("device").inc()
                 else:
                     logger.exception("genserve scheduler step failed")
+                # the step in flight chains through the same donated
+                # pool: whichever of the two failed, both are lost
+                self._drop_inflight()
                 for seq in list(self._running):
                     self._finish_seq(seq, error=e)
                 # the failing call may have CONSUMED the donated pool
@@ -747,7 +803,13 @@ class GenerationEngine:
         stop()): free pages, count the outcome, wake the caller."""
         if drop and seq in self._running:
             self._running.remove(seq)
-        self._release_pages(seq)
+        if seq.row_step > self._read_no:
+            # a row of it is in flight (an overrun row, if it ended at
+            # </s>): the pages it writes stay its own until that step
+            # has been read (_read releases them)
+            self._zombies.append(seq)
+        else:
+            self._release_pages(seq)
         if error is None:
             self._count_outcome(seq, "ok")
         elif isinstance(error, ResourceExhausted):
@@ -915,6 +977,10 @@ class GenerationEngine:
         self._free_pages = list(range(1, self._usable_pages + 1))
         # cached prefix pages lived in the dropped pool: forget them
         self._reset_prefix_cache()
+        # the step in flight ran on the old platform (which may never
+        # answer): its tokens are not read, the re-prefill below picks
+        # the same ones again
+        self._drop_inflight()
         requeue = list(self._running)
         self._running = []
         with self._cond:
@@ -923,9 +989,30 @@ class GenerationEngine:
                 seq.page_table = None
                 seq.cache_len = 0
                 seq.prefill_pos = 0
+                seq.src = -1
                 seq.state = _QUEUED
                 self._queue.appendleft(seq)
             _stats.QUEUE_DEPTH.set(len(self._queue))
+
+    def _drop_inflight(self) -> None:
+        """Forget the step in flight unread (its pool is gone): nothing
+        holds pages for it any more."""
+        self._inflight = None
+        self._read_no = self._step_no
+        self._zombies.clear()
+
+    def _blank_ids(self, kind):
+        """``prev`` for a step with none before it (call under the
+        platform's context): zeros in the shape of the family's int
+        vector, so the first step runs the one variant every step runs."""
+        blank = self._no_ids.get(kind)
+        if blank is None:
+            import jax.numpy as jnp
+
+            counts = getattr(self._family, "STEP_COUNTERS", ())
+            blank = self._no_ids[kind] = jnp.zeros(
+                (self._lmax + len(counts),), jnp.int32)
+        return blank
 
     def _ensure_pool(self):
         if self._pages is None:
@@ -942,8 +1029,21 @@ class GenerationEngine:
             self.stats.cpu_steps += 1
         self._ensure_pool()
         self._admit()
-        self._fused_step()
+        # dispatch N+1, THEN read N: the host's turn (deliver, admit,
+        # plan, pack) runs while the device does
+        nxt = self._launch()  # reads N itself first where it must
+        flight, self._inflight = self._inflight, nxt
+        if flight is not None:
+            self._read(flight)
         self._publish_gauges()
+
+    def _drain(self) -> None:
+        """Read the step in flight NOW, before the plan goes on: every
+        token it picked is on the host when this returns."""
+        flight, self._inflight = self._inflight, None
+        if flight is not None:
+            self.stats.drains += 1
+            self._read(flight)
 
     def _publish_gauges(self) -> None:
         _stats.RUNNING_SEQS.set(len(self._running))
@@ -1041,6 +1141,13 @@ class GenerationEngine:
         while len(seq.page_ids) < need:
             pid = self._alloc_page()
             if pid is None:
+                if self._inflight is not None:
+                    # eviction re-prefills prompt + out and a shed ends a
+                    # stream: both need every token on the host first
+                    self._drain()
+                    if seq not in self._running:
+                        return False  # it ended at the token just read
+                    continue
                 # an eviction may free ZERO pages (every victim page
                 # shared or cache-resident), so alloc-then-evict loops:
                 # each round removes one victim, so it terminates
@@ -1080,16 +1187,22 @@ class GenerationEngine:
             _stats.QUEUE_DEPTH.set(len(self._queue))
 
     # -- the fused ragged step ---------------------------------------------
-    def _fused_step(self) -> None:
-        """ONE device program per scheduler iteration: every running
+    def _launch(self) -> Optional[_Flight]:
+        """Plan, pack and dispatch ONE device program: every running
         decode lane plus at most one prompt-prefill chunk (the oldest
         admitted sequence still prefilling), as ragged per-lane metadata
         into the family's ``fused_step``.  Long prompts never stall the
         running batch — they ride the same program — and decode lanes
-        never pay a separate dispatch while any prompt is prefilling."""
+        never pay a separate dispatch while any prompt is prefilling.
+
+        The plan reads counts only, never a token of the step in flight:
+        a lane whose last token is unread gets a row that names where the
+        device will find it.  A sequence whose tokens, the unread one
+        counted, already number ``max_new`` gets no row."""
         import jax.numpy as jnp
 
-        active = [s for s in self._running if s.state == _DECODE]
+        active = [s for s in self._running if s.state == _DECODE
+                  and len(s.out) + (s.src >= 0) < s.max_new]
         active = [s for s in active if not self._expired(s)]
         # page growth first, for side effects only: a shed or evicted
         # sequence leaves self._running and the re-filter below drops it
@@ -1103,7 +1216,7 @@ class GenerationEngine:
         if chunk_seq is not None and self._expired(chunk_seq):
             chunk_seq = None
         if not active and chunk_seq is None:
-            return
+            return None
         ndec = len(active)
         if chunk_seq is not None:
             remaining = (len(chunk_seq.prefill_tokens)
@@ -1148,7 +1261,7 @@ class GenerationEngine:
         # an (F, V) vocab GEMM every step)
         logit_rows[:] = 0
         for i, seq in enumerate(active):
-            tokens[i] = seq.out[-1]
+            tokens[i] = seq.out[-1] if seq.src < 0 else -(seq.src + 1)
             lane_id[i] = i
             positions[i] = seq.cache_len
             lane_tables[i] = seq.page_table
@@ -1168,36 +1281,71 @@ class GenerationEngine:
         shape = f"f{f}q{tq}x{w}"
         self.programs.add(("ragged", f, tq, w))
         _deviceprof.record_compile("genserve", "ragged", shape)
+        before = self._inflight
         with self._platform_ctx():
             try:
+                # greedy argmax runs inside the program and its (Lmax,)
+                # ints feed the next step on the device (``prev``); a
+                # family with routed experts appends its routing counts
+                # to the same vector, so they cost no second read
                 ids, _logits, self._pages = self._family.fused_step(
                     params, self.cfg, jnp.asarray(meta), self._pages,
-                    lmax=lmax, w=w, tq=tq)
+                    lmax=lmax, w=w, tq=tq,
+                    prev=(before.ids if before is not None else
+                          self._blank_ids(self._device_kind)))
             except Exception:
                 # the failing dispatch may have CONSUMED the donated
                 # pool (donate_argnums): drop it at the dispatch site so
                 # _ensure_pool rebuilds from scratch, whatever the
                 # caller does (NL-JAX04) — and the prefix cache indexes
-                # the dropped pool's content, so it goes too
+                # the dropped pool's content, so it goes too, with the
+                # step in flight
                 self._pages = None
                 self._reset_prefix_cache()
+                self._drop_inflight()
                 raise
-            # greedy argmax runs inside the program: (Lmax,) ints cross
-            # to host, not the (Lmax, V) logits (~MBs/step at real
-            # vocabs) — a bounded 4B-per-row sync, the step's output; a
-            # family with routed experts appends its routing counts to
-            # the same vector, so they cost no second read
-            # nornlint: disable=NL-JAX06
-            host = np.asarray(ids)
+        # the plan moves on at dispatch: counts, never tokens
+        self._step_no += 1
+        if before is not None:
+            self.stats.overlapped_steps += 1
+        for i, seq in enumerate(active):
+            seq.cache_len += 1
+            seq.src = i
+            seq.row_step = self._step_no
+        if chunk_seq is not None:
+            chunk_seq.prefill_pos += n_valid
+            chunk_seq.cache_len = chunk_seq.prefill_pos
+            chunk_seq.row_step = self._step_no
+            if final:
+                # its first token is entry ndec of this step's ids
+                chunk_seq.state = _DECODE
+                chunk_seq.src = ndec
+        return _Flight(self._step_no, ids, t0, shape, tq, active, chunk_seq,
+                       n_valid, final)
+
+    def _read(self, flight: _Flight) -> None:
+        """Bring one dispatched step's ids to the host and act on them:
+        counters and spans of that step (dispatch to read), its tokens
+        delivered, the pages of sequences that ended released."""
+        lmax = self._lmax
+        active, chunk_seq = flight.active, flight.chunk_seq
+        ndec, n_valid, t0 = len(active), flight.n_valid, flight.t0
+        t_wait = time.perf_counter()
+        # (Lmax,) ints cross to host, not the (Lmax, V) logits (~MBs/step
+        # at real vocabs) — a bounded 4B-per-row sync, the step's output
+        # nornlint: disable=NL-JAX06
+        picked = np.asarray(flight.ids).tolist()
         t1 = time.perf_counter()
+        self.stats.read_wait_seconds += t1 - t_wait
+        self._read_no = flight.no
         dt = t1 - t0
-        routing = dict(zip(ROUTING_COUNTERS, host[lmax:].tolist()))
+        routing = dict(zip(ROUTING_COUNTERS, picked[lmax:]))
         for name, count in routing.items():
             setattr(self.stats, name, getattr(self.stats, name) + count)
         if routing:
             _stats.EXPERT_ASSIGNMENTS.inc(routing["expert_assignments"])
             _stats.EXPERT_ROWS_MAX.set(routing["expert_rows_max"])
-        _deviceprof.record_execute("genserve", "ragged", shape, dt)
+        _deviceprof.record_execute("genserve", "ragged", flight.shape, dt)
         # the one dispatch served both phases: observability stays
         # per-phase (retroactive spans in each submitter's trace, the
         # QueryBatcher convention), so dashboards and the trace tests
@@ -1215,7 +1363,7 @@ class GenerationEngine:
                 _tracer.add_span(
                     "genserve.prefill", t0, t1,
                     parent=chunk_seq.trace_ctx,
-                    attrs={"chunk": tq, "valid": n_valid,
+                    attrs={"chunk": flight.tq, "valid": n_valid,
                            "fused_decode_lanes": ndec})
         if active:
             _stats.DECODE_HIST.observe(dt)
@@ -1230,21 +1378,34 @@ class GenerationEngine:
                 _tracer.add_span(
                     "genserve.decode", t0, t1, parent=leader_ctx,
                     attrs={"batch": ndec, "links": links})
-        for i, seq in enumerate(active):
-            seq.cache_len += 1
-            self._emit(seq, int(host[i]))
-        if chunk_seq is not None:
-            chunk_seq.prefill_pos += n_valid
-            chunk_seq.cache_len = chunk_seq.prefill_pos
-            if final:
-                # full prompt resident: publish its pages for sharing,
-                # then the last valid row's logits (logit_rows[ndec])
-                # pick the first token
-                self._register_prefix(chunk_seq)
-                self._emit(chunk_seq, int(host[ndec]))
+        takers = list(zip(active, picked))
+        if chunk_seq is not None and flight.final:
+            # full prompt resident, every slot written by a step that
+            # was read: publish its pages for sharing, then the last
+            # valid row's logits (logit_rows[ndec]) pick the first token
+            self._register_prefix(chunk_seq)
+            takers.append((chunk_seq, picked[ndec]))
+        for seq, tok in takers:
+            if seq.row_step == flight.no:
+                seq.src = -1  # no later step holds a row: out[-1] is next
+            if seq.handle.done:
+                # an overrun row: the sequence ended (</s> found one step
+                # late, a deadline, a cancel) after this row was dispatched
+                self.stats.overrun_rows += 1
+            else:
+                self._emit(seq, tok)
+        if self._zombies:
+            waiting = []
+            for seq in self._zombies:
+                if seq.row_step > flight.no:
+                    waiting.append(seq)
+                else:
+                    self._release_pages(seq)
+            self._zombies = waiting
 
     def _emit(self, seq: _Seq, tok: int) -> None:
-        """Deliver one generated token and advance lifecycle state."""
+        """Deliver one generated token and end the sequence where it
+        ends."""
         seq.out.append(tok)
         if seq.first_token_at == 0.0:
             seq.first_token_at = time.monotonic()
@@ -1254,8 +1415,6 @@ class GenerationEngine:
         if (tok == seq.eos_id and seq.eos_id >= 0) or \
                 len(seq.out) >= seq.max_new:
             self._finish_seq(seq)
-        else:
-            seq.state = _DECODE
 
     def _expired(self, seq: _Seq) -> bool:
         h = seq.handle

@@ -60,7 +60,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nornicdb_tpu.ragged import NULL_PAGE, unpack_ragged_meta
+from nornicdb_tpu.ragged import (
+    NULL_PAGE,
+    ROUTING_COUNTERS,
+    unpack_ragged_meta,
+)
 from nornicdb_tpu.models.layers import dense, rms_norm
 
 _HI = jax.lax.Precision.HIGHEST
@@ -402,11 +406,14 @@ def num_pages(pool: jax.Array) -> int:
 @functools.partial(jax.jit, static_argnames=("cfg", "lmax", "w", "tq"),
                    donate_argnums=(3,))
 def mla_moe_fused_step(params, cfg: DeepSeekV2Config, meta: jax.Array,
-                       pages: jax.Array, *, lmax: int, w: int, tq: int):
+                       pages: jax.Array, *, lmax: int, w: int, tq: int,
+                       prev=None):
     """One fused prefill+decode step over the latent pool, on the engine's
     flat rows (``nornicdb_tpu/ragged.py``: ``meta`` holds F token rows, their
     lanes and positions, ``lmax`` logit rows and the ``(lmax, w)`` page
-    tables; ``tq`` is the chunk block's static width, 1 = decode only).
+    tables; ``tq`` is the chunk block's static width, 1 = decode only;
+    ``prev`` is the previous step's ``ints``, where a row whose token is
+    ``-(src + 1)`` finds it).
 
     Each row's ``[c_kv | k_pe]`` is written once to its (page, slot); the
     decode block (one query a lane) and the chunk block (``tq`` queries of
@@ -419,7 +426,7 @@ def mla_moe_fused_step(params, cfg: DeepSeekV2Config, meta: jax.Array,
     carries both; ``logits`` (lmax, V) f32 for ``logit_rows``; ``pages``
     is DONATED."""
     tokens, lane_id, lane_pos, positions, logit_rows, lane_tables = \
-        unpack_ragged_meta(meta, lmax, w)
+        unpack_ragged_meta(meta, lmax, w, prev)
     f = tokens.shape[0]
     ps = pages.shape[2]
     max_len = w * ps
@@ -491,3 +498,5 @@ def mla_moe_fused_step(params, cfg: DeepSeekV2Config, meta: jax.Array,
 
 # the decoder-family seam (genserve/engine.py); no dense-mode pair
 fused_step = mla_moe_fused_step
+# what ``ints`` carries after the ids (nornicdb_tpu/ragged.py)
+STEP_COUNTERS = ROUTING_COUNTERS

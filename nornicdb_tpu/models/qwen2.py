@@ -30,7 +30,11 @@ from nornicdb_tpu.models.layers import (
     rms_norm,
     rope_freqs,
 )
-from nornicdb_tpu.ragged import NULL_PAGE, pack_ragged_meta
+from nornicdb_tpu.ragged import (
+    NULL_PAGE,
+    pack_ragged_meta,
+    unpack_ragged_meta,
+)
 
 
 @dataclass(frozen=True)
@@ -246,7 +250,8 @@ def _paged_attention(pages, li, page_tables, q, mask):
     donate_argnums=(3,),
 )
 def ragged_fused_step(params, cfg: QwenConfig, meta: jax.Array,
-                      pages: jax.Array, *, lmax: int, w: int, tq: int):
+                      pages: jax.Array, *, lmax: int, w: int, tq: int,
+                      prev=None):
     """One fused prefill+decode step over the paged pool.
 
     meta: the packed int32 array from :func:`pack_ragged_meta` —
@@ -254,18 +259,16 @@ def ragged_fused_step(params, cfg: QwenConfig, meta: jax.Array,
     (Lmax,) logit_rows, and the (Lmax, P) per-lane page tables (row
     Lmax-2 is the chunk lane's table); ``tq`` is the static query width
     of the chunk attention block — ``tq == 1`` declares a decode-only
-    step (no row may carry the chunk lane id).  Attention is the XLA
+    step (no row may carry the chunk lane id); ``prev`` is the previous
+    step's (Lmax,) ids, where a row whose token is ``-(src + 1)`` finds it
+    (``nornicdb_tpu/ragged.py``).  Attention is the XLA
     block-gather (:func:`_paged_attention`) on every platform.
     Returns ((Lmax,) greedy token ids, (Lmax, V) f32 logits for
     ``logit_rows``, advanced pages); ``pages`` is DONATED.
     """
-    f = (meta.shape[0] - lmax - lmax * w) // 4
-    tokens = meta[:f]
-    lane_id = meta[f:2 * f]
-    lane_pos = meta[2 * f:3 * f]
-    positions = meta[3 * f:4 * f]
-    logit_rows = meta[4 * f:4 * f + lmax]
-    lane_tables = meta[4 * f + lmax:].reshape(lmax, w)
+    tokens, lane_id, lane_pos, positions, logit_rows, lane_tables = \
+        unpack_ragged_meta(meta, lmax, w, prev)
+    f = tokens.shape[0]
     p = w
     ps = pages.shape[3]
     max_len = p * ps
@@ -338,11 +341,10 @@ def ragged_fused_step(params, cfg: QwenConfig, meta: jax.Array,
 init_pages = init_kv_pages
 
 
-def fused_step(params, cfg: QwenConfig, meta, pages, *, lmax: int, w: int,
-               tq: int):
+def fused_step(params, cfg: QwenConfig, meta, pages, **kw):
     """The family's step: :func:`ragged_fused_step`, looked up when called
     (fault injectors and tests replace the module attribute)."""
-    return ragged_fused_step(params, cfg, meta, pages, lmax=lmax, w=w, tq=tq)
+    return ragged_fused_step(params, cfg, meta, pages, **kw)
 
 
 def num_pages(pool: jax.Array) -> int:

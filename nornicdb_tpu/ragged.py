@@ -13,6 +13,13 @@ positions (F) | logit_rows (Lmax) | lane_tables (Lmax, W)``.  Lane roles are
 fixed by ``lane_id``: ``< Lmax-2`` a decode lane, ``Lmax-2`` THE chunk lane,
 ``Lmax-1`` the dump lane of padding rows; ``positions == -1`` marks a
 padding row (its write goes to :data:`NULL_PAGE`).
+
+A decode row whose input token the host has not read yet says where the
+PREVIOUS step put it: ``tokens == -(src + 1)`` names entry ``src`` of that
+step's int vector (the ``logit_rows`` row that picked it), which the step
+takes as ``prev`` and resolves on the device (:func:`unpack_ragged_meta`).
+The scheduler keeps one step in flight on this: step N+1 is dispatched
+before step N's ids cross to the host.
 """
 
 from __future__ import annotations
@@ -25,7 +32,9 @@ NULL_PAGE = 0
 
 # what a family with routed experts appends, in this order, to the ``Lmax``
 # greedy ids of its step's one int vector (``GenStats`` fields of the same
-# names; a family without experts appends nothing)
+# names; a family without experts appends nothing).  A family that appends
+# them says so (``STEP_COUNTERS = ROUTING_COUNTERS`` in its module): the
+# scheduler sizes the first step's ``prev`` by it
 ROUTING_COUNTERS = ("expert_assignments", "expert_rows_max", "experts_hit",
                     "routed_rows")
 
@@ -60,9 +69,17 @@ def pack_ragged_meta(lmax: int, w: int, f: int):
                   lane_tables)
 
 
-def unpack_ragged_meta(meta, lmax: int, w: int):
+def unpack_ragged_meta(meta, lmax: int, w: int, prev=None):
     """The device side of :func:`pack_ragged_meta`: the same six views of a
-    traced ``meta`` (``F`` follows from its length)."""
+    traced ``meta`` (``F`` follows from its length).  With ``prev`` (the
+    previous step's int vector) a negative token ``-(src + 1)`` is read
+    from ``prev[src]``; without it every token is taken as written."""
     f = (meta.shape[0] - lmax - lmax * w) // 4
-    return (meta[:f], meta[f:2 * f], meta[2 * f:3 * f], meta[3 * f:4 * f],
+    tokens = meta[:f]
+    if prev is not None:
+        import jax.numpy as jnp  # traced code only: the host side stays numpy
+
+        tokens = jnp.where(
+            tokens < 0, prev[jnp.clip(-1 - tokens, 0, lmax - 1)], tokens)
+    return (tokens, meta[f:2 * f], meta[2 * f:3 * f], meta[3 * f:4 * f],
             meta[4 * f:4 * f + lmax], meta[4 * f + lmax:].reshape(lmax, w))

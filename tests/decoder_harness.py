@@ -97,10 +97,11 @@ class Pool:
         self.pool = family.init_pages(cfg, pages, PAGE)
         self.counts = np.zeros(len(ROUTING_COUNTERS), np.int64)
 
-    def step(self, decode=(), chunk=None):
+    def step(self, decode=(), chunk=None, prev=None):
         """decode: [(token, position, table)]; chunk: (tokens, start,
-        table).  Returns the logits of each decode row, then of the
-        chunk's last row."""
+        table); prev: the int vector of the step before (``self.ints``),
+        where a decode row whose token is ``-(src + 1)`` finds it.  Returns
+        the logits of each decode row, then of the chunk's last row."""
         n_valid = len(chunk[0]) if chunk else 0
         tq = round_up_pow2(n_valid, 16) if chunk else 1
         f = round_up_pow2(len(decode) + n_valid, 8)
@@ -120,11 +121,13 @@ class Pool:
         donated = self.pool
         ints, logits, self.pool = self.family.fused_step(
             self.params, self.cfg, jnp.asarray(meta), donated,
-            lmax=LMAX, w=WIDTH, tq=tq)
+            lmax=LMAX, w=WIDTH, tq=tq,
+            **({} if prev is None else {"prev": jnp.asarray(prev)}))
         assert donated.is_deleted(), "the step copied the pool"
-        ints = np.asarray(ints)
+        self.ints = ints = np.asarray(ints)
         # the greedy ids, then the routing counts of a family that routes
-        assert ints.shape[0] - LMAX in (0, len(ROUTING_COUNTERS))
+        assert ints.shape[0] - LMAX == len(
+            getattr(self.family, "STEP_COUNTERS", ()))  # as it declares
         assert (ints[:LMAX] == np.asarray(logits).argmax(-1)).all()
         if ints.shape[0] > LMAX:
             self.counts += ints[LMAX:]

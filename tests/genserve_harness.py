@@ -59,7 +59,9 @@ def mgr(hooks=None, **kw):
     return manager
 
 
-def engine(manager=None, tokenizer=TOK, **cfg_kw):
+def engine(manager=None, tokenizer=TOK, model=(PARAMS, CFG), **cfg_kw):
+    """An engine of the harness's geometry over ``model`` = (params, cfg),
+    of any decoder family (the small Qwen unless given)."""
     cfg_kw.setdefault("page_size", 16)
     cfg_kw.setdefault("pool_pages", 33)
     cfg_kw.setdefault("max_seqs", 4)
@@ -67,7 +69,7 @@ def engine(manager=None, tokenizer=TOK, **cfg_kw):
     cfg_kw.setdefault("prefill_chunk", 32)
     cfg_kw.setdefault("deadline_ms", 60000)
     eng = GenerationEngine(
-        PARAMS, CFG, tokenizer=tokenizer,
+        *model, tokenizer=tokenizer,
         config=GenServeConfig(**cfg_kw),
         manager=manager or mgr())
     LIVE.append(eng)

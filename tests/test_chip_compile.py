@@ -238,7 +238,8 @@ def test_ragged_fused_step_qwen_widths(one_chip, case):
     gathered cache's extent exists (the copy ``repeat_kv`` made: 528 MB a
     layer), and the bytes accessed stay under what this program read when
     it was written, 1.470 / 2.057 GB, plus a fifth (its predecessor read
-    9.98 / 11.32 GB: PERF.md section 6)."""
+    9.98 / 11.32 GB: PERF.md section 6).  The step compiled is the one the
+    engine serves: it takes ``prev``, the previous step's ids."""
     import re
 
     import jax
@@ -257,6 +258,7 @@ def test_ragged_fused_step_qwen_widths(one_chip, case):
         _sds(meta.shape, jnp.int32, one_chip),
         _sds(pool, jnp.bfloat16, one_chip),
         lmax=lmax, w=w, tq=tq,
+        prev=_sds((lmax,), jnp.int32, one_chip),  # the served variant
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
@@ -290,7 +292,7 @@ def test_mla_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
 
     import jax.numpy as jnp
 
-    from nornicdb_tpu.ragged import pack_ragged_meta
+    from nornicdb_tpu.ragged import ROUTING_COUNTERS, pack_ragged_meta
     from nornicdb_tpu.models import deepseek_v2 as ds
 
     cfg = ds.DEEPSEEK_V2_EP8_5L
@@ -301,6 +303,8 @@ def test_mla_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
         _params_on(ds.init_params, cfg, one_chip), cfg,
         _sds(meta.shape, jnp.int32, one_chip),
         _sds(pool, jnp.bfloat16, one_chip), lmax=lmax, w=w, tq=tq,
+        # the served variant: the ids and the four routing counts
+        prev=_sds((lmax + len(ROUTING_COUNTERS),), jnp.int32, one_chip),
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= int(np.prod(pool)) * 2  # donated

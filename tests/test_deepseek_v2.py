@@ -357,23 +357,27 @@ def test_the_step_carries_its_scopes_and_its_own_module_name():
 
 # ------------------------------------ (i) Qwen's lowered step unchanged
 @pytest.mark.parametrize("tq,sha", [
-    (16, "8d314f28f4e06ff9347a552c6e641ccaee3ffcb880ec45eab66c5f5e7473e8a3"),
-    (1, "c31ce962ff80a5ac1649f8f4e96cd14da0e92ca00df1fb9d2c7e2db7b9b23240")])
+    (16, "9bf7a3c38f557033d3866db8bc5a83112b636976c111ca165fdd559917de5189"),
+    (1, "263a6f5f44476aa9efe38ff02c8a72eff2cacf91e213138468ea3db1238bcb80")])
 def test_qwens_lowered_step_is_what_it_was_before_the_family_seam(tq, sha):
     """The lowered text of ``ragged_fused_step`` for one step class is what
     it was when last measured: a PR that does not mean to change Qwen's
     step (PR 30's family seam moved the scheduler's helpers out of
     ``models/qwen2.py``) may not move the program.  A PR that MEANS to
     change it renews the two digests and measures ``mem-chat-sys4k``; PR 31
-    did (128-wide pool rows, ``layers.grouped_attention``)."""
+    did (128-wide pool rows, ``layers.grouped_attention``), and PR 33 (the
+    served variant takes ``prev``, the previous step's ids, and reads the
+    tokens still in flight there: one ``select`` over a gather before the
+    embedding lookup)."""
     cfg = qwen2.QWEN_SMALL
     params = jax.eval_shape(lambda: qwen2.init_params(cfg,
                                                       jax.random.PRNGKey(0)))
     lmax, w, f = 6, 8, 16
     meta = jax.ShapeDtypeStruct((4 * f + lmax + lmax * w,), jnp.int32)
     pages = jax.eval_shape(lambda: qwen2.init_pages(cfg, 17, 16))
+    prev = jax.ShapeDtypeStruct((lmax,), jnp.int32)
     text = qwen2.ragged_fused_step.lower(params, cfg, meta, pages, lmax=lmax,
-                                         w=w, tq=tq).as_text()
+                                         w=w, tq=tq, prev=prev).as_text()
     assert re.search(r"module @(\S+)", text).group(1) == \
         "jit_ragged_fused_step"
     assert hashlib.sha256(text.encode()).hexdigest() == sha
