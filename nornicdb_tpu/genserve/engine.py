@@ -98,7 +98,6 @@ from nornicdb_tpu.errors import (
 )
 from nornicdb_tpu.genserve import stats as _stats
 from nornicdb_tpu.ragged import (
-    ROUTING_COUNTERS,
     pack_ragged_meta,
     pages_for,
     round_up_pow2,
@@ -155,14 +154,17 @@ class GenStats:
     drains: int = 0
     read_wait_seconds: float = 0.0
     # routed experts (a family that has them appends these to its step's
-    # one int vector, nornicdb_tpu/ragged.py ROUTING_COUNTERS; others leave 0):
-    # top-k assignments that fell on experts held here, rows routed (one
-    # per row per expert layer), and per expert layer the fullest held
-    # expert's rows and the held experts that got any row, summed
+    # one int vector and names them in its STEP_COUNTERS, nornicdb_tpu/
+    # ragged.py; others leave 0): top-k assignments that fell on experts
+    # held here, rows routed (one per row per expert layer), per expert
+    # layer the fullest held expert's rows and the held experts that got
+    # any row, summed; and, for a family with zero-compute experts, the
+    # top-k choices that fell on those
     expert_assignments: int = 0
     expert_rows_max: int = 0
     experts_hit: int = 0
     routed_rows: int = 0
+    zero_assignments: int = 0
 
     def as_dict(self) -> dict:
         return {k: getattr(self, k) for k in self.__dataclass_fields__}
@@ -405,6 +407,9 @@ class GenerationEngine:
         self._manager = manager
         # the decoder family: the module that owns the config class
         self._family = importlib.import_module(type(cfg).__module__)
+        # the counts its step appends to the greedy ids (GenStats fields)
+        self._step_counters: tuple = tuple(
+            getattr(self._family, "STEP_COUNTERS", ()))
         self._page_size = max(1, int(config.page_size))
         self._table_width = pages_for(int(config.max_seq_tokens),
                                       self._page_size)
@@ -1009,9 +1014,8 @@ class GenerationEngine:
         if blank is None:
             import jax.numpy as jnp
 
-            counts = getattr(self._family, "STEP_COUNTERS", ())
             blank = self._no_ids[kind] = jnp.zeros(
-                (self._lmax + len(counts),), jnp.int32)
+                (self._lmax + len(self._step_counters),), jnp.int32)
         return blank
 
     def _ensure_pool(self):
@@ -1339,12 +1343,14 @@ class GenerationEngine:
         self.stats.read_wait_seconds += t1 - t_wait
         self._read_no = flight.no
         dt = t1 - t0
-        routing = dict(zip(ROUTING_COUNTERS, picked[lmax:]))
+        routing = dict(zip(self._step_counters, picked[lmax:]))
         for name, count in routing.items():
             setattr(self.stats, name, getattr(self.stats, name) + count)
         if routing:
             _stats.EXPERT_ASSIGNMENTS.inc(routing["expert_assignments"])
             _stats.EXPERT_ROWS_MAX.set(routing["expert_rows_max"])
+            _stats.ZERO_EXPERT_ASSIGNMENTS.inc(
+                routing.get("zero_assignments", 0))
         _deviceprof.record_execute("genserve", "ragged", flight.shape, dt)
         # the one dispatch served both phases: observability stays
         # per-phase (retroactive spans in each submitter's trace, the

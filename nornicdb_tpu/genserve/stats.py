@@ -109,3 +109,12 @@ EXPERT_ROWS_MAX = _REGISTRY.gauge(
     "Rows the fullest held expert got in the last fused step, summed over "
     "the expert layers",
 )
+# zero-compute (identity) experts (a family whose router scores them; 0
+# forever otherwise): top-k choices that cost no FLOPs and, across
+# expert-parallel ranks, no exchange; rate() against routed rows x top-k is
+# the share of a row's expert work that is free
+ZERO_EXPERT_ASSIGNMENTS = _REGISTRY.counter(
+    "nornicdb_genserve_zero_expert_assignments_total",
+    "Top-k expert choices that fell on zero-compute (identity) experts "
+    "(summed over the expert branches of every fused step)",
+)

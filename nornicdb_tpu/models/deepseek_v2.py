@@ -21,7 +21,10 @@ The layer (huggingface.co/deepseek-ai/DeepSeek-V2 ``config.json`` /
   The serving step (:func:`mla_moe_fused_step`) uses it for its decode
   block and its chunk block alike, and so does the plain :func:`forward`;
   the expanded form as published is the reference's
-  (``models/reference/deepseek_v2.py``).
+  (``models/reference/deepseek_v2.py``).  The projection, the absorbed
+  attention, the step's two blocks and the latent pool are
+  ``models/mla.py``'s, shared with every latent family; YaRN, the score
+  scale and the router are this family's.
 * feed-forward: the first ``first_k_dense_replace`` layers a dense SwiGLU;
   the others ``p = softmax(x W_g)`` in f32 over ALL ``n_routed_experts``,
   the experts in ``n_group`` groups, a group's score its best expert's,
@@ -60,12 +63,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nornicdb_tpu.ragged import (
-    NULL_PAGE,
-    ROUTING_COUNTERS,
-    unpack_ragged_meta,
-)
-from nornicdb_tpu.models.layers import dense, rms_norm
+from nornicdb_tpu.models import mla
+from nornicdb_tpu.models.layers import dense, rms_norm  # noqa: F401 (faults)
+from nornicdb_tpu.ragged import ROUTING_COUNTERS
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -105,18 +105,12 @@ class DeepSeekV2Config:
     @property
     def latent_width(self) -> int:
         """Values a token leaves in the cache, per layer."""
-        return self.kv_lora_rank + self.qk_rope_head_dim
+        return mla.latent_width(self)
 
     @property
     def page_row_width(self) -> int:
-        """A pool row: ``latent_width`` padded with zeros to whole 128-lane
-        tiles (576 -> 640).  The TPU's default layout of an array whose
-        minor dimension is not a multiple of 128 puts ANOTHER dimension
-        minor (here the pages), and a step that scatters rows and gathers
-        pages then copies the whole pool to row-major and back, every step
-        (PERF.md, PR 29 and PR 30); with whole tiles the default IS
-        row-major and scatter, gather and the donated buffer agree."""
-        return -(-self.latent_width // 128) * 128
+        """A pool row: ``latent_width`` in whole 128-lane tiles."""
+        return mla.page_row_width(self)
 
     @property
     def expert_layers(self) -> int:
@@ -174,22 +168,19 @@ def softmax_scale(cfg: DeepSeekV2Config) -> float:
 
 
 def _rope_tables(cfg: DeepSeekV2Config, max_pos: int):
-    """(max_pos, rope/2) cos and sin, angles in float64 then f32."""
-    angles = np.outer(np.arange(max_pos, dtype=np.float64),
-                      yarn_inv_freq(cfg))
-    scale = rope_scale(cfg)
-    return (jnp.asarray(np.cos(angles) * scale, jnp.float32),
-            jnp.asarray(np.sin(angles) * scale, jnp.float32))
+    """(max_pos, rope/2) cos and sin of the YaRN frequencies."""
+    return mla.rope_tables(yarn_inv_freq(cfg), max_pos, rope_scale(cfg))
 
 
-def _rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """Rotate half-pairs of the last axis; cos/sin broadcast against
-    ``x[..., :d/2]``."""
-    xf = x.astype(jnp.float32)
-    d2 = x.shape[-1] // 2
-    x1, x2 = xf[..., :d2], xf[..., d2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
+# the shared MLA under this family's names (``bench/tests/faults_dsv2.py``
+# plants its faults on ``_rope`` and ``_project``: both are looked up here
+# at every call)
+_rope = mla.rope
+
+
+def _project(cfg: DeepSeekV2Config, blk: dict, h: jax.Array, cos, sin):
+    """:func:`mla.project` with nothing scaled."""
+    return mla.project(cfg, blk, h, cos, sin, rope=_rope)
 
 
 # --------------------------------------------------------------- weights
@@ -243,10 +234,7 @@ def init_params(cfg: DeepSeekV2Config, key: jax.Array) -> dict:
 
 
 # --------------------------------------------------------- feed-forward
-def _swiglu(mlp: dict, x: jax.Array) -> jax.Array:
-    gate = dense({"w": mlp["gate"]}, x)
-    return dense({"w": mlp["down"]},
-                 jax.nn.silu(gate) * dense({"w": mlp["up"]}, x))
+_swiglu = mla.swiglu
 
 
 def route(cfg: DeepSeekV2Config, router: jax.Array, x: jax.Array):
@@ -270,35 +258,13 @@ def routed_experts(cfg: DeepSeekV2Config, blk: dict, x: jax.Array,
     """What the HELD experts add for rows x (N, hidden), and the routing
     counts over the ``valid`` rows: f32 (N, hidden), int32 (3,) =
     (assignments on held experts, the fullest held expert's rows, held
-    experts that got a row).
-
-    A masked matmul: every held expert's gate/up runs over every row (at
-    serving batch sizes the cost is reading the expert's weights, once,
-    whoever is routed to it) and a row's gate, zero where it was not
-    routed to that expert, scales the activation before ONE down
-    projection over (expert, width)."""
-    first, count = cfg.held_experts
+    experts that got a row): this family's router, then the masked matmul
+    every latent family shares (:func:`mla.held_experts`)."""
     with jax.named_scope("moe.route"):
         ids, gates = route(cfg, blk["router"], x)
-        # (N, k, count) one-hot of the held experts' local ids: an id
-        # outside first .. first+count-1 gives a zero row
-        on = jax.nn.one_hot(ids - first, count, dtype=jnp.float32)
-        weight = jnp.einsum("nk,nkc->nc", gates, on)
-        rows = on.sum(axis=1)
-        if valid is not None:
-            rows = rows * valid[:, None].astype(jnp.float32)
-        per_expert = rows.sum(axis=0)
-        counts = jnp.stack([per_expert.sum(), per_expert.max(),
-                            (per_expert > 0).sum()]).astype(jnp.int32)
+        weight, counts = mla.held_gates(ids, gates, cfg.held_experts, valid)
     with jax.named_scope("moe.experts"):
-        ex = blk["experts"]
-        gate = jnp.einsum("nh,chi->nci", x, ex["gate"],
-                          preferred_element_type=jnp.float32)
-        up = jnp.einsum("nh,chi->nci", x, ex["up"],
-                        preferred_element_type=jnp.float32)
-        act = (jax.nn.silu(gate) * up * weight[:, :, None]).astype(x.dtype)
-        out = jnp.einsum("nci,cih->nh", act, ex["down"],
-                         preferred_element_type=jnp.float32)
+        out = mla.held_experts(blk["experts"], x, weight)
     return out, counts
 
 
@@ -314,51 +280,7 @@ def _feed_forward(cfg: DeepSeekV2Config, blk: dict, h: jax.Array,
     return h + (routed + shared.astype(jnp.float32)).astype(h.dtype), counts
 
 
-# ------------------------------------------------------------- attention
-def _project(cfg: DeepSeekV2Config, blk: dict, h: jax.Array, cos, sin):
-    """Rows h (N, hidden) at the positions of cos/sin (N, rope/2) ->
-    q_nope (N, heads, nope), q_pe (N, heads, rope) rotated, and the cached
-    row [c_kv after its norm | k_pe rotated] (N, latent_width)."""
-    heads, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
-    x = rms_norm(blk["attn_norm"], h, cfg.rms_norm_eps)
-    c_q = rms_norm(blk["q_a_norm"], dense(blk["q_a"], x), cfg.rms_norm_eps)
-    q = dense(blk["q_b"], c_q).reshape(
-        -1, heads, nope + cfg.qk_rope_head_dim)
-    kv = dense(blk["kv_a"], x)
-    c_kv = rms_norm(blk["kv_a_norm"], kv[:, :cfg.kv_lora_rank],
-                    cfg.rms_norm_eps)
-    k_pe = _rope(kv[:, cfg.kv_lora_rank:], cos, sin)
-    q_pe = _rope(q[..., nope:], cos[:, None], sin[:, None])
-    return q[..., :nope], q_pe, jnp.concatenate([c_kv, k_pe], axis=-1)
-
-
-def absorb_query(blk: dict, q_nope: jax.Array, q_pe: jax.Array):
-    """[q~ | q_pe] (..., heads, latent_width): q_nope through W_kvb,k, so a
-    head's score against a cached row is one dot product."""
-    q_lat = jnp.einsum("...hn,chn->...hc", q_nope, blk["kv_b_k"],
-                       preferred_element_type=jnp.float32)
-    return jnp.concatenate([q_lat.astype(q_nope.dtype), q_pe], axis=-1)
-
-
-def attend_absorbed(cfg: DeepSeekV2Config, blk: dict, q_abs: jax.Array,
-                    rows: jax.Array, mask: jax.Array) -> jax.Array:
-    """q_abs (L, T, heads, width) against the cached rows (L, S, width)
-    under the additive mask (L, 1, T, S) -> (L, T, heads, v_head_dim);
-    width = latent_width, or page_row_width with zeros behind on both
-    sides.  The values are the rows themselves (their c_kv part), expanded
-    through W_kvb,v after the weighted sum."""
-    s = jnp.einsum("lthd,lsd->lhts", q_abs, rows,
-                   preferred_element_type=jnp.float32)
-    p = jax.nn.softmax(s * softmax_scale(cfg) + mask, axis=-1)
-    # over the whole row (the k_pe columns ride along and are dropped):
-    # slicing the gathered rows first would copy them
-    o_lat = jnp.einsum("lhts,lsd->lhtd", p.astype(rows.dtype), rows,
-                       preferred_element_type=jnp.float32)
-    o_lat = o_lat[..., :cfg.kv_lora_rank].astype(rows.dtype)
-    return jnp.einsum("lhtc,chv->lthv", o_lat, blk["kv_b_v"],
-                      preferred_element_type=jnp.float32).astype(rows.dtype)
-
-
+# ------------------------------------------------------------- the model
 def _logits(params: dict, cfg: DeepSeekV2Config, h: jax.Array) -> jax.Array:
     x = rms_norm(params["final_norm"], h, cfg.rms_norm_eps)
     return jnp.einsum("...h,hv->...v", x, params["lm_head"]["w"],
@@ -374,13 +296,9 @@ def forward(params: dict, cfg: DeepSeekV2Config,
     cos, sin = (jnp.tile(a, (b, 1)) for a in _rope_tables(cfg, t))
     mask = jnp.where(jnp.tril(jnp.ones((t, t), bool)), 0.0, -1e30)[None, None]
     h = params["tok_emb"][input_ids].reshape(b * t, -1)
-    lanes = lambda a: a.reshape(b, t, *a.shape[1:])  # noqa: E731
     for blk in params["blocks"]:
-        q_nope, q_pe, rows = _project(cfg, blk, h, cos, sin)
-        o = attend_absorbed(
-            cfg, blk, absorb_query(blk, lanes(q_nope), lanes(q_pe)),
-            lanes(rows), mask)
-        h = h + dense(blk["o"], o.reshape(b * t, -1))
+        h = mla.attend_sequences(cfg, blk, h, b, cos, sin, mask, _project,
+                                 softmax_scale(cfg))
         h, _ = _feed_forward(cfg, blk, h)
     return _logits(params, cfg, h).reshape(b, t, -1)
 
@@ -388,19 +306,12 @@ def forward(params: dict, cfg: DeepSeekV2Config,
 # ------------------------------------------------ the latent page pool
 def init_pages(cfg: DeepSeekV2Config, num_pages: int,
                page_size: int) -> jax.Array:
-    """One pooled latent cache: (layers, num_pages, page_size,
-    page_row_width): one row a token a layer (``[c_kv | k_pe | zeros]``),
-    no K/V axis, no head axis.  Page 0 is the null page.  The step
-    scatters rows by (page, slot) and gathers whole pages by page id: both
-    index the leading page axes and leave the row contiguous, so the pool
-    keeps one layout (see ``page_row_width``)."""
-    return jnp.zeros((cfg.num_hidden_layers, num_pages, page_size,
-                      cfg.page_row_width), jnp.dtype(cfg.dtype))
+    """The latent pool (:func:`mla.init_pages`), one attention block a
+    layer."""
+    return mla.init_pages(cfg, cfg.num_hidden_layers, num_pages, page_size)
 
 
-def num_pages(pool: jax.Array) -> int:
-    """Pages of a pool made by :func:`init_pages` (null page included)."""
-    return pool.shape[1]
+num_pages = mla.num_pages
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "lmax", "w", "tq"),
@@ -425,71 +336,18 @@ def mla_moe_fused_step(params, cfg: DeepSeekV2Config, meta: jax.Array,
     rows routed = valid rows x expert layers), so one device-to-host read
     carries both; ``logits`` (lmax, V) f32 for ``logit_rows``; ``pages``
     is DONATED."""
-    tokens, lane_id, lane_pos, positions, logit_rows, lane_tables = \
-        unpack_ragged_meta(meta, lmax, w, prev)
-    f = tokens.shape[0]
-    ps = pages.shape[2]
-    max_len = w * ps
-    cos_t, sin_t = _rope_tables(cfg, max_len)
-    valid = positions >= 0
-    pos_c = jnp.clip(positions, 0, max_len - 1)
-    cos, sin = cos_t[pos_c], sin_t[pos_c]
-    lane_c = jnp.clip(lane_id, 0, lmax - 1)
-    slot_c = jnp.clip(lane_pos, 0, tq - 1)
-    is_chunk = lane_id == lmax - 2
-    phys = jnp.where(
-        valid, lane_tables[lane_c, jnp.clip(pos_c // ps, 0, w - 1)],
-        NULL_PAGE)
-    off = pos_c % ps
-    # decode block: the decode lanes and, last, a dump lane for every row
-    # that is not a decode row (masked everywhere, never gathered back)
-    ldec = lmax - 1
-    dec_lane = jnp.where(is_chunk | ~valid, ldec - 1,
-                         jnp.minimum(lane_c, ldec - 1))
-    pos_dec = jnp.full((ldec, 1), -1, jnp.int32).at[dec_lane, 0].set(
-        jnp.where(valid & ~is_chunk, positions, -1))
-    slot = jax.lax.broadcasted_iota(jnp.int32, (1, max_len), 1)
-    mask_dec = jnp.where(slot[None] <= pos_dec[:, :, None],
-                         0.0, -1e30)[:, None]
-    dec_tables = lane_tables[:ldec]
-    if tq > 1:
-        # chunk rows scatter into the (1, tq) block; every other row's
-        # index lands out of bounds on the lane axis and is dropped
-        chunk_row = jnp.where(is_chunk & valid, 0, 1)
-        pos_chk = jnp.full((1, tq), -1, jnp.int32).at[
-            chunk_row, slot_c].set(positions, mode="drop")
-        mask_chk = jnp.where(slot[None] <= pos_chk[:, :, None],
-                             0.0, -1e30)[:, None]
-        chunk_table = lane_tables[lmax - 2][None]
-    h = params["tok_emb"][tokens]                    # (F, hidden)
-    pad = cfg.page_row_width - cfg.latent_width      # zeros: score nothing
+    rows = mla.plan_step(
+        meta, pages, _rope_tables(cfg, w * pages.shape[2]), lmax=lmax, w=w,
+        tq=tq, prev=prev)
+    valid, f = rows.valid, rows.tokens.shape[0]
+    h = params["tok_emb"][rows.tokens]               # (F, hidden)
     counts = jnp.zeros((3,), jnp.int32)
     for li, blk in enumerate(params["blocks"]):
-        with jax.named_scope("mla.project"):
-            q_nope, q_pe, row = _project(cfg, blk, h, cos, sin)
-            pages = pages.at[li, phys, off].set(
-                jnp.pad(row, ((0, 0), (0, pad))))
-        with jax.named_scope("mla.absorb"):
-            q_abs = jnp.pad(absorb_query(blk, q_nope, q_pe),
-                            ((0, 0), (0, 0), (0, pad)))  # (F, heads, row)
-        with jax.named_scope("mla.attend"):
-            q_dec = jnp.zeros((ldec, 1) + q_abs.shape[1:], q_abs.dtype)
-            q_dec = q_dec.at[dec_lane, 0].set(q_abs)
-            o_dec = attend_absorbed(
-                cfg, blk, q_dec,
-                pages[li, dec_tables].reshape(ldec, max_len, -1), mask_dec)
-            o = o_dec[dec_lane, 0]                   # (F, heads, v)
-            if tq > 1:
-                q_chk = jnp.zeros((1, tq) + q_abs.shape[1:], q_abs.dtype)
-                q_chk = q_chk.at[chunk_row, slot_c].set(q_abs, mode="drop")
-                o_chk = attend_absorbed(
-                    cfg, blk, q_chk,
-                    pages[li, chunk_table].reshape(1, max_len, -1), mask_chk)
-                o = jnp.where(is_chunk[:, None, None], o_chk[0, slot_c], o)
-        h = h + dense(blk["o"], o.reshape(f, -1))
+        h, pages = mla.attend_step(cfg, blk, rows, pages, li, h, _project,
+                                   softmax_scale(cfg))
         h, layer_counts = _feed_forward(cfg, blk, h, valid)
         counts = counts + layer_counts
-    logits = _logits(params, cfg, h[jnp.clip(logit_rows, 0, f - 1)])
+    logits = _logits(params, cfg, h[jnp.clip(rows.logit_rows, 0, f - 1)])
     routed = valid.sum().astype(jnp.int32) * cfg.expert_layers
     ints = jnp.concatenate([jnp.argmax(logits, axis=-1).astype(jnp.int32),
                             counts, routed[None]])

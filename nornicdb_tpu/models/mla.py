@@ -1,0 +1,307 @@
+"""Multi-head latent attention (MLA) over a latent page pool, and the masked
+matmul over the routed experts a process holds: what every latent family
+(``models/deepseek_v2.py``, ``models/longcat_flash.py``) runs, written once.
+
+One MLA block, whoever owns it: ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb``
+-> per head ``[q_nope | q_pe]``; ``[c_kv | k_pe] = x W_kva``, ``c_kv =
+RMSNorm(c_kv)``; rotary embedding on ``q_pe`` and on the ONE shared
+``k_pe``; per head ``[k_nope | v] = c_kv W_kvb``.  What a token leaves in
+the cache is ``[c_kv | k_pe]``, ``latent_width`` values, stored as a pool
+row of ``page_row_width`` (:func:`init_pages`).  Attention runs in the
+ABSORBED form (``q~ = q_nope W_kvb,k^T``, ``s = q~.c_kv + q_pe.k_pe``, ``o
+= (sum_u p c_kv(u)) W_kvb,v``): every head attends ONE cached row.
+
+A family brings what differs: its rope tables (:func:`rope_tables` of its
+own frequencies), its score scale, its scales of ``q`` and ``c_kv`` if it
+has them, and its router.  A block's parameters are ``attn_norm``,
+``q_a``, ``q_a_norm``, ``q_b``, ``kv_a``, ``kv_a_norm``, ``kv_b_k``,
+``kv_b_v``, ``o``; a config is read for ``num_attention_heads``,
+``qk_nope_head_dim``, ``qk_rope_head_dim``, ``kv_lora_rank``,
+``rms_norm_eps`` and ``dtype`` only.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from nornicdb_tpu.models.layers import dense, rms_norm
+from nornicdb_tpu.ragged import NULL_PAGE, unpack_ragged_meta
+
+
+def latent_width(cfg) -> int:
+    """Values a token leaves in the cache, per attention block."""
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def page_row_width(cfg) -> int:
+    """A pool row: ``latent_width`` padded with zeros to whole 128-lane
+    tiles (576 -> 640).  The TPU's default layout of an array whose minor
+    dimension is not a multiple of 128 puts ANOTHER dimension minor (here
+    the pages), and a step that scatters rows and gathers pages then copies
+    the whole pool to row-major and back, every step (PERF.md, PR 29 and PR
+    30); with whole tiles the default IS row-major and scatter, gather and
+    the donated buffer agree."""
+    return -(-latent_width(cfg) // 128) * 128
+
+
+def init_pages(cfg, blocks: int, num_pages: int, page_size: int) -> jax.Array:
+    """One pooled latent cache: (attention blocks, num_pages, page_size,
+    page_row_width): one row a token a block (``[c_kv | k_pe | zeros]``),
+    no K/V axis, no head axis.  The leading axis is the family's own: a
+    layer with two attention blocks has two.  Page 0 is the null page.
+    The step scatters rows by (page, slot) and gathers whole pages by page
+    id: both index the leading page axes and leave the row contiguous, so
+    the pool keeps one layout (see :func:`page_row_width`)."""
+    return jnp.zeros((blocks, num_pages, page_size, page_row_width(cfg)),
+                     jnp.dtype(cfg.dtype))
+
+
+def num_pages(pool: jax.Array) -> int:
+    """Pages of a pool made by :func:`init_pages` (null page included)."""
+    return pool.shape[1]
+
+
+def rope_tables(inv_freq: np.ndarray, max_pos: int, scale: float = 1.0):
+    """(max_pos, rope/2) cos and sin of the family's frequencies, angles
+    in float64 then f32, each times ``scale``."""
+    angles = np.outer(np.arange(max_pos, dtype=np.float64), inv_freq)
+    return (jnp.asarray(np.cos(angles) * scale, jnp.float32),
+            jnp.asarray(np.sin(angles) * scale, jnp.float32))
+
+
+def rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Rotate half-pairs of the last axis; cos/sin broadcast against
+    ``x[..., :d/2]``."""
+    xf = x.astype(jnp.float32)
+    d2 = x.shape[-1] // 2
+    x1, x2 = xf[..., :d2], xf[..., d2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def project(cfg, blk: dict, h: jax.Array, cos, sin, *, q_scale: float = 1.0,
+            kv_scale: float = 1.0, rope=rope):
+    """Rows h (N, hidden) at the positions of cos/sin (N, rope/2) ->
+    q_nope (N, heads, nope), q_pe (N, heads, rope) rotated, and the cached
+    row [c_kv after its norm | k_pe rotated] (N, latent_width).  A family
+    that scales its low-rank projections says by what: ``q`` (both parts)
+    times ``q_scale``, ``c_kv`` after its norm times ``kv_scale``; ``k_pe``
+    is never scaled.  ``rope`` is the rotation (a family hands in its own
+    name for it so that a planted fault on that name reaches here)."""
+    heads, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    x = rms_norm(blk["attn_norm"], h, cfg.rms_norm_eps)
+    c_q = rms_norm(blk["q_a_norm"], dense(blk["q_a"], x), cfg.rms_norm_eps)
+    q = dense(blk["q_b"], c_q).reshape(
+        -1, heads, nope + cfg.qk_rope_head_dim)
+    kv = dense(blk["kv_a"], x)
+    c_kv = rms_norm(blk["kv_a_norm"], kv[:, :cfg.kv_lora_rank],
+                    cfg.rms_norm_eps)
+    if q_scale != 1.0:
+        q = (q.astype(jnp.float32) * q_scale).astype(q.dtype)
+    if kv_scale != 1.0:
+        c_kv = (c_kv.astype(jnp.float32) * kv_scale).astype(c_kv.dtype)
+    k_pe = rope(kv[:, cfg.kv_lora_rank:], cos, sin)
+    q_pe = rope(q[..., nope:], cos[:, None], sin[:, None])
+    return q[..., :nope], q_pe, jnp.concatenate([c_kv, k_pe], axis=-1)
+
+
+def absorb_query(blk: dict, q_nope: jax.Array, q_pe: jax.Array):
+    """[q~ | q_pe] (..., heads, latent_width): q_nope through W_kvb,k, so a
+    head's score against a cached row is one dot product."""
+    q_lat = jnp.einsum("...hn,chn->...hc", q_nope, blk["kv_b_k"],
+                       preferred_element_type=jnp.float32)
+    return jnp.concatenate([q_lat.astype(q_nope.dtype), q_pe], axis=-1)
+
+
+def attend_absorbed(cfg, blk: dict, q_abs: jax.Array, rows: jax.Array,
+                    mask: jax.Array, score_scale: float) -> jax.Array:
+    """q_abs (L, T, heads, width) against the cached rows (L, S, width)
+    under the additive mask (L, 1, T, S) -> (L, T, heads, v_head_dim);
+    width = latent_width, or page_row_width with zeros behind on both
+    sides; ``score_scale`` is the family's.  The values are the rows
+    themselves (their c_kv part), expanded through W_kvb,v after the
+    weighted sum."""
+    s = jnp.einsum("lthd,lsd->lhts", q_abs, rows,
+                   preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(s * score_scale + mask, axis=-1)
+    # over the whole row (the k_pe columns ride along and are dropped):
+    # slicing the gathered rows first would copy them
+    o_lat = jnp.einsum("lhts,lsd->lhtd", p.astype(rows.dtype), rows,
+                       preferred_element_type=jnp.float32)
+    o_lat = o_lat[..., :cfg.kv_lora_rank].astype(rows.dtype)
+    return jnp.einsum("lhtc,chv->lthv", o_lat, blk["kv_b_v"],
+                      preferred_element_type=jnp.float32).astype(rows.dtype)
+
+
+def attend_sequences(cfg, blk: dict, h: jax.Array, b: int, cos, sin, mask,
+                     project, score_scale: float) -> jax.Array:
+    """h + the block's attention for ``b`` whole sequences of equal length
+    laid end to end in h (b*t, hidden), no cache: the plain batched
+    forward's attention, in the absorbed form.  ``project(cfg, blk, h,
+    cos, sin)`` is the family's projection (:func:`project` with its
+    scales)."""
+    lanes = lambda a: a.reshape(b, -1, *a.shape[1:])  # noqa: E731
+    q_nope, q_pe, rows = project(cfg, blk, h, cos, sin)
+    o = attend_absorbed(cfg, blk, absorb_query(blk, lanes(q_nope),
+                                               lanes(q_pe)),
+                        lanes(rows), mask, score_scale)
+    return h + dense(blk["o"], o.reshape(h.shape[0], -1))
+
+
+class StepRows(NamedTuple):
+    """What one fused step's rows say, unpacked once for every attention
+    block of the step (:func:`plan_step`)."""
+    tokens: jax.Array       # (F,) input ids, ``prev`` resolved
+    logit_rows: jax.Array   # (Lmax,)
+    valid: jax.Array        # (F,) not a padding row
+    cos: jax.Array          # (F, rope/2) at the rows' positions
+    sin: jax.Array
+    phys: jax.Array         # (F,) the page a row's latent is written to
+    off: jax.Array          # (F,) and its slot there
+    dec_lane: jax.Array     # (F,) lane of the decode block (dump lane last)
+    dec_tables: jax.Array   # (Lmax-1, W)
+    mask_dec: jax.Array     # (Lmax-1, 1, 1, S)
+    is_chunk: jax.Array     # (F,)
+    chunk_row: jax.Array | None   # (F,) 0 for a chunk row, else out of bounds
+    slot_c: jax.Array       # (F,) a chunk row's place in the chunk block
+    chunk_table: jax.Array | None  # (1, W)
+    mask_chk: jax.Array | None     # (1, 1, Tq, S)
+
+
+def plan_step(meta: jax.Array, pages: jax.Array, tables, *, lmax: int,
+              w: int, tq: int, prev=None) -> StepRows:
+    """The engine's flat rows (``nornicdb_tpu/ragged.py``: ``meta`` holds F
+    token rows, their lanes and positions, ``lmax`` logit rows and the
+    ``(lmax, w)`` page tables; ``tq`` is the chunk block's static width, 1
+    = decode only; ``prev`` is the previous step's ``ints``) as the two
+    attention blocks see them: the decode block (one query a lane; the
+    decode lanes and, last, a dump lane for every row that is not a decode
+    row, masked everywhere, never gathered back) and the chunk block
+    (``tq`` queries of the chunk lane).  ``tables`` = the family's
+    :func:`rope_tables` over ``w * page_size`` positions."""
+    tokens, lane_id, lane_pos, positions, logit_rows, lane_tables = \
+        unpack_ragged_meta(meta, lmax, w, prev)
+    ps = pages.shape[2]
+    max_len = w * ps
+    cos_t, sin_t = tables
+    valid = positions >= 0
+    pos_c = jnp.clip(positions, 0, max_len - 1)
+    cos, sin = cos_t[pos_c], sin_t[pos_c]
+    lane_c = jnp.clip(lane_id, 0, lmax - 1)
+    slot_c = jnp.clip(lane_pos, 0, tq - 1)
+    is_chunk = lane_id == lmax - 2
+    phys = jnp.where(
+        valid, lane_tables[lane_c, jnp.clip(pos_c // ps, 0, w - 1)],
+        NULL_PAGE)
+    off = pos_c % ps
+    ldec = lmax - 1
+    dec_lane = jnp.where(is_chunk | ~valid, ldec - 1,
+                         jnp.minimum(lane_c, ldec - 1))
+    pos_dec = jnp.full((ldec, 1), -1, jnp.int32).at[dec_lane, 0].set(
+        jnp.where(valid & ~is_chunk, positions, -1))
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, max_len), 1)
+    mask_dec = jnp.where(slot[None] <= pos_dec[:, :, None],
+                         0.0, -1e30)[:, None]
+    dec_tables = lane_tables[:ldec]
+    chunk_row = chunk_table = mask_chk = None
+    if tq > 1:
+        # chunk rows scatter into the (1, tq) block; every other row's
+        # index lands out of bounds on the lane axis and is dropped
+        chunk_row = jnp.where(is_chunk & valid, 0, 1)
+        pos_chk = jnp.full((1, tq), -1, jnp.int32).at[
+            chunk_row, slot_c].set(positions, mode="drop")
+        mask_chk = jnp.where(slot[None] <= pos_chk[:, :, None],
+                             0.0, -1e30)[:, None]
+        chunk_table = lane_tables[lmax - 2][None]
+    return StepRows(tokens, logit_rows, valid, cos, sin, phys, off, dec_lane,
+                    dec_tables, mask_dec, is_chunk, chunk_row, slot_c,
+                    chunk_table, mask_chk)
+
+
+def attend_step(cfg, blk: dict, rows: StepRows, pages: jax.Array, at: int,
+                h: jax.Array, project, score_scale: float):
+    """One attention block of a fused step over pool layer ``at``: each
+    row's ``[c_kv | k_pe]`` (the family's ``project(cfg, blk, h, cos, sin)``) is
+    written once to its (page, slot), then the decode block and the chunk
+    block attend their lanes' gathered pages in the absorbed form.  h (F,
+    hidden) -> (h + attention, pages)."""
+    f = h.shape[0]
+    ldec, max_len = rows.dec_tables.shape[0], rows.mask_dec.shape[-1]
+    pad = page_row_width(cfg) - latent_width(cfg)    # zeros: score nothing
+    with jax.named_scope("mla.project"):
+        q_nope, q_pe, row = project(cfg, blk, h, rows.cos, rows.sin)
+        pages = pages.at[at, rows.phys, rows.off].set(
+            jnp.pad(row, ((0, 0), (0, pad))))
+    with jax.named_scope("mla.absorb"):
+        q_abs = jnp.pad(absorb_query(blk, q_nope, q_pe),
+                        ((0, 0), (0, 0), (0, pad)))  # (F, heads, row)
+    with jax.named_scope("mla.attend"):
+        q_dec = jnp.zeros((ldec, 1) + q_abs.shape[1:], q_abs.dtype)
+        q_dec = q_dec.at[rows.dec_lane, 0].set(q_abs)
+        o_dec = attend_absorbed(
+            cfg, blk, q_dec,
+            pages[at, rows.dec_tables].reshape(ldec, max_len, -1),
+            rows.mask_dec, score_scale)
+        o = o_dec[rows.dec_lane, 0]                  # (F, heads, v)
+        if rows.chunk_row is not None:
+            tq = rows.mask_chk.shape[2]
+            q_chk = jnp.zeros((1, tq) + q_abs.shape[1:], q_abs.dtype)
+            q_chk = q_chk.at[rows.chunk_row, rows.slot_c].set(
+                q_abs, mode="drop")
+            o_chk = attend_absorbed(
+                cfg, blk, q_chk,
+                pages[at, rows.chunk_table].reshape(1, max_len, -1),
+                rows.mask_chk, score_scale)
+            o = jnp.where(rows.is_chunk[:, None, None],
+                          o_chk[0, rows.slot_c], o)
+    return h + dense(blk["o"], o.reshape(f, -1)), pages
+
+
+# ------------------------------------------------------- the held experts
+def swiglu(mlp: dict, x: jax.Array) -> jax.Array:
+    gate = dense({"w": mlp["gate"]}, x)
+    return dense({"w": mlp["down"]},
+                 jax.nn.silu(gate) * dense({"w": mlp["up"]}, x))
+
+
+def held_gates(ids: jax.Array, gates: jax.Array, held: tuple,
+               valid: jax.Array | None = None):
+    """A router's choice (ids, gates: (N, k)) as this process sees it:
+    ``weight`` (N, count) f32 = each row's gate on each HELD expert
+    (``held = (first, count)``; zero where the row was not routed to it),
+    and the counts over the ``valid`` rows, int32 (3,) = (assignments on
+    held experts, the fullest held expert's rows, held experts that got a
+    row)."""
+    first, count = held
+    # (N, k, count) one-hot of the held experts' local ids: an id
+    # outside first .. first+count-1 gives a zero row
+    on = jax.nn.one_hot(ids - first, count, dtype=jnp.float32)
+    weight = jnp.einsum("nk,nkc->nc", gates, on)
+    rows = on.sum(axis=1)
+    if valid is not None:
+        rows = rows * valid[:, None].astype(jnp.float32)
+    per_expert = rows.sum(axis=0)
+    counts = jnp.stack([per_expert.sum(), per_expert.max(),
+                        (per_expert > 0).sum()]).astype(jnp.int32)
+    return weight, counts
+
+
+def held_experts(experts: dict, x: jax.Array, weight: jax.Array) -> jax.Array:
+    """What the held experts add for rows x (N, hidden) under ``weight``
+    (N, held): f32 (N, hidden).  A masked matmul: every held expert's
+    gate/up runs over every row (at serving batch sizes the cost is reading
+    the expert's weights, once, whoever is routed to it) and a row's gate,
+    zero where it was not routed to that expert, scales the activation
+    before ONE down projection over (expert, width): no dropped tokens, no
+    capacity factor."""
+    gate = jnp.einsum("nh,chi->nci", x, experts["gate"],
+                      preferred_element_type=jnp.float32)
+    up = jnp.einsum("nh,chi->nci", x, experts["up"],
+                    preferred_element_type=jnp.float32)
+    act = (jax.nn.silu(gate) * up * weight[:, :, None]).astype(x.dtype)
+    return jnp.einsum("nci,cih->nh", act, experts["down"],
+                      preferred_element_type=jnp.float32)

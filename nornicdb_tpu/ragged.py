@@ -2,7 +2,8 @@
 scheduler and every decoder family's ``fused_step``.
 
 The scheduler (``genserve/engine.py``) packs one int32 host array a step;
-a family (``models/qwen2.py``, ``models/deepseek_v2.py``) unpacks it on the
+a family (``models/qwen2.py``, ``models/deepseek_v2.py``,
+``models/longcat_flash.py``) unpacks it on the
 device.  Nothing here knows a model: page arithmetic, the power-of-two
 bucketing of program shapes, the null page, and the order of the routing
 counts a family may append to the step's greedy ids.
@@ -32,9 +33,10 @@ NULL_PAGE = 0
 
 # what a family with routed experts appends, in this order, to the ``Lmax``
 # greedy ids of its step's one int vector (``GenStats`` fields of the same
-# names; a family without experts appends nothing).  A family that appends
-# them says so (``STEP_COUNTERS = ROUTING_COUNTERS`` in its module): the
-# scheduler sizes the first step's ``prev`` by it
+# names; a family without experts appends nothing).  A family says what its
+# step appends in its module's ``STEP_COUNTERS`` (these four, or these and
+# further ``GenStats`` fields of its own after them): the scheduler sizes
+# the first step's ``prev`` by it and reads every step's counts by it
 ROUTING_COUNTERS = ("expert_assignments", "expert_rows_max", "experts_hit",
                     "routed_rows")
 

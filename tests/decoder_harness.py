@@ -1,7 +1,8 @@
 """What the tests of every decoder family share: a driver of the family's
 ``fused_step`` that packs rows as the scheduler does, and the readings the
 tolerances are stated on.  A family is its module (``models/qwen2.py``,
-``models/deepseek_v2.py``: ``init_pages`` / ``fused_step``); what judges it
+``models/deepseek_v2.py``, ``models/longcat_flash.py``: ``init_pages`` /
+``fused_step``); what judges it
 is the plain float32 forward of ``models/reference/<family>.py``.
 """
 
@@ -9,11 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nornicdb_tpu.ragged import (
-    ROUTING_COUNTERS,
-    pack_ragged_meta,
-    round_up_pow2,
-)
+from nornicdb_tpu.ragged import pack_ragged_meta, round_up_pow2
 
 PAGE, WIDTH, LMAX = 16, 8, 4  # 8 pages a lane = 128 slots; 2 decode lanes
 
@@ -95,7 +92,9 @@ class Pool:
     def __init__(self, family, cfg, params, pages: int = 40):
         self.family, self.cfg, self.params = family, cfg, params
         self.pool = family.init_pages(cfg, pages, PAGE)
-        self.counts = np.zeros(len(ROUTING_COUNTERS), np.int64)
+        # the family's own counts, in its STEP_COUNTERS order
+        self.counters = tuple(getattr(family, "STEP_COUNTERS", ()))
+        self.counts = np.zeros(len(self.counters), np.int64)
 
     def step(self, decode=(), chunk=None, prev=None):
         """decode: [(token, position, table)]; chunk: (tokens, start,
@@ -126,8 +125,7 @@ class Pool:
         assert donated.is_deleted(), "the step copied the pool"
         self.ints = ints = np.asarray(ints)
         # the greedy ids, then the routing counts of a family that routes
-        assert ints.shape[0] - LMAX == len(
-            getattr(self.family, "STEP_COUNTERS", ()))  # as it declares
+        assert ints.shape[0] - LMAX == len(self.counters)  # as it declares
         assert (ints[:LMAX] == np.asarray(logits).argmax(-1)).all()
         if ints.shape[0] > LMAX:
             self.counts += ints[LMAX:]

@@ -315,3 +315,42 @@ def test_mla_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     layouts = set(re.findall(re.escape(shape) + r"\{([0-9,]+)", text))
     assert layouts == {"3,2,1,0"}, layouts
     assert not re.search(re.escape(shape) + r"\S* copy\(", text)
+
+
+@pytest.mark.parametrize("f,tq", [(16, 1), (64, 64)],
+                         ids=["decode", "prefill-chunk"])
+def test_scmoe_mla_fused_step_at_the_benchmark_cut(one_chip, f, tq):
+    """LongCat-Flash's step at the benchmark's cut (published widths, 4
+    double layers, 8 held experts, the 768-wide router, 16,384-row head)
+    and the cell's engine geometry, as the DeepSeek-V2 case above: it fits
+    the chip beside the deployment's 5.43 GB, and the latent pool, TWO pool
+    layers a layer (``bf16[8,8193,16,640]``), goes in and out in ONE
+    row-major layout with no pool-sized copy left in the step."""
+    import re
+
+    import jax.numpy as jnp
+
+    from nornicdb_tpu.ragged import pack_ragged_meta
+    from nornicdb_tpu.models import longcat_flash as lcf
+
+    cfg = lcf.LONGCAT_FLASH_EP64_4L
+    lmax, w, pages, page = 18, 512, 8193, 16
+    meta, _ = pack_ragged_meta(lmax, w, f)
+    pool = (cfg.attention_blocks, pages, page, cfg.page_row_width)
+    assert pool == (8, 8193, 16, 640)
+    compiled = lcf.fused_step.lower(
+        _params_on(lcf.init_params, cfg, one_chip), cfg,
+        _sds(meta.shape, jnp.int32, one_chip),
+        _sds(pool, jnp.bfloat16, one_chip), lmax=lmax, w=w, tq=tq,
+        # the served variant: the ids and the family's five counts
+        prev=_sds((lmax + len(lcf.STEP_COUNTERS),), jnp.int32, one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= int(np.prod(pool)) * 2  # donated
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < (16 << 30) - 5_430_000_000
+    text = compiled.as_text()
+    shape = "bf16[%s]" % ",".join(map(str, pool))
+    layouts = set(re.findall(re.escape(shape) + r"\{([0-9,]+)", text))
+    assert layouts == {"3,2,1,0"}, layouts
+    assert not re.search(re.escape(shape) + r"\S* copy\(", text)

@@ -49,6 +49,7 @@ from decoder_harness import (
 )
 from nornicdb_tpu.ragged import ROUTING_COUNTERS, pages_for
 from nornicdb_tpu.models import deepseek_v2 as ds
+from nornicdb_tpu.models import mla
 from nornicdb_tpu.models import qwen2
 from nornicdb_tpu.models.reference import deepseek_v2 as ref
 
@@ -236,8 +237,8 @@ def test_absorbed_attention_is_the_expanded_one(seed):
     mask = jnp.where(jnp.arange(24)[None, :] <= 19 + jnp.arange(5)[:, None],
                      0.0, -1e30)[None, None]
     want = attend_expanded(F32, blk, q_nope, q_pe, rows, mask)
-    got = ds.attend_absorbed(F32, blk, ds.absorb_query(blk, q_nope, q_pe),
-                             rows, mask)
+    got = mla.attend_absorbed(F32, blk, mla.absorb_query(blk, q_nope, q_pe),
+                              rows, mask, ds.softmax_scale(F32))
     assert np.abs(np.asarray(got - want)).max() < 1e-5
 
 
@@ -380,6 +381,33 @@ def test_qwens_lowered_step_is_what_it_was_before_the_family_seam(tq, sha):
                                          w=w, tq=tq, prev=prev).as_text()
     assert re.search(r"module @(\S+)", text).group(1) == \
         "jit_ragged_fused_step"
+    assert hashlib.sha256(text.encode()).hexdigest() == sha
+
+
+@pytest.mark.parametrize("tq,sha", [
+    (16, "c142c5fc4df536af65b51281ee9794ec08ea3fffce1162a78076b1f419fdfa1a"),
+    (1, "6acb726843cc23cc5f6ad9fe2ca0bd07b186faabf48dbcbf56fb429d7b9fa016")])
+def test_dsv2s_lowered_step_is_what_it_was_before_mla_was_shared(tq, sha):
+    """As the case above, for ``mla_moe_fused_step``: the lowered text for
+    one step class at the small preset is what it was before PR 34 moved
+    the MLA projection, the absorbed attention, the step's two blocks, the
+    latent pool and the masked matmul over held experts into
+    ``models/mla.py`` (same digests before and after the move).  The other
+    latent family (``models/longcat_flash.py``) runs that module too: a PR
+    that changes it reaches BOTH families, renews the two digests and
+    measures ``dsv2-chat-sys4k`` and ``lcf-chat-sys4k``; one that does not
+    mean to may not move this program."""
+    cfg = ds.DEEPSEEK_V2_SMALL
+    params = jax.eval_shape(lambda: ds.init_params(cfg,
+                                                   jax.random.PRNGKey(0)))
+    lmax, w, f = 6, 8, 16
+    meta = jax.ShapeDtypeStruct((4 * f + lmax + lmax * w,), jnp.int32)
+    pages = jax.eval_shape(lambda: ds.init_pages(cfg, 17, 16))
+    prev = jax.ShapeDtypeStruct((lmax + len(ds.STEP_COUNTERS),), jnp.int32)
+    text = ds.fused_step.lower(params, cfg, meta, pages, lmax=lmax, w=w,
+                               tq=tq, prev=prev).as_text()
+    assert re.search(r"module @(\S+)", text).group(1) == \
+        "jit_mla_moe_fused_step"
     assert hashlib.sha256(text.encode()).hexdigest() == sha
 
 

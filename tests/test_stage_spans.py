@@ -446,15 +446,22 @@ def _program_modules() -> dict:
     import jax
     import jax.numpy as jnp
 
-    from nornicdb_tpu.models import deepseek_v2
+    from nornicdb_tpu.models import deepseek_v2, longcat_flash
     from nornicdb_tpu.ops.pallas_kernels import streaming_cosine_topk
 
     embedder = TPUEmbedder(cfg=F32_CFG)
     ids, sel = jnp.ones((2, 16), jnp.int32), jnp.zeros((2,), jnp.int32)
     q, c = jnp.ones((1, 128)), jnp.ones((1024, 128))
     dsv2 = deepseek_v2.DEEPSEEK_V2_SMALL
+    lcf = longcat_flash.LONGCAT_FLASH_SMALL
     lmax, w, f = 4, 8, 16
     return {
+        "step_roofline.lcf": _module_name(longcat_flash.fused_step.lower(
+            jax.eval_shape(lambda: longcat_flash.init_params(
+                lcf, jax.random.PRNGKey(0))), lcf,
+            jax.ShapeDtypeStruct((4 * f + lmax + lmax * w,), jnp.int32),
+            jax.eval_shape(lambda: longcat_flash.init_pages(lcf, 9, 16)),
+            lmax=lmax, w=w, tq=16)),
         "step_roofline.dsv2": _module_name(deepseek_v2.fused_step.lower(
             jax.eval_shape(lambda: deepseek_v2.init_params(
                 dsv2, jax.random.PRNGKey(0))), dsv2,
