@@ -165,6 +165,12 @@ class GenStats:
     experts_hit: int = 0
     routed_rows: int = 0
     zero_assignments: int = 0
+    # attention over live lengths (a family whose step walks its lanes'
+    # page tables in blocks as far as the longest live lane: models/mla.py):
+    # cache slots the steps' attention blocks gathered and scored, and the
+    # slots the same lanes' whole tables hold
+    attn_slots_walked: int = 0
+    attn_slots_table: int = 0
 
     def as_dict(self) -> dict:
         return {k: getattr(self, k) for k in self.__dataclass_fields__}
@@ -1351,6 +1357,8 @@ class GenerationEngine:
             _stats.EXPERT_ROWS_MAX.set(routing["expert_rows_max"])
             _stats.ZERO_EXPERT_ASSIGNMENTS.inc(
                 routing.get("zero_assignments", 0))
+            _stats.ATTN_SLOTS_WALKED.inc(routing.get("attn_slots_walked", 0))
+            _stats.ATTN_SLOTS_TABLE.inc(routing.get("attn_slots_table", 0))
         _deviceprof.record_execute("genserve", "ragged", flight.shape, dt)
         # the one dispatch served both phases: observability stays
         # per-phase (retroactive spans in each submitter's trace, the

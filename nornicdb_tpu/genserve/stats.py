@@ -118,3 +118,17 @@ ZERO_EXPERT_ASSIGNMENTS = _REGISTRY.counter(
     "Top-k expert choices that fell on zero-compute (identity) experts "
     "(summed over the expert branches of every fused step)",
 )
+# attention over live lengths (a family whose step walks its lanes' page
+# tables only as far as the longest live lane reaches; 0 forever
+# otherwise): rate(walked) / rate(table) is the share of a full-table
+# step's gathers and scores that the steps still do
+ATTN_SLOTS_WALKED = _REGISTRY.counter(
+    "nornicdb_genserve_attn_slots_walked_total",
+    "Cache slots the fused steps' attention blocks gathered and scored "
+    "(summed over lanes and attention blocks)",
+)
+ATTN_SLOTS_TABLE = _REGISTRY.counter(
+    "nornicdb_genserve_attn_slots_table_total",
+    "Cache slots the same lanes' whole page tables hold (what a step that "
+    "gathered every page of every lane would have walked)",
+)

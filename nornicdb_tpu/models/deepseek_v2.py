@@ -328,14 +328,15 @@ def mla_moe_fused_step(params, cfg: DeepSeekV2Config, meta: jax.Array,
 
     Each row's ``[c_kv | k_pe]`` is written once to its (page, slot); the
     decode block (one query a lane) and the chunk block (``tq`` queries of
-    the chunk lane) then attend their lanes' gathered pages in the
-    absorbed form.  Returns ``(ints, logits, pages)``: ``ints`` = the
-    ``lmax`` greedy ids followed by the step's routing counts (ROUTING_
-    COUNTERS order: assignments on held experts, the fullest held expert's
-    rows and the held experts hit, each summed over the expert layers, and
-    rows routed = valid rows x expert layers), so one device-to-host read
-    carries both; ``logits`` (lmax, V) f32 for ``logit_rows``; ``pages``
-    is DONATED."""
+    the chunk lane) then attend what is live of their lanes' pages in the
+    absorbed form (:func:`mla.attend_live`).  Returns ``(ints, logits,
+    pages)``: ``ints`` = the ``lmax`` greedy ids followed by the step's
+    counts in :data:`STEP_COUNTERS` order (assignments on held experts, the
+    fullest held expert's rows and the held experts hit, each summed over
+    the expert layers; rows routed = valid rows x expert layers; the slots
+    the attention blocks walked and the slots their whole tables hold:
+    :data:`mla.WALK_COUNTERS`), so one device-to-host read carries both;
+    ``logits`` (lmax, V) f32 for ``logit_rows``; ``pages`` is DONATED."""
     rows = mla.plan_step(
         meta, pages, _rope_tables(cfg, w * pages.shape[2]), lmax=lmax, w=w,
         tq=tq, prev=prev)
@@ -350,11 +351,12 @@ def mla_moe_fused_step(params, cfg: DeepSeekV2Config, meta: jax.Array,
     logits = _logits(params, cfg, h[jnp.clip(rows.logit_rows, 0, f - 1)])
     routed = valid.sum().astype(jnp.int32) * cfg.expert_layers
     ints = jnp.concatenate([jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                            counts, routed[None]])
+                            counts, routed[None],
+                            rows.walk * cfg.num_hidden_layers])
     return ints, logits, pages
 
 
 # the decoder-family seam (genserve/engine.py); no dense-mode pair
 fused_step = mla_moe_fused_step
 # what ``ints`` carries after the ids (nornicdb_tpu/ragged.py)
-STEP_COUNTERS = ROUTING_COUNTERS
+STEP_COUNTERS = ROUTING_COUNTERS + mla.WALK_COUNTERS

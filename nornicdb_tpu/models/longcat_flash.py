@@ -323,8 +323,10 @@ def scmoe_mla_fused_step(params, cfg: LongCatFlashConfig, meta: jax.Array,
     counts in :data:`STEP_COUNTERS` order (assignments on held experts, the
     fullest held expert's rows and the held experts hit, each summed over
     the layers; rows routed = valid rows x layers; top-k choices that fell
-    on zero experts), so one device-to-host read carries both; ``logits``
-    (lmax, V) f32 for ``logit_rows``; ``pages`` is DONATED."""
+    on zero experts; the slots the attention blocks walked and the slots
+    their whole tables hold: :data:`mla.WALK_COUNTERS`), so one
+    device-to-host read carries both; ``logits`` (lmax, V) f32 for
+    ``logit_rows``; ``pages`` is DONATED."""
     rows = mla.plan_step(
         meta, pages, _rope_tables(cfg, w * pages.shape[2]), lmax=lmax, w=w,
         tq=tq, prev=prev)
@@ -343,11 +345,12 @@ def scmoe_mla_fused_step(params, cfg: LongCatFlashConfig, meta: jax.Array,
     logits = _logits(params, cfg, h[jnp.clip(rows.logit_rows, 0, f - 1)])
     routed = rows.valid.sum().astype(jnp.int32) * cfg.num_layers
     ints = jnp.concatenate([jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                            counts[:3], routed[None], counts[3:]])
+                            counts[:3], routed[None], counts[3:],
+                            rows.walk * cfg.attention_blocks])
     return ints, logits, pages
 
 
 # the decoder-family seam (genserve/engine.py)
 fused_step = scmoe_mla_fused_step
 # what ``ints`` carries after the ids (nornicdb_tpu/ragged.py)
-STEP_COUNTERS = ROUTING_COUNTERS + ("zero_assignments",)
+STEP_COUNTERS = ROUTING_COUNTERS + ("zero_assignments",) + mla.WALK_COUNTERS

@@ -152,6 +152,22 @@ def attend_sequences(cfg, blk: dict, h: jax.Array, b: int, cos, sin, mask,
     return h + dense(blk["o"], o.reshape(h.shape[0], -1))
 
 
+# pages of a lane's table that one turn of a step's walk gathers, scores and
+# sums (x page_size slots): the step's two attention blocks walk their
+# lanes' tables in blocks of this many pages, only as far as the longest
+# live lane reaches.  One constant for every latent family; 16 / 32 / 64
+# were read on the chip (PERF.md section 6, PR 36).  A table narrower than
+# a block is walked as one block.
+BLOCK_PAGES = 32
+# what a latent family's step appends, last, to its ``STEP_COUNTERS``
+# (``GenStats`` fields of the same names; ``StepRows.walk`` times the
+# family's attention blocks): the cache slots the step's two attention
+# blocks gathered and scored, summed over their lanes, and the slots a walk
+# of those lanes' whole tables would have (whole blocks; a step that
+# gathered every page of every lane did that)
+WALK_COUNTERS = ("attn_slots_walked", "attn_slots_table")
+
+
 class StepRows(NamedTuple):
     """What one fused step's rows say, unpacked once for every attention
     block of the step (:func:`plan_step`)."""
@@ -163,13 +179,20 @@ class StepRows(NamedTuple):
     phys: jax.Array         # (F,) the page a row's latent is written to
     off: jax.Array          # (F,) and its slot there
     dec_lane: jax.Array     # (F,) lane of the decode block (dump lane last)
-    dec_tables: jax.Array   # (Lmax-1, W)
-    mask_dec: jax.Array     # (Lmax-1, 1, 1, S)
+    dec_tables: jax.Array   # (Lmax-1, W') W' = W up to whole blocks of pages
+    pos_dec: jax.Array      # (Lmax-1, 1) a lane's query position, -1 = none
+    dec_blocks: jax.Array   # () blocks of pages the decode block walks
     is_chunk: jax.Array     # (F,)
     chunk_row: jax.Array | None   # (F,) 0 for a chunk row, else out of bounds
     slot_c: jax.Array       # (F,) a chunk row's place in the chunk block
-    chunk_table: jax.Array | None  # (1, W)
-    mask_chk: jax.Array | None     # (1, 1, Tq, S)
+    chunk_table: jax.Array | None  # (1, W')
+    pos_chk: jax.Array | None      # (1, Tq) the chunk rows' positions
+    chunk_blocks: jax.Array | None  # () blocks the chunk block walks
+    walk: jax.Array         # (2,) WALK_COUNTERS of ONE attention block
+
+
+def _block_pages(w: int) -> int:
+    return min(BLOCK_PAGES, w)
 
 
 def plan_step(meta: jax.Array, pages: jax.Array, tables, *, lmax: int,
@@ -181,8 +204,11 @@ def plan_step(meta: jax.Array, pages: jax.Array, tables, *, lmax: int,
     attention blocks see them: the decode block (one query a lane; the
     decode lanes and, last, a dump lane for every row that is not a decode
     row, masked everywhere, never gathered back) and the chunk block
-    (``tq`` queries of the chunk lane).  ``tables`` = the family's
-    :func:`rope_tables` over ``w * page_size`` positions."""
+    (``tq`` queries of the chunk lane).  Each block walks its lanes' tables
+    in blocks of :data:`BLOCK_PAGES` pages as far as its longest live lane
+    reaches: ``dec_blocks`` / ``chunk_blocks``, read off ``positions``, at
+    least 1; ``walk`` counts what that is of the whole tables.  ``tables``
+    = the family's :func:`rope_tables` over ``w * page_size`` positions."""
     tokens, lane_id, lane_pos, positions, logit_rows, lane_tables = \
         unpack_ragged_meta(meta, lmax, w, prev)
     ps = pages.shape[2]
@@ -203,23 +229,79 @@ def plan_step(meta: jax.Array, pages: jax.Array, tables, *, lmax: int,
                          jnp.minimum(lane_c, ldec - 1))
     pos_dec = jnp.full((ldec, 1), -1, jnp.int32).at[dec_lane, 0].set(
         jnp.where(valid & ~is_chunk, positions, -1))
-    slot = jax.lax.broadcasted_iota(jnp.int32, (1, max_len), 1)
-    mask_dec = jnp.where(slot[None] <= pos_dec[:, :, None],
-                         0.0, -1e30)[:, None]
-    dec_tables = lane_tables[:ldec]
-    chunk_row = chunk_table = mask_chk = None
+    bp = _block_pages(w)
+    n_blocks = -(-w // bp)
+    # whole blocks: the columns behind a table's end are the null page's,
+    # and no position reaches them
+    lane_tables = jnp.pad(lane_tables, ((0, 0), (0, n_blocks * bp - w)))
+
+    def blocks(pos):
+        return jnp.clip(-(-(pos.max() + 1) // (bp * ps)), 1, n_blocks)
+
+    dec_blocks = blocks(pos_dec)
+    walked, lanes = dec_blocks * ldec, ldec
+    chunk_row = chunk_table = pos_chk = chunk_blocks = None
     if tq > 1:
         # chunk rows scatter into the (1, tq) block; every other row's
         # index lands out of bounds on the lane axis and is dropped
         chunk_row = jnp.where(is_chunk & valid, 0, 1)
         pos_chk = jnp.full((1, tq), -1, jnp.int32).at[
             chunk_row, slot_c].set(positions, mode="drop")
-        mask_chk = jnp.where(slot[None] <= pos_chk[:, :, None],
-                             0.0, -1e30)[:, None]
         chunk_table = lane_tables[lmax - 2][None]
+        chunk_blocks = blocks(pos_chk)
+        walked, lanes = walked + chunk_blocks, lanes + 1
+    # every lane of a block walks as far as its longest
+    walk = jnp.stack([walked, jnp.int32(lanes * n_blocks)]) * (bp * ps)
     return StepRows(tokens, logit_rows, valid, cos, sin, phys, off, dec_lane,
-                    dec_tables, mask_dec, is_chunk, chunk_row, slot_c,
-                    chunk_table, mask_chk)
+                    lane_tables[:ldec], pos_dec, dec_blocks, is_chunk,
+                    chunk_row, slot_c, chunk_table, pos_chk, chunk_blocks,
+                    walk)
+
+
+def attend_live(cfg, blk: dict, q_abs: jax.Array, pages: jax.Array, at: int,
+                tables: jax.Array, pos: jax.Array, n_blocks: jax.Array,
+                score_scale: float) -> jax.Array:
+    """:func:`attend_absorbed` over what is live of the lanes' pages in
+    pool layer ``at``: q_abs (L, T, heads, row width) against the first
+    ``n_blocks`` blocks of :data:`BLOCK_PAGES` pages of ``tables`` (L, W'),
+    a query at ``pos`` (L, T) seeing the slots up to its own (-1: none; its
+    output is garbage and never read) -> (L, T, heads, v_head_dim).  One
+    turn gathers a block of every lane's pages, scores it in f32 and folds
+    it into a running softmax (m, l, acc: f32); nothing of a table behind
+    ``n_blocks`` is gathered, scored or summed.  The pool is only read."""
+    lanes, t, heads, width = q_abs.shape
+    bp = _block_pages(tables.shape[1])
+    bs = bp * pages.shape[2]
+    slot = jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, bs), 3)
+    last = pos[:, None, :, None]                     # (L, 1, T, 1)
+
+    def turn(b, state):
+        m, total, acc = state
+        table = jax.lax.dynamic_slice_in_dim(tables, b * bp, bp, axis=1)
+        rows = pages[at, table].reshape(lanes, bs, width)
+        s = jnp.einsum("lthd,lsd->lhts", q_abs, rows,
+                       preferred_element_type=jnp.float32)
+        s = s * score_scale + jnp.where(slot + b * bs <= last, 0.0, -1e30)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        keep = jnp.exp(m - m_new)
+        # over the whole row (the k_pe columns ride along and are dropped):
+        # slicing the gathered rows first would copy them
+        acc = acc * keep[..., None] + jnp.einsum(
+            "lhts,lsd->lhtd", p.astype(rows.dtype), rows,
+            preferred_element_type=jnp.float32)
+        return m_new, total * keep + p.sum(axis=-1), acc
+
+    # a live query sees slot 0, so its m is finite after the first turn and
+    # a block that is wholly masked for it adds exactly 0
+    start = (jnp.full((lanes, heads, t), -1e30, jnp.float32),
+             jnp.zeros((lanes, heads, t), jnp.float32),
+             jnp.zeros((lanes, heads, t, width), jnp.float32))
+    _, total, acc = jax.lax.fori_loop(0, n_blocks, turn, start)
+    o_lat = (acc / total[..., None])[..., :cfg.kv_lora_rank].astype(
+        q_abs.dtype)
+    return jnp.einsum("lhtc,chv->lthv", o_lat, blk["kv_b_v"],
+                      preferred_element_type=jnp.float32).astype(q_abs.dtype)
 
 
 def attend_step(cfg, blk: dict, rows: StepRows, pages: jax.Array, at: int,
@@ -227,10 +309,10 @@ def attend_step(cfg, blk: dict, rows: StepRows, pages: jax.Array, at: int,
     """One attention block of a fused step over pool layer ``at``: each
     row's ``[c_kv | k_pe]`` (the family's ``project(cfg, blk, h, cos, sin)``) is
     written once to its (page, slot), then the decode block and the chunk
-    block attend their lanes' gathered pages in the absorbed form.  h (F,
-    hidden) -> (h + attention, pages)."""
+    block attend what is live of their lanes' pages in the absorbed form
+    (:func:`attend_live`).  h (F, hidden) -> (h + attention, pages)."""
     f = h.shape[0]
-    ldec, max_len = rows.dec_tables.shape[0], rows.mask_dec.shape[-1]
+    ldec = rows.dec_tables.shape[0]
     pad = page_row_width(cfg) - latent_width(cfg)    # zeros: score nothing
     with jax.named_scope("mla.project"):
         q_nope, q_pe, row = project(cfg, blk, h, rows.cos, rows.sin)
@@ -242,20 +324,16 @@ def attend_step(cfg, blk: dict, rows: StepRows, pages: jax.Array, at: int,
     with jax.named_scope("mla.attend"):
         q_dec = jnp.zeros((ldec, 1) + q_abs.shape[1:], q_abs.dtype)
         q_dec = q_dec.at[rows.dec_lane, 0].set(q_abs)
-        o_dec = attend_absorbed(
-            cfg, blk, q_dec,
-            pages[at, rows.dec_tables].reshape(ldec, max_len, -1),
-            rows.mask_dec, score_scale)
+        o_dec = attend_live(cfg, blk, q_dec, pages, at, rows.dec_tables,
+                            rows.pos_dec, rows.dec_blocks, score_scale)
         o = o_dec[rows.dec_lane, 0]                  # (F, heads, v)
         if rows.chunk_row is not None:
-            tq = rows.mask_chk.shape[2]
+            tq = rows.pos_chk.shape[1]
             q_chk = jnp.zeros((1, tq) + q_abs.shape[1:], q_abs.dtype)
             q_chk = q_chk.at[rows.chunk_row, rows.slot_c].set(
                 q_abs, mode="drop")
-            o_chk = attend_absorbed(
-                cfg, blk, q_chk,
-                pages[at, rows.chunk_table].reshape(1, max_len, -1),
-                rows.mask_chk, score_scale)
+            o_chk = attend_live(cfg, blk, q_chk, pages, at, rows.chunk_table,
+                                rows.pos_chk, rows.chunk_blocks, score_scale)
             o = jnp.where(rows.is_chunk[:, None, None],
                           o_chk[0, rows.slot_c], o)
     return h + dense(blk["o"], o.reshape(f, -1)), pages

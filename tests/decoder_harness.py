@@ -63,8 +63,8 @@ def blank_step(lmax: int, w: int, f: int):
     return meta, views
 
 
-def table_of(*pages):
-    table = np.zeros(WIDTH, np.int32)
+def table_of(*pages, width: int = WIDTH):
+    table = np.zeros(width, np.int32)
     table[:len(pages)] = pages
     return table
 
@@ -89,8 +89,10 @@ class Pool:
     """Drives ``family.fused_step`` as the scheduler does: one chunk of one
     lane beside the decode rows of others, through one donated pool."""
 
-    def __init__(self, family, cfg, params, pages: int = 40):
+    def __init__(self, family, cfg, params, pages: int = 40,
+                 width: int = WIDTH):
         self.family, self.cfg, self.params = family, cfg, params
+        self.width = width  # pages of a lane's table
         self.pool = family.init_pages(cfg, pages, PAGE)
         # the family's own counts, in its STEP_COUNTERS order
         self.counters = tuple(getattr(family, "STEP_COUNTERS", ()))
@@ -105,7 +107,7 @@ class Pool:
         tq = round_up_pow2(n_valid, 16) if chunk else 1
         f = round_up_pow2(len(decode) + n_valid, 8)
         meta, (toks, lane, lpos, pos, rows, tables) = blank_step(
-            LMAX, WIDTH, f)
+            LMAX, self.width, f)
         for i, (tok, at, table) in enumerate(decode):
             toks[i], lane[i], pos[i], rows[i] = tok, i, at, i
             tables[i] = table
@@ -120,7 +122,7 @@ class Pool:
         donated = self.pool
         ints, logits, self.pool = self.family.fused_step(
             self.params, self.cfg, jnp.asarray(meta), donated,
-            lmax=LMAX, w=WIDTH, tq=tq,
+            lmax=LMAX, w=self.width, tq=tq,
             **({} if prev is None else {"prev": jnp.asarray(prev)}))
         assert donated.is_deleted(), "the step copied the pool"
         self.ints = ints = np.asarray(ints)

@@ -285,14 +285,15 @@ def test_mla_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     layers, 20 held experts, 12,800-row head) and the cell's engine geometry
     (16 lanes + chunk + dump, page 16, 512-page tables, 8,193-page pool):
     it fits the chip beside the deployment's 5.43 GB, the latent pool goes
-    in and out in ONE row-major layout, and no pool-sized copy is left in
+    in and out in ONE row-major layout, no pool-sized copy is left in
     the step (a 576-wide row makes the compiler put the pages axis minor
-    and copy the whole pool to row-major and back: PERF.md section 5)."""
+    and copy the whole pool to row-major and back: PERF.md section 5), and
+    no gather of every lane's whole table either."""
     import re
 
     import jax.numpy as jnp
 
-    from nornicdb_tpu.ragged import ROUTING_COUNTERS, pack_ragged_meta
+    from nornicdb_tpu.ragged import pack_ragged_meta
     from nornicdb_tpu.models import deepseek_v2 as ds
 
     cfg = ds.DEEPSEEK_V2_EP8_5L
@@ -303,8 +304,8 @@ def test_mla_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
         _params_on(ds.init_params, cfg, one_chip), cfg,
         _sds(meta.shape, jnp.int32, one_chip),
         _sds(pool, jnp.bfloat16, one_chip), lmax=lmax, w=w, tq=tq,
-        # the served variant: the ids and the four routing counts
-        prev=_sds((lmax + len(ROUTING_COUNTERS),), jnp.int32, one_chip),
+        # the served variant: the ids and the family's six counts
+        prev=_sds((lmax + len(ds.STEP_COUNTERS),), jnp.int32, one_chip),
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= int(np.prod(pool)) * 2  # donated
@@ -315,6 +316,18 @@ def test_mla_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     layouts = set(re.findall(re.escape(shape) + r"\{([0-9,]+)", text))
     assert layouts == {"3,2,1,0"}, layouts
     assert not re.search(re.escape(shape) + r"\S* copy\(", text)
+    _no_whole_table_gather(text)
+
+
+def _no_whole_table_gather(text: str) -> None:
+    """The step walks its lanes' tables a block of pages at a time
+    (``models/mla.py``, PR 36): nothing of the extent of every page of
+    every decode-block lane (17 lanes x 512 pages) is left, and what a
+    turn gathers is one block of each lane's pages."""
+    from nornicdb_tpu.models import mla
+
+    assert "[8704,16,640]" not in text and "[17,512,16,640]" not in text
+    assert f"bf16[{17 * mla.BLOCK_PAGES},16,640]" in text
 
 
 @pytest.mark.parametrize("f,tq", [(16, 1), (64, 64)],
@@ -325,7 +338,8 @@ def test_scmoe_mla_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     and the cell's engine geometry, as the DeepSeek-V2 case above: it fits
     the chip beside the deployment's 5.43 GB, and the latent pool, TWO pool
     layers a layer (``bf16[8,8193,16,640]``), goes in and out in ONE
-    row-major layout with no pool-sized copy left in the step."""
+    row-major layout with no pool-sized copy left in the step, and no
+    gather of every lane's whole table either."""
     import re
 
     import jax.numpy as jnp
@@ -342,7 +356,7 @@ def test_scmoe_mla_fused_step_at_the_benchmark_cut(one_chip, f, tq):
         _params_on(lcf.init_params, cfg, one_chip), cfg,
         _sds(meta.shape, jnp.int32, one_chip),
         _sds(pool, jnp.bfloat16, one_chip), lmax=lmax, w=w, tq=tq,
-        # the served variant: the ids and the family's five counts
+        # the served variant: the ids and the family's seven counts
         prev=_sds((lmax + len(lcf.STEP_COUNTERS),), jnp.int32, one_chip),
     ).compile()
     mem = compiled.memory_analysis()
@@ -354,3 +368,4 @@ def test_scmoe_mla_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     layouts = set(re.findall(re.escape(shape) + r"\{([0-9,]+)", text))
     assert layouts == {"3,2,1,0"}, layouts
     assert not re.search(re.escape(shape) + r"\S* copy\(", text)
+    _no_whole_table_gather(text)

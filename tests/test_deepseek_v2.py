@@ -334,6 +334,12 @@ def test_routing_counts_equal_a_numpy_count(held):
         want["routed_rows"] += 13
         hid = ref.expert_layer(cfg, blk, hid, held)
     assert dict(zip(ROUTING_COUNTERS, pool.counts.tolist())) == want
+    # behind them the walk's pair: a table of 8 pages is narrower than a
+    # block, so the decode block's three lanes and the chunk lane each
+    # walked all of it, in every layer
+    assert ds.STEP_COUNTERS == ROUTING_COUNTERS + mla.WALK_COUNTERS
+    assert pool.counts[4:].tolist() == \
+        [LMAX * WIDTH * PAGE * cfg.num_hidden_layers] * 2
     if held == (0, 16):
         assert want["expert_assignments"] == 13 * 2 * cfg.num_experts_per_tok
 
@@ -385,8 +391,8 @@ def test_qwens_lowered_step_is_what_it_was_before_the_family_seam(tq, sha):
 
 
 @pytest.mark.parametrize("tq,sha", [
-    (16, "c142c5fc4df536af65b51281ee9794ec08ea3fffce1162a78076b1f419fdfa1a"),
-    (1, "6acb726843cc23cc5f6ad9fe2ca0bd07b186faabf48dbcbf56fb429d7b9fa016")])
+    (16, "9f11b85a7ec7cef39c71b43cc2651c477f0e15e32e0b0bb9fee8744406b9aa4a"),
+    (1, "f158c711bb7c5d1fb010ba30c92ec71daa3d992f0c1446c7c82e2b981450688b")])
 def test_dsv2s_lowered_step_is_what_it_was_before_mla_was_shared(tq, sha):
     """As the case above, for ``mla_moe_fused_step``: the lowered text for
     one step class at the small preset is what it was before PR 34 moved
@@ -396,7 +402,13 @@ def test_dsv2s_lowered_step_is_what_it_was_before_mla_was_shared(tq, sha):
     latent family (``models/longcat_flash.py``) runs that module too: a PR
     that changes it reaches BOTH families, renews the two digests and
     measures ``dsv2-chat-sys4k`` and ``lcf-chat-sys4k``; one that does not
-    mean to may not move this program."""
+    mean to may not move this program.  PR 36 MEANT to and renewed them:
+    the step's two attention blocks walk their lanes' page tables in
+    blocks of ``mla.BLOCK_PAGES`` pages up to the longest live length with
+    a running softmax (a ``while`` a block in place of one gather of every
+    page of every lane), and the int vector ends with the walk's two
+    counts.  At this case's 8-page tables a block is the whole table, so
+    the digests do not move with ``BLOCK_PAGES``."""
     cfg = ds.DEEPSEEK_V2_SMALL
     params = jax.eval_shape(lambda: ds.init_params(cfg,
                                                    jax.random.PRNGKey(0)))
@@ -478,6 +490,9 @@ def test_the_engine_serves_the_family_through_its_latent_pool():
     assert 0 < stats["expert_assignments"] < stats["routed_rows"] * 4
     assert stats["experts_hit"] <= stats["expert_assignments"]
     assert stats["expert_rows_max"] <= stats["expert_assignments"]
+    # the engine's 8-page tables are narrower than a block: every step
+    # walked all of them, and GenStats read that off the same int vector
+    assert stats["attn_slots_walked"] == stats["attn_slots_table"] > 0
     row = cfg.page_row_width * 4 * cfg.num_hidden_layers * PAGE
     assert hbm["kv_pages"] == 33 * row
     assert hbm["kv_prefix"] == stats["prefix_pages"] * row

@@ -457,6 +457,10 @@ def test_routing_counts_equal_a_numpy_count(held):
         want["experts_hit"] += int((rows > 0).sum())
         want["routed_rows"] += 13
         hid = ref.double_layer(cfg, layer, hid, cos, sin, held)
+    # a table of 8 pages is narrower than a block: the decode block's three
+    # lanes and the chunk lane each walk all of it, in every attention block
+    want["attn_slots_walked"] = want["attn_slots_table"] = \
+        LMAX * WIDTH * PAGE * cfg.attention_blocks
     assert dict(zip(lcf.STEP_COUNTERS, pool.counts.tolist())) == want
     assert want["zero_assignments"] > 0
     if held == (0, 12):
@@ -478,7 +482,12 @@ def test_the_step_carries_its_scopes_and_its_own_module_name():
     module = re.search(r"module @(\S+)", lowered.as_text()).group(1)
     assert module == "jit_scmoe_mla_fused_step"
     assert lcf.num_pages(pages) == 9 and pages.shape[0] == 2 * BF16.num_layers
-    assert lcf.STEP_COUNTERS[-1] == "zero_assignments"
+    # the family's own count stands behind the four every routed family
+    # has, and before the two every latent family has (PR 36): the fifth
+    # of seven
+    assert lcf.STEP_COUNTERS.index("zero_assignments") == 4
+    assert lcf.STEP_COUNTERS[5:] == mla.WALK_COUNTERS
+    assert len(lcf.STEP_COUNTERS) == 7
 
 
 # -------------------------------------- (j), (g): the engine, Heimdall
@@ -542,6 +551,7 @@ def test_the_engine_serves_the_family_through_its_latent_pool():
     assert stats["routed_rows"] == steps_rows * cfg.num_layers
     assert 0 < stats["expert_assignments"] < stats["routed_rows"] * 4
     assert 0 < stats["zero_assignments"] < stats["routed_rows"] * 4
+    assert stats["attn_slots_walked"] == stats["attn_slots_table"] > 0
     assert stats["expert_assignments"] + stats["zero_assignments"] \
         <= stats["routed_rows"] * cfg.moe_topk
     assert stats["experts_hit"] <= stats["expert_assignments"]
