@@ -13,6 +13,23 @@ generation path end to end:
   from ``type(cfg)``.  What a page holds is the family's; which pages a
   sequence holds, the row layout of a step (``nornicdb_tpu/ragged.py``) and the
   prefix cache are the scheduler's.
+* **Page kinds.**  A family whose layers do not all keep a lane's whole
+  history (``models/cohere2_moe.py``: window layers beside full ones) says
+  so in ``page_kinds(cfg)``, one ``(name, horizon)`` a kind; its
+  ``init_pages`` / ``num_pages`` / ``fused_step`` then take and give a
+  tuple, an entry a kind (pools, page counts, table widths).  The scheduler
+  keeps a pool, a free list, reference counts, prefix registrations and a
+  page table a lane FOR EACH KIND (:class:`_Kind`).  A kind with a horizon
+  holds, for a lane, the pages from the one its window still reaches
+  onward: when a step moves the window past a page the lane drops its
+  reference, and the page returns to that kind's free list unless the
+  prefix cache (or another lane) still holds it; a prefix hit hands the
+  new lane, a kind, the pages its first query can still see.  Such a
+  kind's pool is sized by ``max_seqs x (horizon + prefill_chunk + a page)
+  + one cached context``, not ``max_seqs x max_seq_tokens``.  A family
+  without ``page_kinds`` has the one kind ``full``, and nothing of its
+  step's layout, programs or counters differs from a scheduler that knew
+  no kinds.
 * **Paged KV cache** (Ragged Paged Attention, PAPERS.md).  One pooled
   buffer of fixed-size pages shared by every sequence, with per-sequence
   page tables.  Attention block-gathers each sequence's pages; sequences
@@ -98,6 +115,8 @@ from nornicdb_tpu.errors import (
 )
 from nornicdb_tpu.genserve import stats as _stats
 from nornicdb_tpu.ragged import (
+    KindTables,
+    first_page,
     pack_ragged_meta,
     pages_for,
     round_up_pow2,
@@ -171,6 +190,23 @@ class GenStats:
     # slots the same lanes' whole tables hold
     attn_slots_walked: int = 0
     attn_slots_table: int = 0
+    # page kinds (a family with window layers beside full ones:
+    # models/cohere2_moe.py).  From the step's int vector, a pair a kind:
+    # the pages its attention blocks gathered and scored, summed over
+    # lanes and the kind's layers, and the pages that hold a slot those
+    # lanes' live queries may see.  On the host: holds on pages of a kind
+    # with a horizon that lanes let go as their windows moved, and those of
+    # them that went back to the free list (the rest stay with the prefix
+    # cache or another lane)
+    full_pages_walked: int = 0
+    full_pages_held: int = 0
+    window_pages_walked: int = 0
+    window_pages_held: int = 0
+    window_pages_dropped: int = 0
+    window_pages_freed: int = 0
+    # the step's pairs summed over the kinds
+    attn_pages_walked: int = 0
+    attn_pages_held: int = 0
 
     def as_dict(self) -> dict:
         return {k: getattr(self, k) for k in self.__dataclass_fields__}
@@ -330,7 +366,7 @@ class _Seq:
 
     __slots__ = (
         "handle", "prompt", "out", "max_new", "eos_id", "state",
-        "prefill_tokens", "prefill_pos", "page_ids", "page_table",
+        "prefill_tokens", "prefill_pos", "tables", "bases", "held",
         "cache_len", "admit_no", "src", "row_step",
         "submitted_at", "first_token_at", "counted",
         "trace_ctx", "submitted_perf", "prefix_keys", "re_prefill",
@@ -346,8 +382,11 @@ class _Seq:
         self.state = _QUEUED
         self.prefill_tokens: list[int] = []
         self.prefill_pos = 0
-        self.page_ids: list[int] = []
-        self.page_table: Optional[np.ndarray] = None
+        # a page kind each: the lane's table, the logical page its column 0
+        # stands for, and how many of its columns hold a page
+        self.tables: Optional[list] = None
+        self.bases: list[int] = []
+        self.held: list[int] = []
         self.cache_len = 0
         self.admit_no = -1
         # state, prefill_pos and cache_len are the PLAN's: they advance
@@ -375,6 +414,117 @@ class _Seq:
     def trace_id(self) -> Optional[str]:
         ctx = self.trace_ctx
         return None if ctx is None else ctx.trace_id
+
+    # the FIRST kind's table and the pages in it (a family without page
+    # kinds has the one): what a scheduler that knew no kinds kept a lane
+    @property
+    def page_table(self) -> Optional[np.ndarray]:
+        return self.tables[0] if self.tables else None
+
+    @page_table.setter
+    def page_table(self, table) -> None:
+        self.tables, self.bases = [np.asarray(table, np.int32)], [0]
+        self.held = self.held or [len(self.tables[0])]
+
+    @property
+    def page_ids(self) -> list[int]:
+        return self.tables[0][:self.held[0]].tolist() if self.tables else []
+
+    @page_ids.setter
+    def page_ids(self, pids) -> None:
+        self.held = [len(pids)]
+        if self.tables is None:
+            self.page_table = pids
+
+
+class _Kind:
+    """One page kind's allocator state (scheduler-owned, like the pool it
+    indexes): the free list, who holds what, and the shared-prefix cache.
+
+      refs    pid -> live holders (sequences sharing it)
+      cache   chain-key -> pid, LRU order (oldest first); a cached page
+              with refcount 0 stays RESIDENT and reclaimable, it is not
+              on the free list
+      hash    pid -> chain-key (reverse index for reclaim)
+    """
+
+    __slots__ = ("name", "horizon", "width", "usable", "free", "refs",
+                 "cache", "hash")
+
+    def __init__(self, name: str, horizon: Optional[int], width: int,
+                 usable: int):
+        self.name, self.horizon = name, horizon
+        self.width = width      # pages of a lane's table
+        self.usable = usable    # pages of the pool, the null page apart
+        self.cache: "OrderedDict[bytes, int]" = OrderedDict()
+        self.hash: dict[int, bytes] = {}
+        self.refs: dict[int, int] = {}
+        self.free: list[int] = []
+        self.reset()
+
+    def reset(self) -> None:
+        """Pool content invalidated (re-platform / failed donated step):
+        every cached key now describes bytes that no longer exist, and no
+        sequence holds a page of it."""
+        self.cache.clear()
+        self.hash.clear()
+        self.refs.clear()
+        self.free = list(range(1, self.usable + 1))
+
+    def alloc(self) -> Optional[int]:
+        """One physical page for a new holder: the free list first, then
+        the least-recently-used IDLE prefix-cached page (evicting it
+        from the cache — a page some sequence still holds is never
+        reclaimed).  None means genuine pool pressure."""
+        if self.free:
+            return self.free.pop()
+        victim_key = None
+        for key, pid in self.cache.items():  # oldest first
+            if self.refs.get(pid, 0) == 0:
+                victim_key = key
+                break
+        if victim_key is None:
+            return None
+        pid = self.cache.pop(victim_key)
+        self.hash.pop(pid, None)
+        return pid
+
+    def available(self) -> int:
+        """Pages an admission could claim: free + idle prefix-cached."""
+        idle = sum(1 for pid in self.cache.values()
+                   if self.refs.get(pid, 0) == 0)
+        return len(self.free) + idle
+
+    def take(self, pid: int) -> None:
+        """One more holder of a page."""
+        self.refs[pid] = self.refs.get(pid, 0) + 1
+
+    def let_go(self, pid: int) -> bool:
+        """One holder fewer; True where the page went back to the free
+        list.  A page still shared with another live sequence stays theirs
+        (eviction/finish NEVER frees a page out from under its co-holder);
+        a prefix-cached page goes idle-resident (refcount 0), reclaimable
+        LRU by :meth:`alloc` under pool pressure."""
+        refs = self.refs.get(pid, 1) - 1
+        if refs > 0:
+            self.refs[pid] = refs
+            return False
+        self.refs.pop(pid, None)
+        if pid in self.hash:
+            return False
+        self.free.append(pid)
+        return True
+
+    def publish(self, key: bytes, pid: int) -> None:
+        """Offer a page to the prefix cache under its content key.  Pages
+        already cached (the hits an admission reused, or a concurrent
+        same-prompt registration) are skipped — first writer wins, the
+        loser's page simply stays private."""
+        if key in self.cache:
+            self.cache.move_to_end(key)
+        elif pid not in self.hash:
+            self.cache[key] = pid
+            self.hash[pid] = key
 
 
 class _Flight(NamedTuple):
@@ -428,6 +578,26 @@ class GenerationEngine:
         self._prefill_chunk = round_up_pow2(
             max(16, int(config.prefill_chunk)), 16)
         self._max_seqs = max(1, int(config.max_seqs))
+        # the family's page kinds (module note); without ``page_kinds`` the
+        # one kind ``full`` and the seam's plain form: one pool, one width
+        declared = getattr(self._family, "page_kinds", None)
+        self._by_kind = declared is not None
+        self._kinds: list[_Kind] = []
+        for name, horizon in (declared(cfg) if declared else
+                              (("full", None),)):
+            width, usable = self._table_width, self._usable_pages
+            if horizon is not None:
+                # what a lane's window, a chunk's queries and the page the
+                # window starts in can span; the pool: that for every lane
+                # and one cached context
+                width = min(width, pages_for(
+                    int(horizon) + self._prefill_chunk, self._page_size) + 1)
+                usable = min(usable,
+                             self._max_seqs * width + self._table_width)
+            self._kinds.append(_Kind(name, horizon, width, usable))
+        # ``w`` and the pool's page counts as the family's seam takes them
+        self._w = tuple(k.width for k in self._kinds) if self._by_kind \
+            else self._table_width
         # attention-lane count of the fused ragged step: decode lanes
         # 0..max_seqs-1, the chunk lane, and a reserved dump lane for
         # padding rows — ONE constant per engine, never a program-shape
@@ -442,8 +612,6 @@ class GenerationEngine:
         self._thread: Optional[threading.Thread] = None
         # scheduler-owned (no lock: single owner thread)
         self._running: list[_Seq] = []
-        self._free_pages: list[int] = list(
-            range(1, self._usable_pages + 1))
         self._pages = None
         self._admit_counter = 0
         # one step in flight: the dispatched, unread step; how many steps
@@ -454,15 +622,6 @@ class GenerationEngine:
         self._read_no = 0
         self._zombies: list[_Seq] = []
         self._no_ids: dict = {}  # platform -> the first step's ``prev``
-        # shared-prefix page cache (scheduler-owned, like the pool):
-        #   _page_refs     pid -> live holders (sequences sharing it)
-        #   _prefix_cache  chain-key -> pid, LRU order (oldest first);
-        #                  a cached page with refcount 0 stays RESIDENT
-        #                  and reclaimable, it is not on the free list
-        #   _page_hash     pid -> chain-key (reverse index for reclaim)
-        self._page_refs: dict[int, int] = {}
-        self._prefix_cache: "OrderedDict[bytes, int]" = OrderedDict()
-        self._page_hash: dict[int, bytes] = {}
         self._device_kind: Optional[str] = None  # "default" | "cpu"
         self._cpu_params = None
         self._host_params = None
@@ -473,15 +632,71 @@ class GenerationEngine:
 
     @staticmethod
     def _hbm_bytes(self) -> dict:
-        pool = self._pages
-        if pool is None:
+        if self._pages is None:
             return {"kv_pages": 0, "kv_prefix": 0}
-        total = int(pool.size) * pool.dtype.itemsize
-        # kv_prefix is the prefix-cache-resident SUBSET of kv_pages (not
-        # additive residency): how much of the pool is pinned shareable
-        per_page = total // max(1, self._family.num_pages(pool))
-        return {"kv_pages": total,
-                "kv_prefix": len(self._prefix_cache) * per_page}
+        # kv_prefix is the prefix-cache-resident SUBSET of the pools (not
+        # additive residency): how much of them is pinned shareable.  The
+        # first kind's pool is ``kv_pages``, a further kind's
+        # ``kv_pages_<kind>``: the components add up to what is resident
+        out = {"kv_prefix": 0}
+        for kind, pool, pages in zip(self._kinds, self._pools(),
+                                     self._page_counts(self._pages)):
+            total = int(pool.size) * pool.dtype.itemsize
+            out["kv_prefix"] += len(kind.cache) * (total // max(1, pages))
+            out["kv_pages" if kind is self._kinds[0]
+                else f"kv_pages_{kind.name}"] = total
+        return out
+
+    # the FIRST kind's allocator under the names it had before there were
+    # kinds (a family without ``page_kinds`` has no other)
+    _free_pages = property(lambda self: self._kinds[0].free)
+    _page_refs = property(lambda self: self._kinds[0].refs)
+    _prefix_cache = property(lambda self: self._kinds[0].cache)
+    _page_hash = property(lambda self: self._kinds[0].hash)
+
+    def _alloc_page(self) -> Optional[int]:
+        return self._kinds[0].alloc()
+
+    def _available_pages(self) -> int:
+        return self._kinds[0].available()
+
+    def _pools(self) -> tuple:
+        """The pool(s) as a tuple, an entry a kind."""
+        return self._pages if self._by_kind else (self._pages,)
+
+    def _page_counts(self, pages) -> tuple:
+        counts = self._family.num_pages(pages)
+        return counts if self._by_kind else (counts,)
+
+    def _init_pages(self):
+        """A fresh pool in the family's form: one array, or one a kind."""
+        counts = tuple(k.usable + 1 for k in self._kinds)
+        return self._family.init_pages(
+            self.cfg, counts if self._by_kind else counts[0],
+            self._page_size)
+
+    def _blank_meta(self, f: int):
+        """One step's packed rows, every row a padding row and every table
+        null: (meta, the five row views, a :class:`KindTables` a kind; a
+        family without kinds has no ``base``)."""
+        meta, (tokens, lane_id, lane_pos, positions, logit_rows,
+               lane_tables) = pack_ragged_meta(self._lmax, self._w, f)
+        tokens[:] = 0
+        lane_id[:] = self._lmax - 1                  # dump lane default
+        lane_pos[:] = 0
+        positions[:] = -1                            # -1 = padding row
+        logit_rows[:] = 0
+        parts = lane_tables if self._by_kind \
+            else (KindTables(None, lane_tables),)
+        for base, pages in parts:
+            pages[:] = 0
+            if base is not None:
+                base[:] = 0
+        return meta, (tokens, lane_id, lane_pos, positions, logit_rows), parts
+
+    def _shape(self, f: int, tq: int) -> str:
+        w = "+".join(str(k.width) for k in self._kinds)
+        return f"f{f}q{tq}x{w}"
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> None:
@@ -583,30 +798,24 @@ class GenerationEngine:
         params = self._params_for(kind)
         ctx = (jax.default_device(self._cpu_dev()) if kind == "cpu"
                else contextlib.nullcontext())
-        w = self._table_width
+        w = self._w
         lmax = self._lmax
         with ctx:
-            pool = self._family.init_pages(
-                self.cfg, self._usable_pages + 1, self._page_size)
+            pool = self._init_pages()
             for f, tq in self._ragged_classes():
                 if time.monotonic() >= deadline:
                     break
-                meta, (tokens, lane_id, lane_pos, positions, logit_rows,
-                       lane_tables) = pack_ragged_meta(lmax, w, f)
-                tokens[:] = 0
-                lane_id[:] = lmax - 1
-                lane_pos[:] = 0
-                positions[:] = -1
-                logit_rows[:] = 0
-                lane_tables[:] = 0
+                meta, (_, lane_id, _, positions, _), parts = \
+                    self._blank_meta(f)
                 # one real row (writes throwaway page 1) so the compiled
                 # program exercises the full scatter/attend path
                 lane_id[0] = 0
                 positions[0] = 0
-                lane_tables[0, 0] = 1
+                for _, pages in parts:
+                    pages[0, 0] = 1
                 self.programs.add(("ragged", f, tq, w))
                 _deviceprof.record_compile("genserve", "ragged",
-                                           f"f{f}q{tq}x{w}")
+                                           self._shape(f, tq))
                 # the served variant: ``prev`` is always an array
                 ids, _lg, pool = self._family.fused_step(
                     params, self.cfg, jnp.asarray(meta), pool,
@@ -755,7 +964,6 @@ class GenerationEngine:
                 # prefix cache indexes CONTENT of the dropped pool, so
                 # it must go with it
                 self._pages = None
-                self._free_pages = list(range(1, self._usable_pages + 1))
                 self._reset_prefix_cache()
                 with self._cond:
                     queued = list(self._queue)
@@ -830,53 +1038,21 @@ class GenerationEngine:
         seq.handle._finish(error)
 
     def _release_pages(self, seq: _Seq) -> None:
-        for pid in seq.page_ids:
-            refs = self._page_refs.get(pid, 1) - 1
-            if refs > 0:
-                # still shared with another live sequence — eviction/
-                # finish NEVER frees a page out from under its co-holder
-                self._page_refs[pid] = refs
-                continue
-            self._page_refs.pop(pid, None)
-            if pid not in self._page_hash:
-                self._free_pages.append(pid)
-            # else: prefix-cached page goes idle-resident (refcount 0),
-            # reclaimable LRU by _alloc_page under pool pressure
-        seq.page_ids = []
-        seq.page_table = None
+        for kind, table, held in zip(self._kinds, seq.tables or (),
+                                     seq.held):
+            for pid in table[:held].tolist():
+                kind.let_go(pid)
+        seq.tables = None
+        seq.bases, seq.held = [], []
         seq.cache_len = 0
         seq.prefill_pos = 0
 
-    def _alloc_page(self) -> Optional[int]:
-        """One physical page for a new holder: the free list first, then
-        the least-recently-used IDLE prefix-cached page (evicting it
-        from the cache — a page some sequence still holds is never
-        reclaimed).  None means genuine pool pressure."""
-        if self._free_pages:
-            return self._free_pages.pop()
-        victim_key = None
-        for key, pid in self._prefix_cache.items():  # oldest first
-            if self._page_refs.get(pid, 0) == 0:
-                victim_key = key
-                break
-        if victim_key is None:
-            return None
-        pid = self._prefix_cache.pop(victim_key)
-        self._page_hash.pop(pid, None)
-        return pid
-
-    def _available_pages(self) -> int:
-        """Pages an admission could claim: free + idle prefix-cached."""
-        idle = sum(1 for pid in self._prefix_cache.values()
-                   if self._page_refs.get(pid, 0) == 0)
-        return len(self._free_pages) + idle
-
     def _reset_prefix_cache(self) -> None:
         """Pool content invalidated (re-platform / failed donated step):
-        every cached key now describes bytes that no longer exist."""
-        self._prefix_cache.clear()
-        self._page_hash.clear()
-        self._page_refs.clear()
+        no sequence holds a page of it any more, so every kind's allocator
+        starts over, its free list whole."""
+        for kind in self._kinds:
+            kind.reset()
 
     def _prefix_page_keys(self, toks: list[int]) -> list[bytes]:
         """Chained content keys, one per FULL page of ``toks``: key i
@@ -894,25 +1070,43 @@ class GenerationEngine:
 
     def _register_prefix(self, seq: _Seq) -> None:
         """Final prefill chunk landed: publish this sequence's full
-        prompt pages into the prefix cache.  Pages already cached (the
-        hits this admission reused, or a concurrent same-prompt
-        registration) are skipped — first writer wins, the loser's page
-        simply stays private."""
-        if seq.prefix_keys is None or seq.page_table is None:
+        prompt pages into the prefix cache, a kind each (:meth:`_Kind.
+        publish`).  Of a kind with a horizon the lane still holds only what
+        its window reaches: the pages before were published as the lane
+        let them go (:meth:`_slide`)."""
+        if seq.prefix_keys is None or seq.tables is None:
             return
-        ps = self._page_size
         n_full = min(len(seq.prefix_keys),
-                     len(seq.prefill_tokens) // ps, len(seq.page_ids))
-        for idx in range(n_full):
-            key = seq.prefix_keys[idx]
-            pid = int(seq.page_table[idx])
-            if key in self._prefix_cache:
-                self._prefix_cache.move_to_end(key)
-                continue
-            if pid in self._page_hash:
-                continue
-            self._prefix_cache[key] = pid
-            self._page_hash[pid] = key
+                     len(seq.prefill_tokens) // self._page_size)
+        for kind, table, base, held in zip(self._kinds, seq.tables,
+                                           seq.bases, seq.held):
+            for idx in range(base, min(n_full, base + held)):
+                kind.publish(seq.prefix_keys[idx], int(table[idx - base]))
+
+    def _prefix_hits(self, keys: list[bytes], cap: int) -> int:
+        """How many leading pages of a prompt the prefix cache can hand a
+        new lane: the longest run ``n <= cap`` of which EVERY kind holds
+        what the lane's first query (at ``n x page_size``) can still see: a
+        kind without a horizon pages ``0 .. n-1``, one with a horizon the
+        pages from the one its window reaches."""
+        n = min(len(keys), cap)
+        for kind in self._kinds:
+            if kind.horizon is None:
+                n = next((i for i in range(n) if keys[i] not in kind.cache),
+                         n)
+        shrunk = True
+        while n > 0 and shrunk:
+            shrunk = False
+            for kind in self._kinds:
+                if kind.horizon is None:
+                    continue
+                lo = first_page(n * self._page_size, kind.horizon,
+                                self._page_size)
+                gap = next((i for i in range(n - 1, lo - 1, -1)
+                            if keys[i] not in kind.cache), None)
+                if gap is not None:
+                    n, shrunk = gap, True  # no run through a missing page
+        return n
 
     # -- device gating -----------------------------------------------------
     def _mgr(self):
@@ -985,7 +1179,6 @@ class GenerationEngine:
                            self._device_kind, kind, len(self._running))
         self._device_kind = kind
         self._pages = None
-        self._free_pages = list(range(1, self._usable_pages + 1))
         # cached prefix pages lived in the dropped pool: forget them
         self._reset_prefix_cache()
         # the step in flight ran on the old platform (which may never
@@ -996,8 +1189,8 @@ class GenerationEngine:
         self._running = []
         with self._cond:
             for seq in reversed(requeue):
-                seq.page_ids = []
-                seq.page_table = None
+                seq.tables = None
+                seq.bases, seq.held = [], []
                 seq.cache_len = 0
                 seq.prefill_pos = 0
                 seq.src = -1
@@ -1027,8 +1220,7 @@ class GenerationEngine:
     def _ensure_pool(self):
         if self._pages is None:
             with self._platform_ctx():
-                self._pages = self._family.init_pages(
-                    self.cfg, self._usable_pages + 1, self._page_size)
+                self._pages = self._init_pages()
         return self._pages
 
     # -- one scheduler iteration -------------------------------------------
@@ -1057,37 +1249,41 @@ class GenerationEngine:
 
     def _publish_gauges(self) -> None:
         _stats.RUNNING_SEQS.set(len(self._running))
-        used = self._usable_pages - len(self._free_pages)
-        _stats.PAGE_POOL_UTIL.set(used / max(1, self._usable_pages))
-        _stats.PREFIX_PAGES.set(len(self._prefix_cache))
+        usable = sum(k.usable for k in self._kinds)
+        used = usable - sum(len(k.free) for k in self._kinds)
+        _stats.PAGE_POOL_UTIL.set(used / max(1, usable))
+        _stats.PREFIX_PAGES.set(sum(len(k.cache) for k in self._kinds))
 
     def _admit(self) -> None:
+        ps = self._page_size
         while len(self._running) < self._max_seqs:
-            hits: list[int] = []
             with self._cond:
                 if not self._queue:
                     return
                 seq = self._queue[0]
                 toks = seq.prompt + seq.out
-                need = pages_for(len(toks) + 1, self._page_size)
+                need = pages_for(len(toks) + 1, ps)
                 keys = self._prefix_page_keys(toks)
                 # cap reuse below the full prompt: the final chunk must
                 # prefill at least one token to produce the first-token
                 # logits
-                cap = (len(toks) - 1) // self._page_size
-                for idx in range(min(len(keys), cap)):
-                    pid = self._prefix_cache.get(keys[idx])
-                    if pid is None:
-                        break
-                    hits.append(pid)
-                # idle cached hits count as "available" but adopting
-                # them consumes that availability — exclude them before
-                # comparing against the fresh-page requirement
-                idle_hits = sum(1 for pid in hits
-                                if self._page_refs.get(pid, 0) == 0)
-                if (need - len(hits)
-                        > self._available_pages() - idle_hits):
-                    return  # pool pressure: wait for a finisher/evictor
+                n_hit = self._prefix_hits(keys, (len(toks) - 1) // ps)
+                # a kind each: the lane's first logical page, the cached
+                # pages it adopts from there, the fresh ones it takes now
+                # (as far as its table reaches; :meth:`_reach` goes on)
+                plan = []
+                for kind in self._kinds:
+                    lo = first_page(n_hit * ps, kind.horizon, ps)
+                    hits = [kind.cache[keys[i]] for i in range(lo, n_hit)]
+                    fresh = max(0, min(need, lo + kind.width) - n_hit)
+                    # idle cached hits count as "available" but adopting
+                    # them consumes that availability — exclude them
+                    # before comparing against the fresh-page requirement
+                    idle_hits = sum(1 for pid in hits
+                                    if kind.refs.get(pid, 0) == 0)
+                    if fresh > kind.available() - idle_hits:
+                        return  # pool pressure: wait for a finisher/evictor
+                    plan.append((lo, hits, fresh))
                 self._queue.popleft()
                 _stats.QUEUE_DEPTH.set(len(self._queue))
             if seq.handle.shed:
@@ -1103,30 +1299,31 @@ class GenerationEngine:
             seq.admit_no = self._admit_counter
             self._admit_counter += 1
             seq.prefix_keys = keys
-            table = np.zeros((self._table_width,), np.int32)
-            seq.page_ids = []
-            for pid in hits:
-                # shared pages: take a reference, refresh LRU
-                self._page_refs[pid] = \
-                    self._page_refs.get(pid, 0) + 1
-                self._prefix_cache.move_to_end(self._page_hash[pid])
-                seq.page_ids.append(pid)
-            for _ in range(need - len(hits)):
-                pid = self._alloc_page()  # availability checked above
-                self._page_refs[pid] = 1
-                seq.page_ids.append(pid)
-            table[:len(seq.page_ids)] = seq.page_ids
-            seq.page_table = table
-            if hits:
-                reused = len(hits) * self._page_size
+            seq.tables, seq.bases, seq.held = [], [], []
+            for kind, (lo, hits, fresh) in zip(self._kinds, plan):
+                for pid in hits:
+                    # shared pages: take a reference, refresh LRU
+                    kind.take(pid)
+                    kind.cache.move_to_end(kind.hash[pid])
+                pids = hits + [kind.alloc()  # availability checked above
+                               for _ in range(fresh)]
+                for pid in pids[len(hits):]:
+                    kind.take(pid)
+                table = np.zeros((kind.width,), np.int32)
+                table[:len(pids)] = pids
+                seq.tables.append(table)
+                seq.bases.append(lo)
+                seq.held.append(len(pids))
+            if n_hit:
+                reused = n_hit * ps
                 # cached pages already hold these tokens' KV:
                 # prefill starts at the novel suffix
                 seq.prefill_pos = reused
                 seq.cache_len = reused
                 seq.handle.prefix_reused_tokens = reused
-                self.stats.prefix_hits += len(hits)
+                self.stats.prefix_hits += n_hit
                 self.stats.prefix_reused_tokens += reused
-                _stats.PREFIX_HITS.inc(len(hits))
+                _stats.PREFIX_HITS.inc(n_hit)
             seq.re_prefill = bool(seq.out)
             if seq.out:
                 self.stats.readmissions += 1
@@ -1141,40 +1338,86 @@ class GenerationEngine:
                     attrs={"readmission": bool(seq.out)},
                 )
 
-    def _grow(self, seq: _Seq) -> bool:
-        """Ensure the sequence has a page for cache slot ``cache_len``.
-        On an empty free list, evict the youngest OTHER running sequence
-        (requeued at the queue head for readmission).  Returns False only
-        when the sequence had to be shed (cannot happen for a lone
-        sequence: its own bound fits the pool by construction)."""
-        need = pages_for(seq.cache_len + 1, self._page_size)
-        while len(seq.page_ids) < need:
-            pid = self._alloc_page()
-            if pid is None:
-                if self._inflight is not None:
-                    # eviction re-prefills prompt + out and a shed ends a
-                    # stream: both need every token on the host first
-                    self._drain()
-                    if seq not in self._running:
-                        return False  # it ended at the token just read
-                    continue
-                # an eviction may free ZERO pages (every victim page
-                # shared or cache-resident), so alloc-then-evict loops:
-                # each round removes one victim, so it terminates
-                victims = [s for s in self._running
-                           if s is not seq and s.page_ids]
-                if not victims:
-                    self.stats.sheds_pool += 1
-                    _stats.SHEDS.labels("pool_exhausted").inc()
-                    self._finish_seq(seq, error=ResourceExhausted(
-                        "page pool exhausted", reason="pool_exhausted"))
-                    return False
-                victim = max(victims, key=lambda s: s.admit_no)
-                self._evict(victim)
+    def _slide(self, seq: _Seq, first: int) -> None:
+        """The lane's next queries stand at ``first`` and after: of each
+        kind with a horizon, let go the pages wholly behind the window.  A
+        page goes back to the kind's free list unless the prefix cache or
+        another lane still holds it (:meth:`_Kind.let_go`); a prompt page
+        that a lane still PREFILLING lets go is offered to the prefix
+        cache first (its final chunk, which publishes the rest, comes
+        after the window has left it)."""
+        for k, kind in enumerate(self._kinds):
+            if kind.horizon is None:
                 continue
-            self._page_refs[pid] = 1
-            seq.page_ids.append(pid)
-            seq.page_table[len(seq.page_ids) - 1] = pid
+            gone = first_page(first, kind.horizon, self._page_size) \
+                - seq.bases[k]
+            if gone <= 0:
+                continue
+            table, held = seq.tables[k], seq.held[k]
+            drop = min(gone, held)
+            n_full = 0
+            if seq.state == _PREFILL and seq.prefix_keys is not None:
+                n_full = min(len(seq.prefix_keys),
+                             len(seq.prefill_tokens) // self._page_size)
+            freed = 0
+            for col, pid in enumerate(table[:drop].tolist()):
+                if seq.bases[k] + col < n_full:
+                    kind.publish(seq.prefix_keys[seq.bases[k] + col], pid)
+                freed += kind.let_go(pid)
+            table[:held - drop] = table[drop:held]
+            table[held - drop:held] = 0
+            seq.bases[k] += gone
+            seq.held[k] = held - drop
+            self.stats.window_pages_dropped += drop
+            self.stats.window_pages_freed += freed
+            _stats.PAGES_RELEASED.labels(kind.name).inc(drop)
+            if drop and seq.trace_ctx is not None:
+                now = time.perf_counter()
+                _tracer.add_span(
+                    "genserve.pages_released", now, now,
+                    parent=seq.trace_ctx,
+                    attrs={"kind": kind.name, "pages": drop, "freed": freed})
+
+    def _reach(self, seq: _Seq, first: int, last: int) -> bool:
+        """Before a step in which the sequence's rows stand at cache slots
+        ``first .. last``: let go what the window of each kind has passed
+        (:meth:`_slide`), then ensure a page of every kind for every slot
+        up to ``last``.  On an empty free list, evict the youngest OTHER
+        running sequence (requeued at the queue head for readmission).
+        Returns False only when the sequence had to be shed (cannot happen
+        for a lone sequence: its own bound fits the pool by construction)
+        or ended at a token read meanwhile."""
+        self._slide(seq, first)
+        for k, kind in enumerate(self._kinds):
+            while seq.bases[k] + seq.held[k] <= last // self._page_size:
+                pid = kind.alloc()
+                if pid is None:
+                    if self._inflight is not None:
+                        # eviction re-prefills prompt + out and a shed
+                        # ends a stream: both need every token on the
+                        # host first
+                        self._drain()
+                        if seq not in self._running:
+                            return False  # ended at the token just read
+                        continue
+                    # an eviction may free ZERO pages (every victim page
+                    # shared or cache-resident), so alloc-then-evict
+                    # loops: each round removes one victim, so it
+                    # terminates
+                    victims = [s for s in self._running
+                               if s is not seq and s.tables is not None]
+                    if not victims:
+                        self.stats.sheds_pool += 1
+                        _stats.SHEDS.labels("pool_exhausted").inc()
+                        self._finish_seq(seq, error=ResourceExhausted(
+                            "page pool exhausted", reason="pool_exhausted"))
+                        return False
+                    victim = max(victims, key=lambda s: s.admit_no)
+                    self._evict(victim)
+                    continue
+                kind.take(pid)
+                seq.tables[k][seq.held[k]] = pid
+                seq.held[k] += 1
         return True
 
     def _evict(self, victim: _Seq) -> None:
@@ -1218,13 +1461,22 @@ class GenerationEngine:
         # sequence leaves self._running and the re-filter below drops it
         for seq in list(active):
             if seq in self._running:
-                self._grow(seq)
-        active = [s for s in active if s in self._running
-                  and s.state == _DECODE]
+                self._reach(seq, seq.cache_len, seq.cache_len)
         pre = [s for s in self._running if s.state == _PREFILL]
         chunk_seq = min(pre, key=lambda s: s.admit_no) if pre else None
         if chunk_seq is not None and self._expired(chunk_seq):
             chunk_seq = None
+        if chunk_seq is not None:
+            # as far as this step's chunk can reach (a kind without a
+            # horizon got every page at admission: nothing to do there)
+            at = chunk_seq.prefill_pos
+            most = min(len(chunk_seq.prefill_tokens) - at,
+                       self._prefill_chunk)
+            if not self._reach(chunk_seq, at, at + most - 1) \
+                    or chunk_seq.state != _PREFILL:
+                chunk_seq = None  # shed, or evicted by a lane's growth
+        active = [s for s in active if s in self._running
+                  and s.state == _DECODE]
         if not active and chunk_seq is None:
             return None
         ndec = len(active)
@@ -1255,26 +1507,28 @@ class GenerationEngine:
             # flat token rows: decode lanes first, then the chunk, then
             # padding up to the pow2 bucket — F scales with REAL tokens
             f = round_up_pow2(ndec, 8)
-        lmax, w = self._lmax, self._table_width
+        lmax, w = self._lmax, self._w
         # ONE packed int32 host array per step (one H2D transfer); the
-        # names below are writable views into it
-        meta, (tokens, lane_id, lane_pos, positions, logit_rows,
-               lane_tables) = pack_ragged_meta(lmax, w, f)
-        tokens[:] = 0
-        lane_id[:] = lmax - 1                        # dump lane default
-        lane_pos[:] = 0
-        positions[:] = -1                            # -1 = padding row
-        lane_tables[:] = 0
-        # logits are projected only for rows that pick a token: the
-        # decode rows and the chunk's last valid row (Lmax rows, not F —
-        # at real vocabs that is the difference between a (Lmax, V) and
-        # an (F, V) vocab GEMM every step)
-        logit_rows[:] = 0
+        # names below are writable views into it.  Logits are projected
+        # only for rows that pick a token: the decode rows and the chunk's
+        # last valid row (Lmax rows, not F — at real vocabs that is the
+        # difference between a (Lmax, V) and an (F, V) vocab GEMM every
+        # step)
+        meta, (tokens, lane_id, lane_pos, positions, logit_rows), parts = \
+            self._blank_meta(f)
+
+        def seat(lane: int, seq: _Seq) -> None:
+            for (base, pages), table, at in zip(parts, seq.tables,
+                                                seq.bases):
+                pages[lane] = table
+                if base is not None:
+                    base[lane] = at
+
         for i, seq in enumerate(active):
             tokens[i] = seq.out[-1] if seq.src < 0 else -(seq.src + 1)
             lane_id[i] = i
             positions[i] = seq.cache_len
-            lane_tables[i] = seq.page_table
+            seat(i, seq)
             logit_rows[i] = i
         chunk_lane = lmax - 2  # THE chunk lane, fixed by convention
         for j in range(n_valid):
@@ -1284,11 +1538,11 @@ class GenerationEngine:
             lane_pos[fi] = j
             positions[fi] = chunk_seq.prefill_pos + j
         if chunk_seq is not None:
-            lane_tables[chunk_lane] = chunk_seq.page_table
+            seat(chunk_lane, chunk_seq)
             logit_rows[ndec] = ndec + n_valid - 1
         t0 = time.perf_counter()
         params = self._active_params()
-        shape = f"f{f}q{tq}x{w}"
+        shape = self._shape(f, tq)
         self.programs.add(("ragged", f, tq, w))
         _deviceprof.record_compile("genserve", "ragged", shape)
         before = self._inflight
@@ -1359,6 +1613,13 @@ class GenerationEngine:
                 routing.get("zero_assignments", 0))
             _stats.ATTN_SLOTS_WALKED.inc(routing.get("attn_slots_walked", 0))
             _stats.ATTN_SLOTS_TABLE.inc(routing.get("attn_slots_table", 0))
+            for kind in self._kinds if self._by_kind else ():
+                walked = routing.get(f"{kind.name}_pages_walked", 0)
+                held = routing.get(f"{kind.name}_pages_held", 0)
+                self.stats.attn_pages_walked += walked
+                self.stats.attn_pages_held += held
+                _stats.ATTN_PAGES_WALKED.labels(kind.name).inc(walked)
+                _stats.ATTN_PAGES_HELD.labels(kind.name).inc(held)
         _deviceprof.record_execute("genserve", "ragged", flight.shape, dt)
         # the one dispatch served both phases: observability stays
         # per-phase (retroactive spans in each submitter's trace, the
@@ -1452,9 +1713,15 @@ class GenerationEngine:
         with self._lock:
             out["queue_depth"] = len(self._queue)
         out["running_seqs"] = len(self._running)
-        out["free_pages"] = len(self._free_pages)
-        out["prefix_pages"] = len(self._prefix_cache)
-        out["usable_pages"] = self._usable_pages
+        # over every page kind (a family without kinds has the one), then
+        # kind by kind
+        out["free_pages"] = sum(len(k.free) for k in self._kinds)
+        out["prefix_pages"] = sum(len(k.cache) for k in self._kinds)
+        out["usable_pages"] = sum(k.usable for k in self._kinds)
+        out["page_kinds"] = {
+            k.name: {"horizon": k.horizon, "table_width": k.width,
+                     "usable_pages": k.usable, "free_pages": len(k.free),
+                     "prefix_pages": len(k.cache)} for k in self._kinds}
         out["page_size"] = self._page_size
         out["mode"] = "paged"  # API: the one way a decoder is served
         out["device_kind"] = self._device_kind or "unstarted"

@@ -132,3 +132,33 @@ ATTN_SLOTS_TABLE = _REGISTRY.counter(
     "Cache slots the same lanes' whole page tables hold (what a step that "
     "gathered every page of every lane would have walked)",
 )
+# page kinds (a decoder family whose layers keep more than one kind of cache
+# state, window layers beside full ones; 0 forever otherwise).  Pages of a
+# kind with a horizon that lanes let go WHILE THEY LIVED, as their windows
+# moved past them: rate() against generated + prefilled tokens / page_size
+# is the share of a long lane's pages that the pool gets back early
+PAGES_RELEASED = _REGISTRY.counter(
+    "nornicdb_genserve_pages_released_total",
+    "Holds on KV pages that running sequences let go as their attention "
+    "window moved past them, by page kind",
+    labels=("kind",),
+)
+# what the fused steps' attention walked of each kind's tables, and what
+# their lanes' live queries could see: walked / held is how much more than
+# the live, in-window pages a step gathers (1.0 = nothing more)
+ATTN_PAGES_WALKED = _REGISTRY.counter(
+    "nornicdb_genserve_attn_pages_walked_total",
+    "KV pages the fused steps' attention blocks gathered and scored "
+    "(summed over lanes and the kind's layers), by page kind",
+    labels=("kind",),
+)
+ATTN_PAGES_HELD = _REGISTRY.counter(
+    "nornicdb_genserve_attn_pages_held_total",
+    "KV pages that hold a slot the same lanes' live queries may see, by "
+    "page kind",
+    labels=("kind",),
+)
+for _kind in ("full", "window"):
+    PAGES_RELEASED.labels(_kind)
+    ATTN_PAGES_WALKED.labels(_kind)
+    ATTN_PAGES_HELD.labels(_kind)

@@ -259,7 +259,7 @@ def routed_experts(cfg: DeepSeekV2Config, blk: dict, x: jax.Array,
     counts over the ``valid`` rows: f32 (N, hidden), int32 (3,) =
     (assignments on held experts, the fullest held expert's rows, held
     experts that got a row): this family's router, then the masked matmul
-    every latent family shares (:func:`mla.held_experts`)."""
+    every routed family shares (``models/experts.py``)."""
     with jax.named_scope("moe.route"):
         ids, gates = route(cfg, blk["router"], x)
         weight, counts = mla.held_gates(ids, gates, cfg.held_experts, valid)

@@ -41,7 +41,7 @@ layer ``l`` with stream ``h``::
 ``held_experts = (first, count)`` says which routed experts this process
 holds (expert parallelism).  The router keeps its published width and
 top-k; the first sum runs over the held ``i`` only (the masked matmul of
-``models/mla.py``), the zero part is computed whole for every row (a row's
+``models/experts.py``), the zero part is computed whole for every row (a row's
 home rank needs no exchange for it), and what the absent experts would add
 is left out: the partial result goes to the next layer.  Nothing here
 stands in for the other ranks or their exchange.

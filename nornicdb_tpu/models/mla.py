@@ -1,6 +1,9 @@
-"""Multi-head latent attention (MLA) over a latent page pool, and the masked
-matmul over the routed experts a process holds: what every latent family
-(``models/deepseek_v2.py``, ``models/longcat_flash.py``) runs, written once.
+"""Multi-head latent attention (MLA) over a latent page pool: what every
+latent family (``models/deepseek_v2.py``, ``models/longcat_flash.py``) runs,
+written once.  (The masked matmul over the routed experts a process holds
+is ``models/experts.py``'s, for a family without latent attention routes
+too; the latent families call it by its names here, ``held_gates`` /
+``held_experts``, which is where their fault injectors plant.)
 
 One MLA block, whoever owns it: ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb``
 -> per head ``[q_nope | q_pe]``; ``[c_kv | k_pe] = x W_kva``, ``c_kv =
@@ -28,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from nornicdb_tpu.models.experts import held_experts, held_gates  # noqa: F401
 from nornicdb_tpu.models.layers import dense, rms_norm
 from nornicdb_tpu.ragged import NULL_PAGE, unpack_ragged_meta
 
@@ -339,47 +343,9 @@ def attend_step(cfg, blk: dict, rows: StepRows, pages: jax.Array, at: int,
     return h + dense(blk["o"], o.reshape(f, -1)), pages
 
 
-# ------------------------------------------------------- the held experts
+# ------------------------------------------------ the dense feed-forward
 def swiglu(mlp: dict, x: jax.Array) -> jax.Array:
     gate = dense({"w": mlp["gate"]}, x)
     return dense({"w": mlp["down"]},
                  jax.nn.silu(gate) * dense({"w": mlp["up"]}, x))
 
-
-def held_gates(ids: jax.Array, gates: jax.Array, held: tuple,
-               valid: jax.Array | None = None):
-    """A router's choice (ids, gates: (N, k)) as this process sees it:
-    ``weight`` (N, count) f32 = each row's gate on each HELD expert
-    (``held = (first, count)``; zero where the row was not routed to it),
-    and the counts over the ``valid`` rows, int32 (3,) = (assignments on
-    held experts, the fullest held expert's rows, held experts that got a
-    row)."""
-    first, count = held
-    # (N, k, count) one-hot of the held experts' local ids: an id
-    # outside first .. first+count-1 gives a zero row
-    on = jax.nn.one_hot(ids - first, count, dtype=jnp.float32)
-    weight = jnp.einsum("nk,nkc->nc", gates, on)
-    rows = on.sum(axis=1)
-    if valid is not None:
-        rows = rows * valid[:, None].astype(jnp.float32)
-    per_expert = rows.sum(axis=0)
-    counts = jnp.stack([per_expert.sum(), per_expert.max(),
-                        (per_expert > 0).sum()]).astype(jnp.int32)
-    return weight, counts
-
-
-def held_experts(experts: dict, x: jax.Array, weight: jax.Array) -> jax.Array:
-    """What the held experts add for rows x (N, hidden) under ``weight``
-    (N, held): f32 (N, hidden).  A masked matmul: every held expert's
-    gate/up runs over every row (at serving batch sizes the cost is reading
-    the expert's weights, once, whoever is routed to it) and a row's gate,
-    zero where it was not routed to that expert, scales the activation
-    before ONE down projection over (expert, width): no dropped tokens, no
-    capacity factor."""
-    gate = jnp.einsum("nh,chi->nci", x, experts["gate"],
-                      preferred_element_type=jnp.float32)
-    up = jnp.einsum("nh,chi->nci", x, experts["up"],
-                    preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(gate) * up * weight[:, :, None]).astype(x.dtype)
-    return jnp.einsum("nci,cih->nh", act, experts["down"],
-                      preferred_element_type=jnp.float32)

@@ -1,16 +1,23 @@
 """What the tests of every decoder family share: a driver of the family's
 ``fused_step`` that packs rows as the scheduler does, and the readings the
 tolerances are stated on.  A family is its module (``models/qwen2.py``,
-``models/deepseek_v2.py``, ``models/longcat_flash.py``: ``init_pages`` /
-``fused_step``); what judges it
-is the plain float32 forward of ``models/reference/<family>.py``.
+``models/deepseek_v2.py``, ``models/longcat_flash.py``,
+``models/cohere2_moe.py``: ``init_pages`` / ``fused_step``, and
+``page_kinds`` where its layers keep more than one kind of cache state);
+what judges it is the plain float32 forward of
+``models/reference/<family>.py``.
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nornicdb_tpu.ragged import pack_ragged_meta, round_up_pow2
+from nornicdb_tpu.ragged import (
+    first_page,
+    pack_ragged_meta,
+    pages_for,
+    round_up_pow2,
+)
 
 PAGE, WIDTH, LMAX = 16, 8, 4  # 8 pages a lane = 128 slots; 2 decode lanes
 
@@ -59,7 +66,10 @@ def blank_step(lmax: int, w: int, f: int):
     meta, views = pack_ragged_meta(lmax, w, f)
     toks, lane, lpos, pos, rows, tables = views
     toks[:], lane[:], lpos[:], pos[:] = 0, lmax - 1, 0, -1
-    rows[:], tables[:] = 0, 0
+    rows[:] = 0
+    for part in (tables if isinstance(tables, tuple) else [(tables,)]):
+        for view in part:  # a kind's bases and its tables, or the table
+            view[:] = 0
     return meta, views
 
 
@@ -85,14 +95,59 @@ def greedy_gap(forward, params, cfg, ids, out) -> float:
     return float((logits.max(-1) - served).max())
 
 
+class Lane:
+    """One lane's pages of a family with page kinds, as the scheduler keeps
+    them: a table a kind that starts at the first page its window still
+    reaches.  Handed to :meth:`Pool.step` where a one-kind family hands a
+    table; before a step, :meth:`reach` lets go what the step's first query
+    no longer reads (back to the kind's free list) and takes pages up to
+    its last."""
+
+    def __init__(self, pool: "Pool"):
+        self.pool = pool
+        self.base = [0] * len(pool.kinds)
+        self.pages = [[] for _ in pool.kinds]
+        self.ever = [set() for _ in pool.kinds]  # every page it has held
+
+    def reach(self, first: int, last: int) -> None:
+        for k, (_, horizon) in enumerate(self.pool.kinds):
+            lo = first_page(first, horizon, PAGE)
+            while self.base[k] < lo:
+                if self.pages[k]:
+                    self.pool.free[k].append(self.pages[k].pop(0))
+                    self.pool.released[k] += 1
+                self.base[k] += 1
+            while self.base[k] + len(self.pages[k]) <= last // PAGE:
+                self.pages[k].append(self.pool.free[k].pop(0))
+                self.ever[k].add(self.pages[k][-1])
+            assert len(self.pages[k]) <= self.pool.width[k], "table too narrow"
+
+
 class Pool:
     """Drives ``family.fused_step`` as the scheduler does: one chunk of one
-    lane beside the decode rows of others, through one donated pool."""
+    lane beside the decode rows of others, through one donated pool (one a
+    kind, for a family with ``page_kinds``: a lane is then a :class:`Lane`
+    where a one-kind family's is a table)."""
 
-    def __init__(self, family, cfg, params, pages: int = 40,
-                 width: int = WIDTH):
+    def __init__(self, family, cfg, params, pages=40,
+                 width: int = WIDTH, chunk: int = 16):
         self.family, self.cfg, self.params = family, cfg, params
         self.width = width  # pages of a lane's table
+        self.kinds = tuple(family.page_kinds(cfg)) \
+            if hasattr(family, "page_kinds") else None
+        if self.kinds:
+            # a kind with a horizon: its window, a chunk, and a page for
+            # where the window starts inside one
+            self.width = tuple(
+                width if horizon is None else
+                min(width, pages_for(horizon + chunk, PAGE) + 1)
+                for _, horizon in self.kinds)
+            if isinstance(pages, int):
+                pages = (pages,) * len(self.kinds)
+            # first in, first out: a small pool goes round, and a page one
+            # lane let go is soon another's
+            self.free = [list(range(1, n)) for n in pages]
+            self.released = [0] * len(self.kinds)
         self.pool = family.init_pages(cfg, pages, PAGE)
         # the family's own counts, in its STEP_COUNTERS order
         self.counters = tuple(getattr(family, "STEP_COUNTERS", ()))
@@ -108,23 +163,32 @@ class Pool:
         f = round_up_pow2(len(decode) + n_valid, 8)
         meta, (toks, lane, lpos, pos, rows, tables) = blank_step(
             LMAX, self.width, f)
+        def place(i, table, first, last):
+            if not self.kinds:
+                tables[i] = table
+                return
+            table.reach(first, last)
+            for kind, base, held in zip(tables, table.base, table.pages):
+                kind.base[i], kind.pages[i, :len(held)] = base, held
+
         for i, (tok, at, table) in enumerate(decode):
             toks[i], lane[i], pos[i], rows[i] = tok, i, at, i
-            tables[i] = table
+            place(i, table, at, at)
         if chunk:
             ids, start, table = chunk
             for j, tok in enumerate(ids):
                 at = len(decode) + j
                 toks[at], lane[at], lpos[at] = tok, LMAX - 2, j
                 pos[at] = start + j
-            tables[LMAX - 2] = table
+            place(LMAX - 2, table, start, start + n_valid - 1)
             rows[len(decode)] = len(decode) + n_valid - 1
         donated = self.pool
         ints, logits, self.pool = self.family.fused_step(
             self.params, self.cfg, jnp.asarray(meta), donated,
             lmax=LMAX, w=self.width, tq=tq,
             **({} if prev is None else {"prev": jnp.asarray(prev)}))
-        assert donated.is_deleted(), "the step copied the pool"
+        assert all(a.is_deleted() for a in jax.tree.leaves(donated)), \
+            "the step copied the pool"
         self.ints = ints = np.asarray(ints)
         # the greedy ids, then the routing counts of a family that routes
         assert ints.shape[0] - LMAX == len(self.counters)  # as it declares

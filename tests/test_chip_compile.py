@@ -369,3 +369,52 @@ def test_scmoe_mla_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     assert layouts == {"3,2,1,0"}, layouts
     assert not re.search(re.escape(shape) + r"\S* copy\(", text)
     _no_whole_table_gather(text)
+
+
+@pytest.mark.parametrize("f,tq", [(16, 1), (64, 64), (128, 64)],
+                         ids=["decode", "prefill-chunk", "widest"])
+def test_parallel_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
+    """Command A+'s step at the benchmark's cut (published widths, one
+    period of 4 layers, 8 held experts, the 128-wide router, 32,768-row
+    tied head) and the cell's engine geometry by page kind (16 lanes +
+    chunk + dump, page 16; full: 512-page tables, 8,193 pages, one layer;
+    window: 261-page tables, 4,689 pages, three layers): it fits the chip
+    beside the deployment's 5.43 GB, both K/V pools are donated and go in
+    and out in ONE row-major layout with no pool-sized copy left in the
+    step, and nothing of the extent of a lane's whole table is gathered: a
+    turn of a walk takes one block of each lane's pages."""
+    import re
+
+    import jax.numpy as jnp
+
+    from nornicdb_tpu.ragged import pack_ragged_meta
+    from nornicdb_tpu.models import cohere2_moe as cm
+
+    cfg = cm.COMMAND_A_PLUS_EP16_4L
+    lmax, w, pages, page = 18, (512, 261), (8193, 4689), 16
+    meta, _ = pack_ragged_meta(lmax, w, f)
+    pools = [(1, 2, 8193, page, 1024), (3, 2, 4689, page, 1024)]
+    compiled = cm.fused_step.lower(
+        _params_on(cm.init_params, cfg, one_chip), cfg,
+        _sds(meta.shape, jnp.int32, one_chip),
+        tuple(_sds(p, jnp.bfloat16, one_chip) for p in pools),
+        lmax=lmax, w=w, tq=tq,
+        # the served variant: the ids and the family's eight counts
+        prev=_sds((lmax + len(cm.STEP_COUNTERS),), jnp.int32, one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        int(np.prod(p)) * 2 for p in pools)  # both donated
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < (16 << 30) - 5_430_000_000
+    text = compiled.as_text()
+    for pool in pools:
+        shape = "bf16[%s]" % ",".join(map(str, pool))
+        layouts = set(re.findall(re.escape(shape) + r"\{([0-9,]+)", text))
+        assert layouts == {"4,3,2,1,0"}, (shape, layouts)
+        assert not re.search(re.escape(shape) + r"\S* copy\(", text)
+    for whole in ("[17,512,16,1024]", "[8704,16,1024]", "[17,261,16,1024]",
+                  "[4437,16,1024]"):
+        assert whole not in text, whole
+    assert f"bf16[{17 * cm.BLOCK_PAGES},16,1024]" in text \
+        or f"bf16[17,{cm.BLOCK_PAGES},16,1024]" in text

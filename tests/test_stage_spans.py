@@ -446,7 +446,7 @@ def _program_modules() -> dict:
     import jax
     import jax.numpy as jnp
 
-    from nornicdb_tpu.models import deepseek_v2, longcat_flash
+    from nornicdb_tpu.models import cohere2_moe, deepseek_v2, longcat_flash
     from nornicdb_tpu.ops.pallas_kernels import streaming_cosine_topk
 
     embedder = TPUEmbedder(cfg=F32_CFG)
@@ -455,7 +455,15 @@ def _program_modules() -> dict:
     dsv2 = deepseek_v2.DEEPSEEK_V2_SMALL
     lcf = longcat_flash.LONGCAT_FLASH_SMALL
     lmax, w, f = 4, 8, 16
+    cmda, kinds = cohere2_moe.COHERE2_MOE_SMALL, (w, 4)  # a width a kind
     return {
+        "step_roofline.cmda": _module_name(cohere2_moe.fused_step.lower(
+            jax.eval_shape(lambda: cohere2_moe.init_params(
+                cmda, jax.random.PRNGKey(0))), cmda,
+            jax.ShapeDtypeStruct((4 * f + lmax + sum(
+                lmax * (1 + wk) for wk in kinds),), jnp.int32),
+            jax.eval_shape(lambda: cohere2_moe.init_pages(cmda, (9, 9), 16)),
+            lmax=lmax, w=kinds, tq=16)),
         "step_roofline.lcf": _module_name(longcat_flash.fused_step.lower(
             jax.eval_shape(lambda: longcat_flash.init_params(
                 lcf, jax.random.PRNGKey(0))), lcf,
@@ -581,6 +589,51 @@ class TestBenchmarkReaders:
         assert family.STEP_COUNTERS[-2:] == mla.WALK_COUNTERS
         for path in paths:
             assert isinstance(dig(counters, path), int), path
+
+    @pytest.mark.parametrize("metric,paths", [
+        ("attn_walked_share.cmda", ("attn_pages_walked", "attn_pages_held")),
+        ("window_walked_share.cmda", ("window_pages_walked",
+                                      "full_pages_walked")),
+        ("window_pages_dropped_per_request.cmda", ("window_pages_dropped",
+                                                   "completed"))])
+    def test_the_page_kind_counters_are_what_the_cmda_metrics_read(
+            self, snapshot, metric, paths):
+        """PR 37's counts by page kind: the step's four (``models/
+        cohere2_moe.py``'s ``WALK_COUNTERS``, the last four of its
+        ``STEP_COUNTERS``, ``GenStats`` fields of the same names), their
+        sums over the kinds and the scheduler's own count of window pages
+        let go; every engine's snapshot has them (0 for a family with the
+        one kind)."""
+        from nornicdb_tpu.genserve.engine import GenStats
+        from nornicdb_tpu.models import cohere2_moe
+
+        counters, dig = snapshot
+        spec, = [s for s in _metric_specs("counter_ratio")
+                 if s["name"] == metric]
+        assert (spec["numerator"], spec["denominator"]) == tuple(
+            "genserve." + n for n in paths)
+        for path in paths:
+            assert isinstance(dig(counters, "genserve." + path), int), path
+        walk = tuple(n for pair in cohere2_moe.WALK_COUNTERS.values()
+                     for n in pair)
+        assert cohere2_moe.STEP_COUNTERS[-4:] == walk == (
+            "full_pages_walked", "full_pages_held", "window_pages_walked",
+            "window_pages_held")
+        assert set(walk) | {"window_pages_dropped", "window_pages_freed",
+                            "attn_pages_walked", "attn_pages_held"} \
+            <= set(GenStats.__dataclass_fields__)
+
+    def test_the_page_kind_families_render_at_metrics(self):
+        from nornicdb_tpu.genserve import stats as gstats
+        from nornicdb_tpu.telemetry.metrics import REGISTRY
+
+        gstats.PAGES_RELEASED.labels("window").inc(0)
+        text = REGISTRY.render_prometheus()
+        for family in ("nornicdb_genserve_pages_released_total",
+                       "nornicdb_genserve_attn_pages_walked_total",
+                       "nornicdb_genserve_attn_pages_held_total"):
+            for kind in ("full", "window"):
+                assert f'{family}{{kind="{kind}"}}' in text, (family, kind)
 
     @pytest.mark.parametrize("metric", sorted(PLANNED_RATIOS))
     def test_planned_ratio_reads_above_zero(self, snapshot, metric):
