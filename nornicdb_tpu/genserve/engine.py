@@ -185,7 +185,8 @@ class GenStats:
     routed_rows: int = 0
     zero_assignments: int = 0
     # attention over live lengths (a family whose step walks its lanes'
-    # page tables in blocks as far as the longest live lane: models/mla.py):
+    # page tables in blocks as far as the longest live lane: models/mla.py,
+    # and Qwen's over models/kv_walk.py):
     # cache slots the steps' attention blocks gathered and scored, and the
     # slots the same lanes' whole tables hold
     attn_slots_walked: int = 0
@@ -1607,8 +1608,10 @@ class GenerationEngine:
         for name, count in routing.items():
             setattr(self.stats, name, getattr(self.stats, name) + count)
         if routing:
-            _stats.EXPERT_ASSIGNMENTS.inc(routing["expert_assignments"])
-            _stats.EXPERT_ROWS_MAX.set(routing["expert_rows_max"])
+            # a family's counts are its own: routed experts, a walk, both
+            _stats.EXPERT_ASSIGNMENTS.inc(routing.get("expert_assignments", 0))
+            if "expert_rows_max" in routing:
+                _stats.EXPERT_ROWS_MAX.set(routing["expert_rows_max"])
             _stats.ZERO_EXPERT_ASSIGNMENTS.inc(
                 routing.get("zero_assignments", 0))
             _stats.ATTN_SLOTS_WALKED.inc(routing.get("attn_slots_walked", 0))

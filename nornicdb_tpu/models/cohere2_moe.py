@@ -35,9 +35,10 @@ table and a free list FOR EACH KIND (``nornicdb_tpu/ragged.py``,
 has passed while it lives.  A pool is Qwen's layout: ``(layers of the kind,
 2[k|v], pages, page_size, 8 x 128)``, a slot's K (or V) heads side by side.
 The step's two attention blocks walk a lane's table of the layer's kind in
-blocks of :data:`BLOCK_PAGES` pages with a running float32 softmax, from
-the first block a live query's window still reaches to the last live one:
-nothing behind the window or past the live length is gathered.
+blocks of pages with a running float32 softmax (``models/kv_walk.py``, the
+walk Qwen's step runs too: 32 pages a block at this model's 32 KB pages),
+from the first block a live query's window still reaches to the last live
+one: nothing behind the window or past the live length is gathered.
 
 ``held_experts = (first, count)`` says which routed experts this process
 holds (expert parallelism; ``models/experts.py``).  The router keeps its
@@ -64,15 +65,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from nornicdb_tpu.models import experts
+from nornicdb_tpu.models import experts, kv_walk
 from nornicdb_tpu.models.layers import dense
-from nornicdb_tpu.ragged import NULL_PAGE, ROUTING_COUNTERS, unpack_ragged_meta
+from nornicdb_tpu.ragged import ROUTING_COUNTERS
 
 _HI = jax.lax.Precision.HIGHEST
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -295,11 +295,6 @@ def _logits(params: dict, cfg: Cohere2MoeConfig, h: jax.Array) -> jax.Array:
 
 
 # ------------------------------------------- the step's rows, kind by kind
-# pages of a lane's table that one turn of an attention block's walk gathers,
-# scores and sums (x page_size slots); a table narrower than a block is
-# walked as one block.  models/mla.py's constant, read on the chip there
-# (PERF.md section 6, PR 36)
-BLOCK_PAGES = 32
 # what the step appends after ROUTING_COUNTERS (``GenStats`` fields of the
 # same names), a pair a kind: the pages its attention blocks gathered and
 # scored, summed over their lanes and the kind's layers, and the pages that
@@ -309,195 +304,21 @@ WALK_COUNTERS = {"full": ("full_pages_walked", "full_pages_held"),
                  "window": ("window_pages_walked", "window_pages_held")}
 
 
-class KindRows(NamedTuple):
-    """One kind's part of a step: where its layers write each row and what
-    its two attention blocks walk."""
-    phys: jax.Array           # (F,) the page of this kind a row is written to
-    dec_tables: jax.Array     # (Lmax-1, W') W' = W up to whole blocks
-    dec_base: jax.Array       # (Lmax-1,) the logical page of column 0
-    dec_span: tuple           # (first block, behind the last) of the walk
-    chunk_table: jax.Array | None   # (1, W')
-    chunk_base: jax.Array | None    # (1,)
-    chunk_span: tuple | None
-    walk: jax.Array           # (2,) pages walked, pages held, ONE layer
-
-
-class StepRows(NamedTuple):
-    tokens: jax.Array         # (F,) input ids, ``prev`` resolved
-    logit_rows: jax.Array     # (Lmax,)
-    valid: jax.Array          # (F,) not a padding row
-    pos: jax.Array            # (F,) positions, clipped to the tables
-    off: jax.Array            # (F,) a row's slot in its page
-    dec_lane: jax.Array       # (F,) lane of the decode block (dump lane last)
-    pos_dec: jax.Array        # (Lmax-1, 1) a lane's query position, -1 = none
-    is_chunk: jax.Array       # (F,)
-    chunk_row: jax.Array | None   # (F,) 0 for a chunk row, else out of bounds
-    slot_c: jax.Array         # (F,) a chunk row's place in the chunk block
-    pos_chk: jax.Array | None     # (1, Tq)
-    kinds: tuple              # KindRows, a kind
-
-
-def _span(pos, base, horizon, ps: int, bp: int, n_blocks: int):
-    """The blocks of ``bp`` pages that the live queries at ``pos`` (L, T; -1
-    = none) of lanes whose tables start at logical page ``base`` (L,) walk:
-    from the first one a window still reaches to the last live one, at
-    least one block; and the pages (walked a lane, held in all)."""
-    live = pos >= 0
-    rel = pos - base[:, None] * ps                    # slot in its table
-    hi = jnp.clip(-(-(jnp.where(live, rel, -1).max() + 1) // (bp * ps)),
-                  1, n_blocks)
-    first = jnp.zeros_like(pos) if horizon is None else \
-        jnp.maximum(pos - horizon + 1, 0)             # the oldest key seen
-    lo = 0 if horizon is None else jnp.clip(
-        jnp.where(live, first - base[:, None] * ps, n_blocks * bp * ps)
-        .min() // (bp * ps), 0, hi - 1)
-    # a lane's pages from its oldest query's first key to its newest's own
-    lane_live = live.any(axis=1)
-    held = jnp.where(
-        lane_live, jnp.where(live, pos, -1).max(axis=1) // ps
-        - jnp.where(live, first, 2 ** 30).min(axis=1) // ps + 1, 0).sum()
-    return (lo, hi), (hi - lo) * bp, held
-
-
-def plan_step(cfg: Cohere2MoeConfig, meta: jax.Array, pools: tuple, *,
-              lmax: int, w: tuple, tq: int, prev=None) -> StepRows:
-    """The engine's flat rows (``nornicdb_tpu/ragged.py``; ``w`` a width a
-    kind) as the step's two attention blocks see them, kind by kind: the
-    decode block (one query a lane; the decode lanes and, last, a dump lane
-    for every row that is not a decode row) and the chunk block (``tq``
-    queries of the chunk lane)."""
-    tokens, lane_id, lane_pos, positions, logit_rows, lane_tables = \
-        unpack_ragged_meta(meta, lmax, w, prev)
-    ps = pools[0].shape[3]
-    valid = positions >= 0
-    pos = jnp.maximum(positions, 0)
-    lane_c = jnp.clip(lane_id, 0, lmax - 1)
-    slot_c = jnp.clip(lane_pos, 0, tq - 1)
-    is_chunk = lane_id == lmax - 2
-    ldec = lmax - 1
-    dec_lane = jnp.where(is_chunk | ~valid, ldec - 1,
-                         jnp.minimum(lane_c, ldec - 1))
-    pos_dec = jnp.full((ldec, 1), -1, jnp.int32).at[dec_lane, 0].set(
-        jnp.where(valid & ~is_chunk, positions, -1))
-    chunk_row = pos_chk = None
-    if tq > 1:
-        # chunk rows scatter into the (1, tq) block; every other row's
-        # index lands out of bounds on the lane axis and is dropped
-        chunk_row = jnp.where(is_chunk & valid, 0, 1)
-        pos_chk = jnp.full((1, tq), -1, jnp.int32).at[
-            chunk_row, slot_c].set(positions, mode="drop")
-    kinds = []
-    for (_, horizon, _), wk, (base, table) in zip(_kinds(cfg), w, lane_tables,
-                                                  strict=True):
-        col = pos // ps - base[lane_c]
-        phys = jnp.where(valid & (col >= 0) & (col < wk),
-                         table[lane_c, jnp.clip(col, 0, wk - 1)], NULL_PAGE)
-        bp = min(BLOCK_PAGES, wk)
-        n_blocks = -(-wk // bp)
-        # whole blocks: the columns behind a table's end are the null
-        # page's, and no position reaches them
-        table = jnp.pad(table, ((0, 0), (0, n_blocks * bp - wk)))
-        dec_span, walked, held = _span(pos_dec, base[:ldec], horizon, ps, bp,
-                                       n_blocks)
-        walked = walked * ldec
-        chunk_table = chunk_base = chunk_span = None
-        if tq > 1:
-            chunk_table, chunk_base = table[lmax - 2][None], \
-                base[lmax - 2][None]
-            chunk_span, more, held_c = _span(pos_chk, chunk_base, horizon,
-                                             ps, bp, n_blocks)
-            walked, held = walked + more, held + held_c
-        kinds.append(KindRows(
-            phys, table[:ldec], base[:ldec], dec_span, chunk_table,
-            chunk_base, chunk_span,
-            jnp.stack([walked, held]).astype(jnp.int32)))
-    return StepRows(tokens, logit_rows, valid, pos, pos % ps, dec_lane,
-                    pos_dec, is_chunk, chunk_row, slot_c, pos_chk,
-                    tuple(kinds))
-
-
-def attend_pages(cfg: Cohere2MoeConfig, q: jax.Array, pool: jax.Array,
-                 at: int, tables: jax.Array, base: jax.Array, pos: jax.Array,
-                 span: tuple, horizon) -> jax.Array:
-    """Grouped-query attention over what is live, and inside the horizon, of
-    the lanes' pages in pool layer ``at``: q (L, T, heads, d) against blocks
-    ``span`` = (first, behind the last) of :data:`BLOCK_PAGES` pages of
-    ``tables`` (L, W'), whose column 0 is logical page ``base`` (L,); a
-    query at ``pos`` (L, T) sees the slots ``pos - horizon < slot <= pos``
-    (-1: none; its output is garbage and never read) -> (L, T, heads x d).
-    One turn gathers a block of every lane's K and V pages, scores it in f32
-    and folds it into a running softmax (m, l, acc: f32); nothing outside
-    ``span`` is gathered.  The pool is only read."""
-    lanes, t, heads, d = q.shape
-    g = cfg.num_key_value_heads
-    ps = pool.shape[3]
-    bp = min(BLOCK_PAGES, tables.shape[1])
-    bs = bp * ps
-    qg = q.reshape(lanes, t, g, heads // g, d)
-    slot = base[:, None] * ps + jax.lax.broadcasted_iota(
-        jnp.int32, (lanes, bs), 1)                    # (L, bs) at block 0
-    last = pos[:, :, None]                            # (L, T, 1)
-
-    def turn(b, state):
-        m, total, acc = state
-        table = jax.lax.dynamic_slice_in_dim(tables, b * bp, bp, axis=1)
-        k = pool[at, 0, table].reshape(lanes, bs, g, d)
-        v = pool[at, 1, table].reshape(lanes, bs, g, d)
-        s = jnp.einsum("ltgrd,lsgd->lgrts", qg, k,
-                       preferred_element_type=jnp.float32) * d ** -0.5
-        here = (slot + b * bs)[:, None, :]            # (L, 1, bs)
-        seen = here <= last
-        if horizon is not None:
-            seen &= here > last - horizon
-        s = jnp.where(seen[:, None, None], s, -1e30)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        keep = jnp.exp(m - m_new)
-        acc = acc * keep[..., None] + jnp.einsum(
-            "lgrts,lsgd->lgrtd", p.astype(v.dtype), v,
-            preferred_element_type=jnp.float32)
-        return m_new, total * keep + p.sum(axis=-1), acc
-
-    # a block that is wholly masked for a query leaves m at -1e30 and sums
-    # garbage with weight 1; the first block that holds a key it sees (its
-    # own, at the latest) scales that by exp(-1e30 - m) = 0; a masked block
-    # AFTER that adds exp(-1e30 - m) = 0
-    start = (jnp.full((lanes, g, heads // g, t), -1e30, jnp.float32),
-             jnp.zeros((lanes, g, heads // g, t), jnp.float32),
-             jnp.zeros((lanes, g, heads // g, t, d), jnp.float32))
-    _, total, acc = jax.lax.fori_loop(span[0], span[1], turn, start)
-    o = (acc / total[..., None]).astype(q.dtype)      # (L, g, r, T, d)
-    return jnp.transpose(o, (0, 3, 1, 2, 4)).reshape(lanes, t, heads * d)
-
-
-def attend_step(cfg: Cohere2MoeConfig, blk: dict, rows: StepRows,
-                kind: KindRows, pool: jax.Array, at: int, x: jax.Array,
-                rotary, horizon):
+def attend_step(cfg: Cohere2MoeConfig, blk: dict, rows: kv_walk.StepRows,
+                kind: kv_walk.KindRows, pool: jax.Array, at: int,
+                x: jax.Array, rotary, horizon):
     """One layer's attention inside a fused step, over its kind's pool layer
     ``at``: each row's K and V are written once to their (page, slot), then
-    the decode block and the chunk block attend (:func:`attend_pages`).
-    Normed rows x (F, hidden) -> (attention through W_o (F, hidden), pool)."""
+    the decode block and the chunk block attend
+    (``kv_walk.attend_blocks``).  Normed rows x (F, hidden) -> (attention
+    through W_o (F, hidden), pool)."""
     f = x.shape[0]
     with jax.named_scope("attn.project"):
         q, k, v = project(cfg, blk, x, rotary)
         pool = pool.at[at, 0, kind.phys, rows.off].set(k.reshape(f, -1))
         pool = pool.at[at, 1, kind.phys, rows.off].set(v)
-    with jax.named_scope("attn.attend"):
-        ldec = kind.dec_tables.shape[0]
-        q_dec = jnp.zeros((ldec, 1) + q.shape[1:], q.dtype)
-        q_dec = q_dec.at[rows.dec_lane, 0].set(q)
-        o_dec = attend_pages(cfg, q_dec, pool, at, kind.dec_tables,
-                             kind.dec_base, rows.pos_dec, kind.dec_span,
-                             horizon)
-        o = o_dec[rows.dec_lane, 0]                   # (F, heads x d)
-        if rows.chunk_row is not None:
-            tq = rows.pos_chk.shape[1]
-            q_chk = jnp.zeros((1, tq) + q.shape[1:], q.dtype)
-            q_chk = q_chk.at[rows.chunk_row, rows.slot_c].set(q, mode="drop")
-            o_chk = attend_pages(cfg, q_chk, pool, at, kind.chunk_table,
-                                 kind.chunk_base, rows.pos_chk,
-                                 kind.chunk_span, horizon)
-            o = jnp.where(rows.is_chunk[:, None], o_chk[0, rows.slot_c], o)
+    o = kv_walk.attend_blocks(cfg.num_key_value_heads, rows, kind, q, pool,
+                              at, horizon)
     return dense(blk["o"], o), pool
 
 
@@ -541,7 +362,8 @@ def parallel_moe_fused_step(params, cfg: Cohere2MoeConfig, meta: jax.Array,
                             pages: tuple, *, lmax: int, w: tuple, tq: int,
                             prev=None):
     """One fused prefill+decode step over the pools, a kind each, on the
-    engine's flat rows (:func:`plan_step`; ``w`` a table width a kind).  A
+    engine's flat rows (``kv_walk.plan_step``, each kind with its horizon;
+    ``w`` a table width a kind).  A
     layer writes each row's K and V to the row's page of the layer's KIND
     and attends that kind's tables; attention and the expert layer read the
     same normed rows and join the stream together.  Returns ``(ints, logits,
@@ -552,8 +374,10 @@ def parallel_moe_fused_step(params, cfg: Cohere2MoeConfig, meta: jax.Array,
     held, a pair a kind, zeros for a kind no layer is of), so one
     device-to-host read carries both;
     ``logits`` (lmax, V) f32 for ``logit_rows``; ``pages`` is DONATED."""
-    rows = plan_step(cfg, meta, pages, lmax=lmax, w=w, tq=tq, prev=prev)
     kinds, place = _kinds(cfg), _place(cfg)
+    rows = kv_walk.plan_step(
+        meta, pages, tuple(horizon for _, horizon, _ in kinds), lmax=lmax,
+        w=w, tq=tq, prev=prev)
     f = rows.tokens.shape[0]
     # positions stay inside the full kind's table (a lane's whole history);
     # a stack with no full layer may stand anywhere the model allows

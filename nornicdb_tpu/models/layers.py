@@ -75,6 +75,32 @@ def attention(
     return o.astype(q.dtype)
 
 
+def _own(h: int, hkv: int) -> np.ndarray:
+    """own[h, g]: K/V head g is query head h's, shaped to broadcast over
+    (B, H, T, Hkv, Dh)."""
+    return (np.arange(h)[:, None] // (h // hkv)
+            == np.arange(hkv))[None, :, None, :, None]
+
+
+def heads_to_rows(q: jax.Array, hkv: int) -> jax.Array:
+    """q (B, T, H, Dh) -> (B, H, T, Hkv * Dh): each head's Dh query values
+    in its K/V group's lanes of a row-wide vector, zeros elsewhere."""
+    b, t, h, dh = q.shape
+    qh = jnp.transpose(q, (0, 2, 1, 3))[:, :, :, None, :]  # (B, H, T, 1, Dh)
+    return jnp.where(_own(h, hkv), qh, 0).reshape(b, h, t, hkv * dh)
+
+
+def rows_to_heads(o_rows: jax.Array, hkv: int) -> jax.Array:
+    """(B, H, T, Hkv * Dh) -> (B, H, T, Dh): each head's own group's lanes
+    of a row-wide result.  Both this and :func:`heads_to_rows` are selects
+    under one mask: taking o as slices of lanes concatenated over heads
+    reads the wrong lanes on a TPU v5e (this XLA; PERF.md section 6, PR
+    31), and only there."""
+    b, h, t, row = o_rows.shape
+    return jnp.where(_own(h, hkv),
+                     o_rows.reshape(b, h, t, hkv, row // hkv), 0.0).sum(axis=3)
+
+
 def grouped_attention(
     q: jax.Array,
     k: jax.Array,
@@ -88,26 +114,21 @@ def grouped_attention(
     K/V head ``h // (H // Hkv)``.  The ``n_rep``-fold copy of K and V that
     ``attention(q, repeat_kv(k), repeat_kv(v))`` contracts over is never
     made, and a row is never split: each head's Dh query values sit in its
-    group's lanes of a row-wide vector, zeros elsewhere, so both
-    contractions are plain batched matmuls over whole rows (at Qwen2.5's
-    2 x 64 one 128-lane tile; the zeros add exactly 0.0), and the group's
-    lanes of ``p @ V`` are the head's output.  Operands keep their dtype,
-    accumulation and softmax are f32, ``p`` is cast to V's dtype: the
-    arithmetic of :func:`attention`, in another order of reduction.  The
-    zero lanes cost Hkv times the contractions' FLOPs, which a step bound
-    by reading K and V does not notice at Hkv = 2.
+    group's lanes of a row-wide vector, zeros elsewhere
+    (:func:`heads_to_rows`), so both contractions are plain batched matmuls
+    over whole rows (at Qwen2.5's 2 x 64 one 128-lane tile; the zeros add
+    exactly 0.0), and the group's lanes of ``p @ V`` are the head's output
+    (:func:`rows_to_heads`).  Operands keep their dtype, accumulation and
+    softmax are f32, ``p`` is cast to V's dtype: the arithmetic of
+    :func:`attention`, in another order of reduction.  The zero lanes cost
+    Hkv times the contractions' FLOPs, which a step bound by reading K and
+    V does not notice at Hkv = 2.  The fused steps' walk over a paged pool
+    (``models/kv_walk.py``) contracts the same way where a head is narrower
+    than a lane tile.
     """
-    b, t, h, dh = q.shape
+    dh = q.shape[-1]
     hkv = k.shape[-1] // dh
-    # own[h, g]: K/V head g is query head h's.  Both the placing of q and
-    # the taking of o are selects under this mask: taking o as slices of
-    # lanes concatenated over heads reads the wrong lanes on a TPU v5e
-    # (this XLA; PERF.md section 6, PR 31), and only there.
-    own = (np.arange(h)[:, None] // (h // hkv)
-           == np.arange(hkv))[None, :, None, :, None]
-    qh = jnp.transpose(q, (0, 2, 1, 3))[:, :, :, None, :]  # (B, H, T, 1, Dh)
-    q_rows = jnp.where(own, qh, 0).reshape(b, h, t, hkv * dh)
-    s = jnp.einsum("bhqc,bkc->bhqk", q_rows, k,
+    s = jnp.einsum("bhqc,bkc->bhqk", heads_to_rows(q, hkv), k,
                    preferred_element_type=jnp.float32)
     s = s * dh ** -0.5
     if mask is not None:
@@ -115,8 +136,8 @@ def grouped_attention(
     p = jax.nn.softmax(s, axis=-1)
     o_rows = jnp.einsum("bhqk,bkc->bhqc", p.astype(v.dtype), v,
                         preferred_element_type=jnp.float32)
-    o = jnp.where(own, o_rows.reshape(b, h, t, hkv, dh), 0.0).sum(axis=3)
-    return jnp.transpose(o, (0, 2, 1, 3)).astype(q.dtype)
+    return jnp.transpose(rows_to_heads(o_rows, hkv),
+                         (0, 2, 1, 3)).astype(q.dtype)
 
 
 def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from nornicdb_tpu.models import kv_walk
 from nornicdb_tpu.models.layers import (
     apply_rope,
     dense,
@@ -30,11 +31,7 @@ from nornicdb_tpu.models.layers import (
     rms_norm,
     rope_freqs,
 )
-from nornicdb_tpu.ragged import (
-    NULL_PAGE,
-    pack_ragged_meta,
-    unpack_ragged_meta,
-)
+from nornicdb_tpu.ragged import NULL_PAGE, pack_ragged_meta
 
 
 @dataclass(frozen=True)
@@ -144,11 +141,11 @@ def forward(params: dict, cfg: QwenConfig, input_ids: jax.Array) -> jax.Array:
 # mapping logical pages -> physical pool slots: a cache per request would be
 # reallocated to a common length whenever a request joined the batch.
 # Sequences join/leave the batch by allocating/freeing pages; attention
-# block-gathers each sequence's pages into contiguous (S = P*page_size)
-# keys and masks by true length.  Physical page 0 is RESERVED as the null/
-# scratch page: padded lanes and padded chunk positions route their writes
-# there, so a static-shape program never corrupts a live page
-# (``NULL_PAGE``, ``pages_for``: nornicdb_tpu/ragged.py).
+# walks each sequence's table a block of pages at a time and masks by true
+# length.  Physical page 0 is RESERVED as the null/scratch page: padded
+# lanes and padded chunk positions route their writes there, so a
+# static-shape program never corrupts a live page (``NULL_PAGE``,
+# ``pages_for``: nornicdb_tpu/ragged.py).
 #
 # A cache slot's row is its K (or V) heads side by side, kv_heads * head_dim
 # wide: at Qwen2.5's widths 2 x 64 = ONE 128-lane tile.  With (kv_heads,
@@ -156,10 +153,18 @@ def forward(params: dict, cfg: QwenConfig, input_ids: jax.Array) -> jax.Array:
 # another axis minor; the step's scatter and its gather each wanted their
 # own layout and every step copied the whole pool there and back (9.4 + 9.0
 # ms of the chip at 8,193 pages: PERF.md section 6, PR 31).  The rows reach
-# the contraction as stored (``layers.grouped_attention``): no ``repeat_kv``
-# copy, which the chip made in f32 at 528 MB a layer, and no split of a row.
-# What the block-gather still does: it reads EVERY page of EVERY lane's
-# table, live or not (attention over real lengths: ROADMAP G1).
+# the contraction as stored: no ``repeat_kv`` copy, which the chip made in
+# f32 at 528 MB a layer, and no split of a row.
+# What a step reads of the pool (PR 38; ``models/kv_walk.py``, the walk
+# Command A+'s step runs too): each layer's two attention blocks gather,
+# by FLAT page number straight from the pool, only the blocks of 128 pages
+# that the step's longest live lane reaches (three of a 512-page table's
+# four at 4.7k-5.7k tokens), fold them into a running float32 softmax and
+# keep a turn's rows and scores on the chip.  No layer's K or V is sliced
+# out of the pool first (48 copies of 33.6 MB a step before PR 38), and no
+# lane's whole table is gathered.  What it still does: every lane of a
+# block walks as far as the longest, empty lanes too, and the shared prefix
+# pages are read once a lane (ROADMAP G1).
 
 
 def init_kv_pages(cfg: QwenConfig, num_pages: int, page_size: int) -> jax.Array:
@@ -187,21 +192,6 @@ def _apply_rope_rows(x: jax.Array, angles: jax.Array) -> jax.Array:
     ).astype(x.dtype)
 
 
-def _paged_attention(pages, li, page_tables, q, mask):
-    """Block-gather one layer's K/V pages for every sequence and attend.
-    page_tables: (B, P) physical page ids; q: (B, T, H, Dh).  The rows go
-    from the pool to the contraction as they are stored."""
-    b, p = page_tables.shape
-    _, _, _, ps, row = pages.shape
-    # the layer's K (then V) sliced out of the pool first, then gathered:
-    # one gather from the whole pool (``pages[li, 0, page_tables]``) moves
-    # fewer bytes by the compiler's account and is slower on the chip (9.3
-    # against 7.8 ms a decode-only step at 8,193 pages: PERF.md section 6)
-    k_all = pages[li, 0][page_tables].reshape(b, p * ps, row)
-    v_all = pages[li, 1][page_tables].reshape(b, p * ps, row)
-    return grouped_attention(q, k_all, v_all, mask)
-
-
 # -- ragged fused step (genserve v2) -----------------------------------------
 #
 # ONE device program per scheduler iteration serving mixed prefill + decode
@@ -216,8 +206,8 @@ def _paged_attention(pages, li, page_tables, q, mask):
 # blocks inside the one program (one device dispatch) instead of one
 # (Lmax, Tq) cross-product block whose Lmax*Tq padded query rows would
 # dwarf the ~Lmax+Tq real ones:
-#   decode block (Lmax, 1)  single-token lanes, scattered by lane_id
-#   chunk  block (1, Tq)    the prefill chunk, scattered by lane_pos
+#   decode block (Lmax-1, 1) single-token lanes, scattered by lane_id
+#   chunk  block (1, Tq)     the prefill chunk, scattered by lane_pos
 # Lane roles are FIXED by lane_id so the split needs no dynamic count:
 # rows with lane_id < Lmax-2 are decode lanes, lane_id == Lmax-2 is THE
 # chunk lane, lane_id == Lmax-1 is the dump lane for padding rows.
@@ -232,17 +222,24 @@ def _paged_attention(pages, li, page_tables, q, mask):
 # All int32 metadata travels in ONE packed host array (one H2D per step
 # instead of six — the scheduler dispatches this thousands of times a
 # second), and the greedy argmax runs inside the program, so a steady
-# step is exactly one dispatch and one (Lmax,) device->host read.
+# step is exactly one dispatch and one (Lmax + 2,) device->host read.
 # Padding rows route their page writes to NULL_PAGE and mask every key
 # slot; their attention output is garbage never gathered. Masked slots
-# add -1e30 before the f32 softmax, so exp underflows to exactly 0.0 and
+# score -1e30 before the f32 softmax, so exp underflows to exactly 0.0 and
 # null/foreign page content contributes nothing.  What the step is held
 # to is a tolerance against the plain float32 forward of
 # ``models/reference/qwen2.py`` (tests/test_qwen2_step.py; the benchmark
 # holds the chip to the same kind of comparison), not equality with
 # another implementation.
-# Both blocks gather whole tables: the decode block all Lmax lanes' W pages
-# (chunk and dump lanes included), the chunk block its lane's W pages.
+# The two blocks are ``kv_walk``'s: the decode block holds the Lmax-2
+# decode lanes and ONE dump lane (the chunk lane's slot: chunk and padding
+# rows land there, masked everywhere), the chunk block the chunk lane.
+
+# what the step appends to its Lmax greedy ids (``GenStats`` fields of the
+# same names, ``mla.WALK_COUNTERS``' meaning): the cache slots its attention
+# blocks gathered and scored, and the slots those lanes' whole tables hold,
+# each summed over lanes and layers
+STEP_COUNTERS = ("attn_slots_walked", "attn_slots_table")
 
 
 @functools.partial(
@@ -260,49 +257,22 @@ def ragged_fused_step(params, cfg: QwenConfig, meta: jax.Array,
     Lmax-2 is the chunk lane's table); ``tq`` is the static query width
     of the chunk attention block — ``tq == 1`` declares a decode-only
     step (no row may carry the chunk lane id); ``prev`` is the previous
-    step's (Lmax,) ids, where a row whose token is ``-(src + 1)`` finds it
-    (``nornicdb_tpu/ragged.py``).  Attention is the XLA
-    block-gather (:func:`_paged_attention`) on every platform.
-    Returns ((Lmax,) greedy token ids, (Lmax, V) f32 logits for
-    ``logit_rows``, advanced pages); ``pages`` is DONATED.
+    step's ints, where a row whose token is ``-(src + 1)`` finds it
+    (``nornicdb_tpu/ragged.py``).  Attention is ``kv_walk``'s walk over
+    live lengths (one kind, base 0, no horizon) on every platform.
+    Returns (ints = the (Lmax,) greedy token ids followed by the step's
+    counts in :data:`STEP_COUNTERS` order, so one device-to-host read
+    carries both; (Lmax, V) f32 logits for ``logit_rows``; advanced
+    pages); ``pages`` is DONATED.
     """
-    tokens, lane_id, lane_pos, positions, logit_rows, lane_tables = \
-        unpack_ragged_meta(meta, lmax, w, prev)
-    f = tokens.shape[0]
-    p = w
+    rows = kv_walk.plan_step(meta, (pages,), (None,), lmax=lmax, w=w, tq=tq,
+                             prev=prev)
+    kind, = rows.kinds
+    f = rows.tokens.shape[0]
     ps = pages.shape[3]
-    max_len = p * ps
     head_dim = cfg.hidden // cfg.heads
-    full_angles = rope_freqs(head_dim, max_len, cfg.rope_theta)
-    valid = positions >= 0
-    pos_c = jnp.clip(positions, 0, max_len - 1)
-    angles = full_angles[pos_c][:, None, :]          # (F, 1, Dh/2)
-    lane_c = jnp.clip(lane_id, 0, lmax - 1)
-    slot_c = jnp.clip(lane_pos, 0, tq - 1)
-    is_chunk = lane_id == lmax - 2
-    # non-decode rows scatter to the dump lane; chunk/pad collisions
-    # there are harmless (masked, never gathered)
-    dec_lane = jnp.where(is_chunk, lmax - 1, lane_c)
-    phys = jnp.where(
-        valid, lane_tables[lane_c, jnp.clip(pos_c // ps, 0, p - 1)],
-        NULL_PAGE)
-    off = pos_c % ps
-    pos_dec = jnp.full((lmax, 1), -1, jnp.int32).at[dec_lane, 0].set(
-        jnp.where(valid & ~is_chunk, positions, -1))
-    slot = jax.lax.broadcasted_iota(jnp.int32, (1, max_len), 1)
-    mask_dec = jnp.where(slot[None] <= pos_dec[:, :, None],
-                         0.0, -1e30)[:, None]
-    if tq > 1:
-        # chunk rows scatter into the (1, Tq) block; every other row's
-        # index lands out of bounds on the lane axis and is dropped
-        chunk_row = jnp.where(is_chunk & valid, 0, 1)
-        pos_chk = jnp.full((1, tq), -1, jnp.int32).at[
-            chunk_row, slot_c].set(positions, mode="drop")
-        slot_q = jax.lax.broadcasted_iota(jnp.int32, (tq, max_len), 1)
-        mask_chk = jnp.where(slot_q[None] <= pos_chk[:, :, None],
-                             0.0, -1e30)[:, None]
-        chunk_table = lane_tables[lmax - 2][None]
-    h = params["tok_emb"][tokens][:, None]           # (F, 1, hidden)
+    angles = rope_freqs(head_dim, w * ps, cfg.rope_theta)[rows.pos][:, None]
+    h = params["tok_emb"][rows.tokens][:, None]      # (F, 1, hidden)
     for li, blk in enumerate(params["blocks"]):
         x = rms_norm(blk["attn_norm"], h, cfg.rms_eps)
         q = dense(blk["q"], x).reshape(f, 1, cfg.heads, head_dim)
@@ -310,28 +280,26 @@ def ragged_fused_step(params, cfg: QwenConfig, meta: jax.Array,
         v = dense(blk["v"], x)
         q = _apply_rope_rows(q, angles)
         k = _apply_rope_rows(k, angles)
-        pages = pages.at[li, 0, phys, off].set(k.reshape(f, -1))
-        pages = pages.at[li, 1, phys, off].set(v[:, 0])
-        q_dec = jnp.zeros((lmax, 1, cfg.heads, head_dim), q.dtype)
-        q_dec = q_dec.at[dec_lane, 0].set(q[:, 0])
-        o_dec = _paged_attention(pages, li, lane_tables, q_dec, mask_dec)
-        o = o_dec[dec_lane, 0]                       # (F, H, Dh)
-        if tq > 1:
-            q_chk = jnp.zeros((1, tq, cfg.heads, head_dim), q.dtype)
-            q_chk = q_chk.at[chunk_row, slot_c].set(q[:, 0], mode="drop")
-            o_chk = _paged_attention(pages, li, chunk_table, q_chk,
-                                     mask_chk)
-            o = jnp.where(is_chunk[:, None, None], o_chk[0, slot_c], o)
-        o = o[:, None]                               # (F, 1, H, Dh)
-        h = h + dense(blk["o"], o.reshape(f, 1, cfg.heads * head_dim))
+        pages = pages.at[li, 0, kind.phys, rows.off].set(k.reshape(f, -1))
+        pages = pages.at[li, 1, kind.phys, rows.off].set(v[:, 0])
+        o = kv_walk.attend_blocks(cfg.kv_heads, rows, kind, q[:, 0], pages,
+                                  li, None)          # (F, heads x Dh)
+        h = h + dense(blk["o"], o[:, None])
         x = rms_norm(blk["mlp_norm"], h, cfg.rms_eps)
         h = h + dense(
             blk["down"], jax.nn.silu(dense(blk["gate"], x)) * dense(blk["up"], x)
         )
     h = rms_norm(params["final_norm"], h, cfg.rms_eps)
-    h_sel = h[jnp.clip(logit_rows, 0, f - 1)]        # (Lmax, 1, hidden)
+    h_sel = h[jnp.clip(rows.logit_rows, 0, f - 1)]   # (Lmax, 1, hidden)
     logits = _logits(params, cfg, h_sel)[:, 0, :]
-    return jnp.argmax(logits, axis=-1), logits, pages
+    # slots walked (``kind.walk`` counts pages, one layer) and the slots of
+    # the blocks' lanes' whole tables, padded to whole blocks of pages
+    lanes = kind.dec_tables.shape[0] + (tq > 1)
+    table = lanes * kind.dec_tables.shape[1]
+    walk = jnp.stack([kind.walk[0], jnp.int32(table)]) * (ps * cfg.layers)
+    ints = jnp.concatenate([jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                            walk])
+    return ints, logits, pages
 
 
 # -- the decoder-family seam (genserve/engine.py resolves this module from

@@ -213,13 +213,28 @@ def test_bge_m3_forward_packed_full_width(one_chip):
     assert out.shape == (8, 1024)
 
 
+def _pool_gathers_are_flat(text: str, page: int, row: int) -> None:
+    """Every gather of whole pages (slices of ``page`` slots of ``row``
+    values) takes them by ONE index, the flat page number
+    (``models/kv_walk.py``): the one-dimensional gather the chip runs at
+    four times the rate of the gather with an index a pool axis (PERF.md
+    section 6, PRs 31 and 38), and there is such a gather."""
+    import re
+
+    maps = [re.search(r"start_index_map=\{([0-9,]*)\}", line).group(1)
+            for line in text.splitlines()
+            if " gather(" in line
+            and re.search(r"slice_sizes=\{[0-9,]*\b%d,%d\}" % (page, row), line)]
+    assert maps and set(maps) == {"0"}, maps
+
+
 # (lanes, table pages, pool pages, flat rows, chunk width, ceiling on
 # cost_analysis()'s bytes accessed or None)
 _QWEN_STEP = {
     "decode": (10, 16, 129, 8, 1, None),
     "prefill-chunk": (10, 16, 129, 64, 64, None),
-    "cell-decode": (18, 512, 8193, 16, 1, 1.76e9),
-    "cell-prefill-chunk": (18, 512, 8193, 64, 64, 2.47e9),
+    "cell-decode": (18, 512, 8193, 16, 1, 0.924e9),
+    "cell-prefill-chunk": (18, 512, 8193, 64, 64, 1.03e9),
 }
 
 
@@ -227,19 +242,24 @@ _QWEN_STEP = {
 def test_ragged_fused_step_qwen_widths(one_chip, case):
     """The generation step at Qwen2.5-0.5B widths (896 h, 14/2 heads,
     vocab 151,936), 2 layers, page 16, with the attention implementation
-    the engine dispatches on a TPU: the XLA block-gather (the ragged Pallas
-    kernel does not lower — docs/generation.md).  At the default engine
-    geometry (8 lanes + chunk + dump, 16-page tables, 129-page pool) and at
-    the benchmark cell's (16 lanes + chunk + dump, 512-page tables,
-    8,193-page pool), where what the step moves is held too: the pool goes
-    in and out donated, in ONE row-major layout and with no pool-sized
-    copy (a 64-wide row made the compiler put another axis minor and copy
-    the whole pool there and back every step), no f32 array of the
-    gathered cache's extent exists (the copy ``repeat_kv`` made: 528 MB a
-    layer), and the bytes accessed stay under what this program read when
-    it was written, 1.470 / 2.057 GB, plus a fifth (its predecessor read
-    9.98 / 11.32 GB: PERF.md section 6).  The step compiled is the one the
-    engine serves: it takes ``prev``, the previous step's ids."""
+    the engine dispatches on a TPU: the XLA walk over live lengths
+    (``models/kv_walk.py``; the ragged Pallas kernel does not lower —
+    docs/generation.md).  At the default engine geometry (8 lanes + chunk +
+    dump, 16-page tables, 129-page pool) and at the benchmark cell's (16
+    lanes + chunk + dump, 512-page tables, 8,193-page pool), where what the
+    step moves is held too: the pool goes in and out donated, in ONE
+    row-major layout and with no pool-sized copy (a 64-wide row made the
+    compiler put another axis minor and copy the whole pool there and back
+    every step), no f32 array of the gathered cache's extent exists (the
+    copy ``repeat_kv`` made: 528 MB a layer), each attention block is a
+    ``while`` that gathers a block of pages by FLAT page number (every
+    gather of pages has ``start_index_map={0}``), no layer's K or V is
+    sliced out of the pool (``bf16[8193,16,128]``: 48 copies of 33.6 MB a
+    step until PR 38) and no lane's whole table gathered, and the bytes
+    accessed stay under what this program read when it was written, 0.770
+    / 0.858 GB, plus a fifth (its predecessors read 1.470 / 2.057 and 9.98
+    / 11.32 GB: PERF.md section 6).  The step compiled is the one the
+    engine serves: it takes ``prev``, the previous step's ids and counts."""
     import re
 
     import jax
@@ -258,7 +278,8 @@ def test_ragged_fused_step_qwen_widths(one_chip, case):
         _sds(meta.shape, jnp.int32, one_chip),
         _sds(pool, jnp.bfloat16, one_chip),
         lmax=lmax, w=w, tq=tq,
-        prev=_sds((lmax,), jnp.int32, one_chip),  # the served variant
+        # the served variant: the ids and the step's two counts
+        prev=_sds((lmax + len(qwen2.STEP_COUNTERS),), jnp.int32, one_chip),
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
@@ -272,7 +293,14 @@ def test_ragged_fused_step_qwen_widths(one_chip, case):
     gathered = r"f32\[%d,%d,(%d|%d,%d),64\]" % (
         lmax, w * 16, cfg.heads, cfg.kv_heads, cfg.heads // cfg.kv_heads)
     assert not re.search(gathered, text)
+    _pool_gathers_are_flat(text, 16, 128)
     if bytes_ceiling is not None:
+        assert " while(" in text
+        assert "bf16[%d,16,128]" % pages not in text     # a layer's K or V
+        for whole in ("[%d,16,128]" % (lmax * w), "[%d,%d,16,128]" % (lmax, w),
+                      "[%d,16,128]" % ((lmax - 1) * w),
+                      "[%d,%d,16,128]" % (lmax - 1, w)):
+            assert whole not in text, whole              # a whole table
         cost = compiled.cost_analysis()
         cost = cost[0] if isinstance(cost, list) else cost
         assert cost["bytes accessed"] < bytes_ceiling
@@ -382,13 +410,15 @@ def test_parallel_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     beside the deployment's 5.43 GB, both K/V pools are donated and go in
     and out in ONE row-major layout with no pool-sized copy left in the
     step, and nothing of the extent of a lane's whole table is gathered: a
-    turn of a walk takes one block of each lane's pages."""
+    turn of a walk takes one block of each lane's pages, by flat page
+    number (the one-dimensional gather)."""
     import re
 
     import jax.numpy as jnp
 
     from nornicdb_tpu.ragged import pack_ragged_meta
     from nornicdb_tpu.models import cohere2_moe as cm
+    from nornicdb_tpu.models import kv_walk
 
     cfg = cm.COMMAND_A_PLUS_EP16_4L
     lmax, w, pages, page = 18, (512, 261), (8193, 4689), 16
@@ -416,5 +446,8 @@ def test_parallel_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     for whole in ("[17,512,16,1024]", "[8704,16,1024]", "[17,261,16,1024]",
                   "[4437,16,1024]"):
         assert whole not in text, whole
-    assert f"bf16[{17 * cm.BLOCK_PAGES},16,1024]" in text \
-        or f"bf16[17,{cm.BLOCK_PAGES},16,1024]" in text
+    block = kv_walk.block_pages(_sds(pools[0], jnp.bfloat16, one_chip), 512)
+    assert block == 32  # Command A+'s 32 KB pages keep their measured 32
+    assert f"bf16[{17 * block},16,1024]" in text \
+        or f"bf16[17,{block},16,1024]" in text
+    _pool_gathers_are_flat(text, page, 1024)

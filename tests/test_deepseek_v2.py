@@ -364,8 +364,8 @@ def test_the_step_carries_its_scopes_and_its_own_module_name():
 
 # ------------------------------------ (i) Qwen's lowered step unchanged
 @pytest.mark.parametrize("tq,sha", [
-    (16, "9bf7a3c38f557033d3866db8bc5a83112b636976c111ca165fdd559917de5189"),
-    (1, "263a6f5f44476aa9efe38ff02c8a72eff2cacf91e213138468ea3db1238bcb80")])
+    (16, "ef5d7c8530bedd2fcada9dc0988327b50a955fa6b47ffcd630d378feee1ea112"),
+    (1, "4371d4930cc5000069fbf22b7faf74d51bd2de239db5c124fd0fd65a7e415a97")])
 def test_qwens_lowered_step_is_what_it_was_before_the_family_seam(tq, sha):
     """The lowered text of ``ragged_fused_step`` for one step class is what
     it was when last measured: a PR that does not mean to change Qwen's
@@ -375,14 +375,25 @@ def test_qwens_lowered_step_is_what_it_was_before_the_family_seam(tq, sha):
     did (128-wide pool rows, ``layers.grouped_attention``), and PR 33 (the
     served variant takes ``prev``, the previous step's ids, and reads the
     tokens still in flight there: one ``select`` over a gather before the
-    embedding lookup)."""
+    embedding lookup), and PR 38: the step's two attention blocks are
+    ``models/kv_walk.py``'s walk over live lengths (a ``while`` a block
+    that gathers pages by FLAT page number straight from the pool, a
+    running softmax, the row-wide contraction of ``grouped_attention``
+    inside it) in place of a slice of each layer's K and V out of the pool
+    and a gather of every page of every lane; the decode block has
+    ``lmax - 1`` lanes, and the int vector ends with the walk's two counts
+    (``qwen2.STEP_COUNTERS``), so ``prev`` is ``lmax + 2`` wide; the two
+    blocks are one jitted function with the layer's index a value, lowered
+    once and called a layer (``kv_walk.attend_blocks``).  At this
+    case's 8-page tables a block is the whole table, so the digests do not
+    move with ``kv_walk``'s block rule."""
     cfg = qwen2.QWEN_SMALL
     params = jax.eval_shape(lambda: qwen2.init_params(cfg,
                                                       jax.random.PRNGKey(0)))
     lmax, w, f = 6, 8, 16
     meta = jax.ShapeDtypeStruct((4 * f + lmax + lmax * w,), jnp.int32)
     pages = jax.eval_shape(lambda: qwen2.init_pages(cfg, 17, 16))
-    prev = jax.ShapeDtypeStruct((lmax,), jnp.int32)
+    prev = jax.ShapeDtypeStruct((lmax + len(qwen2.STEP_COUNTERS),), jnp.int32)
     text = qwen2.ragged_fused_step.lower(params, cfg, meta, pages, lmax=lmax,
                                          w=w, tq=tq, prev=prev).as_text()
     assert re.search(r"module @(\S+)", text).group(1) == \

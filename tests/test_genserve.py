@@ -233,6 +233,25 @@ class TestEngineScheduling:
         assert eng.programs == programs, "steady state compiled new programs"
         assert len(programs) <= 12
 
+    def test_a_family_with_a_walk_and_no_experts_moves_its_own_counters(self):
+        """Qwen's step appends the walk's two counts and no routing count
+        (``qwen2.STEP_COUNTERS``): the engine reads them off the step's int
+        vector into ``GenStats`` and the two Prometheus counters, and asks
+        the step for no name it does not declare.  The engine's 16-page
+        tables are narrower than a block: every step walks all of them."""
+        from nornicdb_tpu.genserve import stats as gstats
+
+        assert qwen2.STEP_COUNTERS == ("attn_slots_walked", "attn_slots_table")
+        before = gstats.ATTN_SLOTS_WALKED.get()
+        eng = _engine()
+        out = eng.generate(_prompt(21, seed=3), max_new_tokens=5)
+        _assert_reference(_prompt(21, seed=3), out, 5)
+        stats = eng.stats_snapshot()
+        assert stats["attn_slots_walked"] == stats["attn_slots_table"] > 0
+        assert stats["expert_assignments"] == stats["routed_rows"] == 0
+        assert gstats.ATTN_SLOTS_WALKED.get() - before \
+            >= stats["attn_slots_walked"]
+
 
 # ---------------------------------------------------------------------------
 # backend chaos: hang / fail / recover

@@ -571,21 +571,23 @@ class TestBenchmarkReaders:
                 assert isinstance(dig(counters, path), (int, float)), (
                     spec["name"], path)
 
-    @pytest.mark.parametrize("cell", ["dsv2", "lcf"])
+    @pytest.mark.parametrize("cell", ["dsv2", "lcf", "gen"])
     def test_the_walk_counters_are_what_attn_walked_share_reads(
             self, snapshot, cell):
         """PR 36's pair (``models/mla.py``'s ``WALK_COUNTERS``, the last
-        two of both latent families' ``STEP_COUNTERS``): the metric reads
-        them under these names, and every engine's snapshot has them (0
-        for a family whose step walks no table)."""
-        from nornicdb_tpu.models import deepseek_v2, longcat_flash, mla
+        two of both latent families' ``STEP_COUNTERS``, and since PR 38
+        all of Qwen's): the metric reads them under these names, and
+        every engine's snapshot has them (0 for a family whose step
+        counts no walk)."""
+        from nornicdb_tpu.models import deepseek_v2, longcat_flash, mla, qwen2
 
         counters, dig = snapshot
         spec, = [s for s in _metric_specs("counter_ratio")
                  if s["name"] == f"attn_walked_share.{cell}"]
         paths = (spec["numerator"], spec["denominator"])
         assert paths == tuple("genserve." + n for n in mla.WALK_COUNTERS)
-        family = {"dsv2": deepseek_v2, "lcf": longcat_flash}[cell]
+        family = {"dsv2": deepseek_v2, "lcf": longcat_flash,
+                  "gen": qwen2}[cell]
         assert family.STEP_COUNTERS[-2:] == mla.WALK_COUNTERS
         for path in paths:
             assert isinstance(dig(counters, path), int), path
