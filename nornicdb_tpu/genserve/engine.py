@@ -104,7 +104,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass
-from typing import Iterator, NamedTuple, Optional, Sequence
+from typing import Iterator, Optional, Sequence
 
 import numpy as np
 
@@ -208,6 +208,43 @@ class GenStats:
     # the step's pairs summed over the kinds
     attn_pages_walked: int = 0
     attn_pages_held: int = 0
+    # the scheduler thread's cycle, one pass of ``_step`` a turn, each the
+    # seconds of the ``tracer.stage`` of that name (docs/observability.md
+    # "The scheduler's turn"): genserve.turn, and inside it .admit, .plan
+    # (up to the dispatch; a drain inside it counts as read and deliver),
+    # .dispatch (``jnp.asarray(meta)`` and the step's call until it
+    # returns), .read (``read_wait_seconds``, above) and .deliver (what
+    # ``_read`` does once the ids are on the host, their device buffer
+    # given back included).  What is left of a turn
+    # (queued deadlines, the gate) is ``turn_seconds`` less their sum, and
+    # busy turns follow each other with nothing between
+    turns: int = 0
+    turn_seconds: float = 0.0
+    admit_seconds: float = 0.0
+    plan_seconds: float = 0.0
+    dispatch_seconds: float = 0.0
+    deliver_seconds: float = 0.0
+    # a turn less its blocked read: the host's own work; the scheduler
+    # thread's CPU seconds over the turns (``time.thread_time``); and the
+    # first less the second: seconds the thread was runnable and did not
+    # run (the GIL behind the HTTP threads, or the machine)
+    host_turn_seconds: float = 0.0
+    turn_cpu_seconds: float = 0.0
+    host_offcpu_seconds: float = 0.0
+    # dispatches that found the step in flight already finished (the chip
+    # had been waiting for the host), and for those the host's seconds
+    # since the dispatch before, less any blocked read between
+    late_dispatches: int = 0
+    late_host_seconds: float = 0.0
+    # a token's way out, on the consumer's side of a streamed handle: from
+    # ``_deliver`` to the consumer coming back for the next token (the
+    # event decoded, serialised and written), folded in when a stream ends
+    stream_lag_seconds: float = 0.0
+    streamed_tokens: int = 0
+    # a request's waits, an interval an admission each: submit (or requeue)
+    # to seat, and seat to the admission's first token
+    queue_wait_seconds: float = 0.0
+    prefill_wait_seconds: float = 0.0
 
     def as_dict(self) -> dict:
         return {k: getattr(self, k) for k in self.__dataclass_fields__}
@@ -248,7 +285,7 @@ class GenHandle:
             self._tokens.append(tok)
             q = self._stream_q
         if q is not None:
-            q.put(tok)
+            q.put((tok, time.perf_counter()))
 
     def _finish(self, error: Optional[Exception] = None) -> None:
         with self._mu:
@@ -303,26 +340,39 @@ class GenHandle:
         with self._mu:
             if self._stream_q is None:
                 self._stream_q = queue_mod.Queue()
+                now = time.perf_counter()
                 for tok in self._tokens:
-                    self._stream_q.put(tok)
+                    self._stream_q.put((tok, now))
                 if self._done.is_set():
                     self._stream_q.put(None)
             q = self._stream_q
-        while True:
-            try:
-                tok = q.get(timeout=self._time_left())
-            except queue_mod.Empty:
-                if self._done.is_set():
-                    continue  # race: sentinel arriving; loop re-polls
-                if self.deadline and time.monotonic() > (
-                        self.deadline + self._GRACE):
-                    raise self._give_up()
-                continue
-            if tok is None:
-                if self.error is not None:
-                    raise self.error
-                return
-            yield tok
+        lag, back = 0.0, 0
+        try:
+            while True:
+                try:
+                    item = q.get(timeout=self._time_left())
+                except queue_mod.Empty:
+                    if self._done.is_set():
+                        continue  # race: sentinel arriving; loop re-polls
+                    if self.deadline and time.monotonic() > (
+                            self.deadline + self._GRACE):
+                        raise self._give_up()
+                    continue
+                if item is None:
+                    if self.error is not None:
+                        raise self.error
+                    return
+                yield item[0]
+                # the consumer is back for its next token: the one before
+                # is out (decoded, serialised, written to the socket)
+                lag += time.perf_counter() - item[1]
+                back += 1
+        finally:
+            if back:  # once a stream, so sixteen of them lose no update
+                stats = self._engine.stats
+                with self._engine._lock:
+                    stats.stream_lag_seconds += lag
+                    stats.streamed_tokens += back
 
     def stream_text(self) -> Iterator[str]:
         """Decoded text deltas (diffs of the running decode, so any
@@ -368,9 +418,8 @@ class _Seq:
     __slots__ = (
         "handle", "prompt", "out", "max_new", "eos_id", "state",
         "prefill_tokens", "prefill_pos", "tables", "bases", "held",
-        "cache_len", "admit_no", "src", "row_step",
-        "submitted_at", "first_token_at", "counted",
-        "trace_ctx", "submitted_perf", "prefix_keys", "re_prefill",
+        "cache_len", "admit_no", "src", "row_step", "counted",
+        "trace_ctx", "submitted_perf", "since", "prefix_keys", "re_prefill",
     )
 
     def __init__(self, handle: GenHandle, prompt: list[int], max_new: int,
@@ -397,14 +446,16 @@ class _Seq:
         # that holds a row of this sequence
         self.src = -1
         self.row_step = 0
-        self.submitted_at = time.monotonic()
-        self.first_token_at = 0.0
         self.counted = False
         # the submitting request's trace context: scheduler spans attach
         # to it (prefill/decode, queue-wait, eviction) so a GraphRAG
         # answer shows its full generation path in /admin/traces
         self.trace_ctx = None
         self.submitted_perf = 0.0
+        # perf_counter reading its current wait began at: queued (submit,
+        # eviction, a re-platform), then seated; 0 once the admission's
+        # first token is out
+        self.since = 0.0
         # chained page-content keys over this admission's prefill tokens
         # (full pages only); registered into the prefix cache when the
         # final chunk lands
@@ -528,12 +579,16 @@ class _Kind:
             self.hash[pid] = key
 
 
-class _Flight(NamedTuple):
+@dataclass(slots=True)
+class _Flight:
     """One dispatched fused step whose ids the host has not read yet."""
 
     no: int
-    ids: object                 # device: Lmax greedy ids [+ routing]
+    ids: object                 # device: Lmax greedy ids [+ routing]; None
+    #                             once read (the deliver stage lets it go)
     t0: float                   # perf_counter at dispatch
+    waited: float               # stats.read_wait_seconds at dispatch
+    late: bool                  # the step before had finished by then
     shape: str
     tq: int
     active: list                # decode rows' sequences, in lane order
@@ -622,6 +677,7 @@ class GenerationEngine:
         self._step_no = 0
         self._read_no = 0
         self._zombies: list[_Seq] = []
+        self._read_at = 0.0  # perf_counter when the last step's ids landed
         self._no_ids: dict = {}  # platform -> the first step's ``prev``
         self._device_kind: Optional[str] = None  # "default" | "cpu"
         self._cpu_params = None
@@ -630,6 +686,9 @@ class GenerationEngine:
         # fleet telemetry: the KV page pool's HBM residency (weakref'd
         # provider, summed at /metrics render — telemetry/deviceprof.py)
         _deviceprof.register_hbm(self, GenerationEngine._hbm_bytes)
+        # and what /metrics derives from this engine at scrape time: the
+        # turn's phase seconds off ``stats``, the three pool gauges
+        _stats.track(self)
 
     @staticmethod
     def _hbm_bytes(self) -> dict:
@@ -853,7 +912,7 @@ class GenerationEngine:
         # spans (prefill/decode/queue-wait/eviction) attach to it, and
         # the admission decision itself records in the CALLER's trace
         seq.trace_ctx = _tracer.capture()
-        seq.submitted_perf = time.perf_counter()
+        seq.submitted_perf = seq.since = time.perf_counter()
         with _tracer.span("genserve.admit",
                           {"prompt_tokens": len(prompt),
                            "max_new": max_new}) as admit_span:
@@ -932,16 +991,16 @@ class GenerationEngine:
     # nornlint: thread-role=scheduler
     def _loop(self) -> None:
         while not self._stop.is_set():
-            with self._cond:
-                while (not self._queue and not self._running
-                       and self._inflight is None
-                       and not self._stop.is_set()):
-                    self._cond.wait(0.25)
-                if self._stop.is_set():
-                    break
-                self._shed_expired_queued()
-            if self._stop.is_set():
-                break
+            if not (self._queue or self._running
+                    or self._inflight is not None):
+                # nothing to do: no turn.  The queue is looked at again
+                # under the lock submit() appends under, so no wake-up is
+                # lost; a busy loop takes no lock here, and whatever it
+                # waits for lies inside the turn, where it is timed
+                with self._cond:
+                    if not self._queue and not self._stop.is_set():
+                        self._cond.wait(0.25)
+                continue
             try:
                 self._step()
             except Exception as e:  # a broken step must not strand callers:
@@ -1188,8 +1247,10 @@ class GenerationEngine:
         self._drop_inflight()
         requeue = list(self._running)
         self._running = []
+        now = time.perf_counter()
         with self._cond:
             for seq in reversed(requeue):
+                seq.since = now  # queued again
                 seq.tables = None
                 seq.bases, seq.held = [], []
                 seq.cache_len = 0
@@ -1226,34 +1287,63 @@ class GenerationEngine:
 
     # -- one scheduler iteration -------------------------------------------
     def _step(self) -> None:
-        kind = self._gate()
-        self._apply_platform(kind)
-        if kind == "cpu":
-            self.stats.cpu_steps += 1
-        self._ensure_pool()
-        self._admit()
-        # dispatch N+1, THEN read N: the host's turn (deliver, admit,
-        # plan, pack) runs while the device does
-        nxt = self._launch()  # reads N itself first where it must
-        flight, self._inflight = self._inflight, nxt
-        if flight is not None:
-            self._read(flight)
-        self._publish_gauges()
+        """One turn of the scheduler thread, timed where it happens: the
+        turn and its parts are stages (``GenStats`` says which), so the
+        parts tile the turn in the counters, and under a profiler capture
+        on the device trace's own clock."""
+        stats = self.stats
+        waited0 = stats.read_wait_seconds
+        seated0, done0 = stats.admissions, stats.completed
+        with _tracer.stage("genserve.turn", stats, "turn_seconds") as turn:
+            cpu0 = time.thread_time()
+            if self._queue:
+                with self._cond:
+                    self._shed_expired_queued()
+            kind = self._gate()
+            self._apply_platform(kind)
+            if kind == "cpu":
+                stats.cpu_steps += 1
+            self._ensure_pool()
+            with _tracer.stage("genserve.turn.admit", stats, "admit_seconds"):
+                self._admit()
+            # dispatch N+1, THEN read N: the host's turn (deliver, admit,
+            # plan, pack) runs while the device does
+            nxt = self._launch()  # reads N itself first where it must
+            flight, self._inflight = self._inflight, nxt
+            if flight is not None:
+                self._read(flight)
+            # WHICH turns are the long ones (a capture's annotation)
+            turn.set_attr("admitted", stats.admissions - seated0)
+            turn.set_attr("finished", stats.completed - done0)
+            turn.set_attr("chunk", int(nxt is not None
+                                       and nxt.chunk_seq is not None))
+            turn.set_attr("lanes", len(nxt.active) if nxt else 0)
+            turn.set_attr("late", int(nxt is not None and nxt.late))
+            cpu = time.thread_time() - cpu0
+        host = turn.seconds - (stats.read_wait_seconds - waited0)
+        stats.turns += 1
+        stats.host_turn_seconds += host
+        stats.turn_cpu_seconds += cpu
+        stats.host_offcpu_seconds += host - cpu
 
     def _drain(self) -> None:
         """Read the step in flight NOW, before the plan goes on: every
-        token it picked is on the host when this returns."""
+        token it picked is on the host when this returns.  Called from
+        inside the plan stage, whose counter gives these seconds back:
+        they are read and deliver, never both."""
         flight, self._inflight = self._inflight, None
         if flight is not None:
             self.stats.drains += 1
-            self._read(flight)
+            self.stats.plan_seconds -= self._read(flight)
 
-    def _publish_gauges(self) -> None:
-        _stats.RUNNING_SEQS.set(len(self._running))
+    def _pool_gauges(self) -> tuple[int, int, int, int]:
+        """(resident sequences, pages allocated, pages usable, pages the
+        prefix cache indexes), read by ``genserve/stats.py`` at scrape
+        time: nothing sets a gauge a step."""
         usable = sum(k.usable for k in self._kinds)
-        used = usable - sum(len(k.free) for k in self._kinds)
-        _stats.PAGE_POOL_UTIL.set(used / max(1, usable))
-        _stats.PREFIX_PAGES.set(sum(len(k.cache) for k in self._kinds))
+        return (len(self._running),
+                usable - sum(len(k.free) for k in self._kinds), usable,
+                sum(len(k.cache) for k in self._kinds))
 
     def _admit(self) -> None:
         ps = self._page_size
@@ -1330,14 +1420,16 @@ class GenerationEngine:
                 self.stats.readmissions += 1
             self.stats.admissions += 1
             self._running.append(seq)
-            # queue wait lands retroactively in the SUBMITTER's trace
-            # (the QueryBatcher pattern — per-caller attribution)
-            if seq.trace_ctx is not None:
-                _tracer.add_span(
-                    "genserve.queue_wait", seq.submitted_perf,
-                    time.perf_counter(), parent=seq.trace_ctx,
-                    attrs={"readmission": bool(seq.out)},
-                )
+            # queue wait (submit, or the requeue, to this seat) lands
+            # retroactively in the SUBMITTER's trace (the QueryBatcher
+            # pattern — per-caller attribution); from here the sequence
+            # waits for its first token
+            seated = time.perf_counter()
+            _tracer.add_stage(
+                "genserve.queue_wait", seq.since, seated, self.stats,
+                "queue_wait_seconds", parent=seq.trace_ctx,
+                attrs={"readmission": bool(seq.out)})
+            seq.since = seated
 
     def _slide(self, seq: _Seq, first: int) -> None:
         """The lane's next queries stand at ``first`` and after: of each
@@ -1436,6 +1528,7 @@ class GenerationEngine:
         self._running.remove(victim)
         self._release_pages(victim)
         victim.state = _QUEUED
+        victim.since = time.perf_counter()  # queued again
         with self._cond:
             self._queue.appendleft(victim)
             _stats.QUEUE_DEPTH.set(len(self._queue))
@@ -1448,13 +1541,83 @@ class GenerationEngine:
         into the family's ``fused_step``.  Long prompts never stall the
         running batch — they ride the same program — and decode lanes
         never pay a separate dispatch while any prompt is prefilling.
+        Two stages of the turn: the plan up to the packed rows, then the
+        dispatch."""
+        import jax.numpy as jnp
+
+        stats = self.stats
+        with _tracer.stage("genserve.turn.plan", stats, "plan_seconds"):
+            planned = self._plan()
+        if planned is None:
+            return None
+        meta, f, tq, active, chunk_seq, n_valid, final = planned
+        ndec, lmax, w = len(active), self._lmax, self._w
+        before = self._inflight
+        # had the chip already finished the step in flight, it has been
+        # waiting for this dispatch
+        late = before is not None and before.ids.is_ready()
+        with _tracer.stage("genserve.turn.dispatch", stats,
+                           "dispatch_seconds") as sent:
+            params = self._active_params()
+            shape = self._shape(f, tq)
+            if ("ragged", f, tq, w) not in self.programs:
+                self.programs.add(("ragged", f, tq, w))
+                _deviceprof.record_compile("genserve", "ragged", shape)
+            with self._platform_ctx():
+                try:
+                    # greedy argmax runs inside the program and its (Lmax,)
+                    # ints feed the next step on the device (``prev``); a
+                    # family with routed experts appends its routing counts
+                    # to the same vector, so they cost no second read
+                    ids, _logits, self._pages = self._family.fused_step(
+                        params, self.cfg, jnp.asarray(meta), self._pages,
+                        lmax=lmax, w=w, tq=tq,
+                        prev=(before.ids if before is not None else
+                              self._blank_ids(self._device_kind)))
+                except Exception:
+                    # the failing dispatch may have CONSUMED the donated
+                    # pool (donate_argnums): drop it at the dispatch site
+                    # so _ensure_pool rebuilds from scratch, whatever the
+                    # caller does (NL-JAX04) — and the prefix cache indexes
+                    # the dropped pool's content, so it goes too, with the
+                    # step in flight
+                    self._pages = None
+                    self._reset_prefix_cache()
+                    self._drop_inflight()
+                    raise
+        # the plan moves on at dispatch: counts, never tokens
+        self._step_no += 1
+        if before is not None:
+            stats.overlapped_steps += 1
+        if late:
+            stats.late_dispatches += 1
+            stats.late_host_seconds += (sent.start - before.t0) - (
+                stats.read_wait_seconds - before.waited)
+        for i, seq in enumerate(active):
+            seq.cache_len += 1
+            seq.src = i
+            seq.row_step = self._step_no
+        if chunk_seq is not None:
+            chunk_seq.prefill_pos += n_valid
+            chunk_seq.cache_len = chunk_seq.prefill_pos
+            chunk_seq.row_step = self._step_no
+            if final:
+                # its first token is entry ndec of this step's ids
+                chunk_seq.state = _DECODE
+                chunk_seq.src = ndec
+        return _Flight(self._step_no, ids, sent.start,
+                       stats.read_wait_seconds, late, shape, tq, active,
+                       chunk_seq, n_valid, final)
+
+    def _plan(self) -> Optional[tuple]:
+        """The next step's rows: (meta, F, Tq, the decode rows' sequences
+        in lane order, the chunk's sequence, its valid rows, whether it is
+        the prompt's last piece), or None when no lane has work.
 
         The plan reads counts only, never a token of the step in flight:
         a lane whose last token is unread gets a row that names where the
         device will find it.  A sequence whose tokens, the unread one
         counted, already number ``max_new`` gets no row."""
-        import jax.numpy as jnp
-
         active = [s for s in self._running if s.state == _DECODE
                   and len(s.out) + (s.src >= 0) < s.max_new]
         active = [s for s in active if not self._expired(s)]
@@ -1508,7 +1671,7 @@ class GenerationEngine:
             # flat token rows: decode lanes first, then the chunk, then
             # padding up to the pow2 bucket — F scales with REAL tokens
             f = round_up_pow2(ndec, 8)
-        lmax, w = self._lmax, self._w
+        lmax = self._lmax
         # ONE packed int32 host array per step (one H2D transfer); the
         # names below are writable views into it.  Logits are projected
         # only for rows that pick a token: the decode rows and the chunk's
@@ -1541,68 +1704,46 @@ class GenerationEngine:
         if chunk_seq is not None:
             seat(chunk_lane, chunk_seq)
             logit_rows[ndec] = ndec + n_valid - 1
-        t0 = time.perf_counter()
-        params = self._active_params()
-        shape = self._shape(f, tq)
-        self.programs.add(("ragged", f, tq, w))
-        _deviceprof.record_compile("genserve", "ragged", shape)
-        before = self._inflight
-        with self._platform_ctx():
-            try:
-                # greedy argmax runs inside the program and its (Lmax,)
-                # ints feed the next step on the device (``prev``); a
-                # family with routed experts appends its routing counts
-                # to the same vector, so they cost no second read
-                ids, _logits, self._pages = self._family.fused_step(
-                    params, self.cfg, jnp.asarray(meta), self._pages,
-                    lmax=lmax, w=w, tq=tq,
-                    prev=(before.ids if before is not None else
-                          self._blank_ids(self._device_kind)))
-            except Exception:
-                # the failing dispatch may have CONSUMED the donated
-                # pool (donate_argnums): drop it at the dispatch site so
-                # _ensure_pool rebuilds from scratch, whatever the
-                # caller does (NL-JAX04) — and the prefix cache indexes
-                # the dropped pool's content, so it goes too, with the
-                # step in flight
-                self._pages = None
-                self._reset_prefix_cache()
-                self._drop_inflight()
-                raise
-        # the plan moves on at dispatch: counts, never tokens
-        self._step_no += 1
-        if before is not None:
-            self.stats.overlapped_steps += 1
-        for i, seq in enumerate(active):
-            seq.cache_len += 1
-            seq.src = i
-            seq.row_step = self._step_no
-        if chunk_seq is not None:
-            chunk_seq.prefill_pos += n_valid
-            chunk_seq.cache_len = chunk_seq.prefill_pos
-            chunk_seq.row_step = self._step_no
-            if final:
-                # its first token is entry ndec of this step's ids
-                chunk_seq.state = _DECODE
-                chunk_seq.src = ndec
-        return _Flight(self._step_no, ids, t0, shape, tq, active, chunk_seq,
-                       n_valid, final)
+        return meta, f, tq, active, chunk_seq, n_valid, final
 
-    def _read(self, flight: _Flight) -> None:
-        """Bring one dispatched step's ids to the host and act on them:
-        counters and spans of that step (dispatch to read), its tokens
-        delivered, the pages of sequences that ended released."""
+    def _read(self, flight: _Flight) -> float:
+        """Bring one dispatched step's ids to the host (the turn's read
+        stage: blocked while the device runs), then act on them (its
+        deliver stage).  Returns the two stages' seconds."""
+        stats = self.stats
+        with _tracer.stage("genserve.turn.read", stats,
+                           "read_wait_seconds") as wait:
+            # (Lmax,) ints cross to host, not the (Lmax, V) logits
+            # (~MBs/step at real vocabs) — a bounded 4B-per-row sync, the
+            # step's output
+            # nornlint: disable=NL-JAX06
+            picked = np.asarray(flight.ids).tolist()
+        with _tracer.stage("genserve.turn.deliver", stats,
+                           "deliver_seconds") as deliver:
+            self._deliver_step(flight, picked, wait.start + wait.seconds)
+            # the device buffer goes back here, under the stage's clock,
+            # not whenever the caller's frame lets go of the flight: giving
+            # it back is a call into the runtime (on the chip's host about
+            # a millisecond: PERF.md section 5), and it is the host's work
+            flight.ids = None
+        return wait.seconds + deliver.seconds
+
+    def _deliver_step(self, flight: _Flight, picked: list,
+                      t1: float) -> None:
+        """One read step's ids, on the host since ``t1``, acted on:
+        counters and spans of that step, its tokens delivered, the pages
+        of sequences that ended released."""
         lmax = self._lmax
         active, chunk_seq = flight.active, flight.chunk_seq
-        ndec, n_valid, t0 = len(active), flight.n_valid, flight.t0
-        t_wait = time.perf_counter()
-        # (Lmax,) ints cross to host, not the (Lmax, V) logits (~MBs/step
-        # at real vocabs) — a bounded 4B-per-row sync, the step's output
-        # nornlint: disable=NL-JAX06
-        picked = np.asarray(flight.ids).tolist()
-        t1 = time.perf_counter()
-        self.stats.read_wait_seconds += t1 - t_wait
+        ndec, n_valid = len(active), flight.n_valid
         self._read_no = flight.no
+        # the step's own interval: from its dispatch, or from when the
+        # step before it was read (the device began it then, wherever the
+        # device sets the pace), to its ids.  Dispatch to read, which with
+        # one step in flight is about two steps less a turn, is what the
+        # cost model still learns (record_execute: ROADMAP D7)
+        t0 = max(flight.t0, self._read_at)
+        self._read_at = t1
         dt = t1 - t0
         routing = dict(zip(self._step_counters, picked[lmax:]))
         for name, count in routing.items():
@@ -1623,7 +1764,8 @@ class GenerationEngine:
                 self.stats.attn_pages_held += held
                 _stats.ATTN_PAGES_WALKED.labels(kind.name).inc(walked)
                 _stats.ATTN_PAGES_HELD.labels(kind.name).inc(held)
-        _deviceprof.record_execute("genserve", "ragged", flight.shape, dt)
+        _deviceprof.record_execute("genserve", "ragged", flight.shape,
+                                   t1 - flight.t0)
         # the one dispatch served both phases: observability stays
         # per-phase (retroactive spans in each submitter's trace, the
         # QueryBatcher convention), so dashboards and the trace tests
@@ -1685,8 +1827,11 @@ class GenerationEngine:
         """Deliver one generated token and end the sequence where it
         ends."""
         seq.out.append(tok)
-        if seq.first_token_at == 0.0:
-            seq.first_token_at = time.monotonic()
+        if seq.since:  # the admission's first token
+            _tracer.add_stage(
+                "genserve.prefill_wait", seq.since, time.perf_counter(),
+                self.stats, "prefill_wait_seconds", parent=seq.trace_ctx)
+            seq.since = 0.0
         self.stats.generated_tokens += 1
         _stats.TOKENS.inc()
         seq.handle._deliver(tok)

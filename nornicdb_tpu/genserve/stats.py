@@ -9,6 +9,9 @@ imports this module for exactly that reason.
 
 from __future__ import annotations
 
+import threading
+import weakref
+
 from nornicdb_tpu.telemetry.metrics import REGISTRY as _REGISTRY
 
 # generation requests waiting for admission into the running batch; a
@@ -18,6 +21,8 @@ QUEUE_DEPTH = _REGISTRY.gauge(
     "nornicdb_genserve_queue_depth",
     "Generation requests queued for admission into the running batch",
 )
+# the next three are read off the live engines at scrape time (the collect
+# hook at the end of this file): no step sets them
 RUNNING_SEQS = _REGISTRY.gauge(
     "nornicdb_genserve_running_seqs",
     "Sequences currently resident in the continuous decode batch",
@@ -162,3 +167,83 @@ for _kind in ("full", "window"):
     PAGES_RELEASED.labels(_kind)
     ATTN_PAGES_WALKED.labels(_kind)
     ATTN_PAGES_HELD.labels(_kind)
+
+# the scheduler thread's turn (docs/observability.md "The scheduler's
+# turn"): cumulative seconds of each phase of the cycle, the
+# ``genserve.turn.*`` stages' own durations.  read = blocked on the device;
+# the other four are the host's work, and where their rate() nears a
+# step's length the host sets the pace
+TURN_PHASE = _REGISTRY.counter(
+    "nornicdb_genserve_turn_phase_seconds_total",
+    "Seconds of the scheduler thread's cycle, by phase (admit, plan, "
+    "dispatch, read = blocked on the device, deliver)",
+    labels=("phase",),
+)
+# runnable and not running: a turn less its blocked read less the thread's
+# own CPU seconds.  High beside a short plan/deliver = the thread waits for
+# the GIL behind the HTTP threads (or for a core), not for its own Python
+HOST_OFFCPU = _REGISTRY.counter(
+    "nornicdb_genserve_host_offcpu_seconds_total",
+    "Seconds of the scheduler's turns, blocked reads apart, in which its "
+    "thread was not on a CPU",
+)
+# rate() against the steps is the share of steps the chip waited for
+LATE_DISPATCHES = _REGISTRY.counter(
+    "nornicdb_genserve_late_dispatches_total",
+    "Fused steps dispatched after the step before them had already "
+    "finished on the device (the chip waited for the host)",
+)
+# from a token's delivery on the scheduler thread to its consumer coming
+# back for the next (decoded, serialised, written): summed over the tokens
+# of the streams that have ended
+STREAM_LAG = _REGISTRY.counter(
+    "nornicdb_genserve_stream_lag_seconds_total",
+    "Seconds streamed tokens took from the scheduler to their consumer's "
+    "next read, summed over the streams that ended",
+)
+# GenStats field -> the cell it is rendered into
+_FROM_STATS = {
+    "admit_seconds": TURN_PHASE.labels("admit"),
+    "plan_seconds": TURN_PHASE.labels("plan"),
+    "dispatch_seconds": TURN_PHASE.labels("dispatch"),
+    "read_wait_seconds": TURN_PHASE.labels("read"),
+    "deliver_seconds": TURN_PHASE.labels("deliver"),
+    "host_offcpu_seconds": HOST_OFFCPU.labels(),
+    "late_dispatches": LATE_DISPATCHES.labels(),
+    "stream_lag_seconds": STREAM_LAG.labels(),
+}
+# engine -> what the last scrape read of its stats (weak: an engine that is
+# gone leaves the totals where they stand)
+_ENGINES: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_ENGINES_LOCK = threading.Lock()
+
+
+def track(engine) -> None:
+    """A GenerationEngine whose ``stats`` and pool the scrape reads."""
+    with _ENGINES_LOCK:
+        _ENGINES[engine] = {}
+
+
+def _collect() -> None:
+    """Scrape time: the families above move by what each engine's
+    ``GenStats`` moved since the last scrape, and the three pool gauges
+    are summed over the engines that are serving.  Nothing on the
+    scheduler's path touches a cell of these."""
+    with _ENGINES_LOCK:
+        engines = list(_ENGINES.items())
+    running = used = usable = prefix = 0
+    for engine, seen in engines:
+        for field, cell in _FROM_STATS.items():
+            now = getattr(engine.stats, field)
+            cell.inc(now - seen.get(field, 0))
+            seen[field] = now
+        if engine.running:
+            seqs, taken, pages, cached = engine._pool_gauges()
+            running, used = running + seqs, used + taken
+            usable, prefix = usable + pages, prefix + cached
+    RUNNING_SEQS.set(running)
+    PAGE_POOL_UTIL.set(used / max(1, usable))
+    PREFIX_PAGES.set(prefix)
+
+
+_REGISTRY.collect_hook("genserve", _collect)

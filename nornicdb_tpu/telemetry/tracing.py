@@ -251,8 +251,12 @@ class Stage:
         return None if self._span is None else self._span.span_id
 
     def set_attr(self, key: str, value: Any) -> None:
+        """An attribute known only inside the stage: onto the span and
+        the annotation, whichever of them there is."""
         if self._span is not None:
             self._span.set_attr(key, value)
+        if self._ann is not None:
+            self._ann.set_metadata(**{key: value})
 
     def __enter__(self) -> "Stage":
         tracer = self._tracer

@@ -14,6 +14,8 @@ served token list is held to.
   mid-decode) the two lists are equal by construction.
 """
 
+import time
+
 import jax
 import numpy as np
 import pytest
@@ -74,6 +76,21 @@ def engine(manager=None, tokenizer=TOK, model=(PARAMS, CFG), **cfg_kw):
         manager=manager or mgr())
     LIVE.append(eng)
     return eng
+
+
+def settle(eng, timeout: float = 30.0) -> None:
+    """Until nothing is resident and no step is unread: the step after a
+    stream's last is read by the scheduler's next turn."""
+    end = time.monotonic() + timeout
+    while eng._running or eng._inflight is not None or eng._zombies:
+        assert time.monotonic() < end, "the engine did not come to rest"
+        time.sleep(0.005)
+    # and the turn that read it has run to its end (its counters are all
+    # in, its annotation is closed): no further turn begins while idle
+    turns = -1
+    while turns != eng.stats.turns:
+        turns = eng.stats.turns
+        time.sleep(0.02)
 
 
 def prompt(n: int, seed: int = 0) -> list[int]:

@@ -12,7 +12,6 @@ the list an engine of the same geometry gives the prompt alone.
 
 import dataclasses
 import functools
-import time
 import types
 
 import jax
@@ -25,6 +24,7 @@ from genserve_harness import (  # noqa: F401  (the fixture is autouse)
     PARAMS,
     alone as _alone,
     engine as _engine,
+    settle,
     stop_what_the_test_started,
 )
 from nornicdb_tpu.config import GenServeConfig
@@ -76,15 +76,6 @@ def engine(kit, eos_id=None, **cfg_kw):
     tokenizer = None if eos_id is None else \
         types.SimpleNamespace(eos_id=eos_id)
     return _engine(tokenizer=tokenizer, model=kit.model, **cfg_kw)
-
-
-def settle(eng, timeout: float = 30.0) -> None:
-    """Until nothing is resident and no step is unread: the step after a
-    stream's last is read by the scheduler's next turn."""
-    end = time.monotonic() + timeout
-    while eng._running or eng._inflight is not None or eng._zombies:
-        assert time.monotonic() < end, "the engine did not come to rest"
-        time.sleep(0.005)
 
 
 def assert_pool_whole(eng) -> None:
