@@ -191,6 +191,11 @@ class GenStats:
     # slots the same lanes' whole tables hold
     attn_slots_walked: int = 0
     attn_slots_table: int = 0
+    # pages of the run that every live decode lane's table begins with,
+    # gathered ONCE a walk for all of them (models/kv_walk.py; ONE layer of
+    # the kind without a horizon, summed over steps): over ``decode_steps``
+    # it is how much of a step's lanes the prefix cache made one
+    shared_run_pages: int = 0
     # page kinds (a family with window layers beside full ones:
     # models/cohere2_moe.py).  From the step's int vector, a pair a kind:
     # the pages its attention blocks gathered and scored, summed over
@@ -1757,6 +1762,7 @@ class GenerationEngine:
                 routing.get("zero_assignments", 0))
             _stats.ATTN_SLOTS_WALKED.inc(routing.get("attn_slots_walked", 0))
             _stats.ATTN_SLOTS_TABLE.inc(routing.get("attn_slots_table", 0))
+            _stats.SHARED_RUN_PAGES.inc(routing.get("shared_run_pages", 0))
             for kind in self._kinds if self._by_kind else ():
                 walked = routing.get(f"{kind.name}_pages_walked", 0)
                 held = routing.get(f"{kind.name}_pages_held", 0)

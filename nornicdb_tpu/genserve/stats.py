@@ -137,6 +137,16 @@ ATTN_SLOTS_TABLE = _REGISTRY.counter(
     "Cache slots the same lanes' whole page tables hold (what a step that "
     "gathered every page of every lane would have walked)",
 )
+# a run of pages that every live decode lane's table begins with (lanes
+# seated behind one cached prefix hold the same physical pages) is gathered
+# once a walk for all of them (models/kv_walk.py; 0 forever for a family
+# with a walk of its own): rate() against decode steps is the length, in
+# pages, of what a step's lanes have in common
+SHARED_RUN_PAGES = _REGISTRY.counter(
+    "nornicdb_genserve_shared_run_pages_total",
+    "KV pages of the run every live decode lane's table begins with, "
+    "gathered once a walk for all lanes (one layer, summed over fused steps)",
+)
 # page kinds (a decoder family whose layers keep more than one kind of cache
 # state, window layers beside full ones; 0 forever otherwise).  Pages of a
 # kind with a horizon that lanes let go WHILE THEY LIVED, as their windows

@@ -402,9 +402,13 @@ def parallel_moe_fused_step(params, cfg: Cohere2MoeConfig, meta: jax.Array,
     walk = {name: kr.walk * len(layers)
             for kr, (name, _, layers) in zip(rows.kinds, kinds, strict=True)}
     none = jnp.zeros((2,), jnp.int32)                 # a kind no layer is of
+    # the run the decode block gathered once for all its lanes: the kind
+    # without a horizon's, ONE layer (a window kind never shares)
+    run = sum(kr.shared_pages for kr in rows.kinds)
     ints = jnp.concatenate([jnp.argmax(logits, axis=-1).astype(jnp.int32),
                             counts, routed[None],
-                            *(walk.get(name, none) for name in WALK_COUNTERS)])
+                            *(walk.get(name, none) for name in WALK_COUNTERS),
+                            run[None]])
     return ints, logits, tuple(pages)
 
 
@@ -413,4 +417,5 @@ def parallel_moe_fused_step(params, cfg: Cohere2MoeConfig, meta: jax.Array,
 fused_step = parallel_moe_fused_step
 # what ``ints`` carries after the ids (nornicdb_tpu/ragged.py)
 STEP_COUNTERS = ROUTING_COUNTERS + tuple(
-    name for pair in WALK_COUNTERS.values() for name in pair)
+    name for pair in WALK_COUNTERS.values() for name in pair) + (
+    "shared_run_pages",)

@@ -29,6 +29,16 @@ a family:
   (``layers.heads_to_rows`` / ``rows_to_heads``, what
   ``layers.grouped_attention`` does and why: PR 31).
 * **A block is sized by the page's bytes** (:func:`block_pages`).
+* **A run every live lane shares is gathered once.**  Lanes seated behind
+  one prefix hold the SAME physical pages in their tables' first columns
+  (the prefix cache hands out page numbers, nothing is copied).
+  :func:`plan_step` reads that run off the step's own tables, in whole
+  blocks; the decode block's walk gathers those blocks ONE time, from the
+  first live lane's row, and scores them for every lane's query at once,
+  then walks each lane's private blocks from the running softmax the
+  shared ones left.  No run (lanes that share nothing, a kind with a
+  horizon, whose lanes' tables start at unlike pages) is zero turns of
+  the first loop: the per-lane walk, to the letter.
 
 A family with page kinds (``nornicdb_tpu/ragged.py``) has a pool, a table
 and a horizon a kind; one without has the one kind ``full``: ``base`` 0,
@@ -74,10 +84,14 @@ class KindRows(NamedTuple):
     dec_tables: jax.Array     # (Lmax-1, W') W' = W up to whole blocks
     dec_base: jax.Array       # (Lmax-1,) the logical page of column 0
     dec_span: tuple           # (first block, behind the last) of the walk
+    dec_shared: tuple | None  # ((W',) the first live lane's row, its leading
+    #                           blocks that every live lane holds too); None
+    #                           for a kind with a horizon
     chunk_table: jax.Array | None   # (1, W')
     chunk_base: jax.Array | None    # (1,)
     chunk_span: tuple | None
-    walk: jax.Array           # (2,) pages walked, pages held, ONE layer
+    walk: jax.Array           # (2,) pages gathered, pages held, ONE layer
+    shared_pages: jax.Array   # () of them, the decode block's shared run
 
 
 class StepRows(NamedTuple):
@@ -115,6 +129,20 @@ def _span(pos, base, horizon, ps: int, bp: int, n_blocks: int):
         lane_live, jnp.where(live, pos, -1).max(axis=1) // ps
         - jnp.where(live, first, 2 ** 30).min(axis=1) // ps + 1, 0).sum()
     return (lo, hi), (hi - lo) * bp, held
+
+
+def _shared_run(tables, base, live, bp: int, hi):
+    """The run of pages that every ``live`` (L,) lane of ``tables`` (L, W')
+    begins with: (the first live lane's row (W',), its leading blocks of
+    ``bp`` pages in which every live lane holds the same page numbers, at
+    most ``hi``, the walk's end).  Lanes that are not live do not vote; with
+    ONE live lane every column agrees and the run ends where the walk does;
+    with none it is 0."""
+    first = jnp.argmax(live)
+    same = (tables == tables[first]) & (base == base[first])[:, None]
+    common = jnp.cumprod((same | ~live[:, None]).all(axis=0)).sum()
+    return tables[first], jnp.where(live.any(),
+                                    jnp.minimum(common // bp, hi), 0)
 
 
 def plan_step(meta: jax.Array, pools: tuple, horizons: tuple, *, lmax: int,
@@ -163,7 +191,13 @@ def plan_step(meta: jax.Array, pools: tuple, horizons: tuple, *, lmax: int,
         table = jnp.pad(table, ((0, 0), (0, n_blocks * bp - wk)))
         dec_span, walked, held = _span(pos_dec, base[:ldec], horizon, ps, bp,
                                        n_blocks)
-        walked = walked * ldec
+        walked, dec_shared, run = walked * ldec, None, jnp.int32(0)
+        if horizon is None:
+            # a shared block is gathered once, not once a lane
+            dec_shared = _shared_run(table[:ldec], base[:ldec],
+                                     pos_dec[:, 0] >= 0, bp, dec_span[1])
+            run = (dec_shared[1] * bp).astype(jnp.int32)
+            walked = walked - run * (ldec - 1)
         chunk_table = chunk_base = chunk_span = None
         if tq > 1:
             chunk_table, chunk_base = table[lmax - 2][None], \
@@ -172,9 +206,9 @@ def plan_step(meta: jax.Array, pools: tuple, horizons: tuple, *, lmax: int,
                                              ps, bp, n_blocks)
             walked, held = walked + more, held + held_c
         kinds.append(KindRows(
-            phys, table[:ldec], base[:ldec], dec_span, chunk_table,
+            phys, table[:ldec], base[:ldec], dec_span, dec_shared, chunk_table,
             chunk_base, chunk_span,
-            jnp.stack([walked, held]).astype(jnp.int32)))
+            jnp.stack([walked, held]).astype(jnp.int32), run))
     return StepRows(tokens, logit_rows, valid, pos, pos % ps, dec_lane,
                     pos_dec, is_chunk, chunk_row, slot_c, pos_chk,
                     tuple(kinds))
@@ -182,7 +216,7 @@ def plan_step(meta: jax.Array, pools: tuple, horizons: tuple, *, lmax: int,
 
 def attend_pages(kv_heads: int, q: jax.Array, pool: jax.Array, at,
                  tables: jax.Array, base: jax.Array, pos: jax.Array,
-                 span: tuple, horizon) -> jax.Array:
+                 span: tuple, horizon, shared=None) -> jax.Array:
     """Grouped-query attention over what is live, and inside the horizon, of
     the lanes' pages in pool layer ``at`` (an int or a traced scalar): q
     (L, T, heads, d) against blocks ``span`` = (first, behind the last) of
@@ -192,27 +226,38 @@ def attend_pages(kv_heads: int, q: jax.Array, pool: jax.Array, at,
     (-1: none; its output is garbage and never read) -> (L, T, heads x d).
     One turn gathers a block of every lane's K and V pages by flat page
     number, scores it in f32 and folds it into a running softmax (m, l,
-    acc: f32); nothing outside ``span`` is gathered.  bf16 operands stay
-    bf16, ``p`` is cast to V's dtype.  The pool is only read."""
+    acc: f32); nothing outside ``span`` is gathered.  ``shared`` = (row
+    (W',), blocks): the lanes' tables all begin with ``blocks`` blocks of
+    ``row`` (:func:`_shared_run`; their bases agree), and those are
+    gathered ONCE, without a lane axis, and scored for all the lanes'
+    queries in the same turn and the same state; the per-lane turns start
+    behind them.  bf16 operands stay bf16, ``p`` is cast to V's dtype.  The
+    pool is only read."""
     lanes, t, heads, d = q.shape
     g = kv_heads
     layers, _, num_pages, ps, row = pool.shape
     bp = block_pages(pool, tables.shape[1])
     bs = bp * ps
     view = pool.reshape(layers * 2 * num_pages, ps, row)   # no copy
+
+    def by(kv):
+        """A turn's K or V is (L, bs, row), a block a lane, or (bs, row),
+        ONE block for all the lanes."""
+        return "l" if kv.ndim == 3 else ""
+
     if d % 128 == 0:
         # a head fills whole lane tiles: a K/V group at a time
         qx = q.reshape(lanes, t, g, heads // g, d)
         lead, width = (lanes, g, heads // g), d
 
         def scores(k):
-            return jnp.einsum("ltgrd,lsgd->lgrts", qx,
-                              k.reshape(lanes, bs, g, d),
+            return jnp.einsum(f"ltgrd,{by(k)}sgd->lgrts", qx,
+                              k.reshape(*k.shape[:-1], g, d),
                               preferred_element_type=jnp.float32)
 
         def summed(p, v):
-            return jnp.einsum("lgrts,lsgd->lgrtd", p,
-                              v.reshape(lanes, bs, g, d),
+            return jnp.einsum(f"lgrts,{by(v)}sgd->lgrtd", p,
+                              v.reshape(*v.shape[:-1], g, d),
                               preferred_element_type=jnp.float32)
 
         def by_query(o):                              # (L, g, r, T, d)
@@ -223,11 +268,11 @@ def attend_pages(kv_heads: int, q: jax.Array, pool: jax.Array, at,
         lead, width = (lanes, heads), row
 
         def scores(k):
-            return jnp.einsum("lhtc,lsc->lhts", qx, k,
+            return jnp.einsum(f"lhtc,{by(k)}sc->lhts", qx, k,
                               preferred_element_type=jnp.float32)
 
         def summed(p, v):
-            return jnp.einsum("lhts,lsc->lhtc", p, v,
+            return jnp.einsum(f"lhts,{by(v)}sc->lhtc", p, v,
                               preferred_element_type=jnp.float32)
 
         def by_query(o):                              # (L, heads, T, row)
@@ -239,11 +284,12 @@ def attend_pages(kv_heads: int, q: jax.Array, pool: jax.Array, at,
         jnp.int32, (lanes, bs), 1)                    # (L, bs) at block 0
     last = pos[:, :, None]                            # (L, T, 1)
 
-    def turn(b, state):
+    def turn(b, state, tables=tables):
         m, total, acc = state
-        table = jax.lax.dynamic_slice_in_dim(tables, b * bp, bp, axis=1)
-        k = view[(at * 2) * num_pages + table].reshape(lanes, bs, row)
-        v = view[(at * 2 + 1) * num_pages + table].reshape(lanes, bs, row)
+        table = jax.lax.dynamic_slice_in_dim(tables, b * bp, bp, axis=-1)
+        block = table.shape[:-1] + (bs, row)          # (L, bs, row) / (bs, row)
+        k = view[(at * 2) * num_pages + table].reshape(block)
+        v = view[(at * 2 + 1) * num_pages + table].reshape(block)
         here = (slot + b * bs)[:, None, :]            # (L, 1, bs)
         seen = here <= last
         if horizon is not None:
@@ -260,10 +306,15 @@ def attend_pages(kv_heads: int, q: jax.Array, pool: jax.Array, at,
     # garbage with weight 1; the first block that holds a key it sees (its
     # own, at the latest) scales that by exp(-1e30 - m) = 0; a masked block
     # AFTER that adds exp(-1e30 - m) = 0
-    start = (jnp.full(lead + (t,), -1e30, jnp.float32),
+    state = (jnp.full(lead + (t,), -1e30, jnp.float32),
              jnp.zeros(lead + (t,), jnp.float32),
              jnp.zeros(lead + (t, width), jnp.float32))
-    _, total, acc = jax.lax.fori_loop(span[0], span[1], turn, start)
+    if shared is not None:
+        one, blocks = shared
+        state = jax.lax.fori_loop(
+            0, blocks, functools.partial(turn, tables=one), state)
+        span = (jnp.maximum(span[0], blocks), span[1])
+    _, total, acc = jax.lax.fori_loop(span[0], span[1], turn, state)
     return by_query(acc / total[..., None]).reshape(lanes, t, heads * d)
 
 
@@ -285,7 +336,7 @@ def attend_blocks(kv_heads: int, rows: StepRows, kind: KindRows,
         q_dec = q_dec.at[rows.dec_lane, 0].set(q)
         o_dec = attend_pages(kv_heads, q_dec, pool, at, kind.dec_tables,
                              kind.dec_base, rows.pos_dec, kind.dec_span,
-                             horizon)
+                             horizon, kind.dec_shared)
         o = o_dec[rows.dec_lane, 0]                   # (F, heads x d)
         if rows.chunk_row is not None:
             tq = rows.pos_chk.shape[1]

@@ -237,9 +237,11 @@ def _apply_rope_rows(x: jax.Array, angles: jax.Array) -> jax.Array:
 
 # what the step appends to its Lmax greedy ids (``GenStats`` fields of the
 # same names, ``mla.WALK_COUNTERS``' meaning): the cache slots its attention
-# blocks gathered and scored, and the slots those lanes' whole tables hold,
-# each summed over lanes and layers
-STEP_COUNTERS = ("attn_slots_walked", "attn_slots_table")
+# blocks gathered and scored (a block every live lane shares once), and the
+# slots those lanes' whole tables hold, each summed over lanes and layers;
+# and the pages of the run that the decode block gathered once for all its
+# lanes, ONE layer (``kv_walk``: 0 where no two lanes share a first block)
+STEP_COUNTERS = ("attn_slots_walked", "attn_slots_table", "shared_run_pages")
 
 
 @functools.partial(
@@ -298,7 +300,7 @@ def ragged_fused_step(params, cfg: QwenConfig, meta: jax.Array,
     table = lanes * kind.dec_tables.shape[1]
     walk = jnp.stack([kind.walk[0], jnp.int32(table)]) * (ps * cfg.layers)
     ints = jnp.concatenate([jnp.argmax(logits, axis=-1).astype(jnp.int32),
-                            walk])
+                            walk, kind.shared_pages[None]])
     return ints, logits, pages
 
 

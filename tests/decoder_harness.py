@@ -130,9 +130,10 @@ class Pool:
     where a one-kind family's is a table)."""
 
     def __init__(self, family, cfg, params, pages=40,
-                 width: int = WIDTH, chunk: int = 16):
+                 width: int = WIDTH, chunk: int = 16, lmax: int = LMAX):
         self.family, self.cfg, self.params = family, cfg, params
         self.width = width  # pages of a lane's table
+        self.lmax = lmax    # decode lanes + the chunk lane + the dump lane
         self.kinds = tuple(family.page_kinds(cfg)) \
             if hasattr(family, "page_kinds") else None
         if self.kinds:
@@ -153,16 +154,19 @@ class Pool:
         self.counters = tuple(getattr(family, "STEP_COUNTERS", ()))
         self.counts = np.zeros(len(self.counters), np.int64)
 
-    def step(self, decode=(), chunk=None, prev=None):
+    def step(self, decode=(), chunk=None, prev=None, lanes=None):
         """decode: [(token, position, table)]; chunk: (tokens, start,
         table); prev: the int vector of the step before (``self.ints``),
-        where a decode row whose token is ``-(src + 1)`` finds it.  Returns
-        the logits of each decode row, then of the chunk's last row."""
+        where a decode row whose token is ``-(src + 1)`` finds it; lanes:
+        the decode lane each decode row sits in (0, 1, ... where not
+        given).  Returns the logits of each decode row, then of the chunk's
+        last row."""
+        lmax = self.lmax
         n_valid = len(chunk[0]) if chunk else 0
         tq = round_up_pow2(n_valid, 16) if chunk else 1
         f = round_up_pow2(len(decode) + n_valid, 8)
         meta, (toks, lane, lpos, pos, rows, tables) = blank_step(
-            LMAX, self.width, f)
+            lmax, self.width, f)
         def place(i, table, first, last):
             if not self.kinds:
                 tables[i] = table
@@ -172,29 +176,30 @@ class Pool:
                 kind.base[i], kind.pages[i, :len(held)] = base, held
 
         for i, (tok, at, table) in enumerate(decode):
-            toks[i], lane[i], pos[i], rows[i] = tok, i, at, i
-            place(i, table, at, at)
+            seat = i if lanes is None else lanes[i]
+            toks[i], lane[i], pos[i], rows[i] = tok, seat, at, i
+            place(seat, table, at, at)
         if chunk:
             ids, start, table = chunk
             for j, tok in enumerate(ids):
                 at = len(decode) + j
-                toks[at], lane[at], lpos[at] = tok, LMAX - 2, j
+                toks[at], lane[at], lpos[at] = tok, lmax - 2, j
                 pos[at] = start + j
-            place(LMAX - 2, table, start, start + n_valid - 1)
+            place(lmax - 2, table, start, start + n_valid - 1)
             rows[len(decode)] = len(decode) + n_valid - 1
         donated = self.pool
         ints, logits, self.pool = self.family.fused_step(
             self.params, self.cfg, jnp.asarray(meta), donated,
-            lmax=LMAX, w=self.width, tq=tq,
+            lmax=lmax, w=self.width, tq=tq,
             **({} if prev is None else {"prev": jnp.asarray(prev)}))
         assert all(a.is_deleted() for a in jax.tree.leaves(donated)), \
             "the step copied the pool"
         self.ints = ints = np.asarray(ints)
         # the greedy ids, then the routing counts of a family that routes
-        assert ints.shape[0] - LMAX == len(self.counters)  # as it declares
-        assert (ints[:LMAX] == np.asarray(logits).argmax(-1)).all()
-        if ints.shape[0] > LMAX:
-            self.counts += ints[LMAX:]
+        assert ints.shape[0] - lmax == len(self.counters)  # as it declares
+        assert (ints[:lmax] == np.asarray(logits).argmax(-1)).all()
+        if ints.shape[0] > lmax:
+            self.counts += ints[lmax:]
         return np.asarray(logits)[:len(decode) + bool(chunk)]
 
     def serve(self, ids, table, start=0, steps=6, chunk=16):

@@ -253,7 +253,11 @@ def test_ragged_fused_step_qwen_widths(one_chip, case):
     every step), no f32 array of the gathered cache's extent exists (the
     copy ``repeat_kv`` made: 528 MB a layer), each attention block is a
     ``while`` that gathers a block of pages by FLAT page number (every
-    gather of pages has ``start_index_map={0}``), no layer's K or V is
+    gather of pages has ``start_index_map={0}``; the decode block's walk is
+    two: the run of blocks every live lane's table begins with gathered
+    WITHOUT a lane axis, ``bf16[128,16,128]``, from the first live lane's
+    row, then the per-lane turns, ``bf16[17,128,16,128]``: PR 40), no
+    layer's K or V is
     sliced out of the pool (``bf16[8193,16,128]``: 48 copies of 33.6 MB a
     step until PR 38) and no lane's whole table gathered, and the bytes
     accessed stay under what this program read when it was written, 0.770
@@ -278,7 +282,7 @@ def test_ragged_fused_step_qwen_widths(one_chip, case):
         _sds(meta.shape, jnp.int32, one_chip),
         _sds(pool, jnp.bfloat16, one_chip),
         lmax=lmax, w=w, tq=tq,
-        # the served variant: the ids and the step's two counts
+        # the served variant: the ids and the step's three counts
         prev=_sds((lmax + len(qwen2.STEP_COUNTERS),), jnp.int32, one_chip),
     ).compile()
     text = compiled.as_text()
@@ -296,6 +300,13 @@ def test_ragged_fused_step_qwen_widths(one_chip, case):
     _pool_gathers_are_flat(text, 16, 128)
     if bytes_ceiling is not None:
         assert " while(" in text
+        # a decode-only step has no one-lane chunk block: its gather of a
+        # block WITHOUT a lane axis is the shared run's, beside the
+        # per-lane one (in the chunk step the chunk block's reads alike)
+        gathers = set(re.findall(r"= (bf16\[[0-9,]+\])\S* gather\(", text))
+        assert "bf16[128,16,128]" in gathers, gathers
+        assert gathers & {"bf16[%d,128,16,128]" % (lmax - 1),
+                          "bf16[%d,16,128]" % ((lmax - 1) * 128)}, gathers
         assert "bf16[%d,16,128]" % pages not in text     # a layer's K or V
         for whole in ("[%d,16,128]" % (lmax * w), "[%d,%d,16,128]" % (lmax, w),
                       "[%d,16,128]" % ((lmax - 1) * w),
@@ -429,7 +440,7 @@ def test_parallel_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
         _sds(meta.shape, jnp.int32, one_chip),
         tuple(_sds(p, jnp.bfloat16, one_chip) for p in pools),
         lmax=lmax, w=w, tq=tq,
-        # the served variant: the ids and the family's eight counts
+        # the served variant: the ids and the family's nine counts
         prev=_sds((lmax + len(cm.STEP_COUNTERS),), jnp.int32, one_chip),
     ).compile()
     mem = compiled.memory_analysis()
@@ -450,4 +461,7 @@ def test_parallel_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     assert block == 32  # Command A+'s 32 KB pages keep their measured 32
     assert f"bf16[{17 * block},16,1024]" in text \
         or f"bf16[17,{block},16,1024]" in text
+    # and the full kind's shared run, a block WITHOUT a lane axis (in a step
+    # with a chunk the one-lane chunk block's gathers read alike)
+    assert re.search(r"bf16\[%d,16,1024\]\S* gather\(" % block, text)
     _pool_gathers_are_flat(text, page, 1024)

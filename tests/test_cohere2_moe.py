@@ -226,7 +226,7 @@ def test_full_layers_ignore_positions_and_window_layers_do_not():
     out, rows = pool.serve(ids, Lane(pool), steps=4)
     want = harness.reference_rows(ref.forward, params, nope, ids, out)
     assert largest(rows, want) < F32_TOL
-    assert pool.counts[-2:].tolist() == [0, 0]  # no window layer walked
+    assert pool.counts[-3:-1].tolist() == [0, 0]  # no window layer walked
 
 
 # ----------------------- (d) chunked prefill + decode through both pools
@@ -275,12 +275,15 @@ def test_both_pools_serve_the_reference_past_three_windows(cfg, tol, measure):
 
 def test_the_walk_stays_inside_the_window_and_the_live_length():
     """What the step's attention walked, by kind (its int vector's last
-    four): the window layers no more than a window's blocks whatever the
-    context, the full layer as far as the longest lane; and ``held`` is the
-    pages a live query may see."""
+    five): the window layers no more than a window's blocks whatever the
+    context, the full layer as far as the longest lane, ONCE where one lane
+    is live (the run it "shares" is its whole walk: ``shared_run_pages``,
+    which counts the full kind only: lanes' window tables start at unlike
+    pages and never share); and ``held`` is the pages a live query may
+    see."""
     assert cm.STEP_COUNTERS == ROUTING_COUNTERS + (
         "full_pages_walked", "full_pages_held", "window_pages_walked",
-        "window_pages_held")
+        "window_pages_held", "shared_run_pages")
     params = make_params(F32, 3)
     pool = Pool(cm, F32, params, pages=24)
     lane = Lane(pool)
@@ -291,14 +294,54 @@ def test_the_walk_stays_inside_the_window_and_the_live_length():
     pool.step(decode=[(5, 96, lane)])
     counts = dict(zip(pool.counters, pool.counts.tolist()))
     ldec = LMAX - 1
-    # the full kind's 8-page table is one block; its one layer
-    assert counts["full_pages_walked"] == 8 * ldec
+    # the full kind's 8-page table is one block; its one layer; one live
+    # lane: gathered once
+    assert counts["full_pages_walked"] == counts["shared_run_pages"] == 8
     assert counts["full_pages_held"] == 96 // PAGE + 1
     # the window kind's table is 4 pages wide: one block, three layers
     assert counts["window_pages_walked"] == 4 * ldec * 3
     seen = 96 // PAGE - first_page(96, WINDOW, PAGE) + 1
     assert seen == 3 and counts["window_pages_held"] == seen * 3
     assert counts["routed_rows"] == 4  # one row, four expert layers
+
+
+@pytest.mark.parametrize("common,shared", [(127, 0), (128, 1)],
+                         ids=["a-page-short-of-a-block", "a-block"])
+def test_lanes_behind_one_prefix_share_the_full_kinds_run(common, shared):
+    """Two lanes seated behind one prefix of ``common`` pages (lane b finds
+    lane a's page NUMBERS in both kinds, as a prefix hit does, and each then
+    prefills a tail of its own), full tables of two blocks of 128 pages:
+    the full layer's decode block gathers the prefix's whole blocks ONCE
+    (``shared_run_pages``, and ``full_pages_walked`` counts them once) and
+    walks the rest once a lane; the window layers' count is the per-lane
+    walk's, whatever the tables hold (a kind with a horizon never shares);
+    both rows read the reference's logits.  One page short of a block
+    nothing is shared and the full kind's count is the per-lane walk's too."""
+    block, ldec = 128, LMAX - 1
+    params = make_params(F32, 9)
+    pool = Pool(cm, F32, params, pages=(2 * block + 40, 120),
+                width=block + 3, chunk=256)
+    assert pool.width == (block + 3, pages_for(WINDOW + 256, PAGE) + 1)
+    prefix = tokens(21, common * PAGE)
+    a, b = Lane(pool), Lane(pool)
+    pool.serve(prefix, a, steps=1, chunk=256)
+    b.base, b.pages = list(a.base), [list(held) for held in a.pages]
+    ids = [prefix + tokens(22, 2060 - len(prefix)),
+           prefix + tokens(23, 2071 - len(prefix))]
+    for lane, seq in zip((a, b), ids):
+        pool.serve(seq, lane, start=len(prefix), steps=1, chunk=256)
+    assert a.pages[0][:common] == b.pages[0][:common]
+    assert not set(a.pages[0][common:]) & set(b.pages[0][common:])
+    pool.counts[:] = 0
+    got = pool.step(decode=[(5, len(ids[0]), a), (6, len(ids[1]), b)])
+    for row, seq, tok in zip(got, ids, (5, 6)):
+        assert largest(row, ref.forward(params, F32, seq + [tok])[-1]) \
+            < F32_TOL
+    counts = dict(zip(pool.counters, pool.counts.tolist()))
+    assert counts["shared_run_pages"] == shared * block
+    assert counts["full_pages_walked"] == \
+        (shared + (2 - shared) * ldec) * block
+    assert counts["window_pages_walked"] == pool.width[1] * ldec * 3
 
 
 def _broken(monkeypatch, fault: str):

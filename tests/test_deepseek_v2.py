@@ -364,8 +364,8 @@ def test_the_step_carries_its_scopes_and_its_own_module_name():
 
 # ------------------------------------ (i) Qwen's lowered step unchanged
 @pytest.mark.parametrize("tq,sha", [
-    (16, "ef5d7c8530bedd2fcada9dc0988327b50a955fa6b47ffcd630d378feee1ea112"),
-    (1, "4371d4930cc5000069fbf22b7faf74d51bd2de239db5c124fd0fd65a7e415a97")])
+    (16, "64847afe19ad45be9aacac6b961e2fc9f9f1418809994efb224a9c3cc26117a6"),
+    (1, "68e064b882241adfa6be0ed3a66a8ffff83323c99dd91247948bdaf8d64ffb5f")])
 def test_qwens_lowered_step_is_what_it_was_before_the_family_seam(tq, sha):
     """The lowered text of ``ragged_fused_step`` for one step class is what
     it was when last measured: a PR that does not mean to change Qwen's
@@ -384,7 +384,12 @@ def test_qwens_lowered_step_is_what_it_was_before_the_family_seam(tq, sha):
     ``lmax - 1`` lanes, and the int vector ends with the walk's two counts
     (``qwen2.STEP_COUNTERS``), so ``prev`` is ``lmax + 2`` wide; the two
     blocks are one jitted function with the layer's index a value, lowered
-    once and called a layer (``kv_walk.attend_blocks``).  At this
+    once and called a layer (``kv_walk.attend_blocks``); and PR 40: the
+    decode block's walk is two ``while``s, the run of blocks that every
+    live lane's table begins with gathered once from the first live lane's
+    row and scored for all lanes, then the per-lane turns behind it, and
+    the int vector ends with ``shared_run_pages`` (``prev`` is ``lmax + 3``
+    wide).  At this
     case's 8-page tables a block is the whole table, so the digests do not
     move with ``kv_walk``'s block rule."""
     cfg = qwen2.QWEN_SMALL

@@ -234,23 +234,33 @@ class TestEngineScheduling:
         assert len(programs) <= 12
 
     def test_a_family_with_a_walk_and_no_experts_moves_its_own_counters(self):
-        """Qwen's step appends the walk's two counts and no routing count
+        """Qwen's step appends the walk's three counts and no routing count
         (``qwen2.STEP_COUNTERS``): the engine reads them off the step's int
-        vector into ``GenStats`` and the two Prometheus counters, and asks
-        the step for no name it does not declare.  The engine's 16-page
-        tables are narrower than a block: every step walks all of them."""
+        vector into ``GenStats`` and the three Prometheus counters, and
+        asks the step for no name it does not declare.  The engine's
+        16-page tables are narrower than a block: every step walks all of
+        them, the chunk block for its lane and the decode block, where the
+        ONE request is its one live lane, once for all (a run of a whole
+        table a decode step, the lane "sharing" its whole walk), so what is
+        gathered is less than the tables hold."""
         from nornicdb_tpu.genserve import stats as gstats
 
-        assert qwen2.STEP_COUNTERS == ("attn_slots_walked", "attn_slots_table")
+        assert qwen2.STEP_COUNTERS == ("attn_slots_walked", "attn_slots_table",
+                                       "shared_run_pages")
         before = gstats.ATTN_SLOTS_WALKED.get()
+        run_before = gstats.SHARED_RUN_PAGES.get()
         eng = _engine()
         out = eng.generate(_prompt(21, seed=3), max_new_tokens=5)
         _assert_reference(_prompt(21, seed=3), out, 5)
         stats = eng.stats_snapshot()
-        assert stats["attn_slots_walked"] == stats["attn_slots_table"] > 0
+        assert 0 < stats["attn_slots_walked"] < stats["attn_slots_table"]
+        width, = (k["table_width"] for k in stats["page_kinds"].values())
+        assert stats["shared_run_pages"] == width * stats["decode_steps"] > 0
         assert stats["expert_assignments"] == stats["routed_rows"] == 0
         assert gstats.ATTN_SLOTS_WALKED.get() - before \
             >= stats["attn_slots_walked"]
+        assert gstats.SHARED_RUN_PAGES.get() - run_before \
+            >= stats["shared_run_pages"]
 
 
 # ---------------------------------------------------------------------------

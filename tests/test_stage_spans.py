@@ -576,9 +576,9 @@ class TestBenchmarkReaders:
             self, snapshot, cell):
         """PR 36's pair (``models/mla.py``'s ``WALK_COUNTERS``, the last
         two of both latent families' ``STEP_COUNTERS``, and since PR 38
-        all of Qwen's): the metric reads them under these names, and
-        every engine's snapshot has them (0 for a family whose step
-        counts no walk)."""
+        the first two of Qwen's, side by side in each): the metric reads
+        them under these names, and every engine's snapshot has them (0
+        for a family whose step counts no walk)."""
         from nornicdb_tpu.models import deepseek_v2, longcat_flash, mla, qwen2
 
         counters, dig = snapshot
@@ -588,7 +588,8 @@ class TestBenchmarkReaders:
         assert paths == tuple("genserve." + n for n in mla.WALK_COUNTERS)
         family = {"dsv2": deepseek_v2, "lcf": longcat_flash,
                   "gen": qwen2}[cell]
-        assert family.STEP_COUNTERS[-2:] == mla.WALK_COUNTERS
+        at = family.STEP_COUNTERS.index(mla.WALK_COUNTERS[0])
+        assert family.STEP_COUNTERS[at:at + 2] == mla.WALK_COUNTERS
         for path in paths:
             assert isinstance(dig(counters, path), int), path
 
@@ -602,7 +603,8 @@ class TestBenchmarkReaders:
             self, snapshot, metric, paths):
         """PR 37's counts by page kind: the step's four (``models/
         cohere2_moe.py``'s ``WALK_COUNTERS``, the last four of its
-        ``STEP_COUNTERS``, ``GenStats`` fields of the same names), their
+        ``STEP_COUNTERS`` before ``shared_run_pages``, ``GenStats`` fields
+        of the same names), their
         sums over the kinds and the scheduler's own count of window pages
         let go; every engine's snapshot has them (0 for a family with the
         one kind)."""
@@ -618,12 +620,38 @@ class TestBenchmarkReaders:
             assert isinstance(dig(counters, "genserve." + path), int), path
         walk = tuple(n for pair in cohere2_moe.WALK_COUNTERS.values()
                      for n in pair)
-        assert cohere2_moe.STEP_COUNTERS[-4:] == walk == (
+        assert cohere2_moe.STEP_COUNTERS[-5:-1] == walk == (
             "full_pages_walked", "full_pages_held", "window_pages_walked",
             "window_pages_held")
         assert set(walk) | {"window_pages_dropped", "window_pages_freed",
                             "attn_pages_walked", "attn_pages_held"} \
             <= set(GenStats.__dataclass_fields__)
+
+    def test_the_shared_run_counter_is_what_its_metric_reads(self, snapshot):
+        """PR 40's count of the pages a decode block gathered once for all
+        its lanes: the last of the ``STEP_COUNTERS`` of both families that
+        walk ``models/kv_walk.py``, a ``GenStats`` field and a Prometheus
+        counter of its own; the metric divides it by the steps that
+        carried a decode lane, in those two families' cells and no other."""
+        from nornicdb_tpu.genserve import stats as gstats
+        from nornicdb_tpu.genserve.engine import GenStats
+        from nornicdb_tpu.models import cohere2_moe, qwen2
+        from nornicdb_tpu.telemetry.metrics import REGISTRY
+
+        counters, dig = snapshot
+        spec, = [s for s in _metric_specs("counter_ratio")
+                 if s["name"] == "shared_run_pages_per_step.kv"]
+        assert (spec["numerator"], spec["denominator"]) == (
+            "genserve.shared_run_pages", "genserve.decode_steps")
+        assert spec["workloads"] == ["mem-chat-sys4k", "cmda-chat-sys6k"]
+        assert spec["layer"] == "kernels" and spec["moves"] == "tpot_ms"
+        for family in (qwen2, cohere2_moe):
+            assert family.STEP_COUNTERS[-1] == "shared_run_pages"
+        assert "shared_run_pages" in GenStats.__dataclass_fields__
+        assert isinstance(dig(counters, "genserve.shared_run_pages"), int)
+        gstats.SHARED_RUN_PAGES.inc(0)
+        assert "nornicdb_genserve_shared_run_pages_total" in \
+            REGISTRY.render_prometheus()
 
     def test_the_page_kind_families_render_at_metrics(self):
         from nornicdb_tpu.genserve import stats as gstats
