@@ -194,6 +194,10 @@ class GenServeConfig:
     # page-table width is max_seq_tokens / page_size)
     max_seqs: int = 8
     max_seq_tokens: int = 256
+    # slots of a STATE kind's pool (a decoder with state-space layers: the
+    # null slot, max_seqs + 1 lanes', the rest the prefix cache's
+    # snapshots).  Such a decoder has to be given it; no other family reads it
+    state_slots: int = 0
     # max tokens per interleaved prefill chunk (bucketed to powers of two
     # so jits stay bounded)
     prefill_chunk: int = 64

@@ -30,6 +30,19 @@ generation path end to end:
   without ``page_kinds`` has the one kind ``full``, and nothing of its
   step's layout, programs or counters differs from a scheduler that knew
   no kinds.
+* **A state kind.**  Layers that keep one fixed block a lane instead of
+  rows a token (``models/nemotron_h.py``: a Mamba-2 layer's convolution
+  inputs and SSM state) are a kind whose horizon is ``ragged.STATE``: a
+  pool of ``state_slots`` SLOTS, the same free list, reference counts and
+  prefix registrations, no table.  A lane holds one slot; ``meta`` names,
+  a lane, the slot its step reads and the slot it writes, and the step
+  copies on write.  A lane seated for a new or re-queued sequence reads
+  the null slot (zeros) or a SNAPSHOT: when the chunk lane's step ends on
+  a page boundary it writes a fresh slot, registered under that boundary's
+  chain key, and goes on from it (every such chunk end, the oldest idle
+  snapshot reclaimed first, none taken when no slot is free).  A prefix
+  hit is then as long as every page kind can serve AND ends where a
+  snapshot stands; with no snapshot there is no hit.
 * **Paged KV cache** (Ragged Paged Attention, PAPERS.md).  One pooled
   buffer of fixed-size pages shared by every sequence, with per-sequence
   page tables.  Attention block-gathers each sequence's pages; sequences
@@ -115,6 +128,7 @@ from nornicdb_tpu.errors import (
 )
 from nornicdb_tpu.genserve import stats as _stats
 from nornicdb_tpu.ragged import (
+    STATE,
     KindTables,
     first_page,
     pack_ragged_meta,
@@ -213,6 +227,18 @@ class GenStats:
     # the step's pairs summed over the kinds
     attn_pages_walked: int = 0
     attn_pages_held: int = 0
+    # a state kind (a family with state-space layers: models/nemotron_h.py).
+    # From the step's int vector: rows that advanced a live lane's state,
+    # summed over the state layers.  On the host: snapshots of a prefilling
+    # lane's state written at chunk ends on a page boundary, admissions
+    # that began from one, snapshots reclaimed for a newer one or a lane,
+    # and seatings whose step read one slot and wrote another (a copy on
+    # write: a lane's first step, and the steps at and behind a snapshot)
+    ssm_rows: int = 0
+    state_snapshots_taken: int = 0
+    state_snapshot_hits: int = 0
+    state_snapshots_dropped: int = 0
+    state_slots_copied: int = 0
     # the scheduler thread's cycle, one pass of ``_step`` a turn, each the
     # seconds of the ``tracer.stage`` of that name (docs/observability.md
     # "The scheduler's turn"): genserve.turn, and inside it .admit, .plan
@@ -425,6 +451,7 @@ class _Seq:
         "prefill_tokens", "prefill_pos", "tables", "bases", "held",
         "cache_len", "admit_no", "src", "row_step", "counted",
         "trace_ctx", "submitted_perf", "since", "prefix_keys", "re_prefill",
+        "borrowed",
     )
 
     def __init__(self, handle: GenHandle, prompt: list[int], max_new: int,
@@ -438,10 +465,16 @@ class _Seq:
         self.prefill_tokens: list[int] = []
         self.prefill_pos = 0
         # a page kind each: the lane's table, the logical page its column 0
-        # stands for, and how many of its columns hold a page
+        # stands for, and how many of its columns hold a page.  Of a state
+        # kind: the lane's own slot (a table of one), and in ``bases`` the
+        # slot its next step READS (the null slot, a snapshot, then what
+        # its last step wrote)
         self.tables: Optional[list] = None
         self.bases: list[int] = []
         self.held: list[int] = []
+        # (kind, slot): snapshots it holds a reference on until the step
+        # that reads them is packed (a hit it begins from; one it just left)
+        self.borrowed: list = []
         self.cache_len = 0
         self.admit_no = -1
         # state, prefill_pos and cache_len are the PLAN's: they advance
@@ -505,12 +538,14 @@ class _Kind:
       hash    pid -> chain-key (reverse index for reclaim)
     """
 
-    __slots__ = ("name", "horizon", "width", "usable", "free", "refs",
-                 "cache", "hash")
+    __slots__ = ("name", "horizon", "state", "width", "usable", "free",
+                 "refs", "cache", "hash")
 
-    def __init__(self, name: str, horizon: Optional[int], width: int,
-                 usable: int):
+    def __init__(self, name: str, horizon, width: int, usable: int):
         self.name, self.horizon = name, horizon
+        # a state kind: slots for pages, a lane holds ONE, a cached slot is
+        # a snapshot under the chain key of the boundary it stands at
+        self.state = horizon == STATE
         self.width = width      # pages of a lane's table
         self.usable = usable    # pages of the pool, the null page apart
         self.cache: "OrderedDict[bytes, int]" = OrderedDict()
@@ -647,7 +682,19 @@ class GenerationEngine:
         for name, horizon in (declared(cfg) if declared else
                               (("full", None),)):
             width, usable = self._table_width, self._usable_pages
-            if horizon is not None:
+            if horizon == STATE:
+                # a slot a lane (one more: a sequence that ended keeps its
+                # own until its last step is read) and the snapshots
+                width = 1
+                usable = int(config.state_slots) - 1
+                if usable < self._max_seqs + 1:
+                    raise ValueError(
+                        f"genserve state_slots={usable + 1} cannot seat "
+                        f"max_seqs={self._max_seqs} sequences of a decoder "
+                        f"with a state kind ({self._max_seqs + 1} slots + "
+                        "the null slot, and what the prefix cache is to "
+                        "keep as snapshots)")
+            elif horizon is not None:
                 # what a lane's window, a chunk's queries and the page the
                 # window starts in can span; the pool: that for every lane
                 # and one cached context
@@ -702,14 +749,18 @@ class GenerationEngine:
         # kv_prefix is the prefix-cache-resident SUBSET of the pools (not
         # additive residency): how much of them is pinned shareable.  The
         # first kind's pool is ``kv_pages``, a further kind's
-        # ``kv_pages_<kind>``: the components add up to what is resident
+        # ``kv_pages_<kind>``, a state kind's ``state_slots``: the
+        # components add up to what is resident
+        import jax
+
         out = {"kv_prefix": 0}
         for kind, pool, pages in zip(self._kinds, self._pools(),
                                      self._page_counts(self._pages)):
-            total = int(pool.size) * pool.dtype.itemsize
+            total = sum(int(a.size) * a.dtype.itemsize
+                        for a in jax.tree.leaves(pool))
             out["kv_prefix"] += len(kind.cache) * (total // max(1, pages))
-            out["kv_pages" if kind is self._kinds[0]
-                else f"kv_pages_{kind.name}"] = total
+            out["state_slots" if kind.state else "kv_pages"
+                if kind is self._kinds[0] else f"kv_pages_{kind.name}"] = total
         return out
 
     # the FIRST kind's allocator under the names it had before there were
@@ -1107,8 +1158,10 @@ class GenerationEngine:
                                      seq.held):
             for pid in table[:held].tolist():
                 kind.let_go(pid)
+        for kind, slot in seq.borrowed:
+            kind.let_go(slot)
         seq.tables = None
-        seq.bases, seq.held = [], []
+        seq.bases, seq.held, seq.borrowed = [], [], []
         seq.cache_len = 0
         seq.prefill_pos = 0
 
@@ -1145,6 +1198,8 @@ class GenerationEngine:
                      len(seq.prefill_tokens) // self._page_size)
         for kind, table, base, held in zip(self._kinds, seq.tables,
                                            seq.bases, seq.held):
+            if kind.state:
+                continue  # its snapshots were registered as they were taken
             for idx in range(base, min(n_full, base + held)):
                 kind.publish(seq.prefix_keys[idx], int(table[idx - base]))
 
@@ -1153,7 +1208,9 @@ class GenerationEngine:
         new lane: the longest run ``n <= cap`` of which EVERY kind holds
         what the lane's first query (at ``n x page_size``) can still see: a
         kind without a horizon pages ``0 .. n-1``, one with a horizon the
-        pages from the one its window reaches."""
+        pages from the one its window reaches, and a state kind a SNAPSHOT
+        of the state at that boundary (under the key of page ``n - 1``):
+        where none stands there is no hit."""
         n = min(len(keys), cap)
         for kind in self._kinds:
             if kind.horizon is None:
@@ -1164,6 +1221,11 @@ class GenerationEngine:
             shrunk = False
             for kind in self._kinds:
                 if kind.horizon is None:
+                    continue
+                if kind.state:
+                    at = next((i for i in range(n, 0, -1)
+                               if keys[i - 1] in kind.cache), 0)
+                    n, shrunk = at, shrunk or at != n
                     continue
                 lo = first_page(n * self._page_size, kind.horizon,
                                 self._page_size)
@@ -1257,7 +1319,7 @@ class GenerationEngine:
             for seq in reversed(requeue):
                 seq.since = now  # queued again
                 seq.tables = None
-                seq.bases, seq.held = [], []
+                seq.bases, seq.held, seq.borrowed = [], [], []
                 seq.cache_len = 0
                 seq.prefill_pos = 0
                 seq.src = -1
@@ -1370,8 +1432,13 @@ class GenerationEngine:
                 plan = []
                 for kind in self._kinds:
                     lo = first_page(n_hit * ps, kind.horizon, ps)
-                    hits = [kind.cache[keys[i]] for i in range(lo, n_hit)]
-                    fresh = max(0, min(need, lo + kind.width) - n_hit)
+                    if kind.state:
+                        # the snapshot at the hit's end, and the lane's own
+                        hits = [kind.cache[keys[n_hit - 1]]] if n_hit else []
+                        fresh = 1
+                    else:
+                        hits = [kind.cache[keys[i]] for i in range(lo, n_hit)]
+                        fresh = max(0, min(need, lo + kind.width) - n_hit)
                     # idle cached hits count as "available" but adopting
                     # them consumes that availability — exclude them
                     # before comparing against the fresh-page requirement
@@ -1401,10 +1468,17 @@ class GenerationEngine:
                     # shared pages: take a reference, refresh LRU
                     kind.take(pid)
                     kind.cache.move_to_end(kind.hash[pid])
-                pids = hits + [kind.alloc()  # availability checked above
+                pids = hits + [self._alloc(kind)  # availability: above
                                for _ in range(fresh)]
                 for pid in pids[len(hits):]:
                     kind.take(pid)
+                if kind.state:
+                    # its first step reads the snapshot (or the null slot:
+                    # zeros, never what the slot's last holder left) and
+                    # writes its own slot
+                    seq.borrowed += [(kind, pid) for pid in hits]
+                    lo, pids = (hits or [0])[0], pids[len(hits):]
+                    self.stats.state_snapshot_hits += len(hits)
                 table = np.zeros((kind.width,), np.int32)
                 table[:len(pids)] = pids
                 seq.tables.append(table)
@@ -1436,6 +1510,46 @@ class GenerationEngine:
                 attrs={"readmission": bool(seq.out)})
             seq.since = seated
 
+    def _alloc(self, kind: _Kind) -> Optional[int]:
+        """:meth:`_Kind.alloc`, a snapshot it reclaimed counted."""
+        cached = len(kind.cache)
+        pid = kind.alloc()
+        if kind.state:
+            self.stats.state_snapshots_dropped += cached - len(kind.cache)
+        return pid
+
+    def _snapshot(self, seq: _Seq, end: int) -> dict:
+        """The chunk lane's step ends at ``end`` tokens.  On a page boundary
+        the state there is worth keeping: a state kind's step then writes a
+        FRESH slot, registered at once under that boundary's chain key (the
+        device runs the steps in the order they are dispatched, so whoever
+        is seated behind it later reads what this step wrote), and the
+        lane's next step goes on from it.  Every such chunk end, the oldest
+        idle snapshot reclaimed first; none where no slot is free.  Returns
+        {kind's index: the slot}."""
+        ps, keep = self._page_size, {}
+        if end % ps or seq.prefix_keys is None:
+            return keep
+        key = seq.prefix_keys[end // ps - 1]
+        for k, kind in enumerate(self._kinds):
+            if not kind.state or key in kind.cache:
+                continue
+            dropped = self.stats.state_snapshots_dropped
+            slot = self._alloc(kind)
+            if slot is None:
+                continue
+            kind.take(slot)  # the lane's, until its next step has read it
+            kind.publish(key, slot)
+            keep[k] = slot
+            self.stats.state_snapshots_taken += 1
+            if seq.trace_ctx is not None:
+                now = time.perf_counter()
+                _tracer.add_span(
+                    "genserve.state_snapshot", now, now, parent=seq.trace_ctx,
+                    attrs={"tokens": end, "slot": slot, "evicted":
+                           self.stats.state_snapshots_dropped > dropped})
+        return keep
+
     def _slide(self, seq: _Seq, first: int) -> None:
         """The lane's next queries stand at ``first`` and after: of each
         kind with a horizon, let go the pages wholly behind the window.  A
@@ -1445,7 +1559,7 @@ class GenerationEngine:
         cache first (its final chunk, which publishes the rest, comes
         after the window has left it)."""
         for k, kind in enumerate(self._kinds):
-            if kind.horizon is None:
+            if kind.horizon is None or kind.state:
                 continue
             gone = first_page(first, kind.horizon, self._page_size) \
                 - seq.bases[k]
@@ -1487,6 +1601,8 @@ class GenerationEngine:
         or ended at a token read meanwhile."""
         self._slide(seq, first)
         for k, kind in enumerate(self._kinds):
+            if kind.state:
+                continue  # one slot, taken at admission
             while seq.bases[k] + seq.held[k] <= last // self._page_size:
                 pid = kind.alloc()
                 if pid is None:
@@ -1676,6 +1792,8 @@ class GenerationEngine:
             # flat token rows: decode lanes first, then the chunk, then
             # padding up to the pow2 bucket — F scales with REAL tokens
             f = round_up_pow2(ndec, 8)
+        keep = self._snapshot(chunk_seq, chunk_seq.prefill_pos + n_valid) \
+            if chunk_seq is not None else {}
         lmax = self._lmax
         # ONE packed int32 host array per step (one H2D transfer); the
         # names below are writable views into it.  Logits are projected
@@ -1686,12 +1804,24 @@ class GenerationEngine:
         meta, (tokens, lane_id, lane_pos, positions, logit_rows), parts = \
             self._blank_meta(f)
 
-        def seat(lane: int, seq: _Seq) -> None:
-            for (base, pages), table, at in zip(parts, seq.tables,
-                                                seq.bases):
+        def seat(lane: int, seq: _Seq, keep=()) -> None:
+            for k, ((base, pages), table, at) in enumerate(zip(
+                    parts, seq.tables, seq.bases)):
                 pages[lane] = table
                 if base is not None:
                     base[lane] = at
+                if self._kinds[k].state:
+                    # reads ``at``, writes its own slot or the snapshot's,
+                    # and its next step reads what this one writes
+                    if k in keep:
+                        pages[lane, 0] = keep[k]
+                    seq.bases[k] = int(pages[lane, 0])
+                    self.stats.state_slots_copied += at != seq.bases[k]
+            # what it read is the step's now; the snapshot it leaves is its
+            # own until the next step has read it
+            for kind, slot in seq.borrowed:
+                kind.let_go(slot)
+            seq.borrowed = [(self._kinds[k], keep[k]) for k in keep]
 
         for i, seq in enumerate(active):
             tokens[i] = seq.out[-1] if seq.src < 0 else -(seq.src + 1)
@@ -1707,7 +1837,7 @@ class GenerationEngine:
             lane_pos[fi] = j
             positions[fi] = chunk_seq.prefill_pos + j
         if chunk_seq is not None:
-            seat(chunk_lane, chunk_seq)
+            seat(chunk_lane, chunk_seq, keep)
             logit_rows[ndec] = ndec + n_valid - 1
         return meta, f, tq, active, chunk_seq, n_valid, final
 

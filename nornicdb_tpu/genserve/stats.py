@@ -178,6 +178,21 @@ for _kind in ("full", "window"):
     ATTN_PAGES_WALKED.labels(_kind)
     ATTN_PAGES_HELD.labels(_kind)
 
+# a state kind (a decoder family with state-space layers: one slot of
+# recurrent state a lane beside the K/V pages; 0 forever otherwise).  The
+# prefix cache keeps SNAPSHOTS of a prefilling lane's state at chunk ends
+# that fall on a page boundary: taken = slots written as one, hit =
+# admissions that began from one (a prefix hit ends where a snapshot
+# stands), dropped = snapshots reclaimed, oldest idle first, for a newer
+# one or for a lane.  rate(dropped) near rate(taken) with few hits means
+# state_slots is too small for the prompts' shared prefixes
+STATE_SNAPSHOTS = _REGISTRY.counter(
+    "nornicdb_genserve_state_snapshots_total",
+    "Snapshots of a lane's recurrent state in the prefix cache, by event "
+    "(taken, hit, dropped)",
+    labels=("event",),
+)
+
 # the scheduler thread's turn (docs/observability.md "The scheduler's
 # turn"): cumulative seconds of each phase of the cycle, the
 # ``genserve.turn.*`` stages' own durations.  read = blocked on the device;
@@ -221,6 +236,9 @@ _FROM_STATS = {
     "host_offcpu_seconds": HOST_OFFCPU.labels(),
     "late_dispatches": LATE_DISPATCHES.labels(),
     "stream_lag_seconds": STREAM_LAG.labels(),
+    "state_snapshots_taken": STATE_SNAPSHOTS.labels("taken"),
+    "state_snapshot_hits": STATE_SNAPSHOTS.labels("hit"),
+    "state_snapshots_dropped": STATE_SNAPSHOTS.labels("dropped"),
 }
 # engine -> what the last scrape read of its stats (weak: an engine that is
 # gone leaves the totals where they stand)

@@ -1,7 +1,8 @@
 """The routed experts a process holds, under expert parallelism: a router's
 choice as this process sees it, and the masked matmul over the experts it
 holds.  What every routed family (``models/deepseek_v2.py``,
-``models/longcat_flash.py``, ``models/cohere2_moe.py``) runs, written once;
+``models/longcat_flash.py``, ``models/cohere2_moe.py``,
+``models/nemotron_h.py``) runs, written once;
 the router itself (softmax or sigmoid, groups, a bias, zero experts) is the
 family's.  ``held = (first, count)`` names the experts held here; what the
 absent ones would add is left out, and nothing here stands in for the other
@@ -43,11 +44,16 @@ def held_experts(experts: dict, x: jax.Array, weight: jax.Array) -> jax.Array:
     the expert's weights, once, whoever is routed to it) and a row's gate,
     zero where it was not routed to that expert, scales the activation
     before ONE down projection over (expert, width): no dropped tokens, no
-    capacity factor."""
-    gate = jnp.einsum("nh,chi->nci", x, experts["gate"],
-                      preferred_element_type=jnp.float32)
+    capacity factor.  An expert with a ``gate`` matrix is the gated form,
+    ``down(silu(gate x) * up x)``; one without (Nemotron-H's) the ungated
+    one, ``down(relu(up x)^2)``."""
+    gated = "gate" in experts
+    if gated:
+        gate = jnp.einsum("nh,chi->nci", x, experts["gate"],
+                          preferred_element_type=jnp.float32)
     up = jnp.einsum("nh,chi->nci", x, experts["up"],
                     preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(gate) * up * weight[:, :, None]).astype(x.dtype)
+    act = jax.nn.silu(gate) * up if gated else jnp.square(jax.nn.relu(up))
+    act = (act * weight[:, :, None]).astype(x.dtype)
     return jnp.einsum("nci,cih->nh", act, experts["down"],
                       preferred_element_type=jnp.float32)

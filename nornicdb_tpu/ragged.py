@@ -3,8 +3,9 @@ scheduler and every decoder family's ``fused_step``.
 
 The scheduler (``genserve/engine.py``) packs one int32 host array a step;
 a family (``models/qwen2.py``, ``models/deepseek_v2.py``,
-``models/longcat_flash.py``, ``models/cohere2_moe.py``) unpacks it on the
-device.  Nothing here knows a model: page arithmetic, the power-of-two
+``models/longcat_flash.py``, ``models/cohere2_moe.py``,
+``models/nemotron_h.py``) unpacks it on the device.  Nothing here knows a
+model: page arithmetic, the power-of-two
 bucketing of program shapes, the null page, and the order of the routing
 counts a family may append to the step's greedy ids.
 
@@ -34,6 +35,19 @@ with ``base (Lmax) | lane_tables (Lmax, W_k)`` for each kind in turn
 its lane's window still reaches onward (``base`` is 0 for a kind without).
 A family without ``page_kinds`` has the one kind ``full`` and the layout
 above, unchanged.
+
+**A state kind.**  Layers that keep no row a token but one fixed block a
+lane (a state-space layer's convolution inputs and SSM state) are a kind
+whose ``horizon`` is :data:`STATE`.  Its pool is SLOTS, a lane holds one, and
+there is no table: the kind's part of ``meta`` is ``read (Lmax) | write
+(Lmax)``, the slot a lane's step reads its state from and the slot it writes
+the advanced state to (the layout of a kind one page wide:
+:class:`KindTables` ``base`` = read, ``pages[:, 0]`` = write; its entry of
+``w`` is 1).  They differ when the lane begins from a snapshot or from
+nothing (read :data:`NULL_PAGE`: zeros) or leaves a snapshot behind: the
+step copies on write, no dispatch of its own.  A write to
+:data:`NULL_PAGE` is dropped, so slot 0 stays zeros: lanes without a
+sequence, the dump lane and padding rows advance nothing.
 """
 
 from __future__ import annotations
@@ -45,6 +59,9 @@ import numpy as np
 # physical page 0: padded lanes and padded chunk positions write here, so a
 # static-shape program never corrupts a live page
 NULL_PAGE = 0
+
+# the ``horizon`` of a state kind (module note): no tokens back, a slot a lane
+STATE = "state"
 
 # what a family with routed experts appends, in this order, to the ``Lmax``
 # greedy ids of its step's one int vector (``GenStats`` fields of the same
@@ -74,15 +91,15 @@ def pages_for(n_tokens: int, page_size: int) -> int:
 class KindTables(NamedTuple):
     """One page kind's part of ``meta``: each lane's first logical page and
     its table from there on."""
-    base: object    # (Lmax,)
-    pages: object   # (Lmax, W_k)
+    base: object    # (Lmax,); a state kind: the slot a lane reads
+    pages: object   # (Lmax, W_k); a state kind: (Lmax, 1) the slot it writes
 
 
 def first_page(position: int, horizon, page_size: int) -> int:
     """The first logical page a query at ``position`` still reads in a kind
     of this ``horizon``: it sees the keys ``j`` with ``position - horizon <
     j <= position``."""
-    if horizon is None:
+    if horizon is None or horizon == STATE:
         return 0
     return max(0, position - horizon + 1) // page_size
 
@@ -139,3 +156,10 @@ def unpack_ragged_meta(meta, lmax: int, w, prev=None):
     rest = meta[4 * f + lmax:]
     return (*rows, _kind_tables(rest, lmax, w) if isinstance(w, tuple)
             else rest.reshape(lmax, w))
+
+
+def split_state(meta, lmax: int):
+    """``meta`` of a family whose LAST kind is a state kind (module note)
+    -> (the meta of the kinds before it alone, read (Lmax,), write
+    (Lmax,)): host array or traced alike."""
+    return meta[:-2 * lmax], meta[-2 * lmax:-lmax], meta[-lmax:]

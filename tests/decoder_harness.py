@@ -2,8 +2,9 @@
 ``fused_step`` that packs rows as the scheduler does, and the readings the
 tolerances are stated on.  A family is its module (``models/qwen2.py``,
 ``models/deepseek_v2.py``, ``models/longcat_flash.py``,
-``models/cohere2_moe.py``: ``init_pages`` / ``fused_step``, and
-``page_kinds`` where its layers keep more than one kind of cache state);
+``models/cohere2_moe.py``, ``models/nemotron_h.py``: ``init_pages`` /
+``fused_step``, and ``page_kinds`` where its layers keep more than one kind
+of cache state, a STATE kind among them or not);
 what judges it is the plain float32 forward of
 ``models/reference/<family>.py``.
 """
@@ -13,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from nornicdb_tpu.ragged import (
+    STATE,
     first_page,
     pack_ragged_meta,
     pages_for,
@@ -101,16 +103,42 @@ class Lane:
     reaches.  Handed to :meth:`Pool.step` where a one-kind family hands a
     table; before a step, :meth:`reach` lets go what the step's first query
     no longer reads (back to the kind's free list) and takes pages up to
-    its last."""
+    its last.  Of a STATE kind the lane holds ONE slot and names, a step,
+    the slot it reads (``begin``: the null slot, or a snapshot; afterwards
+    what it wrote last) and the slot it writes (its own, or once a fresh
+    one that stays behind as a snapshot: :meth:`snapshot`)."""
 
-    def __init__(self, pool: "Pool"):
+    def __init__(self, pool: "Pool", begin: int = 0):
         self.pool = pool
         self.base = [0] * len(pool.kinds)
         self.pages = [[] for _ in pool.kinds]
         self.ever = [set() for _ in pool.kinds]  # every page it has held
+        # a state kind: the lane's own slot, what its next step reads, and
+        # where its next step writes if not to its own
+        self.slot = [pool.free[k].pop(0) if horizon == STATE else None
+                     for k, (_, horizon) in enumerate(pool.kinds)]
+        self.read = [begin] * len(pool.kinds)
+        self.keep = None
+
+    def snapshot(self) -> int:
+        """The lane's NEXT step writes a fresh slot, which stays as it is
+        from then on (the step after reads it and writes the lane's own
+        again): the slot."""
+        k, = [k for k, s in enumerate(self.slot) if s is not None]
+        self.keep = self.pool.free[k].pop(0)
+        return self.keep
+
+    def slots(self, k: int) -> tuple:
+        """(read, write) of the step being packed; the next one reads what
+        this one writes."""
+        write = self.slot[k] if self.keep is None else self.keep
+        read, self.read[k], self.keep = self.read[k], write, None
+        return read, write
 
     def reach(self, first: int, last: int) -> None:
         for k, (_, horizon) in enumerate(self.pool.kinds):
+            if horizon == STATE:
+                continue
             lo = first_page(first, horizon, PAGE)
             while self.base[k] < lo:
                 if self.pages[k]:
@@ -140,7 +168,7 @@ class Pool:
             # a kind with a horizon: its window, a chunk, and a page for
             # where the window starts inside one
             self.width = tuple(
-                width if horizon is None else
+                1 if horizon == STATE else width if horizon is None else
                 min(width, pages_for(horizon + chunk, PAGE) + 1)
                 for _, horizon in self.kinds)
             if isinstance(pages, int):
@@ -172,8 +200,12 @@ class Pool:
                 tables[i] = table
                 return
             table.reach(first, last)
-            for kind, base, held in zip(tables, table.base, table.pages):
-                kind.base[i], kind.pages[i, :len(held)] = base, held
+            for k, (kind, base, held) in enumerate(zip(
+                    tables, table.base, table.pages)):
+                if table.slot[k] is not None:
+                    kind.base[i], kind.pages[i, 0] = table.slots(k)
+                else:
+                    kind.base[i], kind.pages[i, :len(held)] = base, held
 
         for i, (tok, at, table) in enumerate(decode):
             seat = i if lanes is None else lanes[i]

@@ -465,3 +465,49 @@ def test_parallel_moe_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     # with a chunk the one-lane chunk block's gathers read alike)
     assert re.search(r"bf16\[%d,16,1024\]\S* gather\(" % block, text)
     _pool_gathers_are_flat(text, page, 1024)
+
+
+@pytest.mark.parametrize("f,tq", [(16, 1), (128, 64)],
+                         ids=["decode", "widest"])
+def test_hybrid_fused_step_at_the_benchmark_cut(one_chip, f, tq):
+    """Nemotron 3 Nano's step at the benchmark's cut (published widths, the
+    first 27 layers: 12 Mamba-2, 11 expert, 4 attention; 16 held experts,
+    the 128-wide router, a 16,384-row head) and the cell's engine geometry
+    (16 lanes + chunk + dump, page 16, 512-page tables over 8,193 K/V
+    pages, 82 state slots): it fits the chip beside the deployment's 5.43
+    GB; the K/V pool AND both halves of the state pool are donated, and the
+    2.06 GB of float32 SSM state goes in and out in one row-major layout
+    with no pool-sized copy left in the step (twelve layers each gather 17
+    lanes' slots and scatter them back in place)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from nornicdb_tpu.ragged import pack_ragged_meta
+    from nornicdb_tpu.models import nemotron_h as nh
+
+    cfg = nh.NEMOTRON_3_NANO_EP8_27L
+    lmax, w = 18, (512, 1)
+    meta, _ = pack_ragged_meta(lmax, w, f)
+    pools = jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda: nh.init_pages(cfg, (8193, 82), 16)))
+    compiled = nh.fused_step.lower(
+        _params_on(nh.init_params, cfg, one_chip), cfg,
+        _sds(meta.shape, jnp.int32, one_chip), pools, lmax=lmax, w=w, tq=tq,
+        # the served variant: the ids and the family's eight counts
+        prev=_sds((lmax + len(nh.STEP_COUNTERS),), jnp.int32, one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    held = sum(int(np.prod(p.shape)) * p.dtype.itemsize
+               for p in jax.tree.leaves(pools))
+    assert held == 536_936_448 + 2_099_871_744
+    assert mem.alias_size_in_bytes >= held  # every pool donated
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < (16 << 30) - 5_430_000_000
+    text = compiled.as_text()
+    ssm = "f32[12,82,64,64,128]"
+    layouts = set(re.findall(re.escape(ssm) + r"\{([0-9,]+)", text))
+    assert layouts == {"4,3,2,1,0"}, layouts
+    assert not re.search(re.escape(ssm) + r"\S* copy\(", text)

@@ -251,7 +251,10 @@ def test_one_program_variant_a_step_class(kit):
 
 
 def test_the_pipeline_added_no_option():
-    assert len(GenServeConfig.__dataclass_fields__) == 10
+    # ten, and since PR 41 ``state_slots``: the one option the state kind
+    # brought (the size of a pool that only a family with state-space
+    # layers has, and has to be given)
+    assert len(GenServeConfig.__dataclass_fields__) == 11
 
 
 def _alone_of(kit, prompts, max_new, **geometry):

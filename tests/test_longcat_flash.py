@@ -505,7 +505,9 @@ def test_the_family_plugs_into_the_seam_with_no_new_option():
     from nornicdb_tpu.config import GenServeConfig
     from nornicdb_tpu.genserve import GenerationEngine, GenStats
 
-    assert len(GenServeConfig.__dataclass_fields__) == 10
+    # (the eleventh is PR 41's ``state_slots``, which no family without a
+    # state kind reads)
+    assert len(GenServeConfig.__dataclass_fields__) == 11
     for name in ("init_pages", "num_pages", "fused_step"):
         assert callable(getattr(lcf, name)), name
     for gone in ("prefill", "decode", "decode_step", "generate"):

@@ -446,7 +446,12 @@ def _program_modules() -> dict:
     import jax
     import jax.numpy as jnp
 
-    from nornicdb_tpu.models import cohere2_moe, deepseek_v2, longcat_flash
+    from nornicdb_tpu.models import (
+        cohere2_moe,
+        deepseek_v2,
+        longcat_flash,
+        nemotron_h,
+    )
     from nornicdb_tpu.ops.pallas_kernels import streaming_cosine_topk
 
     embedder = TPUEmbedder(cfg=F32_CFG)
@@ -456,7 +461,15 @@ def _program_modules() -> dict:
     lcf = longcat_flash.LONGCAT_FLASH_SMALL
     lmax, w, f = 4, 8, 16
     cmda, kinds = cohere2_moe.COHERE2_MOE_SMALL, (w, 4)  # a width a kind
+    nemo, slots = nemotron_h.NEMOTRON_H_SMALL, (w, 1)  # pages, a state slot
     return {
+        "step_roofline.nemo": _module_name(nemotron_h.fused_step.lower(
+            jax.eval_shape(lambda: nemotron_h.init_params(
+                nemo, jax.random.PRNGKey(0))), nemo,
+            jax.ShapeDtypeStruct((4 * f + lmax + sum(
+                lmax * (1 + wk) for wk in slots),), jnp.int32),
+            jax.eval_shape(lambda: nemotron_h.init_pages(nemo, (9, 5), 16)),
+            lmax=lmax, w=slots, tq=16)),
         "step_roofline.cmda": _module_name(cohere2_moe.fused_step.lower(
             jax.eval_shape(lambda: cohere2_moe.init_params(
                 cmda, jax.random.PRNGKey(0))), cmda,
