@@ -33,9 +33,11 @@ step reads each lane's state from the slot ``meta`` names and writes the
 advanced state to the slot it names (they differ where a lane begins from a
 snapshot, or from nothing, or leaves a snapshot behind); a write to the
 null slot is dropped, so lanes without a sequence and padding rows advance
-nothing and slot 0 stays zeros.  Decode rows are one recurrence a lane; the
-chunk lane's rows run the chunked (matmul) form of the same recurrence from
-the lane's state, rows that are no token at ``dt = 0``.
+nothing and slot 0 stays zeros.  Decode rows are one plain step of the
+recurrence a lane (:func:`ssm_step`; on a TPU in place over the pool's
+slots, :func:`step_slots_in_place`); the chunk lane's rows run the chunked
+(matmul) form of the same recurrence from the lane's state
+(:func:`ssd_block`), rows that are no token at ``dt = 0``.
 
 ``held_experts = (first, count)`` says which routed experts this process
 holds (expert parallelism; ``models/experts.py``): the router keeps its
@@ -62,11 +64,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from nornicdb_tpu.models import experts, kv_walk
 from nornicdb_tpu.models.layers import dense, rms_norm
@@ -300,7 +305,11 @@ def ssd_block(x, dt, a, b, c, s0):
               + sum_{s <= t} exp(cum_t - cum_s) dt_s (C_t B_s) x_s
         S_T = exp(cum_T) S0 + sum_s exp(cum_T - cum_s) dt_s x_s (x) B_s
 
-    every exponent <= 0.  T = 1 is one step of the recurrence a lane."""
+    every exponent <= 0.  The form for a CHUNK's rows (``T = tq`` up to
+    64): its matmuls do a block of rows at once from one read of the state.
+    At ``T = 1`` it is one step of the recurrence a lane and the same
+    numbers as :func:`ssm_step`, which the decode block takes instead: here
+    a lane's whole state would be a matmul's operand for ONE row."""
     lanes, t, h, p = x.shape
     g, n = b.shape[2:]
     r = h // g
@@ -322,6 +331,119 @@ def ssd_block(x, dt, a, b, c, s0):
     return y.reshape(lanes, t, h, p), s_t.reshape(lanes, h, p, n)
 
 
+def ssm_step(x, dt, a, b, c, s0):
+    """ONE plain step of the recurrence a lane, :func:`ssd_block`'s own
+    formula at ``T = 1`` written out: x (L, H, P), dt (L, H) (0: the lane
+    keeps its state), a (H,), b and c (L, G, N), s0 (L, H, P, N), all f32 ->
+    (y (L, H, P) without the D term, the advanced state (L, H, P, N))::
+
+        S_1 = exp(dt a) S0 + dt x (x) B;   y_1 = S_1 C
+
+    elementwise in float32: a lane's state is read once, multiplied and
+    added once, reduced once over N and written once, and none of it is a
+    matmul's operand."""
+    lanes, h, p = x.shape
+    g, n = b.shape[1:]
+    r = h // g
+    s0 = s0.reshape(lanes, g, r, p, n)
+    keep = jnp.exp(dt * a).reshape(lanes, g, r, 1, 1)
+    feed = (dt[..., None] * x).reshape(lanes, g, r, p, 1)
+    s_1 = keep * s0 + feed * b[:, :, None, None, :]
+    y = (s_1 * c[:, :, None, None, :]).sum(axis=-1)
+    return y.reshape(lanes, h, p), s_1.reshape(lanes, h, p, n)
+
+
+def step_slots(ssm, src, dst, alive, x, dt, a, b, c):
+    """:func:`ssm_step` over the flat pool ``ssm`` (slots, H, P, N): each
+    lane's state gathered from slot ``src`` (L,), advanced, and scattered to
+    slot ``dst`` (L,) where ``alive`` (a write that is not is dropped) ->
+    (y (L, H, P), ssm).  Three passes over the lanes' states, as XLA has
+    them; what a backend without :func:`step_slots_in_place` runs."""
+    y, s_1 = ssm_step(x, dt, a, b, c, ssm[src])
+    to = jnp.where(alive, dst, ssm.shape[0])
+    return y, ssm.at[to].set(s_1, mode="drop")
+
+
+def _step_kernel(src, dst, alive, keep, feed, b, c, s0, y, s_1, *, heads, r,
+                 turn):
+    """One lane's block of ``heads`` heads: refs keep (L, H) in SMEM, feed
+    and y (1, 1, P, heads) (a head a lane of the tile, so that a head's
+    ``dt x`` is a column over P and broadcasts over N), b and c (1, G, N), s0
+    and s_1 (1, heads, P, N), the lane's slot of the pool read and written.
+    A head at a time: 8 vector registers of state, a row of B and of C;
+    ``turn`` heads a turn of the loop, so that one head's lane reductions
+    run under the next one's loads and multiplies."""
+    lane, block = pl.program_id(0), pl.program_id(1)
+    written = alive[lane] != 0
+    at = jax.lax.broadcasted_iota(jnp.int32, feed.shape[2:], 1)
+    fed = feed[0, 0]
+
+    def heads_of(k, out):
+        for h in range(turn):
+            h = k * turn + h
+            g = (block * heads + h) // r
+            dtx = jnp.sum(jnp.where(at == h, fed, 0.0), axis=1, keepdims=True)
+            new = keep[lane, block * heads + h] * s0[0, h] \
+                + dtx * b[0, pl.ds(g, 1), :]
+            # a lane that writes no slot is pointed at the null one: zeros
+            s_1[0, h] = jnp.where(written, new, 0.0)
+            y_h = jnp.sum(new * c[0, pl.ds(g, 1), :], axis=1, keepdims=True)
+            out = jnp.where(at == h, y_h, out)
+        return out
+
+    y[0, 0] = jax.lax.fori_loop(0, heads // turn, heads_of,
+                                jnp.zeros(fed.shape, jnp.float32))
+
+
+_HEADS_A_TURN = 4   # of the kernel's loop: 2 and 8 are slower on the chip
+
+
+def step_slots_in_place(ssm, src, dst, alive, x, dt, a, b, c):
+    """:func:`step_slots` as ONE pass on a TPU: a Pallas kernel whose blocks
+    are slots of the pool itself (the slot numbers prefetched as scalars,
+    the pool aliased in and out), so a lane's state comes from its read slot
+    into VMEM, is advanced there and goes to its write slot: read once,
+    written once, no gathered copy and no scatter.  ``dst`` of a lane that is
+    not ``alive`` must be its layer's null slot, which gets zeros.  A step
+    never reads a slot that another of its lanes writes (a snapshot is read
+    by LATER steps: ``genserve/engine.py`` ``_snapshot``), so the order of
+    the lanes does not show.  The heads' loop is a ``fori_loop`` of four
+    heads a turn: it lowers in 0.07 s a step class where 16 heads unrolled
+    take 0.18 and 32 take 0.33 (a warm start lowers every class:
+    :func:`mamba_layer`), and runs at what the pool through VMEM and back
+    costs with no arithmetic, where a head a turn takes twice that (PERF.md
+    section 6, PR 43)."""
+    lanes, h, p = x.shape
+    g, n = b.shape[1:]
+    # 2 MB of state a block: a lane's 64 heads at the published sizes
+    heads = math.gcd(h, max(1, (2 << 20) // (4 * p * n)))
+    keep = jnp.exp(dt * a)
+    feed = (dt[..., None] * x).reshape(lanes, h // heads, heads, p)
+    # an index map is handed (lane, block of heads, src, dst, alive)
+    tile = pl.BlockSpec((1, 1, p, heads), lambda i, j, *_: (i, j, 0, 0))
+    row = pl.BlockSpec((1, g, n), lambda i, j, *_: (i, 0, 0))
+    y, ssm = pl.pallas_call(
+        functools.partial(_step_kernel, heads=heads, r=h // g,
+                          turn=math.gcd(heads, _HEADS_A_TURN)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(lanes, h // heads),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM), tile, row, row,
+                pl.BlockSpec((1, heads, p, n),
+                             lambda i, j, src, *_: (src[i], j, 0, 0))],
+            out_specs=[
+                tile,
+                pl.BlockSpec((1, heads, p, n),
+                             lambda i, j, src, dst, _: (dst[i], j, 0, 0))]),
+        out_shape=[jax.ShapeDtypeStruct((lanes, h // heads, p, heads),
+                                        jnp.float32),
+                   jax.ShapeDtypeStruct(ssm.shape, ssm.dtype)],
+        input_output_aliases={7: 1}, name="ssm_step",
+    )(src, dst, alive.astype(jnp.int32), keep, feed.swapaxes(2, 3), b, c,
+      ssm)
+    return y.swapaxes(2, 3).reshape(lanes, h, p), ssm
+
+
 def _lane_block(cfg, blk, state, at, xbc, dt, lane, slot, live, read, write):
     """One block of lanes through a Mamba layer's convolution and
     recurrence: the rows' xBC (F, conv_dim) and dt (F, H) scattered to
@@ -329,7 +451,15 @@ def _lane_block(cfg, blk, state, at, xbc, dt, lane, slot, live, read, write):
     out of bounds and is dropped), ``live`` (L, T) its rows that are tokens,
     each lane's state read from slot ``read`` (L,) of layer ``at`` and the
     advanced state written to slot ``write`` (a write to the null slot is
-    dropped).  Returns (y + D x' (L, T, d_inner) f32, state)."""
+    dropped).  The block's static ``T`` picks the recurrence's form: one
+    row a lane (the decode block, ``(lmax - 2, 1)`` in every step class) is
+    the plain step, elementwise over the state, where the step is lowered
+    for a TPU one pass over the pool's own slots
+    (:func:`step_slots_in_place`) and elsewhere gather, :func:`ssm_step`,
+    scatter (:func:`step_slots`); a block of rows (the chunk block, ``(1,
+    tq)``) is :func:`ssd_block` between a gather and a scatter of its one
+    lane.  One algorithm at two shapes, no option and no step class of its
+    own.  Returns (y + D x' (L, T, d_inner) f32, state)."""
     lanes, t = live.shape
     heads, p, n = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size
     g, keep = cfg.n_groups, cfg.conv_kernel - 1
@@ -354,14 +484,22 @@ def _lane_block(cfg, blk, state, at, xbc, dt, lane, slot, live, read, write):
         c = out[..., cfg.d_inner + g * n:].reshape(lanes, t, g, n)
         dt = jnp.zeros((lanes, t, heads), jnp.float32).at[lane, slot].set(
             dt, mode="drop") * live[..., None]
-        y, ssm_new = ssd_block(x, dt, -jnp.exp(blk["A_log"]), b, c,
-                               ssm[at * slots + read])
+        a = -jnp.exp(blk["A_log"])
+        src, dst, alive = at * slots + read, at * slots + write, \
+            write != NULL_PAGE
+        to = jnp.where(alive, dst, layers * slots)
+        if t == 1:
+            y, ssm = jax.lax.platform_dependent(
+                ssm, src, dst, alive, x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0],
+                tpu=step_slots_in_place, default=step_slots)
+            y = y[:, None]
+        else:
+            y, ssm_new = ssd_block(x, dt, a, b, c, ssm[src])
+            ssm = ssm.at[to].set(ssm_new, mode="drop")
         y = y + blk["D"][:, None] * x
-        to = jnp.where(write == NULL_PAGE, layers * slots, at * slots + write)
         state = {"conv": conv.at[to].set(conv_new, mode="drop")
                  .reshape(state["conv"].shape),
-                 "ssm": ssm.at[to].set(ssm_new, mode="drop")
-                 .reshape(state["ssm"].shape)}
+                 "ssm": ssm.reshape(state["ssm"].shape)}
     return y.reshape(lanes, t, cfg.d_inner), state
 
 
@@ -397,8 +535,12 @@ def state_rows(rows: kv_walk.StepRows, read, write, lmax: int) -> StateRows:
 def mamba_layer(cfg: NemotronHConfig, blk: dict, lanes: StateRows,
                 x: jax.Array, state: dict, at):
     """One Mamba-2 layer inside a fused step, over state pool layer ``at``
-    (a value: a step lowers this once for all its Mamba layers): normed
-    rows x (F, hidden) -> (the mixer's output (F, hidden), state)."""
+    (a value: a step lowers this once for all its Mamba layers, and a warm
+    start traces and lowers every step class before it can ask the compile
+    cache, so what is traced here counts once a class and what is traced a
+    layer twelve-fold): normed rows x (F, hidden) -> (the mixer's output
+    (F, hidden), state).  The decode block advances each lane by the plain
+    step, the chunk block by the chunked form (:func:`_lane_block`)."""
     with jax.named_scope("ssm.project"):
         proj = dense(blk["in_proj"], x)
         z = proj[:, :cfg.d_inner]

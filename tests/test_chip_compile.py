@@ -511,3 +511,37 @@ def test_hybrid_fused_step_at_the_benchmark_cut(one_chip, f, tq):
     layouts = set(re.findall(re.escape(ssm) + r"\{([0-9,]+)", text))
     assert layouts == {"4,3,2,1,0"}, layouts
     assert not re.search(re.escape(ssm) + r"\S* copy\(", text)
+
+
+def test_hybrid_decode_block_reads_a_lanes_state_once(one_chip):
+    """The decode-only class of the step above: on a TPU the decode block's
+    recurrence is one Pallas kernel a Mamba layer over the pool's own slots
+    (``nemotron_h.step_slots_in_place``; ``lax.platform_dependent`` takes it
+    when the step is lowered for the chip, as here), so no gathered copy of
+    the 16 lanes' states exists in the step (the gather, the update and the
+    scatter were three passes of 33.5 MB in and out a layer: PERF.md
+    section 6, PR 43), and the pool still goes in and out aliased."""
+    import jax
+    import jax.numpy as jnp
+
+    from nornicdb_tpu.ragged import pack_ragged_meta
+    from nornicdb_tpu.models import nemotron_h as nh
+
+    cfg = nh.NEMOTRON_3_NANO_EP8_27L
+    lmax, w = 18, (512, 1)
+    meta, _ = pack_ragged_meta(lmax, w, 16)
+    pools = jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        jax.eval_shape(lambda: nh.init_pages(cfg, (8193, 82), 16)))
+    compiled = nh.fused_step.lower(
+        _params_on(nh.init_params, cfg, one_chip), cfg,
+        _sds(meta.shape, jnp.int32, one_chip), pools, lmax=lmax, w=w, tq=1,
+        prev=_sds((lmax + len(nh.STEP_COUNTERS),), jnp.int32, one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == len(cfg.layers_of(nh.MAMBA))
+    assert "f32[16,64,64,128]" not in text  # the lanes' states, gathered
+    held = sum(int(np.prod(p.shape)) * p.dtype.itemsize
+               for p in jax.tree.leaves(pools))
+    assert compiled.memory_analysis().alias_size_in_bytes >= held

@@ -30,6 +30,7 @@ reference's logits), never on sampled tokens.  Tolerances, and why:
 """
 
 import dataclasses
+import functools
 import http.client
 import json
 import re
@@ -53,6 +54,8 @@ from decoder_harness import (
     typical,
     with_norm_scales,
 )
+from jax.experimental import pallas as pl
+
 from nornicdb_tpu.models import experts
 from nornicdb_tpu.models import nemotron_h as nh
 from nornicdb_tpu.models.reference import nemotron_h as ref
@@ -155,21 +158,35 @@ def test_bf16_step_is_within_tolerance_and_fp8_is_not(seed):
     assert typical(low, want) > BF16_TOL, typical(low, want)
 
 
-@pytest.mark.parametrize("t", [1, 7, 16])
-def test_the_chunked_recurrence_is_the_sequential_one(t):
-    """``ssd_block`` (the matmul form over a block of rows, from a state)
-    against one token after the other in numpy float64; rows at dt = 0
-    (padding) neither decay nor feed the state."""
-    rng = np.random.default_rng(t)
-    lanes, h, p, g, n = 3, 8, 4, 2, 5
+def plain_step(x, dt, a, b, c, s0):
+    """``ssm_step`` in ``ssd_block``'s shapes at ``T = 1``."""
+    y, s_1 = nh.ssm_step(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], s0)
+    return y[:, None], s_1
+
+
+def recurrence_inputs(seed, lanes, t, h, p, g, n):
+    rng = np.random.default_rng(seed)
     x = rng.normal(size=(lanes, t, h, p))
     dt = rng.uniform(0.01, 0.5, size=(lanes, t, h))
-    dt[1, t // 2:] = 0.0  # lane 1 ends half way
+    dt[1, t // 2:] = 0.0  # lane 1 ends half way (at t = 1: it has no token)
     a = -rng.uniform(0.5, 8.0, size=h)
     b, c = rng.normal(size=(2, lanes, t, g, n))
     s0 = rng.normal(size=(lanes, h, p, n))
-    y, s_t = nh.ssd_block(*(jnp.asarray(v, jnp.float32)
-                            for v in (x, dt, a, b, c, s0)))
+    return x, dt, a, b, c, s0
+
+
+@pytest.mark.parametrize("t,form", [
+    (1, nh.ssd_block), (7, nh.ssd_block), (16, nh.ssd_block),
+    (1, plain_step)], ids=["1", "7", "16", "1-plain"])
+def test_the_chunked_recurrence_is_the_sequential_one(t, form):
+    """``ssd_block`` (the matmul form over a block of rows, from a state),
+    and at ``t = 1`` ``ssm_step`` (the decode block's plain step), against
+    one token after the other in numpy float64; rows at dt = 0 (padding)
+    neither decay nor feed the state: lane 1 at ``t = 1`` keeps its own."""
+    lanes, h, p, g, n = 3, 8, 4, 2, 5
+    x, dt, a, b, c, s0 = recurrence_inputs(t, lanes, t, h, p, g, n)
+    y, s_t = form(*(jnp.asarray(v, jnp.float32)
+                    for v in (x, dt, a, b, c, s0)))
     s, want = s0.copy(), np.zeros_like(x)
     for i in range(t):
         bh, ch = (np.repeat(v[:, i], h // g, axis=1) for v in (b, c))
@@ -180,6 +197,66 @@ def test_the_chunked_recurrence_is_the_sequential_one(t):
     np.testing.assert_allclose(np.asarray(y) * live[..., None],
                                want * live[..., None], atol=2e-5)
     np.testing.assert_allclose(np.asarray(s_t), s, atol=2e-5)
+    if t == 1:
+        assert (np.asarray(s_t)[1] == s0[1].astype(np.float32)).all()
+
+
+@pytest.mark.parametrize("h,g", [(8, 1), (8, 2), (8, 8), (6, 3)])
+def test_the_plain_step_is_the_chunked_form_at_one_row(h, g):
+    """The two forms of one algorithm at the shape where they meet:
+    ``ssm_step`` against ``ssd_block`` at ``T = 1``, in float32, for one
+    group, several heads a group (``H / G`` 8, 4, 2) and a group a head; a
+    lane at dt = 0 comes back bit for bit."""
+    lanes, p, n = 4, 4, 16
+    args = [jnp.asarray(v, jnp.float32)
+            for v in recurrence_inputs(100 * h + g, lanes, 1, h, p, g, n)]
+    (y, s_1), (want_y, want_s) = plain_step(*args), nh.ssd_block(*args)
+    assert y.shape == want_y.shape and s_1.shape == want_s.shape
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(s_1), np.asarray(want_s),
+                               atol=2e-5)
+    assert (np.asarray(s_1)[1] == np.asarray(args[5])[1]).all()
+
+
+def interpreted(monkeypatch):
+    """The TPU kernel on this backend: Pallas's interpreter in place of its
+    compiler (the program takes no option for it)."""
+    monkeypatch.setattr(nh.pl, "pallas_call",
+                        functools.partial(pl.pallas_call, interpret=True))
+
+
+@pytest.mark.parametrize("h,g", [(8, 1), (8, 2), (8, 8), (32, 4)])
+def test_the_one_pass_kernel_is_gather_step_scatter(monkeypatch, h, g):
+    """``step_slots_in_place`` (the decode block on a TPU: the pool's own
+    slots as the kernel's blocks) against ``step_slots`` (gather, plain
+    step, scatter) over one pool of three layers: lanes that read and write
+    their own slot, one that begins from the null slot, one that leaves a
+    snapshot behind (reads one slot, writes another), one at dt = 0, and
+    lanes that write nothing, whose layer's null slot stays zeros."""
+    interpreted(monkeypatch)
+    lanes, p, n, slots, layer = 7, 8, 16, 9, 1
+    rng = np.random.default_rng(10 * h + g)
+    x, dt, a, b, c, _ = (jnp.asarray(v, jnp.float32) for v in
+                         recurrence_inputs(h + g, lanes, 1, h, p, g, n))
+    ssm = rng.normal(size=(3, slots, h, p, n)).astype(np.float32)
+    ssm[:, 0] = 0.0
+    read = np.array([3, 1, 0, 5, 2, 0, 0])
+    write = np.array([3, 1, 4, 6, 2, 0, 0])
+    src, dst = (jnp.asarray(layer * slots + v, jnp.int32)
+                for v in (read, write))
+    args = (src, dst, jnp.asarray(write != 0), x[:, 0], dt[:, 0], a,
+            b[:, 0], c[:, 0])
+    flat = jnp.asarray(ssm.reshape((-1,) + ssm.shape[2:]))
+    want_y, want = nh.step_slots(flat, *args)
+    y, got = jax.jit(nh.step_slots_in_place, donate_argnums=0)(
+        jnp.array(flat), *args)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    got = np.asarray(got).reshape(ssm.shape)
+    assert not got[:, 0].any()
+    assert (got[[0, 2]] == ssm[[0, 2]]).all()             # other layers
+    assert (got[layer, [5, 7, 8]] == ssm[layer, [5, 7, 8]]).all()
+    assert (got[layer, 1] == ssm[layer, 1]).all()         # the lane at dt = 0
 
 
 # ------------------------------------------- (b) the config and the cut
@@ -364,6 +441,32 @@ def test_padding_rows_and_empty_lanes_advance_nothing():
         assert np.abs(got - exp).max() < 1e-5
 
 
+@pytest.mark.parametrize("seed", [12, 13])
+def test_the_decode_block_and_the_chunk_block_agree_on_one_token(seed):
+    """The same token behind the same prompt, once as a decode row (the
+    plain step, ``T = 1``) and once as a one-token chunk (the chunked form
+    over a bucket of 16 rows, 15 of them padding at dt = 0): the same
+    logits, and the same convolution inputs and SSM state left in the
+    lane's slot, in float32."""
+    params = make_params(F32, seed)
+    ids = tokens(seed, 21)
+
+    def run(as_chunk: bool):
+        pool = new_pool(params)
+        lane = Lane(pool)
+        tok = int(prefill(pool, lane, ids)[0].argmax())
+        logits = pool.step(chunk=([tok], len(ids), lane))[-1] if as_chunk \
+            else pool.step(decode=[(tok, len(ids), lane)])[0]
+        return logits, {k: np.asarray(v[:, lane.slot[1]])
+                        for k, v in pool.pool[1].items()}
+
+    (dec, dec_state), (chk, chk_state) = run(False), run(True)
+    assert largest(dec[None], chk[None]) < F32_TOL
+    assert np.abs(dec_state["ssm"]).max() > 0.01
+    for part in ("conv", "ssm"):
+        assert np.abs(dec_state[part] - chk_state[part]).max() < 1e-5, part
+
+
 def _rotated(plain):
     """``attend_step`` with q and k rotated by position (half pairs): what
     the config's unused ``rope_theta`` would do."""
@@ -444,6 +547,44 @@ def _broken(monkeypatch, fault: str):
         STEP_COUNTERS=nh.STEP_COUNTERS)
 
 
+def test_the_step_on_the_one_pass_kernel_is_the_step(monkeypatch):
+    """The whole fused step with the decode block on the TPU's kernel (here
+    under Pallas's interpreter, as ``platform_dependent``'s default): the
+    same logits, the same states and a null slot of zeros as the step this
+    backend runs, for decode lanes beside a chunk with padding rows, empty
+    lanes, and a lane that goes on from the snapshot its prefill left."""
+    params = make_params(F32, 14)
+    prompts = [tokens(14 + i, 20 + 3 * i) for i in range(3)]
+
+    def run(family):
+        pool = new_pool(params, family=family)
+        lanes = [Lane(pool) for _ in prompts]
+        # lane 1's last chunk leaves a snapshot: its first decode row reads
+        # that slot and writes the lane's own
+        firsts = [int(prefill(pool, lane, ids,
+                              snapshot_at=len(ids) if i else None)[0].argmax())
+                  for i, (lane, ids) in enumerate(zip(lanes[:2], prompts))]
+        decode = [(tok, len(ids), lane) for tok, ids, lane in
+                  zip(firsts, prompts, lanes)]
+        logits = [pool.step(decode=decode,
+                            chunk=(prompts[2][:13], 0, lanes[2]))[:2]]
+        decode = [(int(row.argmax()), at + 1, lane)
+                  for row, (_, at, lane) in zip(logits[0], decode)]
+        logits.append(pool.step(decode=decode))
+        state = pool.pool[1]
+        assert not np.asarray(state["ssm"][:, 0]).any()
+        return np.concatenate(logits), np.asarray(state["ssm"])
+
+    want, want_states = run(nh)  # traced before the kernel is planted
+    interpreted(monkeypatch)
+    # a new function object: the branches' traces are cached by function
+    monkeypatch.setattr(nh, "step_slots",
+                        lambda *args: nh.step_slots_in_place(*args))
+    rows, states = run(_broken(monkeypatch, "no fault"))
+    assert largest(rows, want) < F32_TOL
+    assert np.abs(states - want_states).max() < 1e-5
+
+
 @pytest.mark.parametrize("fault", [
     "gates_unscaled", "gates_unnormalised", "shared_dropped", "held_dropped",
     "rope_in_attention", "padding_advances", "dt_bias_left_out"])
@@ -492,15 +633,21 @@ def test_a_state_kept_wrongly_is_outside_the_tolerance(fault):
     assert typical(rows, want) > WIRING_TOL, (fault, typical(rows, want))
 
 
-def test_the_step_carries_its_scopes_and_its_own_module_name():
+def lowered_step(f: int, tq: int):
+    """The small config's step class (F flat rows, a chunk bucket of tq),
+    lowered from shapes."""
     params = jax.eval_shape(lambda: nh.init_params(BF16,
                                                    jax.random.PRNGKey(0)))
     w = (8, 1)
     meta = jax.ShapeDtypeStruct(
-        (4 * 16 + LMAX + sum(LMAX * (1 + wk) for wk in w),), jnp.int32)
+        (4 * f + LMAX + sum(LMAX * (1 + wk) for wk in w),), jnp.int32)
     pages = jax.eval_shape(lambda: nh.init_pages(BF16, (9, 5), PAGE))
-    lowered = nh.fused_step.lower(params, BF16, meta, pages, lmax=LMAX, w=w,
-                                  tq=16)
+    return nh.fused_step.lower(params, BF16, meta, pages, lmax=LMAX, w=w,
+                               tq=tq)
+
+
+def test_the_step_carries_its_scopes_and_its_own_module_name():
+    lowered = lowered_step(16, 16)
     text = lowered.as_text(debug_info=True)
     for scope in ("ssm.project", "ssm.conv", "ssm.scan", "ssm.gate",
                   "ssm.out", "attn.project", "attn.attend", "moe.route",
@@ -511,6 +658,72 @@ def test_the_step_carries_its_scopes_and_its_own_module_name():
     assert nh.STEP_COUNTERS == ROUTING_COUNTERS + (
         "attn_slots_walked", "attn_slots_table", "shared_run_pages",
         "ssm_rows")
+
+
+# ----------------------------- (c') what a step class costs a warm start
+def scan_ops(text: str, part: str | None = None) -> list[str]:
+    """Of a lowered module's text (``debug_info=True``), or of ``part`` of
+    it, the location name of every exponential and every ``dot_general``
+    inside scope ``ssm.scan``: ``ssm.scan/exp``, ``ssm.scan/dot_general``."""
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    found = [names.get(loc, "") for loc in re.findall(
+        r"stablehlo\.(?:exponential|dot_general)\b.*loc\((#loc\d+)\)",
+        text if part is None else part)]
+    return [name for name in found if "ssm.scan" in name]
+
+
+@pytest.mark.parametrize("f,tq", [(8, 1), (32, 16)], ids=["decode", "chunk"])
+def test_a_step_class_lowers_the_mamba_layer_once_and_no_matmul_a_decode_row(
+        f, tq):
+    """What a step class costs a WARM start (PERF.md section 7 (nn)): the
+    engine traces and lowers every one of its step classes before it can
+    ask the compile cache, so whatever is traced once a LAYER counts
+    twelve-fold in the cell's ``stack_s``, eleven classes over.  Held here
+    on the lowered module of a decode-only and of a chunk class: (a) the
+    Mamba layer's body is ONE private function, called once a Mamba layer
+    (``mamba_layer`` is one ``jax.jit`` with the pool layer a value), and
+    the recurrence's ops are in it alone; (b) the decode block's recurrence
+    is the plain step: a decode-only class holds no ``dot_general`` inside
+    ``ssm.scan`` (the chunked form's ``highest`` matmuls load a lane's whole
+    state into the MXU for ONE row: 0.44 ms a layer where the state read
+    and written once is 0.09), and a class with a chunk holds
+    ``ssd_block``'s four, the chunk block's, and no more."""
+    text = lowered_step(f, tq).as_text(debug_info=True)
+    assert len(re.findall(r"func\.func private @mamba_layer\w*\(",
+                          text)) == 1
+    assert len(re.findall(r"call @mamba_layer\(", text)) == N_MAMBA
+    body = text[text.index("func.func private @mamba_layer("):]
+    body = body[:body.index("\n  } loc(")]
+    scan = scan_ops(text)
+    assert scan and scan == scan_ops(text, body)  # once, whatever the layers
+    matmuls = [name for name in scan if name.endswith("dot_general")]
+    assert len(matmuls) == (0 if tq == 1 else 4), matmuls
+
+
+def test_the_engines_step_classes_and_programs_do_not_grow():
+    """(c) of the guard above: a step class is 7-9 s of compile in a cold
+    start and a trace, a lowering and an executable to load in a warm one
+    (PERF.md section 7 (nn)), so the choice between the recurrence's two
+    forms may not be a class of its own: at the cell's geometry (16 lanes, a
+    64-token chunk) the engine lists eleven classes as it did, ``warmup()``
+    compiles just those, and traffic (chunks of every bucket beside decode
+    rows, decode-only steps) adds no program to them."""
+    eng, params = small_engine(max_seqs=16, prefill_chunk=64,
+                               state_slots=40, pool_pages=129)
+    classes = eng._ragged_classes()
+    assert len(classes) == 11
+    assert [c for c in classes if c[1] == 1] == [(8, 1), (16, 1)]
+    eng.warmup(timeout=600)
+    warmed = set(eng.programs)
+    assert len(warmed) == 11
+    prompts = [tokens(90 + i, n) for i, n in enumerate((9, 40, 70, 100, 23))]
+    handles = [eng.submit(ids, max_new_tokens=6) for ids in prompts]
+    outs = [h.result() for h in handles]
+    for ids, out in zip(prompts, outs):
+        assert_reference(params, F32, ids, out)
+    assert eng.stats_snapshot()["ssm_rows"] > 0
+    assert set(eng.programs) == warmed
+    settled(eng)
 
 
 # ------------------------------------- (d) the scheduler's state kind
