@@ -21,10 +21,12 @@ import queue
 import re
 import threading
 import time
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Iterator, Optional
 
 from nornicdb_tpu.telemetry.metrics import count_error as _count_error
+from nornicdb_tpu.telemetry.tracing import tracer as _tracer
 
 log = logging.getLogger(__name__)
 
@@ -43,6 +45,36 @@ from nornicdb_tpu.heimdall.registry import (
     ModelInfo,
     ModelRegistry,
 )
+
+
+# distinct prompt heads whose ids an EngineGenerator keeps, least recently
+# used out first: a deployment sends one system message, or a few
+PROMPT_HEADS = 8
+# a request's way in, counted in the manager's registry (``heimdall_*``)
+WAY_IN_COUNTERS = ("prompt_head_hits", "prompt_head_misses",
+                   "prompt_tokens_cached", "prompt_tokens_encoded",
+                   "tokenize_seconds")
+
+
+class Prompt(str):
+    """A prompt that names its ``head``: the text before its last message
+    (the assistant's preamble, the system message, earlier turns), which
+    repeats from request to request and ends in white space.  To a backend
+    it is a ``str``; :class:`EngineGenerator` encodes a head once.  Made
+    only by :func:`with_head`."""
+
+    head = ""
+
+
+def with_head(text: str, head: str) -> str:
+    """``text`` naming ``head`` where it still begins with it byte for byte
+    (a plugin's ``pre_prompt`` may have rewritten either part) and the head
+    ends in white space; else a plain ``str``."""
+    if not (head[-1:].isspace() and text.startswith(head)):
+        return str(text)
+    prompt = Prompt(text)
+    prompt.head = head
+    return prompt
 
 
 @dataclass
@@ -184,13 +216,51 @@ class EngineGenerator(Generator):
         # tooling and the model registry read these)
         self.cfg = engine.cfg
         self.params = engine.params
+        # the way in: WAY_IN_COUNTERS (a HeimdallManager hands its own
+        # registry), and the ids of the heads prompts repeat, exact only
+        # where the tokenizer declares that no token spans white space
+        self.metrics = MetricsRegistry()
+        self._split = getattr(self.tokenizer, "SPLITS_AT_WHITESPACE", False)
+        self._heads: OrderedDict[str, tuple[int, ...]] = OrderedDict()
+        self._heads_lock = threading.Lock()
 
     def _ids(self, prompt: str) -> list[int]:
-        """The prompt's TAIL within the model's trained window: for
-        in-image checkpoints rope positions beyond it were never seen in
-        training."""
-        ids = self.tokenizer.encode(prompt, add_special=False)
+        """The prompt's ids, its TAIL kept within the model's trained
+        window: for in-image checkpoints rope positions beyond it were
+        never seen in training."""
+        with _tracer.stage("heimdall.tokenize") as stage:
+            ids, cached = self._encode(prompt)
+            stage.set_attr("cached_tokens", cached)
+        self.metrics.inc("prompt_tokens_cached", cached)
+        self.metrics.inc("prompt_tokens_encoded", len(ids) - cached)
+        self.metrics.inc("tokenize_seconds", stage.seconds)
         return ids[-self.max_context:] or [1]
+
+    def _encode(self, prompt: str) -> tuple[list[int], int]:
+        """(the whole prompt's ids, how many of them the head cache gave).
+        A head the prompt names (:class:`Prompt`) is encoded once and
+        kept; then only the rest of the prompt is encoded.  The ids are
+        ``encode(prompt)``'s exactly: the head ends in white space."""
+        head = getattr(prompt, "head", "")
+        if not (head and self._split):
+            return self.tokenizer.encode(prompt, add_special=False), 0
+        with self._heads_lock:
+            head_ids = self._heads.get(head)
+            if head_ids is not None:
+                self._heads.move_to_end(head)
+        if head_ids is None:
+            self.metrics.inc("prompt_head_misses")
+            head_ids = tuple(self.tokenizer.encode(head, add_special=False))
+            with self._heads_lock:
+                self._heads[head] = head_ids
+                if len(self._heads) > PROMPT_HEADS:
+                    self._heads.popitem(last=False)
+            cached = 0
+        else:
+            self.metrics.inc("prompt_head_hits")
+            cached = len(head_ids)
+        tail = self.tokenizer.encode(prompt[len(head):], add_special=False)
+        return [*head_ids, *tail], cached
 
     def _cap(self, max_tokens: int) -> int:
         """Bound decode length to one trained window beyond the prompt:
@@ -288,8 +358,13 @@ class HeimdallManager:
         self.bifrost = Bifrost()
         self.metrics = HeimdallMetrics()
         # named-metrics registry with Prometheus rendering
-        # (ref: pkg/heimdall/metrics.go)
+        # (ref: pkg/heimdall/metrics.go); the way-in counters stand at 0
+        # until a backend that tokenizes counts into them
         self.metrics_registry = MetricsRegistry()
+        for name in WAY_IN_COUNTERS:
+            self.metrics_registry.inc(name, 0.0)
+        if isinstance(generator, EngineGenerator):
+            generator.metrics = self.metrics_registry
         # model registry; the construction generator is the default
         # reasoning model (ref: ModelInfo types.go:32, scheduler model pick)
         self.models = ModelRegistry()
@@ -430,14 +505,19 @@ class HeimdallManager:
 
     # -- generation (ref: Generate scheduler.go:178) ---------------------------
     def generate(self, prompt: str, max_tokens: int = 128,
-                 generator: Optional[Generator] = None) -> str:
+                 generator: Optional[Generator] = None,
+                 head: str = "") -> str:
         """One generation with metric/error accounting. PluginHost wraps
         this method to apply pre_prompt hooks — any alternate-model path
         must also flow through here, never call a backend directly, or
         plugin guards (redaction, veto) become evadable by picking a
-        different registered model."""
+        different registered model.  ``head``: the prompt's head as it
+        was built (:meth:`_build_prompt`), named to the backend where the
+        hooked prompt still begins with it."""
         t0 = time.perf_counter()
         backend = generator if generator is not None else self.generator
+        if head:
+            prompt = with_head(prompt, head)
         try:
             out = backend.generate(prompt, max_tokens)
             self.metrics.generations += 1
@@ -517,14 +597,17 @@ class HeimdallManager:
                 break
         return ctx
 
-    def _build_prompt(self, ctx, messages: list[dict[str, str]]) -> str:
+    def _build_prompt(self, ctx, messages: list[dict[str, str]]) -> Prompt:
         """One prompt assembly for streamed AND non-streamed chat — the two
-        paths must never drift in format."""
+        paths must never drift in format.  Its head is everything before
+        the last message's line, with the newline that ends it."""
         prompt_parts = [ctx.build_final_prompt()]
         for m in messages:
             prompt_parts.append(f"{m.get('role', 'user')}: {m.get('content', '')}")
         prompt_parts.append("assistant:")
-        return "\n".join(prompt_parts)
+        split = max(1, len(prompt_parts) - 2)
+        head = "\n".join(prompt_parts[:split]) + "\n"
+        return with_head(head + "\n".join(prompt_parts[split:]), head)
 
     def _dispatch_action(self, action: dict):
         """Shared action dispatch; returns the raw result (or error dict)."""
@@ -627,12 +710,14 @@ class HeimdallManager:
             response["action_result"] = action_result
         return response
 
-    def _generate_with(self, generator, prompt: str, max_tokens: int) -> str:
+    def _generate_with(self, generator, prompt: Prompt,
+                       max_tokens: int) -> str:
         """Dispatch through self.generate so the PluginHost wrapper (and
         its pre_prompt hooks) applies to every backend."""
         if generator is self.generator:
-            return self.generate(prompt, max_tokens)
-        return self.generate(prompt, max_tokens, generator=generator)
+            return self.generate(prompt, max_tokens, head=prompt.head)
+        return self.generate(prompt, max_tokens, generator=generator,
+                             head=prompt.head)
 
     def chat_stream(self, messages: list[dict[str, str]],
                     max_tokens: int = 128, model: Optional[str] = None,
@@ -737,8 +822,8 @@ class HeimdallManager:
             yield {"object": "chat.completion.chunk", "choices": [],
                    "notification": note}
         # plugin guards (redaction, veto) apply to streamed prompts too
-        prompt = self.pre_prompt_transform(
-            self._build_prompt(ctx, messages))
+        built = self._build_prompt(ctx, messages)
+        prompt = with_head(self.pre_prompt_transform(built), built.head)
         pieces: list[str] = []
         t0 = time.perf_counter()
         try:

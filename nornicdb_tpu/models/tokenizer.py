@@ -27,6 +27,11 @@ class HashTokenizer:
     ids 0..3 are reserved: 0=<s>/CLS, 1=<pad>, 2=</s>, 3=<unk>.
     """
 
+    # no token spans white space (``_WORD_RE``): for a ``head`` that ends
+    # in white space, encode(head + tail) == encode(head) + encode(tail)
+    # without special tokens, so a caller may encode a repeated head once
+    SPLITS_AT_WHITESPACE = True
+
     def __init__(self, vocab_size: int = 1024):
         self.vocab_size = vocab_size
         self.cls_id = 0
@@ -71,6 +76,8 @@ class HashTokenizer:
 class HFTokenizer:
     """Minimal HuggingFace tokenizer.json reader (WordPiece/BPE vocab only;
     whitespace pre-tokenization). Used when real model assets are mounted."""
+
+    SPLITS_AT_WHITESPACE = True  # words by ``_WORD_RE``, as HashTokenizer
 
     def __init__(self, path: str):
         with open(path) as f:
