@@ -480,12 +480,14 @@ def percentile(values, q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
-def counter_ratio(spec: dict, before: dict, after: dict, bench: dict):
+def counter_ratio(spec: dict, before: dict, after: dict, bench: dict,
+                  config: dict | None = None):
     """``numerator`` / ``denominator``, each a dotted path into the
     program's counters (read as a delta over the window) or, as
-    ``bench.<name>``, one of the window's own numbers.  A path that does not
-    resolve (a counter that this program does not have: a parent laid under
-    a newer benchmark) finds nothing to read."""
+    ``bench.<name>``, one of the window's own numbers, times ``scale`` (or,
+    with ``config_scale``, times a number of the cell's ``config``).  A path
+    that does not resolve (a counter that this program does not have: a
+    parent laid under a newer benchmark) finds nothing to read."""
     def read(path):
         try:
             return bench[path[6:]] if path.startswith("bench.") \
@@ -494,9 +496,11 @@ def counter_ratio(spec: dict, before: dict, after: dict, bench: dict):
             return None
 
     num, den = read(spec["numerator"]), read(spec["denominator"])
-    if num is None or not den:
+    scale = selfcheck.config_scale(spec, config) if "config_scale" in spec \
+        else spec.get("scale", 1.0)
+    if num is None or not den or scale is None:
         return None
-    return spec.get("scale", 1.0) * num / den
+    return scale * num / den
 
 
 def trace_metric(spec: dict, config: dict, peaks: dict, cap: dict, ctx: dict):
@@ -707,7 +711,7 @@ def run_cell(args) -> dict:
                     "failed": len(inside) - len(done)}
     if not args.trace:
         wanted = [m["name"] for m in manifest["end_to_end"]
-                  if args.workload in m.get("workloads", [args.workload])]
+                  if args.workload in selfcheck.metric_cells(manifest, m)]
         metrics = {n: values[n] for n in wanted if n in values}
     else:
         cap_events = trace_mod.load_events(tracer.dir)
@@ -728,11 +732,11 @@ def run_cell(args) -> dict:
             if not args.rehearse_cpu else work.peaks_for("TPU v5e")
         metrics, unread = {}, []
         for m in manifest["per_layer"]:
-            if args.workload not in m.get("workloads", [args.workload]):
+            if args.workload not in selfcheck.metric_cells(manifest, m):
                 continue
             spec = load_json(HERE, "metrics", m["name"] + ".json")
             if spec["reader"] == "counter_ratio":
-                value = counter_ratio(spec, before, after, bench)
+                value = counter_ratio(spec, before, after, bench, config)
             elif spec["reader"] == "window":
                 value = bench.get(spec["value"])
             else:

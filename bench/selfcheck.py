@@ -6,9 +6,10 @@
 Holds ``BENCHMARK.json`` to the limits a driver refuses a manifest over
 (names, units, lengths, counts, the quarter rule for four-chip cells), checks
 that every name in it leads to its file, that each per-layer metric's
-``moves`` is an end-to-end metric that every cell in its ``workloads``
-reports, and runs ``bench/trace.py`` and ``bench/work.py`` on a small
-recorded trace with known answers.  Exits 1 with every fault it found.
+``moves`` is an end-to-end metric that every cell it is read in reports
+(:func:`metric_cells`), and runs ``bench/trace.py`` and ``bench/work.py`` on
+a small recorded trace with known answers.  Exits 1 with every fault it
+found.
 """
 
 from __future__ import annotations
@@ -32,8 +33,34 @@ KEYS = {"configs": {"name", "source", "file", "reduced", "why"},
         "workloads": {"name", "config", "traffic", "chips", "why"},
         "end_to_end": {"name", "unit", "better", "bound", "source"},
         "per_layer": {"name", "unit", "better", "source", "layer", "moves"}}
-WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "proj", "head_dim",
-               "expansion", "experts_per")
+# a key names a width (a size of a row) where it ends in ``_dim`` or
+# ``_rank`` or holds one of these words whole, unless its last word is
+# ``layers``: ``num_hidden_layers`` is a depth
+WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "proj",
+               "projection", "expansion", "expand", "experts_per")
+
+
+def names_a_width(key: str) -> bool:
+    words = key.split("_")
+    if key.endswith(("_dim", "_rank")):
+        return True
+    if words[-1] == "layers":
+        return False
+    return any(w.split("_") == words[i:i + w.count("_") + 1]
+               for w in WIDTH_WORDS for i in range(len(words)))
+
+
+def metric_cells(m: dict, metric: dict) -> list[str]:
+    """The cells in which ``metric`` of manifest ``m`` is read: its
+    ``workloads``; without that key every cell, or, for a per-layer metric,
+    every cell that reports the end-to-end metric it ``moves`` (so a cell a
+    later PR adds reads it with no edit)."""
+    cells = [w["name"] for w in m["workloads"]]
+    if "workloads" in metric:
+        return metric["workloads"]
+    moves = next((e for e in m["end_to_end"]
+                  if e["name"] == metric.get("moves")), {})
+    return moves.get("workloads", cells)
 
 
 def line_ok(text, limit: int = 200) -> bool:
@@ -95,8 +122,7 @@ def check_manifest(m: dict, faults: list[str]) -> None:
         if len(c["reduced"]) > 16:
             say(f"config {c['name']}: reduced has over 16 keys")
         for key in c["reduced"]:
-            if not NAME.match(key) or key.endswith(("_dim", "_rank")) \
-                    or any(w in key for w in WIDTH_WORDS):
+            if not NAME.match(key) or names_a_width(key):
                 say(f"config {c['name']}: reduced may not name {key!r}")
         if c["name"] not in {w["config"] for w in m["workloads"]}:
             say(f"config {c['name']}: no cell uses it")
@@ -137,7 +163,7 @@ def check_manifest(m: dict, faults: list[str]) -> None:
                 and 0.01 <= e["bound"] <= 0.1):
             say(f"end-to-end {e['name']}: bound {e.get('bound')} is outside "
                 "0.01 to 0.1")
-        for cell in e.get("workloads", list(cells)):
+        for cell in metric_cells(m, e):
             if cell in reports:
                 reports[cell].add(e["name"])
     layered = {name: 0 for name in cells}
@@ -149,7 +175,7 @@ def check_manifest(m: dict, faults: list[str]) -> None:
             say(f"per-layer {e['name']}: moves {e.get('moves')!r} is no "
                 "end-to-end metric")
             continue
-        for cell in e.get("workloads", list(cells)):
+        for cell in metric_cells(m, e):
             if cell in reports and e["moves"] not in reports[cell]:
                 say(f"per-layer {e['name']}: cell {cell} does not report "
                     f"{e['moves']}")
@@ -252,7 +278,7 @@ def check_files(m: dict, faults: list[str]) -> None:
             metric = next((e for e in m["end_to_end"] if e["name"] == name),
                           None)
             if name and (metric is None or w["name"] not in
-                         metric.get("workloads", [w["name"]])):
+                         metric_cells(m, metric)):
                 say(f"cell {w['name']}: {name!r} is not an end-to-end "
                     "metric of this cell")
     for e in m["per_layer"]:
@@ -263,9 +289,17 @@ def check_files(m: dict, faults: list[str]) -> None:
             if spec.get(key) != e[key]:
                 say(f"metric {e['name']}: {key} differs between the manifest "
                     "and bench/metrics")
-        if spec.get("workloads") != e.get("workloads"):
+        # the manifest lists a metric's cells; a file that states them too
+        # (where a test reads the list off the file) states the same
+        if "workloads" in spec and spec["workloads"] != e.get("workloads"):
             say(f"metric {e['name']}: workloads differ between the manifest "
                 "and bench/metrics")
+        if "config_scale" in spec:
+            for w in m["workloads"]:
+                if w["name"] in metric_cells(m, e) and config_scale(
+                        spec, load("configs", w["config"] + ".json")) is None:
+                    say(f"metric {e['name']}: no path of config_scale in "
+                        f"configuration {w['config']}")
         if spec.get("reader") not in ("counter_ratio", "trace", "window"):
             say(f"metric {e['name']}: reader {spec.get('reader')!r}")
         if "work" in spec:
@@ -277,6 +311,21 @@ def check_files(m: dict, faults: list[str]) -> None:
         if spec.get("kind") == "roofline" and \
                 not e["name"].split(".")[0].endswith("_roofline"):
             say(f"metric {e['name']}: a roofline share is <kernel>_roofline")
+
+
+def config_scale(spec: dict, config: dict):
+    """A ``counter_ratio``'s ``scale`` times the number at the first of
+    ``config_scale.paths`` (dotted, a list index a number) that the cell's
+    configuration has, to ``config_scale.power``; None where it has none."""
+    for path in spec["config_scale"]["paths"]:
+        tree = config
+        try:
+            for key in path.split("."):
+                tree = tree[int(key)] if isinstance(tree, list) else tree[key]
+        except (KeyError, IndexError, TypeError, ValueError):
+            continue
+        return spec.get("scale", 1.0) * tree ** spec["config_scale"]["power"]
+    return None
 
 
 def check_trace(faults: list[str]) -> None:

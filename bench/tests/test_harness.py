@@ -1,14 +1,20 @@
 """The harness's own rules, on the CPU and without a server: a counter the
 program lacks finds nothing to read; a chat request is the same request in
 any process and holds what the cell states; a closed loop reports no
-latency; a configuration whose family file is missing is refused; the manifest and every file under ``bench/`` pass ``selfcheck``.
+latency; a configuration whose family file is missing is refused; the
+manifest and every file under ``bench/`` pass ``selfcheck``; no two
+per-layer files share a reader; a cell a later PR adds reads the shared
+readers with no edit; a scale taken from the configuration; a cut may name a
+depth and no width.
 
     python3 -m pytest bench/tests/test_harness.py -q
 """
 
+import glob
 import hashlib
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -47,6 +53,31 @@ def test_counter_ratio_reads_the_window():
                              {"seconds": 20.0}) == 1.5
     assert run.counter_ratio(spec, {"g": {"a": 0}}, {"g": {"a": 30}},
                              {}) is None
+
+
+ROUTED = {"numerator": "g.hits", "denominator": "g.rows",
+          "config_scale": {"paths": ["generator.num_experts_per_tok",
+                                     "generator.moe_topk"], "power": -1}}
+
+
+@pytest.mark.parametrize("generator,value", [
+    ({"num_experts_per_tok": 6}, 2.0), ({"moe_topk": 12}, 1.0),
+    ({"num_experts_per_tok": 4, "moe_topk": 12}, 3.0), ({}, None)])
+def test_counter_ratio_takes_its_scale_from_the_configuration(generator,
+                                                             value):
+    """The first path the cell's configuration has; none, nothing read."""
+    got = run.counter_ratio(ROUTED, {"g": {"hits": 0, "rows": 0}},
+                            {"g": {"hits": 24, "rows": 2}}, {},
+                            {"generator": generator})
+    assert got == value
+
+
+def test_config_scale_indexes_a_list():
+    spec = {"config_scale": {"paths": ["generator.held_experts.1"],
+                             "power": 1}, "scale": 0.5}
+    config = {"generator": {"held_experts": [0, 20]}}
+    assert selfcheck.config_scale(spec, config) == 10.0
+    assert selfcheck.config_scale(spec, {"generator": {}}) is None
 
 
 def chat_cell():
@@ -128,10 +159,15 @@ def test_selfcheck_accepts_the_manifest_and_refuses_a_missing_family():
     assert selfcheck.main() == 0
     with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    assert len(manifest["workloads"]) == 3 and len(manifest["configs"]) == 3
+    # at least: a later PR adds cells and configurations and may not edit this
+    assert len(manifest["workloads"]) >= 8 and len(manifest["configs"]) >= 8
     assert [m["name"] for m in manifest["end_to_end"]] == [
         "search_qps", "embed_p50_ms", "tpot_ms", "setup_s"]
-    assert all(m.get("workloads") for m in manifest["per_layer"])
+    cells = {w["name"] for w in manifest["workloads"]}
+    assert len(manifest["per_layer"]) <= 128
+    for m in manifest["per_layer"]:
+        read_in = selfcheck.metric_cells(manifest, m)
+        assert read_in and set(read_in) <= cells, m["name"]
     faults: list = []
     plain = os.path.isfile
     os.path.isfile = lambda p: plain(p) and not p.endswith("qwen2.py")
@@ -157,3 +193,95 @@ def test_selfcheck_refuses_a_closed_loop_latency(monkeypatch):
     faults: list = []
     selfcheck.check_files(manifest, faults)
     assert any("closed loop" in f for f in faults), faults
+
+
+# what a per-layer file reads: every field but its name, cells and prose
+READER_KEYS = ("reader", "kind", "programs", "work", "numerator",
+               "denominator", "scale", "config_scale", "value", "unit",
+               "better", "layer", "moves", "source")
+
+
+def tier1_pinned() -> set:
+    """Copies of a reader that tier-1 tests pin: ``tests/test_genserve_turn.py``
+    reads every ``*.chat`` file and its list of the first four chat cells;
+    ``tests/test_stage_spans.py`` finds ``attn_walked_share.<cell>`` by name
+    (its ``cell`` cases other than ``gen``) and
+    ``shared_run_pages_per_step.kv`` with its two cells."""
+    root = os.path.dirname(BENCH)
+    with open(os.path.join(root, "tests", "test_stage_spans.py")) as f:
+        spans = f.read()
+    cells = re.search(r'parametrize\("cell", \[([^]]*)\]\)\s*def '
+                      r'test_the_walk_counters_are_what_attn_walked_share_reads',
+                      spans)
+    walked = {f"attn_walked_share.{c}" for c in re.findall(
+        r'"(\w+)"', cells.group(1)) if c != "gen"}
+    kv = set(re.findall(r'"(shared_run_pages_per_step\.\w+)"', spans))
+    chat = {os.path.basename(p)[:-5] for p in glob.glob(
+        os.path.join(BENCH, "metrics", "*.chat.json"))}
+    return chat | walked | kv
+
+
+def test_no_two_metric_files_share_a_reader():
+    """One file a reader, read in every cell the manifest names for it: of
+    the files that share a reader, all but one are copies a tier-1 test
+    pins (they go when those tests are edited)."""
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    files: dict = {}
+    for m in manifest["per_layer"]:
+        with open(os.path.join(BENCH, "metrics", m["name"] + ".json")) as f:
+            spec = json.load(f)
+        key = json.dumps({k: spec.get(k) for k in READER_KEYS},
+                         sort_keys=True)
+        files.setdefault(key, set()).add(m["name"])
+    pinned = tier1_pinned()
+    assert len(pinned) == 13, pinned
+    shared = [names for names in files.values() if len(names - pinned) > 1]
+    assert not shared, shared
+
+
+def test_a_cell_added_later_reads_the_shared_readers_with_no_edit():
+    """A chat cell that a later PR appends to the cells and to ``tpot_ms``'s
+    list reads every per-layer metric that names no cells; a file of the
+    benchmark states a cell list only where a tier-1 test reads it there."""
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    tpot = next(e for e in manifest["end_to_end"] if e["name"] == "tpot_ms")
+    chat = set(tpot["workloads"])
+    manifest["workloads"].append(dict(manifest["workloads"][-1],
+                                      name="new-chat-sys4k",
+                                      traffic="chat-new"))
+    tpot["workloads"].append("new-chat-sys4k")
+    shared = [m["name"] for m in manifest["per_layer"] if "workloads" not in m]
+    assert {"device_idle.gen", "host_turn_ms_per_step.gen",
+            "read_wait_ms_per_step.gen"} <= set(shared)
+    for m in manifest["per_layer"]:
+        cells = selfcheck.metric_cells(manifest, m)
+        assert ("new-chat-sys4k" in cells) == (m["name"] in shared)
+        if m["name"] in shared:
+            assert set(cells) - {"new-chat-sys4k"} == chat, m["name"]
+    faults: list = []
+    selfcheck.check_manifest(manifest, faults)
+    assert not faults, faults
+    pinned = tier1_pinned()
+    for m in manifest["per_layer"]:
+        with open(os.path.join(BENCH, "metrics", m["name"] + ".json")) as f:
+            assert ("workloads" in json.load(f)) == (
+                m["name"] in pinned - {"attn_walked_share.dsv2",
+                                       "attn_walked_share.lcf"}), m["name"]
+
+
+@pytest.mark.parametrize("key,refused", [
+    ("num_hidden_layers", False), ("num_layers", False), ("vocab_size", False),
+    ("hidden_size", True), ("moe_intermediate_size", True),
+    ("kv_lora_rank", True), ("head_dim", True), ("ssm_state_size", True),
+    ("num_experts_per_tok", True), ("expand", True)])
+def test_reduced_may_name_a_depth_and_no_width(key, refused):
+    """Width words match whole: the source's ``num_hidden_layers`` is a
+    depth a cut may name; ``hidden_size`` stays refused."""
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    manifest["configs"][0]["reduced"] = [key]
+    faults: list = []
+    selfcheck.check_manifest(manifest, faults)
+    assert any(f"may not name {key!r}" in f for f in faults) == refused, faults
